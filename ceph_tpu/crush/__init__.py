@@ -39,7 +39,8 @@ from .types import (
 )
 from .hashfn import crush_hash32, crush_hash32_2, crush_hash32_3, crush_hash32_4, crush_hash32_5
 from .mapper_ref import crush_do_rule, crush_ln
-from .builder import build_flat_map, build_two_level_map
+from .builder import (
+    build_flat_map, build_skewed_two_level_map, build_two_level_map)
 
 __all__ = [
     "CRUSH_BUCKET_UNIFORM", "CRUSH_BUCKET_LIST", "CRUSH_BUCKET_TREE",
@@ -50,5 +51,5 @@ __all__ = [
     "RULE_CHOOSELEAF_FIRSTN", "RULE_CHOOSELEAF_INDEP", "RULE_EMIT",
     "crush_hash32", "crush_hash32_2", "crush_hash32_3", "crush_hash32_4",
     "crush_hash32_5", "crush_do_rule", "crush_ln",
-    "build_flat_map", "build_two_level_map",
+    "build_flat_map", "build_skewed_two_level_map", "build_two_level_map",
 ]

@@ -259,3 +259,30 @@ def build_two_level_map(n_hosts: int, osds_per_host: int,
     m.add_bucket(make_bucket(-1, root_alg, 2, host_ids, host_weights))
     rid = add_simple_rule(m, -1, 1, "firstn")
     return m, -1, rid
+
+
+def build_skewed_two_level_map(n_hosts: int, osds_per_host: int,
+                               seed: int = 42):
+    """The deployment-shaped two-level map the bulk-placement runs share
+    (bench.py, chip_smoke.py, the TPU cross-validation tests): per-OSD
+    bucket weights skewed over [0.5, 2.0), 10 % of the OSDs reweighted to
+    0.5 and 2 % out, so the firstn retry ladder actually fires.  Returns
+    (map, chooseleaf_firstn_rule_id, reweight (n_osds,) int64)."""
+    import numpy as np
+
+    m, _root, rid = build_two_level_map(n_hosts, osds_per_host)
+    rng = np.random.default_rng(seed)
+    for b in m.buckets:
+        if b is not None and b.type == 1:
+            b.item_weights = [int(w) for w in
+                              rng.integers(0x8000, 0x20000, b.size)]
+            b.weight = sum(b.item_weights)
+    root = m.bucket(-1)
+    root.item_weights = [m.bucket(h).weight for h in root.items]
+    root.weight = sum(root.item_weights)
+    n_osds = n_hosts * osds_per_host
+    reweight = np.full(n_osds, 0x10000, dtype=np.int64)
+    idx = rng.permutation(n_osds)
+    reweight[idx[:n_osds // 10]] = 0x8000
+    reweight[idx[n_osds // 10:n_osds // 10 + n_osds // 50]] = 0
+    return m, rid, reweight

@@ -7,7 +7,9 @@ from the in-repo oracle ``crush.mapper_ref`` and cross-validated in
 tests/test_native.py).
 
 The shared library builds with the system C compiler at first call and is
-cached next to the source keyed by a source hash; no pip/cmake involved.
+cached next to the source, keyed by the source, the compiler flags and the
+host CPU's feature flags (it is built ``-march=native``); no pip/cmake
+involved.
 """
 
 from __future__ import annotations
@@ -31,17 +33,36 @@ class NativeUnavailable(RuntimeError):
     pass
 
 
+_CFLAGS = ("-O3", "-march=native", "-funroll-loops", "-shared", "-fPIC")
+
+
+def _cpu_features() -> bytes:
+    """What ``-march=native`` resolves to on this host: the CPU's model
+    and feature flags.  Part of the library's name, so a library built
+    on one machine is never loaded on another that copied the tree."""
+    try:
+        with open("/proc/cpuinfo", "rb") as f:
+            lines = f.read().splitlines()
+    except OSError:
+        import platform
+        return platform.processor().encode()
+    keep = (b"model name", b"flags", b"Features")
+    return b"\n".join(sorted({ln for ln in lines if ln.startswith(keep)}))
+
+
 def _build() -> str:
+    h = hashlib.sha256()
     with open(_SRC, "rb") as f:
-        tag = hashlib.sha256(f.read()).hexdigest()[:16]
-    out = os.path.join(_DIR, f"_baseline_{tag}.so")
+        h.update(f.read())
+    h.update(" ".join(_CFLAGS).encode())
+    h.update(_cpu_features())
+    out = os.path.join(_DIR, f"_baseline_{h.hexdigest()[:16]}.so")
     if os.path.exists(out):
         return out
     for cc in ("cc", "gcc", "clang"):
         try:
             subprocess.run(
-                [cc, "-O3", "-march=native", "-funroll-loops", "-shared",
-                 "-fPIC", "-o", out + ".tmp", _SRC],
+                [cc, *_CFLAGS, "-o", out + ".tmp", _SRC],
                 check=True, capture_output=True, timeout=120)
             os.replace(out + ".tmp", out)
             return out

@@ -340,17 +340,23 @@ def _ln_bound_kernel(u_ref, out_ref):
     out_ref[...] = _ln_f32_pl(u_ref[...].astype(_U32))
 
 
+def _ln_f32_table(u, interpret: bool = False):
+    """f32_ln over a (128, 512) int32 block through the filter kernel's
+    own Pallas lowering (one whole-array program)."""
+    return pl.pallas_call(
+        _ln_bound_kernel,
+        out_shape=jax.ShapeDtypeStruct((128, 512), jnp.float32),
+        interpret=interpret,
+    )(u)
+
+
 @functools.lru_cache(maxsize=None)
 def _ln_f32_bound(interpret: bool) -> float:
     """max |f32_ln(u) - crush_ln(u)| over every 16-bit u, with the f32
     evaluated by the same Pallas lowering the filter kernel uses."""
     from ceph_tpu.ops.crush_kernel import crush_ln
     u = jnp.arange(65536, dtype=jnp.int32).reshape(128, 512)
-    approx = pl.pallas_call(
-        _ln_bound_kernel,
-        out_shape=jax.ShapeDtypeStruct((128, 512), jnp.float32),
-        interpret=interpret,
-    )(u)
+    approx = _ln_f32_table(u, interpret)
     exact = crush_ln(u.ravel().astype(jnp.uint32)).astype(jnp.float32)
     return float(jnp.max(jnp.abs(approx.ravel() - exact)))
 

@@ -2040,14 +2040,18 @@ class OSDDaemon(Dispatcher):
     def _ec_shard_candidates(self, pg: PG, n: int) -> dict[int, list[int]]:
         """Per-shard holder candidates: current position first, then the
         holders from prior intervals (PastIntervals — after a remap the
-        chunk still lives on its old positional holder)."""
+        chunk still lives on its old positional holder).  A past holder
+        the map has since marked down is no candidate: a read sent to
+        it is never answered, and the gather would wait on it forever
+        instead of moving on to a parity shard."""
         cand: dict[int, list[int]] = {}
         intervals = [pg.up] + list(reversed(pg.info.past_up))
         for s in range(n):
             seen: list[int] = []
             for iv in intervals:
                 if s < len(iv) and iv[s] != CEPH_NOSD \
-                        and iv[s] not in seen:
+                        and iv[s] not in seen \
+                        and self.osdmap.is_up(iv[s]):
                     seen.append(iv[s])
             cand[s] = seen
         return cand
@@ -3861,13 +3865,32 @@ class OSDDaemon(Dispatcher):
                 f"{len(targets)} targets)")
         cctx = (reqid, state, si, stripes, targets, size)
         fut.add_done_callback(
-            lambda f, c=cctx: self._ec_decode_done(*c, f))
+            lambda f, c=cctx: self._off_engine_thread(
+                c[1]["pgid"], lambda: self._ec_decode_done(*c, f)))
         return True
+
+    def _off_engine_thread(self, pgid, fn) -> None:
+        """Run a decode-engine continuation on an op-queue worker of its
+        PG instead of the engine's completion thread.  The continuation
+        takes the daemon lock, and a thread that holds that lock may be
+        waiting on a future of the same engine — BlueStore settles and
+        verifies block checksums through the decode engine from inside
+        op handlers.  The completion thread delivers batches in order,
+        so parking it on the lock stalls both sides until the store's
+        digest timeout gives up and checksums on the host."""
+        if self.opwq is None:
+            fn()
+            return
+        from types import SimpleNamespace
+        # straight into the shard queue: no payload to throttle, and the
+        # completion thread must not block on the intake throttle either
+        self.opwq.enqueue(pgid, "subop",
+                          (lambda _carrier: fn(), SimpleNamespace(), 0))
 
     def _ec_decode_done(self, reqid, state: dict, si, stripes, targets,
                         size: int, fut) -> None:
-        """Decode-engine completion continuation (runs on the decode
-        engine's completion thread): overlay the rebuilt rows and
+        """Decode-engine completion continuation (handed to an op-queue
+        worker by _off_engine_thread): overlay the rebuilt rows and
         finish the gather — client reply, rmw overlay-and-drain, or
         recovery store/push."""
         err = fut.exception()

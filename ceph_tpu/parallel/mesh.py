@@ -9,7 +9,6 @@ slice the mesh should be laid out so ``ec`` rides the minor (fastest ICI) axis â
 from __future__ import annotations
 
 import jax
-import numpy as np
 from jax.sharding import Mesh
 
 
@@ -51,11 +50,8 @@ def make_mesh(n_devices: int | None = None, *, ec: int | None = None,
         if n % ec:
             raise ValueError(f"ec={ec} does not divide n={n}")
         dp = n // ec
-    try:
-        from jax.experimental import mesh_utils
-        dev_array = mesh_utils.create_device_mesh((dp, ec), devices=devices[:n])
-    except Exception:
-        dev_array = np.array(devices[:n]).reshape(dp, ec)
+    from jax.experimental import mesh_utils
+    dev_array = mesh_utils.create_device_mesh((dp, ec), devices=devices[:n])
     return Mesh(dev_array, axis_names=("dp", "ec"))
 
 

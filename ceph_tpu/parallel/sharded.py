@@ -22,22 +22,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-import inspect
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma in jax 0.8
-_CHECK_KW = ("check_vma" if "check_vma" in
-             inspect.signature(_shard_map).parameters else "check_rep")
-
-
-def shard_map(f, **kwargs):
-    if "check_rep" in kwargs:
-        kwargs[_CHECK_KW] = kwargs.pop("check_rep")
-    return _shard_map(f, **kwargs)
 
 from ceph_tpu.gf.matrix import recovery_matrix
 from ceph_tpu.gf.tables import bit_matrix
@@ -120,11 +104,11 @@ def make_cluster_step(mesh, gen: np.ndarray, ids, weights, reweight,
         bad = jax.lax.psum(local_bad, ("dp", "ec"))
         return rebuilt, bad
 
-    recover_sharded = shard_map(
+    recover_sharded = jax.shard_map(
         recover, mesh=mesh,
         in_specs=(P("dp", "ec", None),),
         out_specs=(P("dp", None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
     def step(xs, data):

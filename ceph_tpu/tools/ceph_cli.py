@@ -119,6 +119,8 @@ def parse_command(words: list[str]) -> dict:
 
 
 def main(argv=None) -> int:
+    from ceph_tpu.common.compile_cache import place_compile_cache
+    place_compile_cache()
     ap = argparse.ArgumentParser(prog="ceph")
     ap.add_argument("-m", "--mon-host", required=True,
                     help="comma-separated monitor addresses")

@@ -116,6 +116,8 @@ def run_test(m, rules, min_x: int, max_x: int, num_rep: int,
 
 
 def main(argv=None) -> int:
+    from ceph_tpu.common.compile_cache import place_compile_cache
+    place_compile_cache()
     p = argparse.ArgumentParser(prog="crush_test")
     p.add_argument("--num-rep", type=int, default=3)
     p.add_argument("--min-x", type=int, default=0)

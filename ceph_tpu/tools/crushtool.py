@@ -141,6 +141,8 @@ def build_layered(num_osds: int, layers: list[tuple[str, str, int]]):
 
 
 def main(argv=None) -> int:
+    from ceph_tpu.common.compile_cache import place_compile_cache
+    place_compile_cache()
     p = argparse.ArgumentParser(prog="crushtool")
     p.add_argument("-c", "--compile", metavar="TXT")
     p.add_argument("-d", "--decompile", metavar="BIN")

@@ -22,6 +22,8 @@ import threading
 
 
 def main(argv=None) -> int:
+    from ceph_tpu.common.compile_cache import place_compile_cache
+    place_compile_cache()
     p = argparse.ArgumentParser(prog="ceph-tpu-daemon")
     p.add_argument("--role", required=True,
                    choices=["mon", "osd", "mgr", "mds", "rgw"])
@@ -38,8 +40,10 @@ def main(argv=None) -> int:
                         "device transfer data plane)")
     p.add_argument("--jax-cpu-devices", type=int, default=0,
                    help="force the cpu platform with N local devices "
-                        "BEFORE jax initializes (the virtual-mesh test "
-                        "tier; production uses the real backend)")
+                        "BEFORE jax initializes: a chip belongs to one "
+                        "process, so every daemon of a multi-process "
+                        "cluster but the one that owns it is started "
+                        "with this")
     p.add_argument("--store-type", default="filestore")
     p.add_argument("--store-path", default="")
     p.add_argument("--auth-key", default="")

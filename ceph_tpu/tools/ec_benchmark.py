@@ -75,6 +75,8 @@ def bench_decode(codec, object_size: int, iterations: int, batch: int,
 
 
 def main(argv=None) -> int:
+    from ceph_tpu.common.compile_cache import place_compile_cache
+    place_compile_cache()
     p = argparse.ArgumentParser(prog="ec_benchmark")
     p.add_argument("--plugin", "-p", default="jerasure")
     p.add_argument("--parameter", "-P", action="append", default=[],

@@ -69,6 +69,8 @@ def run(store, n_objects: int, obj_size: int, n_threads: int) -> dict:
 
 
 def main(argv=None) -> int:
+    from ceph_tpu.common.compile_cache import place_compile_cache
+    place_compile_cache()
     ap = argparse.ArgumentParser(prog="objectstore-bench")
     ap.add_argument("--type", default="bluestore",
                     choices=["memstore", "filestore", "bluestore"])

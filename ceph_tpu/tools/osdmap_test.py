@@ -54,6 +54,8 @@ def test_map_pgs(m: OSDMap, out=sys.stdout, dump: bool = False) -> dict:
 
 
 def main(argv=None) -> int:
+    from ceph_tpu.common.compile_cache import place_compile_cache
+    place_compile_cache()
     p = argparse.ArgumentParser(prog="osdmap_test")
     p.add_argument("--hosts", type=int, default=32)
     p.add_argument("--per-host", type=int, default=4)

@@ -43,6 +43,8 @@ def simulate(hosts: int = 16, per_host: int = 4, objects: int = 4096,
 
 
 def main(argv=None) -> int:
+    from ceph_tpu.common.compile_cache import place_compile_cache
+    place_compile_cache()
     ap = argparse.ArgumentParser(prog="psim")
     ap.add_argument("--hosts", type=int, default=16)
     ap.add_argument("--per-host", type=int, default=4)

@@ -34,9 +34,13 @@ class ObjBencher:
     def _obj(self, i: int) -> str:
         return f"{self.run_name}_{i}"
 
-    def _drive(self, seconds: float, submit) -> dict:
+    def _drive(self, seconds: float, submit, max_ops: int | None = None,
+               check=None) -> dict:
         """Window-bounded aio loop shared by all workloads.  `submit(i)`
-        returns an AioCompletion for work item i."""
+        returns an AioCompletion for work item i.  The run ends at the
+        deadline, or once ``max_ops`` items were submitted and reaped;
+        ``check(i, completion)`` judges a successful completion, and a
+        False from it counts the op as an error."""
         start = time.perf_counter()
         deadline = start + seconds
         in_flight: list[tuple[int, float, object]] = []
@@ -45,7 +49,8 @@ class ObjBencher:
         lat_max = 0.0
         while True:
             now = time.perf_counter()
-            stop = now >= deadline
+            stop = now >= deadline or (max_ops is not None
+                                       and started >= max_ops)
             # reap whatever is done (front-first keeps completion order
             # roughly FIFO, like obj_bencher's slot scan)
             still = []
@@ -55,7 +60,8 @@ class ObjBencher:
                     lat_sum += lat
                     lat_max = max(lat_max, lat)
                     finished += 1
-                    if c.get_return_value() < 0:
+                    if c.get_return_value() < 0 or (
+                            check is not None and not check(i, c)):
                         errors += 1
                 elif now - t0 > self.op_timeout:
                     # a lost completion must not hang the bench forever
@@ -71,6 +77,7 @@ class ObjBencher:
                 c = submit(started)
                 in_flight.append((started, time.perf_counter(), c))
                 started += 1
+                stop = max_ops is not None and started >= max_ops
             time.sleep(0.0005)
         elapsed = time.perf_counter() - start
         done = finished - errors
@@ -86,19 +93,34 @@ class ObjBencher:
             "concurrent": self.concurrent,
         }
 
-    def write_bench(self, seconds: float) -> dict:
-        payload = bytes(range(256)) * (self.obj_size // 256 + 1)
-        payload = payload[:self.obj_size]
+    def write_bench(self, seconds: float, payload_of=None,
+                    max_objects: int | None = None) -> dict:
+        """``payload_of(i)`` supplies object i's bytes (default: one
+        fixed pattern); ``max_objects`` ends the run after that many
+        writes instead of at the deadline."""
+        if payload_of is None:
+            payload = bytes(range(256)) * (self.obj_size // 256 + 1)
+            payload = payload[:self.obj_size]
+            payload_of = lambda i: payload      # noqa: E731
         res = self._drive(
             seconds,
-            lambda i: self.io.aio_write_full(self._obj(i), payload))
+            lambda i: self.io.aio_write_full(self._obj(i), payload_of(i)),
+            max_ops=max_objects)
         res["mode"] = "write"
         return res
 
-    def seq_read_bench(self, seconds: float, n_objects: int) -> dict:
+    def seq_read_bench(self, seconds: float, n_objects: int,
+                       payload_of=None,
+                       max_objects: int | None = None) -> dict:
+        """With ``payload_of`` every read is compared with the bytes
+        object i was written with, and a mismatch counts as an error."""
+        n = max(1, n_objects)
+        check = None
+        if payload_of is not None:
+            check = lambda i, c: c.data == payload_of(i % n)  # noqa: E731
         res = self._drive(
-            seconds,
-            lambda i: self.io.aio_read(self._obj(i % max(1, n_objects))))
+            seconds, lambda i: self.io.aio_read(self._obj(i % n)),
+            max_ops=max_objects, check=check)
         res["mode"] = "seq"
         return res
 
@@ -113,6 +135,8 @@ class ObjBencher:
 
 
 def main(argv=None) -> int:
+    from ceph_tpu.common.compile_cache import place_compile_cache
+    place_compile_cache()
     ap = argparse.ArgumentParser(prog="rados bench")
     ap.add_argument("--mon", required=True, help="mon host:port")
     ap.add_argument("-p", "--pool", type=int, required=True)

@@ -18,6 +18,8 @@ import sys
 
 
 def main(argv=None) -> int:
+    from ceph_tpu.common.compile_cache import place_compile_cache
+    place_compile_cache()
     p = argparse.ArgumentParser(prog="rados")
     p.add_argument("--mon", required=True, help="mon host(s)")
     p.add_argument("-p", "--pool", type=int, required=True)

@@ -30,6 +30,8 @@ def _split_at(spec: str) -> tuple[str, str]:
 
 
 def main(argv=None) -> int:
+    from ceph_tpu.common.compile_cache import place_compile_cache
+    place_compile_cache()
     p = argparse.ArgumentParser(prog="rbd")
     p.add_argument("--mon", required=True, help="mon host(s)")
     p.add_argument("-p", "--pool", type=int, required=True)

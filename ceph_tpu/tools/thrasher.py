@@ -690,6 +690,8 @@ def run_soak(duration: float = 25.0, seed: int = 7,
 if __name__ == "__main__":
     import json
     import sys
+    from ceph_tpu.common.compile_cache import place_compile_cache
+    place_compile_cache()
     flags = ("--chaos", "--scrub-storm")
     args = [a for a in sys.argv[1:] if a not in flags]
     res = run_soak(duration=float(args[0]) if args else 25.0,

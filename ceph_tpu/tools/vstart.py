@@ -401,23 +401,32 @@ class ProcCluster:
     vstart.sh spawns real daemons; qa/standalone/ceph-helpers.sh
     run_mon:437 / run_osd:596).  kill_osd(9) is real SIGKILL process
     death; the filestore survives for the restart.
+
+    One process per chip: an accelerator belongs to the first process
+    that touches JAX, and a second one that needs it fails or hangs.
+    Every child is therefore started pinned to the CPU platform
+    (``--jax-cpu-devices``), except the single daemon the caller names
+    in ``chip_owner`` ("osd.0"), which keeps the default backend.  The
+    parent must then stay off JAX itself.
     """
 
     def __init__(self, n_osds: int = 3, n_mons: int = 1,
                  base_path: str = "", auth_key: str = "",
-                 ms_type: str = "async", jax_cpu_devices: int = 0):
+                 ms_type: str = "async", jax_cpu_devices: int = 0,
+                 chip_owner: str | None = None):
         import tempfile
         self.n_osds = n_osds
         self.n_mons = n_mons
         self.base_path = base_path or tempfile.mkdtemp(prefix="proccluster-")
         self.auth_key = auth_key
         #: OSD messenger stack: "ici" = cross-process ici-wire (TCP
-        #: control plane + device transfer data plane); OSD processes
-        #: then pin a cpu backend with jax_cpu_devices local devices
-        #: (the virtual-mesh tier; real deployments use the real chips)
+        #: control plane + device transfer data plane), which needs at
+        #: least two local devices per process
         self.ms_type = ms_type
+        #: virtual CPU devices each pinned child sees
         self.jax_cpu_devices = jax_cpu_devices or (
-            2 if ms_type == "ici" else 0)
+            2 if ms_type == "ici" else 1)
+        self.chip_owner = chip_owner
         self.procs: dict[str, object] = {}   # "mon.0" / "osd.2" -> Popen
         self.mon_addrs: list[str] = []
         self.clients: list[RadosClient] = []
@@ -434,6 +443,8 @@ class ProcCluster:
                "--store-path", f"{self.base_path}/{role}.{rid}"]
         if self.auth_key:
             cmd += ["--auth-key", self.auth_key]
+        if f"{role}.{rid}" != self.chip_owner:
+            cmd += ["--jax-cpu-devices", str(self.jax_cpu_devices)]
         cmd += extra
         proc = subprocess.Popen(cmd, stdout=subprocess.PIPE,
                                 stderr=subprocess.DEVNULL, text=True)
@@ -483,8 +494,6 @@ class ProcCluster:
         extra = ["--mon-host", self.mon_host, "--heartbeats"]
         if self.ms_type != "async":
             extra += ["--ms-type", self.ms_type]
-        if self.jax_cpu_devices:
-            extra += ["--jax-cpu-devices", str(self.jax_cpu_devices)]
         return self._spawn("osd", osd_id, extra)
 
     def kill_osd(self, osd_id: int) -> None:

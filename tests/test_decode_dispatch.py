@@ -324,3 +324,43 @@ def test_degraded_read_rides_decode_engine():
                    for o in c.osds.values()) > 0
     finally:
         c.stop()
+
+
+def test_degraded_reads_after_osd_down_on_bluestore(tmp_path):
+    """An OSD dies and the map marks it down; every object is then read
+    back at depth.  Two things used to stall this: the gather asked the
+    down OSD — still the shard's past-interval holder — for its chunk
+    and waited forever; and the decode continuation, parked on the
+    daemon lock on the engine's completion thread, kept the block-
+    checksum digest of a shard read (made under that lock, through the
+    same engine) from ever being delivered, until BlueStore's digest
+    timeout fell back to host crc32."""
+    from ceph_tpu.tools.rados_bench import ObjBencher
+    from ceph_tpu.tools.vstart import MiniCluster
+    c = MiniCluster(n_osds=4, ms_type="loopback", store_type="bluestore",
+                    base_path=str(tmp_path)).start()
+    try:
+        client = c.client(timeout=30.0)
+        pool = c.create_pool(client, pool_type="erasure", k=2, m=2,
+                             epoch_timeout=60.0)
+        # 16 blocks a shard: past bluestore_batched_read_min, so shard
+        # reads verify through the engine
+        size, n = 64 << 10, 8
+        payload_of = lambda i: bytes([i + 1]) * size      # noqa: E731
+        bench = ObjBencher(client.open_ioctx(pool), obj_size=size,
+                           concurrent=4, op_timeout=20.0)
+        assert bench.write_bench(60.0, payload_of, n)["errors"] == 0
+        fallbacks = telemetry.bluestore_stats().dump()["csum_fallbacks"]
+        c.kill_osd(2)
+        rc, out = client.mon_command({"prefix": "osd down", "id": "2"})
+        assert rc == 0, out
+        client.wait_for_epoch(c.mon.osdmap.epoch)
+        res = bench.seq_read_bench(60.0, n, payload_of, n)
+        assert res["total_writes_or_reads"] == n and res["errors"] == 0
+        assert res["latency_max_s"] < 15.0
+        assert (telemetry.bluestore_stats().dump()["csum_fallbacks"]
+                == fallbacks)
+        assert sum(o.perf.value("ec_decode_submits")
+                   for o in c.osds.values()) > 0
+    finally:
+        c.stop()

@@ -1,0 +1,212 @@
+"""The smoke path's kernels, compiled for a described v5e chip.
+
+No TPU is attached where the tests run, but the TPU compiler is
+installed: it compiles for a chip that is described and not attached,
+and refuses what the chip's compiler would refuse (a VMEM limit, an
+unaligned slice, a custom call it cannot partition).  Interpret-mode
+tests cannot see any of that.  One case per kernel on ``chip_smoke.py``'s
+path, at the smoke's shapes; nothing runs, so these say nothing about
+results or times.
+
+This is the only file that describes a topology, and it does so inside
+a module-scoped fixture: only the xdist worker that is handed this file
+loads the TPU library.
+"""
+
+import functools
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (
+    Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding)
+
+from ceph_tpu.crush import build_flat_map, build_skewed_two_level_map
+from ceph_tpu.crush.fastpath import detect
+from ceph_tpu.ops import pallas_straw2 as ps
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    from jax.experimental import mesh_utils
+    return Mesh(mesh_utils.create_device_mesh((4, 1), devices=topo.devices),
+                axis_names=("dp", "ec"))
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without one; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, *specs) -> str:
+    return jax.jit(fn).lower(*specs).compile().as_text()
+
+
+def _spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# -- GF(2^8) encode / decode --------------------------------------------------
+
+@pytest.mark.parametrize("s,b,bc", [(2048, 4096, 4096), (128, 65536, 4096)])
+def test_encode_pallas(one_chip, s, b, bc):
+    from ceph_tpu.ops.gf_kernel import _G, _encode_pallas, _pick_bc
+    k, m = 8, 4
+    assert _pick_bc(b) == bc
+    text = _encode_pallas.lower(
+        _spec(one_chip, (_G * k * 8, _G * m * 8), jnp.int8),
+        _spec(one_chip, (s, k, b), jnp.uint8),
+        k=k, m=m, bc=bc).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("s", [128, 2048])
+def test_decode_pattern_table(one_chip, s):
+    """The heterogeneous-pattern decode a degraded read rides: per-stripe
+    gather from the stacked (P, k*8, t*8) table."""
+    from ceph_tpu.ops.gf_kernel import _decode_xla
+    k, t = 8, 4
+    _decode_xla.lower(
+        _spec(one_chip, (2, k * 8, t * 8), jnp.int8),
+        _spec(one_chip, (s,), jnp.int32),
+        _spec(one_chip, (s, k, 4096), jnp.uint8),
+        k=k, t=t).compile()
+
+
+def test_encode_shard_map_four_chips(mesh4):
+    """The engine's mesh route: one fused Pallas program per device
+    under shard_map, batch split on the stripe axis."""
+    from ceph_tpu.ops.gf_kernel import _G, _pallas_sharded_fn
+    k, m, s, b = 8, 4, 2048, 4096
+    rows = NamedSharding(mesh4, PartitionSpec(("dp", "ec"), None, None))
+    rep = NamedSharding(mesh4, PartitionSpec())
+    compiled = _pallas_sharded_fn(rows, k, m, b).lower(
+        _spec(rows, (s, k, b), jnp.uint8),
+        _spec(rep, (_G * k * 8, _G * m * 8), jnp.int8)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    (out_sh,) = jax.tree_util.tree_leaves(compiled.output_shardings)
+    assert len(out_sh.device_set) == 4
+
+
+# -- CRUSH column kernels -----------------------------------------------------
+
+N_PGS = 65536
+
+
+@pytest.fixture(scope="module")
+def columns():
+    """PallasColumns over the 250-host x 40-OSD deployment map."""
+    crush_map, rid, _rw = build_skewed_two_level_map(250, 40)
+    return ps.PallasColumns(detect(crush_map, rid))
+
+
+#: the two-stage schedule's shapes at numrep 3: every lane gets
+#: R = numrep + 1 columns, then FastMapper.STAGE2_CAP overflowing lanes
+#: get R = numrep + DEFAULT_BLOCK
+STAGES = [(N_PGS, 4), (4096, 9)]
+
+
+@pytest.mark.parametrize("n,R", STAGES)
+def test_root_columns(one_chip, columns, n, R):
+    text = _compile(lambda xs: columns.root_columns(xs, None, R),
+                    _spec(one_chip, (n,), jnp.uint32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("n,R", STAGES)
+def test_leaf_columns(one_chip, columns, n, R):
+    text = _compile(lambda xs, pos: columns.leaf_columns(xs, pos, R),
+                    _spec(one_chip, (n,), jnp.uint32),
+                    _spec(one_chip, (R, n), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+# R = tries + numrep is the cannot-overflow recompute
+@pytest.mark.parametrize("n,R", STAGES + [(N_PGS, 54)])
+def test_consume_columns(one_chip, n, R):
+    col = _spec(one_chip, (R, n), jnp.int32)
+    text = _compile(
+        functools.partial(ps.consume_columns, numrep=3, tries=51),
+        col, col, _spec(one_chip, (R, n), jnp.bool_))
+    assert "tpu_custom_call" in text
+
+
+def test_ln_f32_table(one_chip):
+    """The eager program _ln_f32_bound measures the filter's error
+    bound with."""
+    text = _compile(ps._ln_f32_table,
+                    _spec(one_chip, (128, 512), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_froot_columns(one_chip, monkeypatch):
+    """The approx-filter root kernel runs on flat buckets of 512-1024
+    items (not the smoke's map); its error bound is a measurement on the
+    chip, so the test supplies a value of the measured order."""
+    fmap, _root, frid = build_flat_map(600)
+    pc = ps.PallasColumns(detect(fmap, frid))
+    assert 512 <= pc.S_root <= 1024
+    monkeypatch.setattr(ps, "_ln_f32_bound", lambda interpret: 2.0 ** 22)
+    text = _compile(lambda xs: pc.froot_columns(xs, None, 4),
+                    _spec(one_chip, (8192,), jnp.uint32))
+    assert "tpu_custom_call" in text
+
+
+# -- checksum, compression, placement ladder ----------------------------------
+
+def test_block_digest(one_chip):
+    """One shard write's BlueStore blocks: 128 x 4 KiB (also the scrub
+    digest; the two channels share the program)."""
+    from ceph_tpu.ops.checksum_kernel import _jit_digest
+    s, w = 128, 4096
+    _jit_digest().lower(
+        _spec(one_chip, (s, w), jnp.uint8),
+        _spec(one_chip, (s, 32), jnp.uint32),
+        _spec(one_chip, (s, 4), jnp.uint8), w=w).compile()
+
+
+def test_bitplane_transpose(one_chip):
+    from ceph_tpu.ops.compression_kernel import _jit_planes
+    _jit_planes().lower(_spec(one_chip, (128, 4096), jnp.uint8)).compile()
+
+
+@pytest.mark.parametrize("erasure", [False, True])
+def test_placement_ladder(one_chip, erasure):
+    from ceph_tpu.ops.placement_kernel import _ladder_jit
+    n_pgs, w, pairs, n_osds = 1024, (12 if erasure else 3), 1, 10000
+    i32 = functools.partial(_spec, one_chip, dtype=jnp.int32)
+    _ladder_jit(erasure).lower(
+        i32((n_pgs, w)),                                   # raw
+        _spec(one_chip, (n_pgs,), jnp.uint32),             # pps
+        i32((n_pgs,)), i32((n_pgs, w)), i32((n_pgs,)),     # raw_len, upmap
+        i32((n_pgs, pairs, 2)),                            # upmap items
+        i32((n_pgs, w)), i32((n_pgs,)), i32((n_pgs,)),     # temps
+        i32((n_osds,)),                                    # state
+        _spec(one_chip, (n_osds,), jnp.int64),             # weight
+        i32((n_osds,))).compile()                          # affinity
