@@ -1,0 +1,1 @@
+from perfbench.harness.readers import noncompute_share as read  # noqa: F401
