@@ -1350,23 +1350,12 @@ def main(argv=None) -> None:
         # device residency samples — before summarizing.  A retrace
         # count above the handful of shapes this harness uses is the
         # regression tell.
-        from ceph_tpu.common import tracing
         telemetry.set_fence_for_timing(True)
-        # trace the fenced calls with a zero slow threshold: every one
-        # lands in the slow ring, so the JSON records a tail-latency
-        # digest (count + p99 root-span duration) next to the
-        # throughput headline
-        tracing.set_slow_threshold(0.0)
         for _ in range(3):
-            with tracing.trace_ctx(name="bench ec_encode",
-                                   daemon="bench"):
-                encode(data)
-            with tracing.trace_ctx(name="bench crush_map",
-                                   daemon="bench"):
-                bm.do_rule(rid, xs, numrep, rw)
+            encode(data)
+            bm.do_rule(rid, xs, numrep, rw)
         telemetry.set_fence_for_timing(False)
         out["kernel_telemetry"] = telemetry.registry().summary()
-        out["slow_traces"] = tracing.slow_summary()
 
     if "dispatch_sweep" in secs:
         # cross-op coalescing: offered-concurrency sweep through the

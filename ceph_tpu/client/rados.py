@@ -16,6 +16,7 @@ import threading
 import time
 from contextlib import contextmanager
 
+from ceph_tpu.common import tracing
 from ceph_tpu.common.context import CephTpuContext
 from ceph_tpu.messages import MMonCommand, MMonCommandAck, MOSDMapMsg, MOSDOp
 from ceph_tpu.messages.osd_msgs import (
@@ -115,6 +116,10 @@ class _Waiter:
         #: map epochs coalesce into it (it targets from the newest map
         #: when it fires) instead of queueing duplicate sends
         self.resend_queued = False
+        #: the op's trace root, when this op opened one (common/tracing:
+        #: begun on the submitting thread, finished by whichever thread
+        #: completes the op)
+        self.root = None
 
 
 class AioCompletion:
@@ -149,6 +154,9 @@ class AioCompletion:
         # wake any blocked waiter: a cancelled op never gets its reply
         # (get_return_value reads -ETIMEDOUT from the missing reply)
         self._w.event.set()
+        root, self._w.root = self._w.root, None
+        tracing.set_attrs(root, cancelled=True)
+        tracing.finish_root(root)
 
 
 class RadosClient(Dispatcher):
@@ -359,7 +367,12 @@ class RadosClient(Dispatcher):
                 self._tracker_for(w.msg.qos_tenant).track_resp(
                     getattr(msg, "qos_phase", 0))
                 w.reply = msg
-                w.event.set()
+                # the completion wake; then the op's root (if it opened
+                # one) closes here, on the reply thread
+                with tracing.span("client complete", daemon=str(self.name)):
+                    w.event.set()
+                root, w.root = w.root, None
+                tracing.finish_root(root)
             return True
         if isinstance(msg, MWatchNotify):
             cb = self._watch_cbs.get((msg.pool, msg.oid))
@@ -679,31 +692,40 @@ class RadosClient(Dispatcher):
             w = _Waiter(msg, pool_id, is_write, direct,
                         fixed_pgid=pgid)
             self._waiters[tid] = w
-        self._send_op(w)
+        # the trace root is HERE, where `rados bench`-style traffic
+        # enters (operate() comes through too): an untraced thread
+        # opens one when tracing is armed (tracing_sample_rate, or a
+        # live profiler session); its span covers submit through the
+        # completion wake.  An already-traced caller's op joins its
+        # trace.  Unarmed and untraced, this is one check.
+        joins = tracing.current()
+        if not joins and tracing.armed():
+            w.root = tracing.begin_root(f"osd_op {oid}", str(self.name))
+        if w.root is not None:
+            with tracing.joined(w.root.trace_id, w.root.span_id), \
+                    tracing.span("client submit", daemon=str(self.name)):
+                self._send_op(w)
+        elif joins:
+            with tracing.span("client submit", daemon=str(self.name)):
+                self._send_op(w)
+        else:
+            self._send_op(w)
         return AioCompletion(self, tid, w)
 
     def operate(self, pool_id: int, oid: str, ops: list[OSDOpField],
                 snapid: int = 0, direct: bool = False,
                 pgid: tuple[int, int] | None = None,
                 tenant: str | None = None) -> MOSDOpReply:
-        # head sampling (tracing_sample_rate): an untraced op opens a
-        # trace at the configured rate, whose root span covers submit
-        # through reply — the tail-retention check then decides whether
-        # the completed trace is worth keeping.  Explicit trace_ctx
-        # callers pass through (already traced).
-        from ceph_tpu.common import tracing
-        with tracing.maybe_sampled(f"osd_op {oid}",
-                                   daemon=f"client.{self.client_id}"):
-            c = self.aio_operate(pool_id, oid, ops, snapid=snapid,
-                                 direct=direct, pgid=pgid,
-                                 tenant=tenant)
-            if not c.wait_for_complete(self.timeout):
-                c.cancel()
-                raise TimeoutError(f"op {c.tid} on {oid} timed out")
-            if c.get_return_value() < 0:
-                raise OSError(-c.get_return_value(),
-                              f"op on {oid} failed")
-            return c.reply
+        # head sampling and the trace root live in aio_operate
+        c = self.aio_operate(pool_id, oid, ops, snapid=snapid,
+                             direct=direct, pgid=pgid, tenant=tenant)
+        if not c.wait_for_complete(self.timeout):
+            c.cancel()
+            raise TimeoutError(f"op {c.tid} on {oid} timed out")
+        if c.get_return_value() < 0:
+            raise OSError(-c.get_return_value(),
+                          f"op on {oid} failed")
+        return c.reply
 
     # -- pools ----------------------------------------------------------------
 

@@ -118,7 +118,9 @@ class CephTpuContext:
             "shared PG-mapping-service telemetry: epoch-update "
             "latency, pools recomputed vs reused, changed-PG counts, "
             "epoch-skips, cache lookups vs scalar fallbacks, and the "
-            "per-epoch device/delta/host-tail phase split")
+            "per-epoch device/delta/host-tail phase split (`device` is "
+            "the wall time of OSDMapMapping.update(), host and device "
+            "both; the update_to span tree splits it: dump_tracing)")
         self.admin.register_command(
             "dump_pipeline_profile",
             lambda **kw: telemetry.pipeline_profile_dump(),

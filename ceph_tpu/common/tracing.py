@@ -3,22 +3,45 @@ tail retention of slow traces (src/tracing/oprequest.tp +
 src/common/zipkin_trace.h analogs, Dapper-style span model).
 
 A trace is a tree of spans.  Each span has a span_id, a
-parent_span_id, begin/end times, and key/value attributes (pool, pg,
-op size, kernel batch shape); point events (OpTracker stages,
-messenger tx, device h2d/d2h) attach to the span that was current when
-they fired.  The ids ride the message frame (a flagged header
-extension carrying ``(trace_id, parent_span_id)``, see msg.message):
-the client's root span parents its op's tx span, every receiver opens
-an ``rx <MsgType>`` dispatch span parented to the sender's span, and
-the whole client → primary → shard → commit tree reconstructs from the
-rows.  ``dump(trace_id)`` returns the flat time-ordered rows (the
-admin-socket payload); ``span_tree(trace_id)`` nests them.
+parent_span_id, a begin and an end, a layer, and key/value attributes
+(pool, pg, op size, kernel batch shape); point events (OpTracker
+stages) attach to the span that was current when they fired.  The ids
+ride the message frame (a flagged header extension carrying
+``(trace_id, parent_span_id)``, see msg.message): the client's root
+span parents its op's hop span, every receiver opens an
+``rx <MsgType>`` dispatch span parented to the hop, and the whole
+client → primary → shard → commit tree reconstructs from the rows.
+``dump(trace_id)`` returns the flat time-ordered rows (the admin-socket
+payload); ``span_tree(trace_id)`` nests them.
+
+ONE CLOCK.  A span's ``start``/``end`` are ``time.perf_counter_ns()``:
+on Linux the clock ``time.monotonic()`` reads too, so the dispatch
+engine's phase marks, the mapping service's timers and a benchmark
+harness's own stamps all lie on one timeline with the spans, and no
+wall-clock step can skew a duration.  Wall-clock time is kept once per
+trace (at its first row) and rows carry ``t`` = that anchor plus the
+span's offset, for display and for correlating with logs.
+
+TWO SINKS.  Every span goes to the in-memory table below.  A span
+opened and closed on one thread (the ``span(...)`` context manager) is
+also entered as a ``jax.profiler.TraceAnnotation`` of the same name
+while a profiler session is live, so that in any profile the program's
+spans lie in the host plane of the same xplane as the device's
+operations, on the profiler's clock.  Cross-thread spans (an op's
+root, a message hop, a queue wait, an engine request) live in the
+table only.
 
 Sampling policy — head sampling plus tail retention:
 
   * ``tracing_sample_rate`` (config): probability that an UNTRACED
-    client op opens a trace (``maybe_sampled``).  Explicit
+    root site (``RadosClient.aio_operate``, the mapping service's
+    ``update_to``) opens a trace (``begin_root``).  Explicit
     ``trace_ctx`` calls are always traced (a forced trace).
+  * a live profiler session (``jax.profiler.start_trace``) arms every
+    root site: each op of the session is traced, and its traces are
+    held until the next session starts (up to ``SESSION_CAP``; beyond
+    it new roots go unsampled, so a session's first traces — the ones
+    a reader clips to — are never the ones lost).
   * ``tracing_slow_threshold`` (config): a completed trace whose ROOT
     span ran at least this long is promoted into a bounded slow-trace
     ring (``tracing_slow_ring`` entries) instead of being evicted with
@@ -28,34 +51,46 @@ Sampling policy — head sampling plus tail retention:
 Propagation is THREAD-SCOPED: the dispatch loop installs the current
 (trace_id, span_id) for the duration of handling a traced message, so
 synchronous fan-out (the op pipeline) is covered; work handed to
-timers/workers starts untraced unless it re-enters with set_current
-from the ids stored on the message.
+queues, timers and engine threads re-enters with ``set_current`` /
+``joined`` from the ids carried on the message or the request.
+
+Cost: an untraced thread pays one thread-local read per span site; a
+root site pays one ``armed()`` check (a module float compare plus
+``TraceAnnotation.is_enabled()``, ~50 ns).  When on, a span is one
+object, one counter increment and two GIL-atomic dict operations; the
+registry lock is taken once per trace (creation, eviction), not per
+span.
 """
 
 from __future__ import annotations
 
+import itertools
 import os
 import random
 import threading
 import time
-from collections import OrderedDict
-from contextlib import contextmanager
 
 from ceph_tpu.common import lockdep
 
 _tls = threading.local()
 # import-time module lock: named under CEPH_TPU_LOCKDEP=1 (the env
-# gate is read before any module imports), plain otherwise
+# gate is read before any module imports), plain otherwise.  Guards
+# the trace TABLES (creation, eviction, promotion); rows of one trace
+# are appended with GIL-atomic dict/list operations
 _lock = lockdep.make_lock("tracing::registry")
 
+now_ns = time.perf_counter_ns
+
 #: active/recent traces kept for stitching (FIFO eviction; slow traces
-#: survive in the dedicated ring below)
+#: survive in the dedicated ring below, a profiler session's are pinned)
 _ACTIVE_CAP_DEFAULT = 512
 _active_cap = _ACTIVE_CAP_DEFAULT
+#: traces one profiler session may pin (then its new roots go unsampled)
+SESSION_CAP = 4096
 #: span+event rows per trace (runaway-fan-out guard)
 MAX_ROWS_PER_TRACE = 4096
 
-#: head-sampling probability for maybe_sampled (0 = only explicit traces)
+#: head-sampling probability for root sites (0 = only explicit traces)
 _DEFAULT_SAMPLE_RATE = 0.0
 _sample_rate = _DEFAULT_SAMPLE_RATE
 #: root-span duration (seconds) at/above which a completed trace is
@@ -66,29 +101,104 @@ _DEFAULT_SLOW_RING = 64
 _slow_ring_size = _DEFAULT_SLOW_RING
 
 #: trace_id -> _Trace (insertion-ordered for FIFO eviction)
-_traces: "OrderedDict[int, _Trace]" = OrderedDict()
+_traces: "dict[int, _Trace]" = {}
 #: trace_id -> completed slow-trace snapshot (tail retention)
-_slow: "OrderedDict[int, dict]" = OrderedDict()
+_slow: "dict[int, dict]" = {}
+#: a profiler session was live at the last root site, and how many
+#: traces it has pinned
+_session_live = False
+_session_pinned = 0
 
+
+# -- layers -------------------------------------------------------------------
+
+#: The benchmark's layer names (BENCHMARK.json ``layer``), by span
+#: name: readers sum a layer's spans and need no list of names.  A
+#: name not listed here is looked up by its first word.
+LAYER_CLIENT = "client"
+LAYER_MSGR = "messenger"
+LAYER_OPQ = "OSD op queue"
+LAYER_ECB = "PG / EC backend"
+LAYER_ENGINE = "dispatch engine"
+LAYER_STORE = "objectstore"
+LAYER_MAPPING = "mapping service"
+LAYER_KERNELS = "kernels"
+
+#: the dispatch engine's phases (telemetry.PHASES), as child spans of
+#: an engine request
+ENGINE_PHASES = ("queue_wait", "build", "place", "launch", "compute",
+                 "materialize", "deliver")
+
+LAYERS = {
+    # client/rados.py
+    "client submit": LAYER_CLIENT, "client complete": LAYER_CLIENT,
+    # msg/: one hop span per message (send queue + encode + wire +
+    # decode) and the receiver's dispatch span
+    "msg": LAYER_MSGR, "rx": LAYER_MSGR,
+    # osd/daemon.py (queue boundary) + osd/op_queue.py
+    "opq wait": LAYER_OPQ,
+    # osd/daemon.py, the primary and the shards
+    "osd op": LAYER_ECB, "ec prepare": LAYER_ECB,
+    "ec encode submit": LAYER_ECB, "ec continuation": LAYER_ECB,
+    "ec daemon lock wait": LAYER_ECB, "ec local commit": LAYER_ECB,
+    "ec fan-out": LAYER_ECB, "ec sub-write": LAYER_ECB,
+    "ec sub-write ack": LAYER_ECB, "osd reply": LAYER_ECB,
+    "rep op": LAYER_ECB,
+    # ops/dispatch.py: the request and its phases; ops/telemetry.py
+    "device": LAYER_ENGINE, "engine": LAYER_ENGINE,
+    "kernel": LAYER_KERNELS,
+    # objectstore/
+    "bluestore commit": LAYER_STORE, "bluestore apply": LAYER_STORE,
+    "bluestore csum settle": LAYER_STORE, "bluestore fsync": LAYER_STORE,
+    "bluestore kv commit": LAYER_STORE,
+    "bluestore on_commit": LAYER_STORE,
+    "objectstore commit": LAYER_STORE,
+    # osd/mapping.py
+    "update_to": LAYER_MAPPING, "mapping": LAYER_MAPPING,
+}
+
+
+def layer_of(name: str) -> str:
+    """The layer a span of this name belongs to ('' = none)."""
+    layer = LAYERS.get(name)
+    if layer is None:
+        layer = LAYERS.get(name.partition(" ")[0], "")
+    return layer
+
+
+# -- the profiler sink ---------------------------------------------------------
+
+def _no_session() -> bool:
+    return False
+
+
+_profiler_on = _no_session
+_Annotation = None
+try:
+    from jax.profiler import TraceAnnotation as _Annotation
+    _profiler_on = _Annotation.is_enabled
+except Exception:        # no jax, or one without the TraceMe binding
+    pass
+
+
+def armed() -> bool:
+    """Whether an untraced root site may open a trace: the sample rate
+    says so, or a profiler session is live (then every root is
+    sampled).  The one check an unarmed root site pays."""
+    return _sample_rate > 0.0 or _profiler_on()
+
+
+# -- spans ---------------------------------------------------------------------
 
 class Span:
-    """One node of a trace tree.
-
-    Two clocks per span, deliberately: ``start``/``end`` are
-    wall-clock DISPLAY timestamps (row ordering, dashboards, humans
-    correlating with logs), while ``start_mono``/``end_mono`` pair a
-    monotonic clock for every DURATION — an NTP step mid-span used to
-    yield negative/skewed durations, which then mis-ranked the
-    slow-trace tail sampling exactly when a clock jump made latency
-    interesting.
-    """
+    """One node of a trace tree.  ``start``/``end`` are
+    ``time.perf_counter_ns()`` readings (``end`` None while open)."""
 
     __slots__ = ("trace_id", "span_id", "parent_span_id", "name",
-                 "daemon", "start", "end", "attrs", "start_mono",
-                 "end_mono")
+                 "daemon", "start", "end", "attrs")
 
     def __init__(self, trace_id: int, span_id: int, parent_span_id: int,
-                 name: str, daemon: str, start: float,
+                 name: str, daemon: str, start: int,
                  attrs: dict | None = None):
         self.trace_id = trace_id
         self.span_id = span_id
@@ -96,63 +206,75 @@ class Span:
         self.name = name
         self.daemon = daemon
         self.start = start
-        self.start_mono = time.monotonic()
-        self.end: float | None = None
-        self.end_mono: float | None = None
-        self.attrs = attrs or {}
+        self.end: int | None = None
+        self.attrs = attrs if attrs is not None else {}
 
     @property
     def duration(self) -> float | None:
-        """Monotonic-clock duration (never negative, NTP-immune)."""
-        return (None if self.end_mono is None
-                else self.end_mono - self.start_mono)
-
-    def row(self) -> dict:
-        r = {"trace_id": self.trace_id, "daemon": self.daemon,
-             "event": self.name, "t": self.start, "kind": "span",
-             "span_id": self.span_id,
-             "parent_span_id": self.parent_span_id,
-             "dur": self.duration}
-        if self.attrs:
-            r["attrs"] = dict(self.attrs)
-        return r
+        """Seconds on the monotonic clock (never negative)."""
+        return None if self.end is None else (self.end - self.start) / 1e9
 
 
 class _Trace:
     __slots__ = ("trace_id", "spans", "events", "root_span_id",
-                 "started", "completed", "dropped_rows")
+                 "wall0", "t0", "completed", "dropped_rows", "pinned")
 
     def __init__(self, trace_id: int):
         self.trace_id = trace_id
         #: span_id -> Span (insertion ordered)
-        self.spans: "OrderedDict[int, Span]" = OrderedDict()
-        #: (span_id, daemon, event, t) point events
-        self.events: list[tuple[int, str, str, float]] = []
+        self.spans: "dict[int, Span]" = {}
+        #: (span_id, daemon, event, t_ns) point events
+        self.events: list[tuple[int, str, str, int]] = []
         self.root_span_id = 0
-        self.started = time.time()
+        #: the one wall-clock reading of the trace, with the monotonic
+        #: reading it pairs: rows display ``wall0 + (t - t0)``
+        self.wall0 = time.time()
+        self.t0 = now_ns()
         self.completed = False
         self.dropped_rows = 0
+        #: opened under a profiler session: held until the next one
+        self.pinned = False
 
     def n_rows(self) -> int:
         return len(self.spans) + len(self.events)
 
+    def wall(self, t_ns: int) -> float:
+        return self.wall0 + (t_ns - self.t0) / 1e9
+
     def rows(self) -> list[dict]:
-        out = [sp.row() for sp in self.spans.values()]
+        out = []
+        # list(...) of a dict view / a list is one GIL-atomic copy:
+        # writers append without the registry lock
+        for sp in list(self.spans.values()):
+            r = {"trace_id": self.trace_id, "daemon": sp.daemon,
+                 "event": sp.name, "t": self.wall(sp.start),
+                 "kind": "span", "span_id": sp.span_id,
+                 "parent_span_id": sp.parent_span_id,
+                 "dur": sp.duration, "start_ns": sp.start,
+                 "end_ns": sp.end, "layer": layer_of(sp.name)}
+            if sp.attrs:
+                r["attrs"] = dict(sp.attrs)
+            out.append(r)
         out.extend({"trace_id": self.trace_id, "daemon": d, "event": e,
-                    "t": t, "kind": "event", "span_id": sid}
-                   for sid, d, e, t in self.events)
-        out.sort(key=lambda r: r["t"])
+                    "t": self.wall(t), "kind": "event", "span_id": sid,
+                    "start_ns": t}
+                   for sid, d, e, t in list(self.events))
+        out.sort(key=lambda r: r["start_ns"])
         return out
 
 
 # -- ids and thread context ---------------------------------------------------
 
-def new_trace_id() -> int:
-    return int.from_bytes(os.urandom(8), "big") >> 1 or 1
+# A per-process random prefix and a counter: ids stay unique across the
+# daemons of a cluster (31 random bits tell processes apart) without a
+# syscall per span.  63 bits, never 0: they ride the frame as u64.
+_ID_PREFIX = (int.from_bytes(os.urandom(4), "big") >> 1 or 1) << 32
+_id_counter = itertools.count(1)
 
 
 def new_span_id() -> int:
-    return int.from_bytes(os.urandom(8), "big") >> 1 or 1
+    """A span id — or a trace id: one sequence serves both."""
+    return _ID_PREFIX | (next(_id_counter) & 0xFFFFFFFF) or 1
 
 
 def current() -> int:
@@ -177,248 +299,430 @@ def set_current(trace_id, span_id: int = 0):
     return prev
 
 
+class _Null:
+    """The no-op context manager an untraced site gets (one shared
+    object: no allocation on the untraced path)."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NULL = _Null()
+
+
+class joined:
+    """Run a block under (trace_id, span_id) — a thread picking up
+    work that carries its ids on a message or a request."""
+
+    __slots__ = ("_ctx", "_prev")
+
+    def __init__(self, trace_id: int, span_id: int = 0):
+        self._ctx = (trace_id, span_id)
+
+    def __enter__(self):
+        self._prev = set_current(self._ctx)
+
+    def __exit__(self, *exc):
+        set_current(self._prev)
+        return False
+
+
 # -- trace table internals ----------------------------------------------------
 
-def _get_trace(tid: int, create: bool = True) -> _Trace | None:
-    """Caller must hold _lock."""
+def _get_trace(tid: int) -> _Trace | None:
     tr = _traces.get(tid)
-    if tr is None and create:
-        if tid in _slow:
-            # the trace already completed, was promoted, and aged out
-            # of the active table: a straggler row must not resurrect
-            # an empty ghost that would shadow the archived snapshot
-            return None
-        tr = _Trace(tid)
-        _traces[tid] = tr
-        while len(_traces) > _active_cap:
-            _evict_one_locked()
+    if tr is not None:
+        return tr
+    with _lock:
+        tr = _traces.get(tid)
+        if tr is None:
+            if tid in _slow:
+                # the trace already completed, was promoted, and aged
+                # out of the active table: a straggler row must not
+                # resurrect an empty ghost that would shadow the
+                # archived snapshot
+                return None
+            tr = _Trace(tid)
+            _traces[tid] = tr
+            while _n_unpinned_locked() > _active_cap:
+                if not _evict_one_locked():
+                    break
     return tr
 
 
-def _evict_one_locked() -> None:
-    """Drop one trace: COMPLETED (fast, un-promoted) traces go first —
-    an in-flight trace may still turn out slow, and evicting it would
-    defeat tail retention exactly when sampling load makes it matter.
-    Only when every retained trace is still open does the oldest open
-    one go (the runaway bound must hold regardless)."""
+def _n_unpinned_locked() -> int:
+    return len(_traces) - _session_pinned
+
+
+def _evict_one_locked() -> bool:
+    """Drop one unpinned trace: COMPLETED (fast, un-promoted) traces go
+    first — an in-flight trace may still turn out slow, and evicting it
+    would defeat tail retention exactly when sampling load makes it
+    matter.  Only when every retained trace is still open does the
+    oldest open one go (the runaway bound must hold regardless)."""
+    oldest = None
     for tid, tr in _traces.items():
+        if tr.pinned:
+            continue
         if tr.completed:
             del _traces[tid]
-            return
-    _traces.popitem(last=False)
+            return True
+        if oldest is None:
+            oldest = tid
+    if oldest is None:
+        return False
+    del _traces[oldest]
+    return True
 
 
 def begin_span(name: str, daemon: str, trace_id: int | None = None,
                parent_span_id: int | None = None,
-               attrs: dict | None = None) -> Span | None:
+               attrs: dict | None = None,
+               start: int | None = None) -> Span | None:
     """Open a span.  trace_id/parent default to the thread context;
-    returns None when there is no trace to attach to.  Does NOT touch
-    the thread context — callers that dispatch work under the span
-    install it via set_current."""
-    tid = current() if trace_id is None else trace_id
+    returns None when there is no trace to attach to.  ``start`` (a
+    ``perf_counter_ns`` reading) backdates it.  Does NOT touch the
+    thread context — callers that dispatch work under the span install
+    it via set_current."""
+    ctx = getattr(_tls, "ctx", (0, 0))
+    tid = ctx[0] if trace_id is None else trace_id
     if not tid:
         return None
-    parent = current_span() if parent_span_id is None else parent_span_id
-    sp = Span(tid, new_span_id(), parent, name, daemon,
-              time.time(), attrs)
-    with _lock:
-        tr = _get_trace(tid)
-        if tr is None or tr.n_rows() >= MAX_ROWS_PER_TRACE:
-            if tr is not None:
-                tr.dropped_rows += 1
-            return None
-        tr.spans[sp.span_id] = sp
-        if not tr.root_span_id and not parent:
-            tr.root_span_id = sp.span_id
+    if parent_span_id is None:
+        parent_span_id = ctx[1] if ctx[0] == tid else 0
+    tr = _get_trace(tid)
+    if tr is None:
+        return None
+    if tr.n_rows() >= MAX_ROWS_PER_TRACE:
+        tr.dropped_rows += 1
+        return None
+    sp = Span(tid, new_span_id(), parent_span_id, name, daemon,
+              now_ns() if start is None else start, attrs)
+    tr.spans[sp.span_id] = sp
+    if not parent_span_id and not tr.root_span_id:
+        tr.root_span_id = sp.span_id
     return sp
 
 
-def finish_span(span: Span | None, t: float | None = None) -> None:
-    """Close a span.  ``t`` (wall clock) overrides the DISPLAY end
-    timestamp only — duration math always pairs the monotonic clock,
-    with an explicit t treated as a caller-computed wall offset from
-    the span's own start (``t=span.start`` = instantaneous marker), so
-    a stepped wall clock can never produce a negative duration."""
+def finish_span(span: Span | None, end: int | None = None) -> None:
+    """Close a span, now or at ``end`` (a ``perf_counter_ns`` reading).
+    Closing a closed span again can only move its end later: a message
+    hop is closed by its sender once the bytes are written and again,
+    later, by a receiver in the same process."""
     if span is None:
         return
-    with _lock:
-        if t is None:
-            span.end = time.time()
-            span.end_mono = time.monotonic()
-        else:
-            span.end = t
-            span.end_mono = span.start_mono + max(0.0, t - span.start)
+    t = now_ns() if end is None else end
+    if span.end is None or t > span.end:
+        span.end = max(t, span.start)
 
 
-def span_event(span: Span | None, event: str,
-               t: float | None = None) -> None:
-    """Attach a point event to an open span."""
-    if span is None:
-        return
-    record(span.daemon, event, trace_id=span.trace_id,
-           span_id=span.span_id, t=t)
+def add_span(name: str, daemon: str, trace_id: int, parent_span_id: int,
+             start: int, end: int, attrs: dict | None = None
+             ) -> Span | None:
+    """Record a span whose interval is already known (an engine phase
+    read off the batch's marks)."""
+    sp = begin_span(name, daemon, trace_id=trace_id,
+                    parent_span_id=parent_span_id, attrs=attrs,
+                    start=start)
+    if sp is not None:
+        sp.end = max(end, start)
+    return sp
+
+
+def find_span(trace_id: int, span_id: int) -> Span | None:
+    """The span of these ids, if this process holds it."""
+    tr = _traces.get(trace_id)
+    return tr.spans.get(span_id) if tr is not None else None
 
 
 def set_attrs(span: Span | None, **attrs) -> None:
-    if span is None:
-        return
-    with _lock:
+    if span is not None:
         span.attrs.update(attrs)
 
 
-@contextmanager
+class _SpanCtx:
+    """``with span(...)``: a child span of the thread's current span
+    for the duration of the block, entered as a profiler annotation of
+    the same name while a session is live."""
+
+    __slots__ = ("_name", "_daemon", "_attrs", "_span", "_prev", "_ann")
+
+    def __init__(self, name: str, daemon: str, attrs: dict | None):
+        self._name = name
+        self._daemon = daemon
+        self._attrs = attrs
+
+    def _begin(self) -> Span | None:
+        return begin_span(self._name, self._daemon, attrs=self._attrs)
+
+    _finish = staticmethod(finish_span)
+
+    def __enter__(self) -> Span | None:
+        sp = self._span = self._begin()
+        self._ann = None
+        if sp is None:        # row-cap hit, or a root left unsampled
+            return None
+        self._prev = set_current(sp.trace_id, sp.span_id)
+        if _profiler_on():
+            self._ann = _Annotation(self._name)
+            self._ann.__enter__()
+        return sp
+
+    def __exit__(self, *exc):
+        sp = self._span
+        if sp is not None:
+            if self._ann is not None:
+                self._ann.__exit__(*exc)
+            set_current(self._prev)
+            self._finish(sp)
+        return False
+
+
 def span(name: str, daemon: str = "", **attrs):
     """Open a child span of the thread's current span for the duration
-    of the block; no-op (yields None) when the thread is untraced."""
-    tid = current()
-    if not tid:
-        yield None
-        return
-    sp = begin_span(name, daemon or "span", attrs=attrs or None)
-    if sp is None:        # row-cap hit
-        yield None
-        return
-    prev = set_current(tid, sp.span_id)
-    try:
-        yield sp
-    finally:
-        set_current(prev)
-        finish_span(sp)
+    of the block; a shared no-op (yields None) when the thread is
+    untraced."""
+    if not getattr(_tls, "ctx", (0, 0))[0]:
+        return _NULL
+    return _SpanCtx(name, daemon or "span", attrs or None)
 
 
-@contextmanager
-def trace_ctx(trace_id: int | None = None, name: str = "trace",
-              daemon: str = "client"):
-    """Open (or join) a trace for the calling thread.  The contextmanager
-    opens a span; when that span is the trace's ROOT, exiting completes
-    the trace (tail-retention check against tracing_slow_threshold)."""
-    tid = trace_id or new_trace_id()
-    join = current() == tid
-    sp = begin_span(name, daemon, trace_id=tid,
-                    parent_span_id=current_span() if join else 0)
-    prev = set_current(tid, sp.span_id if sp else 0)
-    try:
-        yield tid
-    finally:
-        set_current(prev)
-        finish_span(sp)
+# -- roots ---------------------------------------------------------------------
+
+def _sampled() -> bool:
+    """Whether this untraced root opens a trace; True with the second
+    value says a profiler session pins it."""
+    global _session_live, _session_pinned
+    if _profiler_on():
+        with _lock:
+            if not _session_live:
+                # a new session: the last one's traces have been read
+                # (or never will be) and age out with the rest
+                _session_live = True
+                _session_pinned = 0
+                for tr in _traces.values():
+                    tr.pinned = False
+                while _n_unpinned_locked() > _active_cap:
+                    if not _evict_one_locked():
+                        break
+            return _session_pinned < SESSION_CAP
+    _session_live = False
+    return _sample_rate > 0.0 and random.random() < _sample_rate
+
+
+def begin_root(name: str, daemon: str, attrs: dict | None = None
+               ) -> Span | None:
+    """Open a NEW trace's root span on an untraced thread, if the
+    sampling policy says so (callers gate on ``armed()`` first: that
+    is the whole cost of an unarmed site).  The root may be finished
+    on another thread (``finish_root``); the thread context is the
+    caller's to install (``joined``)."""
+    global _session_pinned
+    if not _sampled():
+        return None
+    sp = begin_span(name, daemon, trace_id=new_span_id(), parent_span_id=0,
+                    attrs=attrs)
+    if sp is not None and _session_live:
+        with _lock:
+            tr = _traces.get(sp.trace_id)
+            if tr is not None and not tr.pinned:
+                tr.pinned = True
+                _session_pinned += 1
+    return sp
+
+
+def finish_root(root: Span | None) -> None:
+    """Close a root and complete its trace (the tail-retention check
+    against tracing_slow_threshold)."""
+    if root is None:
+        return
+    finish_span(root)
+    _maybe_complete(root.trace_id, root)
+
+
+class _RootCtx(_SpanCtx):
+    """``with root(...)`` on an untraced thread: a same-thread root,
+    annotated like a span and completed on exit."""
+
+    __slots__ = ()
+
+    def _begin(self) -> Span | None:
+        return begin_root(self._name, self._daemon, self._attrs)
+
+    _finish = staticmethod(finish_root)
+
+
+def root(name: str, daemon: str, **attrs):
+    """A root site whose work runs on the calling thread: opens a new
+    trace when armed and the thread is untraced, joins the caller's
+    trace with a child span otherwise; a shared no-op when neither."""
+    if getattr(_tls, "ctx", (0, 0))[0]:
+        return _SpanCtx(name, daemon, attrs or None)
+    return _RootCtx(name, daemon, attrs or None) if armed() else _NULL
+
+
+class trace_ctx:
+    """Open (or join) a trace for the calling thread — a FORCED trace,
+    whatever the sampling policy.  Opens a span; when that span is the
+    trace's ROOT, exiting completes the trace (tail-retention check
+    against tracing_slow_threshold).  Yields the trace id."""
+
+    __slots__ = ("_tid", "_name", "_daemon", "_span", "_prev")
+
+    def __init__(self, trace_id: int | None = None, name: str = "trace",
+                 daemon: str = "client"):
+        self._tid = trace_id
+        self._name = name
+        self._daemon = daemon
+
+    def __enter__(self) -> int:
+        tid = self._tid or new_span_id()
+        self._tid = tid
+        join = current() == tid
+        sp = self._span = begin_span(
+            self._name, self._daemon, trace_id=tid,
+            parent_span_id=current_span() if join else 0)
+        self._prev = set_current(tid, sp.span_id if sp else 0)
+        return tid
+
+    def __exit__(self, *exc):
+        set_current(self._prev)
+        sp = self._span
         if sp is not None:
-            _maybe_complete(tid, sp)
+            finish_span(sp)
+            _maybe_complete(self._tid, sp)
+        return False
 
 
-@contextmanager
-def maybe_sampled(name: str = "op", daemon: str = "client"):
-    """Head sampling: join the current trace if one exists, else open a
-    new one with probability ``tracing_sample_rate``.  Yields the trace
-    id (0 when unsampled)."""
-    tid = current()
-    if tid:
-        yield tid
-        return
-    if _sample_rate <= 0.0 or random.random() >= _sample_rate:
-        yield 0
-        return
-    with trace_ctx(name=name, daemon=daemon) as t:
-        yield t
-
-
-def _maybe_complete(tid: int, root: Span) -> None:
+def _maybe_complete(tid: int, root_span: Span) -> None:
     with _lock:
         tr = _traces.get(tid)
-        if tr is None or tr.root_span_id != root.span_id:
+        if tr is None or tr.root_span_id != root_span.span_id:
             return
         tr.completed = True
-        dur = root.duration or 0.0
+        dur = root_span.duration or 0.0
         if dur < _slow_threshold:
             return
         _slow[tid] = {
             "trace_id": tid,
-            "root": root.name,
-            "daemon": root.daemon,
+            "root": root_span.name,
+            "daemon": root_span.daemon,
             "duration": round(dur, 6),
-            "completed_at": root.end,
+            "completed_at": tr.wall(root_span.end),
             "n_spans": len(tr.spans),
             "rows": tr.rows(),
         }
         while len(_slow) > _slow_ring_size:
-            _slow.popitem(last=False)
+            del _slow[next(iter(_slow))]
 
 
 # -- event recording ----------------------------------------------------------
 
 def record(daemon: str, event: str, trace_id: int | None = None,
-           span_id: int | None = None, t: float | None = None) -> None:
+           span_id: int | None = None) -> None:
     """Attach a point event to a trace (to the thread's current span
     when it belongs to the same trace)."""
-    tid = trace_id if trace_id is not None else current()
+    ctx = getattr(_tls, "ctx", (0, 0))
+    tid = trace_id if trace_id is not None else ctx[0]
     if not tid:
         return
     if span_id is None:
-        span_id = current_span() if current() == tid else 0
-    stamp_t = time.time() if t is None else t
-    with _lock:
-        tr = _get_trace(tid)
-        if tr is None or tr.n_rows() >= MAX_ROWS_PER_TRACE:
-            if tr is not None:
-                tr.dropped_rows += 1
-            return
-        if not span_id:
-            # an event recorded off-thread (explicit trace_id) still
-            # belongs in the tree: attach it to the trace root
-            span_id = tr.root_span_id
-        tr.events.append((span_id, daemon, event, stamp_t))
+        span_id = ctx[1] if ctx[0] == tid else 0
+    tr = _get_trace(tid)
+    if tr is None:
+        return
+    if tr.n_rows() >= MAX_ROWS_PER_TRACE:
+        tr.dropped_rows += 1
+        return
+    # an event recorded off-thread (explicit trace_id) still belongs
+    # in the tree: attach it to the trace root
+    tr.events.append((span_id or tr.root_span_id, daemon, event,
+                      now_ns()))
 
 
 def stamp(msg, daemon: str) -> None:
     """Transport send hook: a message sent by a thread holding a trace
-    inherits the ids (once) — the send itself becomes an instantaneous
-    ``tx <MsgType>`` span whose span_id rides the frame as the
-    receiver's parent, so the rx dispatch span parents under this hop.
-    Runs on the CALLER's thread — transports that encode later on an
-    event loop still carry the ids because they live on the message."""
+    inherits the ids (once), and the send opens the message's HOP span
+    ``msg <MsgType>``, whose span_id rides the frame as the receiver's
+    parent.  The hop stays open over the send queue, the encode, the
+    wire and the decode: the sender closes it once the bytes are
+    written (``sent``), and a receiver in the same process closes it
+    again, later, as its dispatch begins (``received``).  Runs on the
+    CALLER's thread — transports that encode later on an event loop
+    still carry the ids because they live on the message."""
     if getattr(msg, "trace_id", 0):
         return
     tid = current()
     if not tid:
         return
     msg.trace_id = tid
-    sp = begin_span(f"tx {type(msg).__name__}", daemon, trace_id=tid)
+    sp = begin_span(f"msg {type(msg).__name__}", daemon, trace_id=tid)
     if sp is not None:
-        finish_span(sp, t=sp.start)      # instantaneous hop marker
         msg.parent_span_id = sp.span_id
+        msg._hop_span = sp
     else:
         msg.parent_span_id = current_span()
 
 
+def sent(msg) -> None:
+    """Transport hook: the message's bytes are written (or handed to
+    an in-process peer)."""
+    sp = getattr(msg, "_hop_span", None)
+    if sp is not None:
+        t = now_ns()
+        # where the hop's time went: up to here the send queue, the
+        # encode and the write; from here the wire, the peer's reader
+        # and its decode
+        sp.attrs.setdefault("sent_us", (t - sp.start) // 1000)
+        finish_span(sp, t)
+
+
+def received(trace_id: int, hop_span_id: int) -> None:
+    """Receiver hook: dispatch of a traced message begins, so its hop
+    — if this process holds it — ends here."""
+    sp = find_span(trace_id, hop_span_id)
+    if sp is not None and sp.name.startswith("msg "):
+        finish_span(sp)
+
+
 # -- query surface ------------------------------------------------------------
-
-def events(trace_id: int) -> list[dict]:
-    return [{"daemon": r["daemon"], "event": r["event"], "t": r["t"]}
-            for r in dump(trace_id)]
-
 
 def dump(trace_id: int | None = None) -> list[dict]:
     """Stitched span-structured timeline(s), time-ordered — the
     admin-socket payload.  Every row carries span_id (and, for spans,
-    parent_span_id/dur/attrs).  Falls back to the slow ring for traces
-    already evicted from the active table."""
+    parent_span_id/dur/start_ns/end_ns/layer/attrs).  Falls back to the
+    slow ring for traces already evicted from the active table."""
     with _lock:
         if trace_id is None:
             out = []
-            for tr in _traces.values():
+            for tr in list(_traces.values()):
                 out.extend(tr.rows())
             # slow-ring-only traces (already evicted from the active
             # table) stay visible in the unfiltered view too
             for tid, snap in _slow.items():
                 if tid not in _traces:
                     out.extend(dict(r) for r in snap["rows"])
-            out.sort(key=lambda r: r["t"])
+            out.sort(key=lambda r: r["start_ns"])
             return out
         tr = _traces.get(trace_id)
         if tr is not None:
             return tr.rows()
         snap = _slow.get(trace_id)
         return [dict(r) for r in snap["rows"]] if snap else []
+
+
+def completed_traces() -> list[list[dict]]:
+    """The rows of every completed trace still in the table, one list
+    per trace, oldest first (what a reader of a profiler session's
+    spans walks)."""
+    with _lock:
+        done = [tr for tr in _traces.values() if tr.completed]
+    return [tr.rows() for tr in done]
 
 
 def trace_ids() -> list[int]:
@@ -439,6 +743,7 @@ def tree_from_rows(rows: list[dict]) -> list[dict]:
                 "parent_span_id": r.get("parent_span_id", 0),
                 "name": r.get("event"), "daemon": r.get("daemon"),
                 "start": r.get("t"), "dur": r.get("dur"),
+                "layer": r.get("layer", ""),
                 "attrs": r.get("attrs", {}),
                 "events": [], "children": []}
     roots: list[dict] = []
@@ -487,17 +792,6 @@ def slow_trace_digests(limit: int = 16,
     return out
 
 
-def slow_summary() -> dict:
-    """{count, p99_root_ms} over the slow ring — bench.py's tail-latency
-    digest."""
-    with _lock:
-        durs = sorted(s["duration"] for s in _slow.values())
-    if not durs:
-        return {"count": 0, "p99_root_ms": 0.0}
-    p99 = durs[min(len(durs) - 1, int(0.99 * (len(durs) - 1) + 0.999))]
-    return {"count": len(durs), "p99_root_ms": round(p99 * 1e3, 3)}
-
-
 # -- policy knobs -------------------------------------------------------------
 
 def set_sample_rate(rate) -> None:
@@ -515,16 +809,18 @@ def set_slow_ring(size: int) -> None:
     _slow_ring_size = max(1, int(size))
     with _lock:
         while len(_slow) > _slow_ring_size:
-            _slow.popitem(last=False)
+            del _slow[next(iter(_slow))]
 
 
 def set_active_cap(size: int) -> None:
-    """Bound on concurrently retained (non-slow) traces; test surface."""
+    """Bound on concurrently retained (non-slow, unpinned) traces; test
+    surface."""
     global _active_cap
     _active_cap = max(1, int(size))
     with _lock:
-        while len(_traces) > _active_cap:
-            _traces.popitem(last=False)
+        while _n_unpinned_locked() > _active_cap:
+            if not _evict_one_locked():
+                break
 
 
 def configure_from_conf(conf) -> None:
@@ -555,9 +851,12 @@ def configure_from_conf(conf) -> None:
 def reset() -> None:
     """Drop every trace and restore default policy (test isolation)."""
     global _sample_rate, _slow_threshold, _slow_ring_size, _active_cap
+    global _session_live, _session_pinned
     with _lock:
         _traces.clear()
         _slow.clear()
+        _session_live = False
+        _session_pinned = 0
     _sample_rate = _DEFAULT_SAMPLE_RATE
     _slow_threshold = _DEFAULT_SLOW_THRESHOLD
     _slow_ring_size = _DEFAULT_SLOW_RING

@@ -37,7 +37,7 @@ import threading
 import time
 import zlib
 
-from ceph_tpu.common import lockdep
+from ceph_tpu.common import lockdep, tracing
 
 from .message import Message
 from .messenger import Connection, ConnectionPolicy, EntityName, Messenger
@@ -235,7 +235,6 @@ class TcpConnection(Connection):
     def send_message(self, msg: Message) -> None:
         if self._down:
             return
-        from ceph_tpu.common import tracing
         from ceph_tpu.msg.features import FEATURE_TRACE, FEATURE_TRACE_SPANS
         if self.features & FEATURE_TRACE:
             # NEVER emit the trace header extension against a peer
@@ -319,7 +318,7 @@ class TcpConnection(Connection):
                     frame = self._frame(backlog[0])
                     sock.sendall(frame)
                     self.messenger.count_sent(len(frame))
-                    backlog.pop(0)
+                    tracing.sent(backlog.pop(0))
                 except OSError:
                     with self._lock:
                         if self._sock is not None:

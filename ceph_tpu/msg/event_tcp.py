@@ -48,6 +48,7 @@ import threading
 import time
 import zlib
 
+from ceph_tpu.common import tracing
 from ceph_tpu.auth.handshake import (
     AUTH_CEPHX_ENTITY, AUTH_CEPHX_TICKET, accept_ticket, entity_proof,
     proof as _sess_proof, ticket_for)
@@ -123,7 +124,6 @@ class EventConnection(Connection):
     def send_message(self, msg: Message) -> None:
         if self._down:
             return
-        from ceph_tpu.common import tracing
         from ceph_tpu.msg.features import FEATURE_TRACE, FEATURE_TRACE_SPANS
         if self.features & FEATURE_TRACE:
             # NEVER emit the trace header extension against a peer
@@ -515,6 +515,7 @@ class EventConnection(Connection):
                 # message and are not message traffic)
                 if _msg is not None:
                     self.messenger.count_sent(len(head))
+                    tracing.sent(_msg)
             else:
                 break
             if self.state == _OPEN:

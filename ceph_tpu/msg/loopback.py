@@ -9,7 +9,7 @@ from __future__ import annotations
 import queue
 import threading
 
-from ceph_tpu.common import lockdep
+from ceph_tpu.common import lockdep, tracing
 
 from .message import Message
 from .messenger import Connection, EntityName, Messenger
@@ -29,7 +29,6 @@ class LoopbackConnection(Connection):
     def send_message(self, msg: Message) -> None:
         if self._down:
             return
-        from ceph_tpu.common import tracing
         tracing.stamp(msg, str(self.messenger.my_name))
         with _registry_lock:
             peer = _registry.get(self.peer_addr)
@@ -40,6 +39,7 @@ class LoopbackConnection(Connection):
         data = msg.encode()
         self.messenger.count_sent(len(data))
         peer._enqueue(data, sender=self.messenger)
+        tracing.sent(msg)
 
     def mark_down(self) -> None:
         self._down = True

@@ -203,10 +203,13 @@ class Messenger:
             # inherits the ids (common/tracing.stamp), and work handed
             # to shard queues re-parents here via the message
             from ceph_tpu.common import tracing
+            hop = getattr(msg, "parent_span_id", 0)
+            # the message's hop span (send queue + encode + wire +
+            # decode) ends where its dispatch begins
+            tracing.received(tid, hop)
             rx_span = tracing.begin_span(
                 f"rx {type(msg).__name__}", str(self.my_name),
-                trace_id=tid,
-                parent_span_id=getattr(msg, "parent_span_id", 0))
+                trace_id=tid, parent_span_id=hop)
             if rx_span is not None:
                 msg.parent_span_id = rx_span.span_id
             prev_trace = tracing.set_current(

@@ -36,6 +36,8 @@ import zlib
 
 _WAL_HDR = struct.Struct("<II")   # block index, intra-block offset
 
+from ceph_tpu.common import tracing
+
 from .kv import LogDB
 from .objectstore import ObjectStore
 from .transaction import (
@@ -119,6 +121,9 @@ class BlueStoreLite(ObjectStore):
                      .add_u64("txc")
                      .add_time_avg("commit_lat")
                      .add_time_avg("apply_lat")
+                     .add_time_avg("csum_lat")
+                     .add_time_avg("fsync_lat")
+                     .add_time_avg("kv_commit_lat")
                      .add_u64("csum_batches")
                      .add_u64("csum_blocks")
                      .add_u64("csum_scalar_blocks")
@@ -925,7 +930,6 @@ class BlueStoreLite(ObjectStore):
         # commit span on the calling op's trace: a traced write shows
         # objectstore commit time next to network fan-out and device
         # time (no-op context when the thread is untraced)
-        from ceph_tpu.common import tracing
         with tracing.span("bluestore commit", daemon="bluestore",
                           txns=len(txns)):
             self._queue_transactions(txns, on_commit)
@@ -986,13 +990,17 @@ class BlueStoreLite(ObjectStore):
 
             try:
                 t_apply = _time.perf_counter()
-                apply_ops()
-                self.perf.tinc("apply_lat",
-                               _time.perf_counter() - t_apply)
+                with tracing.span("bluestore apply", daemon="bluestore"):
+                    apply_ops()
+                t_csum = _time.perf_counter()
+                self.perf.tinc("apply_lat", t_csum - t_apply)
                 # settle the batch's checksum debt (one coalesced
                 # device digest, scalar oracle on any failure) BEFORE
                 # the fsync and KV build below read the final metas
-                self._flush_pending_csums(cache)
+                with tracing.span("bluestore csum settle",
+                                  daemon="bluestore"):
+                    self._flush_pending_csums(cache)
+                self.perf.tinc("csum_lat", _time.perf_counter() - t_csum)
             except Exception:
                 self._freed = []
                 self._wal_pending = {}
@@ -1010,48 +1018,57 @@ class BlueStoreLite(ObjectStore):
             # of pure deferred writes touched no block, so it pays no
             # data fsync at all (the KV commit carries the WAL bytes).
             if self._block_dirty:
-                self._f.flush()
-                os.fsync(self._f.fileno())
+                t_sync = _time.perf_counter()
+                with tracing.span("bluestore fsync", daemon="bluestore",
+                                  what="block"):
+                    self._f.flush()
+                    os.fsync(self._f.fileno())
+                self.perf.tinc("fsync_lat",
+                               _time.perf_counter() - t_sync)
                 self._block_dirty = False
-            # the KV mutations come from the FINAL cache state, never
-            # eagerly per-op: a KV transaction applies sets before rms,
-            # so a remove+recreate of one key in a batch (recovery's
-            # replace-wholesale push) must collapse to a single set
-            for (cid, oid), m in cache.items():
-                if cid == "__coll__":
-                    if m is not None:
-                        kvt.set("coll", oid, b"1")
+            t_kv = _time.perf_counter()
+            with tracing.span("bluestore kv commit", daemon="bluestore"):
+                # the KV mutations come from the FINAL cache state, never
+                # eagerly per-op: a KV transaction applies sets before rms,
+                # so a remove+recreate of one key in a batch (recovery's
+                # replace-wholesale push) must collapse to a single set
+                for (cid, oid), m in cache.items():
+                    if cid == "__coll__":
+                        if m is not None:
+                            kvt.set("coll", oid, b"1")
+                        else:
+                            kvt.rmkey("coll", oid)
+                    elif m is not None:
+                        self._put_meta(kvt, cid, oid, m)
                     else:
-                        kvt.rmkey("coll", oid)
-                elif m is not None:
-                    self._put_meta(kvt, cid, oid, m)
-                else:
-                    kvt.rmkey("obj", _okey(cid, oid))
-            new_wal_keys: dict[str, list[str]] = {}
-            for okey, entries in self._wal_pending.items():
-                for seq, bi, boff, data in entries:
-                    k = self._wal_key(okey, seq)
-                    kvt.set("wal", k, _WAL_HDR.pack(bi, boff) + data)
-                    new_wal_keys.setdefault(okey, []).append(k)
-            for key in self._wal_rms:
-                kvt.rmkey("wal", key)
-            self._db.submit_transaction(kvt)
-            # index maintenance AFTER the commit landed
-            for key in self._wal_rms:
-                okey = key.rsplit("\x00", 1)[0]
-                lst = self._wal_index.get(okey)
-                if lst and key in lst:
-                    lst.remove(key)
-            for okey, keys in new_wal_keys.items():
-                self._wal_index.setdefault(okey, []).extend(keys)
-            self._wal_pending = {}
-            self._wal_rms = []
-            self._alloc.release(self._freed)
-            self._freed = []
+                        kvt.rmkey("obj", _okey(cid, oid))
+                new_wal_keys: dict[str, list[str]] = {}
+                for okey, entries in self._wal_pending.items():
+                    for seq, bi, boff, data in entries:
+                        k = self._wal_key(okey, seq)
+                        kvt.set("wal", k, _WAL_HDR.pack(bi, boff) + data)
+                        new_wal_keys.setdefault(okey, []).append(k)
+                for key in self._wal_rms:
+                    kvt.rmkey("wal", key)
+                self._db.submit_transaction(kvt)
+                # index maintenance AFTER the commit landed
+                for key in self._wal_rms:
+                    okey = key.rsplit("\x00", 1)[0]
+                    lst = self._wal_index.get(okey)
+                    if lst and key in lst:
+                        lst.remove(key)
+                for okey, keys in new_wal_keys.items():
+                    self._wal_index.setdefault(okey, []).extend(keys)
+                self._wal_pending = {}
+                self._wal_rms = []
+                self._alloc.release(self._freed)
+                self._freed = []
+            self.perf.tinc("kv_commit_lat", _time.perf_counter() - t_kv)
             self.perf.inc("txc", len(txns))
             self.perf.tinc("commit_lat", _time.perf_counter() - t_start)
         if on_commit:
-            on_commit()
+            with tracing.span("bluestore on_commit", daemon="bluestore"):
+                on_commit()
 
     def apply_transaction(self, txn: Transaction) -> None:
         self.queue_transactions([txn])

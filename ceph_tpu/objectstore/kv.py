@@ -10,6 +10,7 @@ import struct
 import threading
 import zlib
 
+from ceph_tpu.common import tracing
 from ceph_tpu.msg.encoding import Decoder, Encoder
 
 
@@ -165,8 +166,10 @@ class LogDB(MemDB):
         with self._lock:
             assert self._f is not None, "LogDB not open"
             self._f.write(_FRAME.pack(len(blob), zlib.crc32(blob)) + blob)
-            self._f.flush()
-            os.fsync(self._f.fileno())
+            with tracing.span("bluestore fsync", daemon="bluestore",
+                              what="kv"):
+                self._f.flush()
+                os.fsync(self._f.fileno())
         MemDB.submit_transaction(self, t)
 
     def compact(self) -> None:

@@ -71,7 +71,7 @@ from collections import OrderedDict, deque
 
 import numpy as np
 
-from ceph_tpu.common import failpoint, lockdep
+from ceph_tpu.common import failpoint, lockdep, tracing
 from ceph_tpu.ops import telemetry
 from ceph_tpu.qos.dmclock import BACKGROUND_BEST_EFFORT
 
@@ -146,7 +146,7 @@ class DispatchFuture:
 
 class _Request:
     __slots__ = ("key", "fn", "data", "aux", "stripes", "future",
-                 "t_submit", "label", "cache_entries", "trace", "span",
+                 "t_submit", "label", "cache_entries", "trace",
                  "place", "fallback", "cost_tag")
 
     def __init__(self, key, fn, data, stripes, label=None,
@@ -174,13 +174,12 @@ class _Request:
             key[0] if isinstance(key, tuple) and key
             and isinstance(key[0], str) else "dispatch")
         self.cache_entries = cache_entries
-        # a traced submitter gets a per-request device span covering
-        # the coalesced call (timed_kernel's span runs on the engine
-        # thread, outside every op's trace context)
-        from ceph_tpu.common import tracing
+        # a traced submitter gets a per-request engine span with the
+        # batch's phases as children, recorded at delivery
+        # (_deliver_traced); timed_kernel's span runs on the engine
+        # thread, outside every op's trace context
         tid = tracing.current()
         self.trace = (tid, tracing.current_span()) if tid else None
-        self.span = None
 
 
 class _Batch:
@@ -836,25 +835,6 @@ class DeviceDispatchEngine:
                 aux_batch = tuple(placement.put(a) for a in aux_batch)
             t_place_end = time.monotonic()
             profile["place"] = t_place_end - t_build_end
-            traced = [r for r in reqs if r.trace is not None]
-            if traced:
-                from ceph_tpu.common import tracing
-                for r in traced:
-                    r.span = tracing.begin_span(
-                        f"device {r.label}", "device",
-                        trace_id=r.trace[0], parent_span_id=r.trace[1])
-                    if r.span is not None:
-                        # the per-phase story a slow traced op needs:
-                        # how long it queued for coalescing company and
-                        # how long the padded batch took to assemble,
-                        # next to the existing h2d/compute/d2h events
-                        tracing.span_event(
-                            r.span, "queue-wait "
-                            f"{(now - r.t_submit) * 1e3:.3f}ms")
-                        tracing.span_event(
-                            r.span,
-                            f"build {profile['build'] * 1e3:.3f}ms")
-                        tracing.span_event(r.span, f"h2d {r.data.nbytes}B")
             before = None
             if reqs[0].cache_entries is not None and not via_fallback:
                 try:
@@ -982,35 +962,14 @@ class DeviceDispatchEngine:
                 self.stats.set_in_flight(len(self._inflight)
                                          + self._building)
                 self._cv.notify_all()
-            dt = time.monotonic() - batch.t_dispatch
             for req, (a, b) in zip(batch.reqs, batch.slices):
-                if req.span is not None:
-                    # the batch is already popped from _inflight: an
-                    # escaped span-sink error here would revive the
-                    # loop with this batch's remaining futures stranded
-                    # forever — tracing must never wedge completions
-                    try:
-                        from ceph_tpu.common import tracing
-                        if exc is None:
-                            tracing.span_event(req.span,
-                                               f"compute {dt * 1e3:.3f}ms")
-                            tracing.span_event(
-                                req.span, f"d2h {host[a:b].nbytes}B")
-                        attrs = {"kernel": req.label,
-                                 "batch": len(batch.reqs),
-                                 "coalesced": len(batch.reqs) > 1,
-                                 "error": exc is not None}
-                        if batch.misses is not None:
-                            attrs["retrace"] = batch.misses > 0
-                        tracing.set_attrs(req.span, **attrs)
-                        tracing.finish_span(req.span)
-                    except Exception:
-                        pass
                 try:
-                    if exc is not None:
-                        req.future._deliver(None, exc)
+                    value = None if exc is not None else host[a:b]
+                    if req.trace is not None:
+                        self._deliver_traced(req, value, exc, batch,
+                                             t_ready, t_mat)
                     else:
-                        req.future._deliver(host[a:b], None)
+                        req.future._deliver(value, exc)
                 except BaseException as e:  # noqa: BLE001 — see below
                     # _deliver shields continuations with `except
                     # Exception` only; one raising past that (SystemExit
@@ -1079,6 +1038,62 @@ class DeviceDispatchEngine:
                 except Exception:
                     pass   # the ledger must never wedge completions
 
+
+    @staticmethod
+    def _deliver_traced(req: _Request, value, exc, batch: _Batch,
+                        t_ready: float, t_mat: float) -> None:
+        """Deliver a traced submitter's result under its spans: the
+        request (submit -> delivered) with the batch's seven phases
+        (telemetry.PHASES) as children over their real intervals, read
+        off the same marks the phase ledger records.  Continuations
+        run under the `engine deliver` child, so whatever they fan out
+        stays in the op's tree.  The marks are time.monotonic()
+        readings: on Linux the clock of the spans' perf_counter_ns.
+        The batch is already popped from _inflight, so nothing here
+        may raise past the delivery: tracing must never wedge
+        completions."""
+        tid, parent = req.trace
+        rs = deliver = None
+        try:
+            attrs = {"kernel": req.label, "batch": len(batch.reqs),
+                     "coalesced": len(batch.reqs) > 1,
+                     "error": exc is not None,
+                     "h2d_bytes": int(req.data.nbytes)}
+            if value is not None:
+                attrs["d2h_bytes"] = int(value.nbytes)
+            if batch.misses is not None:
+                attrs["retrace"] = batch.misses > 0
+            rs = tracing.begin_span(
+                f"device {req.label}", "device", trace_id=tid,
+                parent_span_id=parent, attrs=attrs,
+                start=int(req.t_submit * 1e9))
+            pr = batch.profile
+            if rs is not None and pr is not None and exc is None:
+                t_build = pr["t0"] + pr["build"]
+                marks = (req.t_submit, pr["t0"], t_build,
+                         t_build + pr["place"], pr["t_launch_end"],
+                         t_ready, t_mat)
+                for name, t_a, t_b in zip(tracing.ENGINE_PHASES, marks,
+                                          marks[1:]):
+                    tracing.add_span(
+                        f"engine {name}", "device", tid, rs.span_id,
+                        int(t_a * 1e9), int(t_b * 1e9),
+                        # the host's wait for the device's result
+                        {"device_wait": True} if name == "compute"
+                        else None)
+                deliver = tracing.begin_span(
+                    "engine deliver", "device", trace_id=tid,
+                    parent_span_id=rs.span_id, start=int(t_mat * 1e9))
+        except Exception:
+            pass
+        under = deliver or rs
+        try:
+            with tracing.joined(tid, under.span_id if under is not None
+                                else parent):
+                req.future._deliver(value, exc)
+        finally:
+            tracing.finish_span(deliver)
+            tracing.finish_span(rs)
 
     # -- supervised recovery (retry ladder, breaker, probe) -------------------
 

@@ -41,7 +41,7 @@ from __future__ import annotations
 import time
 from collections import deque
 
-from ceph_tpu.common import lockdep
+from ceph_tpu.common import lockdep, tracing
 
 #: latency bucket upper bounds, seconds (log-spaced: 10 us .. 1 s)
 LATENCY_BOUNDS = (
@@ -699,8 +699,12 @@ class MappingStats:
 
     The PHASE split answers ROADMAP item 2's standing question — is
     the epoch cost device or host: each computed epoch divides into
-    ``device`` (pool remaps through the mapper/dispatch engine, pps
-    seeding included), ``delta`` (changed-PG candidate extraction: the
+    ``device`` (the WALL time of ``OSDMapMapping.update()``: pool
+    remaps through the mapper/dispatch engine, pps seeding, operand
+    build and the fused ladder — host work and device work both, the
+    name notwithstanding; the ``update_to`` span tree of
+    common/tracing splits it by stage and says which of it waited on
+    the device), ``delta`` (changed-PG candidate extraction: the
     on-device raw-table diff plus state/affinity/override membership),
     and ``host_tail`` (the per-candidate pipeline tail — upmap/
     affinity/temp filtering through ``_finish_from`` — that still
@@ -1413,16 +1417,12 @@ def timed_kernel(name: str, fn, *, batch: int = 0, bytes_in: int = 0,
     if not _REG.enabled:
         return fn()
     ks = _REG.kernel(name)
-    # device span on the calling op's trace (common/tracing): a traced
-    # slow write shows WHERE its device time went — h2d operand bytes,
-    # compute wall time, d2h result bytes, and whether the call
-    # retraced.  Free when the thread is untraced (begin_span returns
-    # None on trace_id 0 without taking the table lock).
-    from ceph_tpu.common import tracing
-    dev_span = tracing.begin_span(f"device {name}", "device") \
+    # kernel span on the calling op's trace (common/tracing): a traced
+    # slow write shows WHERE its device time went — the span is the
+    # call's wall time, its attributes the operand and result bytes and
+    # whether the call retraced.  Free when the thread is untraced.
+    dev_span = tracing.begin_span(f"kernel {name}", "device") \
         if tracing.current() else None
-    if dev_span is not None and bytes_in:
-        tracing.span_event(dev_span, f"h2d {bytes_in}B")
     before = None
     if cache_entries is not None:
         try:
@@ -1464,13 +1464,12 @@ def timed_kernel(name: str, fn, *, batch: int = 0, bytes_in: int = 0,
     ks.record(dt, batch=batch, bytes_in=bytes_in, bytes_out=bytes_out,
               misses=misses)
     if dev_span is not None:
-        tracing.span_event(dev_span, f"compute {dt * 1e3:.3f}ms")
-        if bytes_out:
-            tracing.span_event(dev_span, f"d2h {bytes_out}B")
+        # fenced, the span is the host's wait for the device's result
         tracing.set_attrs(dev_span, kernel=name, batch=batch,
                           bytes_in=bytes_in, bytes_out=bytes_out,
                           retrace=misses > 0,
-                          fenced=_REG.fence_for_timing)
+                          fenced=_REG.fence_for_timing,
+                          device_wait=bool(_REG.fence_for_timing))
         tracing.finish_span(dev_span)
     return out
 

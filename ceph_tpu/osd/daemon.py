@@ -37,7 +37,9 @@ from __future__ import annotations
 
 import threading
 import time
+from contextlib import contextmanager
 
+from ceph_tpu.common import tracing
 from ceph_tpu.common.context import CephTpuContext
 from ceph_tpu.common.logging import dout
 from ceph_tpu.common.perf_counters import PerfCountersBuilder
@@ -242,6 +244,8 @@ class OSDDaemon(Dispatcher):
         self.osdmap = OSDMap()
         from ceph_tpu.common.lockdep import make_lock
         self._lock = make_lock(f"OSD::osd_lock({osd_id})")
+        #: this daemon's name on its spans (common/tracing)
+        self._tname = f"osd.{osd_id}"
         self.pgs: dict[tuple[int, int], PG] = {}
         self._in_flight: dict[tuple[int, int], _InFlight] = {}
         #: ops from clients ahead of our map; flushed on map advance
@@ -359,6 +363,8 @@ class OSDDaemon(Dispatcher):
                      .add_time_avg("op_w_latency")
                      .add_time_avg("map_scan_latency")
                      .add_time_avg("qos_wait")
+                     .add_time_avg("op_before_dequeue_op_lat")
+                     .add_time_avg("subop_w_latency")
                      .add_time_avg("scrub_chunk_latency")
                      .create_perf_counters())
         self.ctx.perf.add(self.perf)
@@ -520,17 +526,27 @@ class OSDDaemon(Dispatcher):
         message).  ``served`` is the dmclock (phase, queue-wait) pair:
         the phase is stamped onto the message for the reply's echo
         (client rho accounting) and counted in the qos perf set, and a
-        traced op gets a ``qos_wait`` event so ``tracing show``
+        traced op's ``opq wait`` span (opened at enqueue) closes here
+        with the class and phase as attributes, so ``tracing show``
         explains a throttled op."""
-        handler, msg, cost = item
-        from ceph_tpu.common import tracing
-        # parent under the rx dispatch span deliver() stored on the msg
-        prev = tracing.set_current(getattr(msg, "trace_id", 0),
-                                   getattr(msg, "parent_span_id", 0))
+        handler, msg, cost, *rest = item
+        qspan = rest[0] if rest else None
+        if qspan is not None:
+            # enqueue -> dequeue, as a span of the op's tree: the
+            # handler parents under it
+            tracing.finish_span(qspan)
+            prev = tracing.set_current(qspan.trace_id, qspan.span_id)
+        else:
+            # parent under the rx dispatch span deliver() stored on
+            # the msg
+            prev = tracing.set_current(getattr(msg, "trace_id", 0),
+                                       getattr(msg, "parent_span_id", 0))
         try:
             if served is not None:
                 phase, wait = served
                 msg._qos_phase = phase
+                if isinstance(msg, MOSDECSubOpWrite):
+                    msg._q_wait = wait      # for subop_w_latency
                 if phase == PHASE_RESERVATION:
                     self.perf.inc("qos_reservation_served")
                 elif phase == PHASE_WEIGHT:
@@ -538,11 +554,9 @@ class OSDDaemon(Dispatcher):
                 elif phase == PHASE_LIMIT:
                     self.perf.inc("qos_limit_served")
                 self.perf.tinc("qos_wait", wait)
-                if tracing.current():   # untraced majority skips the
-                    tracing.record(     # event formatting entirely
-                        f"osd.{self.osd_id}",
-                        f"qos_wait {wait * 1000.0:.2f}ms class={klass} "
-                        f"phase={PHASE_NAMES.get(phase, phase)}")
+                self.perf.tinc("op_before_dequeue_op_lat", wait)
+                tracing.set_attrs(qspan, klass=klass,
+                                  phase=PHASE_NAMES.get(phase, phase))
             handler(msg)
         finally:
             tracing.set_current(prev)
@@ -627,14 +641,19 @@ class OSDDaemon(Dispatcher):
         exactly the backpressure the reference applies at the front door."""
         if self.opwq is not None:
             cost = min(self._op_cost(msg), self._op_throttle.max_amount)
+            # intake throttle + scheduler wait, one span: enqueue here,
+            # dequeue in _opwq_handle (None on an untraced thread)
+            qspan = tracing.begin_span("opq wait", self._tname)
             self._op_throttle.get(cost)
             if not self.opwq.enqueue(shard_key, klass,
-                                     (handler, msg, cost),
+                                     (handler, msg, cost, qspan),
                                      delta=getattr(msg, "qos_delta", 1),
                                      rho=getattr(msg, "qos_rho", 1)):
                 # client backlog cap: refuse (no reply) — the client's
                 # timeout resend retries once the shard drains
                 self._op_throttle.put(cost)
+                tracing.set_attrs(qspan, refused=True)
+                tracing.finish_span(qspan)
                 trk = getattr(msg, "_trk", None)
                 if trk is not None:
                     trk.mark_event("refused: client backlog at cap")
@@ -790,7 +809,6 @@ class OSDDaemon(Dispatcher):
         # v4 tail: completed slow traces (tail-sampled span trees),
         # historic slow-op digests, and the pipeline-profile phase
         # digest — the mgr insights module's feed
-        from ceph_tpu.common import tracing
         from ceph_tpu.ops import telemetry
         con = self.msgr.connect_to(mgr_addr, EntityName("mgr", mgr_rank))
         con.send_message(MMgrReport(
@@ -2433,7 +2451,6 @@ class OSDDaemon(Dispatcher):
         # run on whatever thread flushed them: re-join the op's trace
         # from the message so the fan-out stays attributed
         tid = getattr(msg, "trace_id", 0)
-        from ceph_tpu.common import tracing
         if tid and tracing.current() != tid:
             prev = tracing.set_current(
                 tid, getattr(msg, "parent_span_id", 0))
@@ -2441,6 +2458,10 @@ class OSDDaemon(Dispatcher):
                 return self._handle_op(msg)
             finally:
                 tracing.set_current(prev)
+        with tracing.span("osd op", daemon=self._tname):
+            self._do_handle_op(msg)
+
+    def _do_handle_op(self, msg: MOSDOp) -> None:
         if getattr(msg, "_trk", None) is None:
             kinds = ",".join(str(op.op) for op in msg.ops)
             msg._trk = self.op_tracker.create_request(
@@ -2610,7 +2631,8 @@ class OSDDaemon(Dispatcher):
         if not reply.qos_phase:
             reply.qos_phase = getattr(msg, "_qos_phase", 0)
         if msg.connection is not None:
-            msg.connection.send_message(reply)
+            with tracing.span("osd reply", daemon=self._tname):
+                msg.connection.send_message(reply)
 
     def _reply_err(self, msg: MOSDOp, code: int) -> None:
         self._op_send_reply(
@@ -2967,7 +2989,8 @@ class OSDDaemon(Dispatcher):
         cid = self._pg_cid(pg.pgid)
         for op in msg.ops:
             if op.op in (OP_WRITE, OP_WRITEFULL):
-                self._ec_write_op(msg, pool, pg, op)
+                with tracing.span("ec prepare", daemon=self._tname):
+                    self._ec_write_op(msg, pool, pg, op)
                 return
             if op.op == OP_READ:
                 self.perf.inc("op_r")
@@ -3275,10 +3298,11 @@ class OSDDaemon(Dispatcher):
         window = np.frombuffer(data[s0 * si.width:s1 * si.width],
                                dtype=np.uint8)
         stripes = si.split(window)
-        fut = codec.submit_chunks(
-            engine, stripes,
-            cost_tag=(getattr(msg, "qos_tenant", "") or "client",
-                      "client"))
+        with tracing.span("ec encode submit", daemon=self._tname):
+            fut = codec.submit_chunks(
+                engine, stripes,
+                cost_tag=(getattr(msg, "qos_tenant", "") or "client",
+                          "client"))
         self.perf.inc("ec_dispatch_submits")
         trk = getattr(msg, "_trk", None)
         if trk is not None:
@@ -3293,6 +3317,19 @@ class OSDDaemon(Dispatcher):
         fut.add_done_callback(
             lambda f, c=cctx: self._ec_write_committed(c, f))
         return data
+
+    @contextmanager
+    def _daemon_lock_traced(self):
+        """``with self._lock`` whose WAIT is a span of the op's tree:
+        the continuation runs on the engine's completion thread and
+        meets the shard workers here (PERF.md D1)."""
+        with tracing.span("ec daemon lock wait", daemon=self._tname):
+            # analysis: allow[blocking] -- the daemon lock the continuation always took (`with self._lock`), spelled out so that its wait is a span
+            self._lock.acquire()
+        try:
+            yield
+        finally:
+            self._lock.release()
 
     def _ec_wpend_state(self, pg: PG, oid: str) -> dict:
         """Find or create the pending-write gate for an object with
@@ -3320,11 +3357,11 @@ class OSDDaemon(Dispatcher):
         release the pending-write gate once the last in-flight commit
         for the object lands."""
         msg = c["msg"]
-        # re-join the op's trace: this engine thread has no trace
-        # context, but the commit's shard fan-out must carry the op's
-        # trace id so replica dispatch spans stitch into one tree
+        # the engine delivers a traced request's continuation under
+        # its `engine deliver` span, so the fan-out below carries the
+        # op's trace and stitches into one tree; an inline delivery (a
+        # stopped engine) re-joins from the message
         tid = getattr(msg, "trace_id", 0)
-        from ceph_tpu.common import tracing
         if tid and tracing.current() != tid:
             prev = tracing.set_current(
                 tid, getattr(msg, "parent_span_id", 0))
@@ -3337,8 +3374,9 @@ class OSDDaemon(Dispatcher):
         waiting: list = []
         requeue: list = []
         try:
-            self._ec_write_committed_locked(c, fut, msg, st, reqid,
-                                            waiting, requeue)
+            with tracing.span("ec continuation", daemon=self._tname):
+                self._ec_write_committed_locked(c, fut, msg, st, reqid,
+                                                waiting, requeue)
         finally:
             # OUTER finally: an exception escaping the commit (store or
             # send error) must not strand the ops the gate release just
@@ -3355,7 +3393,7 @@ class OSDDaemon(Dispatcher):
         """Locked half of _ec_write_committed.  Ops to re-dispatch are
         EXTENDED into waiting/requeue (never rebound) so the caller's
         outer finally sees them even if the commit raises."""
-        with self._lock:
+        with self._daemon_lock_traced():
             pg = self.pgs.get(c["pgid"])
             live = (pg is not None
                     and self._ec_reads.get(c["gid"]) is st
@@ -3444,45 +3482,49 @@ class OSDDaemon(Dispatcher):
         size_attr = str(len(data)).encode()
         from ceph_tpu.osd.ec_util import HashInfo
         waiting = set()
-        for shard, osd in shard_osds.items():
-            if osd != self.osd_id:
-                waiting.add(osd)
-                continue
-            soid = f"{msg.oid}:{shard}"
-            new_shard, base_ok = self._patched_shard(
-                pg.pgid, msg.oid, shard, sub[shard], shard_off,
-                shard_len, truncate,
-                expected_prior=entry.prior_version)
-            t = Transaction()
-            if base_ok:
-                (t.truncate(cid, soid, 0)
-                 .write(cid, soid, 0, new_shard)
-                 .setattr(cid, soid, "size", size_attr)
-                 .setattr(cid, soid, "_v", v_attr)
-                 .setattr(cid, soid, "hinfo",
-                          HashInfo.compute(new_shard)))
-            # unusable base: the shard stays untouched with its stale
-            # version/hash (detected-bad everywhere) until the scheduled
-            # repair rewrites it; only the log entry lands now
-            t.ops.extend(meta_t.ops)
-            self.store.apply_transaction(t)
+        with tracing.span("ec local commit", daemon=self._tname):
+            for shard, osd in shard_osds.items():
+                if osd != self.osd_id:
+                    waiting.add(osd)
+                    continue
+                soid = f"{msg.oid}:{shard}"
+                new_shard, base_ok = self._patched_shard(
+                    pg.pgid, msg.oid, shard, sub[shard], shard_off,
+                    shard_len, truncate,
+                    expected_prior=entry.prior_version)
+                t = Transaction()
+                if base_ok:
+                    (t.truncate(cid, soid, 0)
+                     .write(cid, soid, 0, new_shard)
+                     .setattr(cid, soid, "size", size_attr)
+                     .setattr(cid, soid, "_v", v_attr)
+                     .setattr(cid, soid, "hinfo",
+                              HashInfo.compute(new_shard)))
+                # unusable base: the shard stays untouched with its
+                # stale version/hash (detected-bad everywhere) until the
+                # scheduled repair rewrites it; only the log entry lands
+                # now
+                t.ops.extend(meta_t.ops)
+                self.store.apply_transaction(t)
         with self._lock:
             if waiting:
                 self._in_flight[reqid] = _InFlight(msg, set(waiting),
                                                    reply)
-        for shard, osd in shard_osds.items():
-            if osd == self.osd_id:
-                continue
-            con = self._osd_con(osd)
-            if con is None:
-                self._ack_shard(reqid, osd, -107)
-                continue
-            con.send_message(MOSDECSubOpWrite(
-                reqid=reqid, pgid=msg.pgid, oid=f"{msg.oid}:{shard}",
-                shard=shard, chunk=sub[shard], epoch=self.osdmap.epoch,
-                obj_size=len(data), entry=entry_blob,
-                offset=shard_off, shard_len=shard_len,
-                truncate=truncate))
+        with tracing.span("ec fan-out", daemon=self._tname,
+                          shards=len(waiting)):
+            for shard, osd in shard_osds.items():
+                if osd == self.osd_id:
+                    continue
+                con = self._osd_con(osd)
+                if con is None:
+                    self._ack_shard(reqid, osd, -107)
+                    continue
+                con.send_message(MOSDECSubOpWrite(
+                    reqid=reqid, pgid=msg.pgid, oid=f"{msg.oid}:{shard}",
+                    shard=shard, chunk=sub[shard], epoch=self.osdmap.epoch,
+                    obj_size=len(data), entry=entry_blob,
+                    offset=shard_off, shard_len=shard_len,
+                    truncate=truncate))
         if not waiting:
             self._op_send_reply(msg, reply)
 
@@ -3527,6 +3569,16 @@ class OSDDaemon(Dispatcher):
         return out, True
 
     def _handle_ec_write(self, msg: MOSDECSubOpWrite) -> None:
+        t0 = time.perf_counter()
+        with tracing.span("ec sub-write", daemon=self._tname,
+                          shard=msg.shard):
+            self._do_handle_ec_write(msg)
+        # replica-side write latency, queue wait included (the
+        # reference stamps at receive)
+        self.perf.tinc("subop_w_latency", time.perf_counter() - t0
+                       + getattr(msg, "_q_wait", 0.0))
+
+    def _do_handle_ec_write(self, msg: MOSDECSubOpWrite) -> None:
         pool = self.osdmap.pools.get(msg.pgid[0])
         if pool is not None:
             if self._park_subop(self._handle_ec_write, msg, pool):
@@ -3576,7 +3628,8 @@ class OSDDaemon(Dispatcher):
             result=result))
 
     def _handle_ec_write_reply(self, msg: MOSDECSubOpWriteReply) -> None:
-        self._ack_shard(msg.reqid, msg.from_osd, msg.result)
+        with tracing.span("ec sub-write ack", daemon=self._tname):
+            self._ack_shard(msg.reqid, msg.from_osd, msg.result)
 
     def _start_ec_read(self, msg: MOSDOp, pool, up, cid: str,
                        op=None) -> None:
@@ -3915,7 +3968,6 @@ class OSDDaemon(Dispatcher):
         # op's span tree (same rule as _ec_write_committed)
         msg = state.get("msg")
         tid = getattr(msg, "trace_id", 0) if msg is not None else 0
-        from ceph_tpu.common import tracing
         if tid and tracing.current() != tid:
             prev = tracing.set_current(
                 tid, getattr(msg, "parent_span_id", 0))

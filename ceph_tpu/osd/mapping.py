@@ -50,7 +50,7 @@ from collections import deque
 
 import numpy as np
 
-from ceph_tpu.common import lockdep
+from ceph_tpu.common import lockdep, tracing
 from ceph_tpu.crush.types import CRUSH_ITEM_NONE, CrushMap
 from ceph_tpu.ops import telemetry
 
@@ -139,18 +139,26 @@ def _changed_rows(old: np.ndarray, new: np.ndarray,
         return np.zeros(0, dtype=np.int64)
     try:
         import jax.numpy as jnp
-        if (mesh is not None and getattr(mesh, "size", 1) > 1
-                and old.shape[0] % mesh.size == 0):
-            # single sharded placement straight from host (jnp.asarray
-            # first would pay an extra default-device transfer)
-            import jax
-            from jax.sharding import NamedSharding, PartitionSpec
-            spec = NamedSharding(
-                mesh, PartitionSpec(tuple(mesh.axis_names), None))
-            o, n = jax.device_put(old, spec), jax.device_put(new, spec)
-        else:
-            o, n = jnp.asarray(old), jnp.asarray(new)
-        mask = np.asarray(jnp.any(o != n, axis=1))
+        with tracing.span("mapping delta upload", daemon="mapping"):
+            if (mesh is not None and getattr(mesh, "size", 1) > 1
+                    and old.shape[0] % mesh.size == 0):
+                # single sharded placement straight from host
+                # (jnp.asarray first would pay an extra default-device
+                # transfer)
+                import jax
+                from jax.sharding import NamedSharding, PartitionSpec
+                spec = NamedSharding(
+                    mesh, PartitionSpec(tuple(mesh.axis_names), None))
+                o, n = (jax.device_put(old, spec),
+                        jax.device_put(new, spec))
+            else:
+                o, n = jnp.asarray(old), jnp.asarray(new)
+        with tracing.span("mapping delta diff", daemon="mapping"):
+            dev_mask = jnp.any(o != n, axis=1)      # async dispatch
+        # the host's wait for the device's answer, and its copy back
+        with tracing.span("mapping delta read-back", daemon="mapping",
+                          device_wait=True):
+            mask = np.asarray(dev_mask)
     except Exception:   # scalar backend / no device: host diff
         mask = (old != new).any(axis=1)
     return np.flatnonzero(mask)
@@ -400,82 +408,92 @@ class OSDMapMapping:
         # below, so a mid-update exception (device error, future
         # timeout) leaves the old state fully consistent and the next
         # successful update diffs against the right old map
-        prev = _Tables(self.osdmap if self.epoch >= 0 else None,
-                       self._raw, self._pps, self._sigs, self.epoch,
-                       fused=self._fused, fused_w=self._fused_w,
-                       tail_sigs=self._tail_sigs)
-        # drop reachability memos of dead crush content before reuse
-        csig, sigs = pool_signatures(m, self._reach)
-        self._reach = {k: v for k, v in self._reach.items()
-                       if k[0] == csig}
-        weights = np.zeros(max(m.max_osd, 1), dtype=np.int64)
-        weights[:len(m.osd_weight)] = m.osd_weight
-        raw: dict[int, np.ndarray] = {}
-        pps_t: dict[int, np.ndarray] = {}
-        recomputed: list[int] = []
-        reused: list[int] = []
-        futures: list[tuple[int, object]] = []
-        bm = None
-        for pool_id, pool in m.pools.items():
-            sig = sigs[pool_id]
-            invalid = sig[0] == "invalid"
-            if prev.sigs.get(pool_id) == sig and pool_id in prev.raw:
-                raw[pool_id] = prev.raw[pool_id]
-                if pool_id in prev.pps:
-                    pps_t[pool_id] = prev.pps[pool_id]
-                reused.append(pool_id)
-                continue
-            recomputed.append(pool_id)
-            if invalid:
-                # invalid rule -> empty raw, matching _pg_to_raw_osds's []
-                raw[pool_id] = np.zeros((pool.pg_num, 0), dtype=np.int32)
-                continue
-            pgids = np.arange(pool.pg_num, dtype=np.uint32)
-            # pps seeds depend ONLY on (pool_id, pg_num, pgp_num) —
-            # reweight/crush churn recomputes the raw table but may
-            # reuse the seeds (noticeable per epoch on slow hosts)
-            old_pool = (prev.osdmap.pools.get(pool_id)
-                        if prev.osdmap is not None else None)
-            pps = (prev.pps.get(pool_id)
-                   if (old_pool is not None
-                       and old_pool.pg_num == pool.pg_num
-                       and old_pool.pgp_num == pool.pgp_num)
-                   else None)
-            if (self.backend == "scalar"
-                    or pool.pg_num < self.min_device_pgs):
+        with tracing.span("mapping signatures", daemon="mapping"):
+            prev = _Tables(self.osdmap if self.epoch >= 0 else None,
+                           self._raw, self._pps, self._sigs, self.epoch,
+                           fused=self._fused, fused_w=self._fused_w,
+                           tail_sigs=self._tail_sigs)
+            # drop reachability memos of dead crush content before reuse
+            csig, sigs = pool_signatures(m, self._reach)
+            self._reach = {k: v for k, v in self._reach.items()
+                           if k[0] == csig}
+        # pps seeds, weights and the per-pool CRUSH remaps
+        with tracing.span("mapping crush", daemon="mapping"):
+            weights = np.zeros(max(m.max_osd, 1), dtype=np.int64)
+            weights[:len(m.osd_weight)] = m.osd_weight
+            raw: dict[int, np.ndarray] = {}
+            pps_t: dict[int, np.ndarray] = {}
+            recomputed: list[int] = []
+            reused: list[int] = []
+            futures: list[tuple[int, object]] = []
+            bm = None
+            for pool_id, pool in m.pools.items():
+                sig = sigs[pool_id]
+                invalid = sig[0] == "invalid"
+                if prev.sigs.get(pool_id) == sig and pool_id in prev.raw:
+                    raw[pool_id] = prev.raw[pool_id]
+                    if pool_id in prev.pps:
+                        pps_t[pool_id] = prev.pps[pool_id]
+                    reused.append(pool_id)
+                    continue
+                recomputed.append(pool_id)
+                if invalid:
+                    # invalid rule -> empty raw, matching _pg_to_raw_osds's []
+                    raw[pool_id] = np.zeros((pool.pg_num, 0), dtype=np.int32)
+                    continue
+                pgids = np.arange(pool.pg_num, dtype=np.uint32)
+                # pps seeds depend ONLY on (pool_id, pg_num, pgp_num) —
+                # reweight/crush churn recomputes the raw table but may
+                # reuse the seeds (noticeable per epoch on slow hosts)
+                old_pool = (prev.osdmap.pools.get(pool_id)
+                            if prev.osdmap is not None else None)
+                pps = (prev.pps.get(pool_id)
+                       if (old_pool is not None
+                           and old_pool.pg_num == pool.pg_num
+                           and old_pool.pgp_num == pool.pgp_num)
+                       else None)
+                if (self.backend == "scalar"
+                        or pool.pg_num < self.min_device_pgs):
+                    if pps is None:
+                        pps = pps_batch_scalar(pool, pgids)
+                    pps_t[pool_id] = pps
+                    raw[pool_id] = scalar_rows(m.crush, pool.crush_rule,
+                                               pps, pool.size, weights)
+                    continue
                 if pps is None:
-                    pps = pps_batch_scalar(pool, pgids)
+                    pps = pps_batch(pool, pgids)
                 pps_t[pool_id] = pps
-                raw[pool_id] = scalar_rows(m.crush, pool.crush_rule,
-                                           pps, pool.size, weights)
-                continue
-            if pps is None:
-                pps = pps_batch(pool, pgids)
-            pps_t[pool_id] = pps
-            if bm is None:
-                # mapper_for reuses the compiled mapper across epochs
-                # for unchanged crush content (and bounds the dict for
-                # the tool path)
-                bm = self.mapper_for(m.crush, csig)
-            if engine is not None:
-                from ceph_tpu.ops.dispatch import BACKGROUND_BEST_EFFORT
-                from ceph_tpu.ops.dispatch import submit_do_rule
-                futures.append((pool_id, submit_do_rule(
-                    engine, bm, pool.crush_rule, pps, pool.size,
-                    weights,
-                    cost_tag=("system", BACKGROUND_BEST_EFFORT))))
-            else:
-                raw[pool_id] = np.asarray(bm.do_rule(
-                    pool.crush_rule, pps, pool.size, weights))
-        for pool_id, fut in futures:
-            raw[pool_id] = np.asarray(fut.result(timeout=120.0))
+                if bm is None:
+                    # mapper_for reuses the compiled mapper across epochs
+                    # for unchanged crush content (and bounds the dict for
+                    # the tool path)
+                    bm = self.mapper_for(m.crush, csig)
+                if engine is not None:
+                    from ceph_tpu.ops.dispatch import BACKGROUND_BEST_EFFORT
+                    from ceph_tpu.ops.dispatch import submit_do_rule
+                    # the engine request's span parents under
+                    # `mapping crush`, the span that waits for it
+                    # below
+                    futures.append((pool_id, submit_do_rule(
+                        engine, bm, pool.crush_rule, pps, pool.size,
+                        weights,
+                        cost_tag=("system", BACKGROUND_BEST_EFFORT))))
+                else:
+                    raw[pool_id] = np.asarray(bm.do_rule(
+                        pool.crush_rule, pps, pool.size, weights))
+            # the engine's completion thread has already copied the
+            # result to the host (its `materialize` phase): this is the
+            # wait for the engine, and np.asarray is free
+            for pool_id, fut in futures:
+                raw[pool_id] = np.asarray(fut.result(timeout=120.0))
         fused: dict[int, np.ndarray] = {}
         fused_w: dict[int, int] = {}
         tail_sigs: dict[int, tuple] = {}
         if self.fused and self.backend != "scalar":
             try:
-                self._build_fused(m, sigs, raw, pps_t, prev, engine,
-                                  fused, fused_w, tail_sigs)
+                with tracing.span("mapping ladder", daemon="mapping"):
+                    self._build_fused(m, sigs, raw, pps_t, prev, engine,
+                                      fused, fused_w, tail_sigs)
             except Exception as e:
                 from ceph_tpu.common.logging import dout
                 dout("mapping", 0, "fused placement ladder failed, "
@@ -512,47 +530,51 @@ class OSDMapMapping:
                 < self.min_device_pgs:
             return
         from ceph_tpu.ops import placement_kernel as pk
-        width, pairs = pk.pool_widths(m)
-        vectors = m.dense_osd_vectors()
-        state, weight, affinity = vectors
-        epoch_digest = (hash(state.tobytes()), hash(weight.tobytes()),
-                        hash(affinity.tobytes()), width, pairs)
-        ov = _pool_override_digests(m)
-        jobs: list[tuple[int, object]] = []
-        for pool_id, pool in m.pools.items():
-            if pool_id not in raw:
-                continue
-            tsig = (sigs[pool_id], epoch_digest, ov.get(pool_id))
-            tail_sigs[pool_id] = tsig
-            if (prev.tail_sigs.get(pool_id) == tsig
-                    and pool_id in prev.fused
-                    and raw.get(pool_id) is prev.raw.get(pool_id)):
-                fused[pool_id] = prev.fused[pool_id]
-                fused_w[pool_id] = prev.fused_w[pool_id]
-                continue
-            pps = pps_t.get(pool_id)
-            if pps is None:
-                # invalid-rule pools skip the remap, but the ladder
-                # still needs the affinity seed (it is what
-                # _finish_pg_mapping would compute per read)
-                pgids = np.arange(pool.pg_num, dtype=np.uint32)
-                pps = pps_batch(pool, pgids)
-                pps_t[pool_id] = pps
-            jobs.append((pool_id, pk.build_operands(
-                m, pool_id, pool, raw[pool_id], pps, width=width,
-                pairs=pairs, vectors=vectors)))
+        with tracing.span("mapping ladder operands", daemon="mapping"):
+            width, pairs = pk.pool_widths(m)
+            vectors = m.dense_osd_vectors()
+            state, weight, affinity = vectors
+            epoch_digest = (hash(state.tobytes()), hash(weight.tobytes()),
+                            hash(affinity.tobytes()), width, pairs)
+            ov = _pool_override_digests(m)
+            jobs: list[tuple[int, object]] = []
+            for pool_id, pool in m.pools.items():
+                if pool_id not in raw:
+                    continue
+                tsig = (sigs[pool_id], epoch_digest, ov.get(pool_id))
+                tail_sigs[pool_id] = tsig
+                if (prev.tail_sigs.get(pool_id) == tsig
+                        and pool_id in prev.fused
+                        and raw.get(pool_id) is prev.raw.get(pool_id)):
+                    fused[pool_id] = prev.fused[pool_id]
+                    fused_w[pool_id] = prev.fused_w[pool_id]
+                    continue
+                pps = pps_t.get(pool_id)
+                if pps is None:
+                    # invalid-rule pools skip the remap, but the ladder
+                    # still needs the affinity seed (it is what
+                    # _finish_pg_mapping would compute per read)
+                    pgids = np.arange(pool.pg_num, dtype=np.uint32)
+                    pps = pps_batch(pool, pgids)
+                    pps_t[pool_id] = pps
+                jobs.append((pool_id, pk.build_operands(
+                    m, pool_id, pool, raw[pool_id], pps, width=width,
+                    pairs=pairs, vectors=vectors)))
         if not jobs:
             return
         if engine is not None:
             from ceph_tpu.ops.dispatch import BACKGROUND_BEST_EFFORT
             from ceph_tpu.ops.dispatch import submit_finish_ladder
-            futs = [(pid, submit_finish_ladder(
-                engine, op,
-                cost_tag=("system", BACKGROUND_BEST_EFFORT)))
-                    for pid, op in jobs]
-            for pid, fut in futs:
-                fused[pid] = np.asarray(fut.result(timeout=120.0))
-                fused_w[pid] = width
+            # submit and wait under ONE span, so that the engine
+            # requests parent under the span that waits for them
+            with tracing.span("mapping ladder run", daemon="mapping"):
+                futs = [(pid, submit_finish_ladder(
+                    engine, op,
+                    cost_tag=("system", BACKGROUND_BEST_EFFORT)))
+                        for pid, op in jobs]
+                for pid, fut in futs:
+                    fused[pid] = np.asarray(fut.result(timeout=120.0))
+                    fused_w[pid] = width
         else:
             # per-pool direct calls, NOT a concatenated group: pool
             # pg_nums are powers of two in practice, so each pool hits
@@ -711,8 +733,19 @@ class SharedPGMappingService:
         delta since ``from_epoch`` (default: the service's previous
         epoch).  Concurrent callers advancing the same epoch share one
         computation; a burst queues and only the newest target is
-        computed."""
-        with self._cv:
+        computed.
+
+        A trace root site (common/tracing): an untraced caller opens
+        an ``update_to`` trace when tracing is armed, a traced one gets
+        a child span; every stage below is a span of that tree, one
+        per epoch (never per PG)."""
+        with tracing.root("update_to", daemon="mapping",
+                          epoch=osdmap.epoch):
+            return self._update_to(osdmap, from_epoch)
+
+    def _update_to(self, osdmap: OSDMap,
+                   from_epoch: int | None) -> MapUpdate:
+        with tracing.span("mapping cv wait", daemon="mapping"), self._cv:
             if from_epoch is None:
                 from_epoch = self.epoch
             target = osdmap.epoch
@@ -749,8 +782,9 @@ class SharedPGMappingService:
             info = mapping.update(work, engine=self._engine())
             device_s = time.perf_counter() - t0
             if chain_valid:
-                changed, full, delta_s, host_tail_s = \
-                    self._compute_delta(info)
+                with tracing.span("mapping delta", daemon="mapping"):
+                    changed, full, delta_s, host_tail_s = \
+                        self._compute_delta(info)
             else:
                 # prev tables came from a warm() outside the online
                 # sequence: a delta against them would be discarded
@@ -763,7 +797,7 @@ class SharedPGMappingService:
             raise
         dt = time.perf_counter() - t0
         cached_pgs = sum(int(r.shape[0]) for r in mapping._raw.values())
-        with self._cv:
+        with tracing.span("mapping install", daemon="mapping"), self._cv:
             prev = info.prev
             newt = _Tables(work, mapping._raw, mapping._pps,
                            mapping._sigs, work.epoch,
@@ -786,24 +820,25 @@ class SharedPGMappingService:
             self._epoch = max(self._epoch, work.epoch)
             self._updating = False
             self._cv.notify_all()
-        if skipped > 0:
-            self.stats.record_skip(skipped)
-        self.stats.record_update(
-            seconds=dt, recomputed=len(info.recomputed),
-            reused=len(info.reused),
-            changed=(len(changed) if not full else cached_pgs),
-            cached_pgs=cached_pgs, cached_pools=len(mapping._raw))
-        self.stats.record_fused_epoch(mapping.fused_complete())
-        # where did this epoch go: device remap vs candidate
-        # extraction vs the host pipeline tail (ROADMAP item 2's
-        # bottleneck question, readable via dump_mapping_stats)
-        self.stats.record_phases(device_s=device_s, delta_s=delta_s,
-                                 host_tail_s=host_tail_s)
-        with self._cv:
-            # work.epoch >= target and _epoch is monotonic, so the
-            # cache is guaranteed at/past the caller's map now; the
-            # delta is clamped to the CALLER's epoch, not the head
-            return self._delta_since(from_epoch, target)
+        with tracing.span("mapping account", daemon="mapping"):
+            if skipped > 0:
+                self.stats.record_skip(skipped)
+            self.stats.record_update(
+                seconds=dt, recomputed=len(info.recomputed),
+                reused=len(info.reused),
+                changed=(len(changed) if not full else cached_pgs),
+                cached_pgs=cached_pgs, cached_pools=len(mapping._raw))
+            self.stats.record_fused_epoch(mapping.fused_complete())
+            # where did this epoch go: device remap vs candidate
+            # extraction vs the host pipeline tail (ROADMAP item 2's
+            # bottleneck question, readable via dump_mapping_stats)
+            self.stats.record_phases(device_s=device_s, delta_s=delta_s,
+                                     host_tail_s=host_tail_s)
+            with self._cv:
+                # work.epoch >= target and _epoch is monotonic, so the
+                # cache is guaranteed at/past the caller's map now; the
+                # delta is clamped to the CALLER's epoch, not the head
+                return self._delta_since(from_epoch, target)
 
     def warm(self, osdmap: OSDMap) -> None:
         """Make the cache serve THIS map object — the offline-consumer
@@ -923,8 +958,10 @@ class SharedPGMappingService:
                     mask = np.flatnonzero((oldp != newp).any(axis=1))
                     changed.extend((pool_id, int(pg)) for pg in mask)
                     continue
-                for pg in _changed_rows(oldp, newp, mesh=mesh):
-                    changed.append((pool_id, int(pg)))
+                rows = _changed_rows(oldp, newp, mesh=mesh)
+                with tracing.span("mapping delta list", daemon="mapping",
+                                  rows=len(rows)):
+                    changed.extend((pool_id, int(pg)) for pg in rows)
                 continue
             # shared width or pg_num moved (override growth, pool
             # resize): normalize to a common layout and compare the
@@ -939,7 +976,8 @@ class SharedPGMappingService:
                     changed.append((pool_id, int(pg)))
             changed.extend((pool_id, pg)
                            for pg in range(k, newp.shape[0]))
-        return sorted(changed)
+        with tracing.span("mapping delta sort", daemon="mapping"):
+            return sorted(changed)
 
     def _compute_delta(self, info: _UpdateInfo):
         """Exact changed-PG set for one epoch transition.  With

@@ -341,11 +341,18 @@ def test_qos_wait_trace_event_explains_throttled_op():
                                daemon="client") as tid:
             io.write_full("traced-obj", b"payload")
         rows = tracing.dump(tid)
-        events = [r for r in rows if r.get("event", "").startswith(
-            "qos_wait")]
-        assert events, rows
-        assert "phase=" in events[0]["event"]
-        assert "class=client" in events[0]["event"]
+        waits = [r for r in rows if r.get("event") == "opq wait"]
+        assert waits, rows
+        # enqueue -> dequeue is a span with its interval; the class and
+        # the dmclock phase that served the op are its attributes
+        assert waits[0]["kind"] == "span" and waits[0]["dur"] >= 0
+        assert waits[0]["layer"] == "OSD op queue"
+        assert waits[0]["attrs"]["klass"].startswith("client")
+        assert waits[0]["attrs"]["phase"] in ("reservation", "weight",
+                                              "limit")
+        # the handler runs under it
+        assert any(r.get("parent_span_id") == waits[0]["span_id"]
+                   and r["event"] == "osd op" for r in rows)
     finally:
         cluster.stop()
 

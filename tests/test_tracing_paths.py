@@ -1,0 +1,289 @@
+"""The program's span trees where the traffic is: ``aio_write_full`` on
+an EC pool and one ``update_to`` epoch give one complete tree each,
+with a span of every layer on it and a critical path that is named end
+to end; a live profiler session arms the roots and receives the
+same-thread spans as annotations; unarmed, nothing is recorded."""
+
+from __future__ import annotations
+
+import os
+import sys
+import time
+
+import numpy as np
+import pytest
+
+from ceph_tpu.common import tracing
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+from perfbench.harness import span_readers  # noqa: E402
+
+OP_LAYERS = {tracing.LAYER_CLIENT, tracing.LAYER_MSGR, tracing.LAYER_OPQ,
+             tracing.LAYER_ECB, tracing.LAYER_ENGINE, tracing.LAYER_STORE}
+#: the share of a root's interval that no span of the critical path
+#: names, at toy size on a shared CPU: what is left are thread
+#: hand-overs between one span's end and the next one's start
+UNNAMED_MAX = 0.10
+
+
+@pytest.fixture(autouse=True)
+def _clean_tracing():
+    tracing.reset()
+    yield
+    tracing.reset()
+
+
+@pytest.fixture(scope="module")
+def ec_cluster(tmp_path_factory):
+    from ceph_tpu.tools.vstart import MiniCluster
+    c = MiniCluster(n_osds=6, store_type="bluestore", ms_type="async",
+                    base_path=str(tmp_path_factory.mktemp("stores"))
+                    ).start()
+    try:
+        c.wait_for_osd_count(6)
+        client = c.client(timeout=30.0)
+        pool = c.create_pool(client, pool_type="erasure", k=4, m=2,
+                             pg_num=8)
+        io = client.open_ioctx(pool)
+        for i in range(8):      # connections up, programs compiled
+            io.write_full(f"warm-{i}", b"w" * 4096)
+        yield c, io
+    finally:
+        c.stop()
+
+
+def _op_traces():
+    return [rows for rows in tracing.completed_traces()
+            if span_readers.root_of(rows)["event"].startswith("osd_op ")]
+
+
+def _write(io, names, size=4096):
+    done = [io.aio_write_full(n, os.urandom(size)) for n in names]
+    for c in done:
+        assert c.wait_for_complete(30.0) and c.get_return_value() == 0
+    # the op is complete when its reply wakes the client; the primary's
+    # thread that sent the reply closes its own spans a moment later
+    time.sleep(0.2)
+
+
+def test_aio_write_gives_one_complete_tree_per_op(ec_cluster):
+    _c, io = ec_cluster
+    tracing.set_sample_rate(1.0)
+    names = [f"traced-{i}" for i in range(6)]
+    _write(io, names)
+    tracing.set_sample_rate(0.0)
+    traces = _op_traces()
+    assert sorted(span_readers.root_of(t)["event"] for t in traces) \
+        == sorted(f"osd_op {n}" for n in names)
+    for rows in traces:
+        spans = [r for r in rows if r["kind"] == "span"]
+        ids = {r["span_id"] for r in spans}
+        root = span_readers.root_of(rows)
+        assert root["layer"] == "" and root["end_ns"] is not None
+        # ONE tree: every other span's parent is a span of the trace
+        assert all(r["parent_span_id"] in ids
+                   for r in spans if r is not root)
+        assert all(r["end_ns"] is not None for r in spans), \
+            [r["event"] for r in spans if r["end_ns"] is None]
+        names_here = {r["event"] for r in spans}
+        # every boundary of the write path has its span
+        for want in ("client submit", "client complete", "msg MOSDOp",
+                     "rx MOSDOp", "opq wait", "osd op", "ec prepare",
+                     "ec encode submit", "device ec_encode",
+                     "ec continuation", "ec daemon lock wait",
+                     "ec local commit", "ec fan-out",
+                     "msg MOSDECSubOpWrite", "ec sub-write",
+                     "ec sub-write ack", "osd reply", "msg MOSDOpReply",
+                     "bluestore commit", "bluestore apply",
+                     "bluestore csum settle", "bluestore fsync",
+                     "bluestore kv commit"):
+            assert want in names_here, (want, sorted(names_here))
+        for phase in tracing.ENGINE_PHASES:
+            assert f"engine {phase}" in names_here
+        # five remote shards, each with its queue wait and its commit
+        assert sum(r["event"] == "ec sub-write" for r in spans) == 5
+        assert sum(r["event"] == "bluestore commit" for r in spans) == 6
+        q = [r for r in spans if r["event"] == "opq wait"]
+        assert len(q) == 6
+        assert all({"klass", "phase"} <= set(r["attrs"]) for r in q)
+        # the critical path holds a span of every layer and is named
+        path = span_readers.critical_path(rows)
+        assert OP_LAYERS <= set(path), path
+        assert path["unnamed"] <= UNNAMED_MAX * path["root"], path
+        assert sum(v for k, v in path.items() if k != "root") \
+            == path["root"]
+        on_path = {r["event"] for r, _d in span_readers.path_spans(rows)}
+        # of the fan-out only the slowest branch is on the path
+        assert sum(r["event"] == "ec sub-write"
+                   for r, _d in span_readers.path_spans(rows)) == 1
+        assert {"client submit", "engine deliver", "ec continuation",
+                "ec fan-out", "client complete"} <= on_path
+
+
+def test_engine_phases_are_child_spans_with_their_intervals(ec_cluster):
+    _c, io = ec_cluster
+    tracing.set_sample_rate(1.0)
+    _write(io, ["phased"])
+    tracing.set_sample_rate(0.0)
+    (rows,) = _op_traces()
+    spans = [r for r in rows if r["kind"] == "span"]
+    req = next(r for r in spans if r["event"] == "device ec_encode")
+    phases = [r for r in spans if r["parent_span_id"] == req["span_id"]]
+    assert [r["event"] for r in phases] \
+        == [f"engine {p}" for p in tracing.ENGINE_PHASES]
+    # gapless, in order, inside the request: a reader can lay them on
+    # a timeline and subtract them from their parent
+    assert phases[0]["start_ns"] == req["start_ns"]
+    for a, b in zip(phases, phases[1:]):
+        assert a["end_ns"] == b["start_ns"]
+    assert phases[-1]["end_ns"] <= req["end_ns"]
+    compute = phases[tracing.ENGINE_PHASES.index("compute")]
+    assert compute["attrs"] == {"device_wait": True}
+    assert {"h2d_bytes", "d2h_bytes", "retrace", "batch"} \
+        <= set(req["attrs"])
+    # no duration is formatted into a name any more
+    assert not [r["event"] for r in rows
+                if r["event"].rstrip().endswith("ms")
+                and r["kind"] == "event" and "kernel" not in r["event"]]
+
+
+def test_unarmed_aio_writes_record_nothing(ec_cluster, monkeypatch):
+    _c, io = ec_cluster
+    made = []
+
+    class Counting(tracing.Span):
+        __slots__ = ()
+
+        def __init__(self, *a, **kw):
+            made.append(a)
+            super().__init__(*a, **kw)
+
+    monkeypatch.setattr(tracing, "Span", Counting)
+    assert not tracing.armed()
+    _write(io, [f"quiet-{i}" for i in range(36)])
+    assert tracing.trace_ids() == [] and made == []
+    # armed, the same writes do allocate: the count above is no accident
+    tracing.set_sample_rate(1.0)
+    _write(io, ["loud"])
+    assert made and len(tracing.trace_ids()) == 1
+
+
+@pytest.fixture(scope="module")
+def mapping_service():
+    """A two-level map whose pool is large enough for device CRUSH and
+    the fused ladder (osdmap_mapping_min_pgs), as perfbench stands it
+    up, at toy size."""
+    from ceph_tpu.common.context import CephTpuContext
+    from ceph_tpu.crush import build_two_level_map
+    from ceph_tpu.osd import OSDMap, PGPool
+    crush, _root, rid = build_two_level_map(8, 4)
+    m = OSDMap(crush=crush, epoch=2)
+    m.set_max_osd(32)
+    for o in range(32):
+        m.osd_state[o] = 3
+        m.osd_weight[o] = 0x10000
+    m.pools[1] = PGPool(pool_id=1, size=3, crush_rule=rid, pg_num=2048)
+    ctx = CephTpuContext("tracing-paths-map")
+    svc = ctx.mapping_service()
+    assert svc.update_to(m).full
+    state = {"map": m}
+
+    def epoch(weight: int):
+        new = state["map"].copy()
+        new.epoch = state["map"].epoch + 1
+        new.osd_weight[5] = weight
+        upd = svc.update_to(new, from_epoch=state["map"].epoch)
+        state["map"] = new
+        return upd
+
+    epoch(0)            # every program of an epoch compiled
+    epoch(0x10000)
+    try:
+        yield svc, epoch
+    finally:
+        for eng in (ctx._dispatch, ctx._decode_dispatch):
+            if eng is not None:
+                eng.stop()
+
+
+def test_update_to_gives_one_complete_tree_per_epoch(mapping_service):
+    svc, epoch = mapping_service
+    assert tracing.trace_ids() == []
+    tracing.set_sample_rate(1.0)
+    upd = epoch(0)
+    tracing.set_sample_rate(0.0)
+    assert not upd.full and upd.changed
+    (rows,) = tracing.completed_traces()
+    root = span_readers.root_of(rows)
+    assert root["event"] == "update_to"
+    assert root["attrs"]["epoch"] == svc.epoch
+    spans = [r for r in rows if r["kind"] == "span"]
+    names = [r["event"] for r in spans]
+    for want in ("mapping cv wait", "mapping signatures", "mapping crush",
+                 "mapping ladder", "mapping ladder operands",
+                 "mapping ladder run", "mapping delta",
+                 "mapping delta sort",
+                 "mapping install", "mapping account",
+                 "device crush_rule", "device pg_finish"):
+        assert names.count(want) == 1, (want, names)
+    # both engine round trips carry their seven phases
+    for phase in tracing.ENGINE_PHASES:
+        assert names.count(f"engine {phase}") == 2
+    path = span_readers.critical_path(
+        rows, span_readers.by_layer_and_wait)
+    assert {tracing.LAYER_MAPPING, tracing.LAYER_ENGINE,
+            span_readers.KERNELS} <= set(path), path
+    assert path["unnamed"] <= UNNAMED_MAX * path["root"], path
+    assert sum(v for k, v in path.items() if k != "root") == path["root"]
+    # a traced caller's update_to joins its trace and opens no root
+    tracing.reset()
+    with tracing.trace_ctx(name="caller", daemon="t") as tid:
+        epoch(0x10000)
+    assert tracing.trace_ids() == [tid]
+    joined = [r for r in tracing.dump(tid) if r["event"] == "update_to"]
+    assert len(joined) == 1 and joined[0]["parent_span_id"]
+
+
+def test_profiler_session_arms_roots_and_receives_annotations(
+        ec_cluster, mapping_service, tmp_path):
+    import jax
+    from perfbench.harness import trace as trace_mod
+    _c, io = ec_cluster
+    _svc, epoch = mapping_service
+    assert not tracing.armed()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert tracing.armed()
+        _write(io, ["profiled-0", "profiled-1"])
+        epoch(0)
+    finally:
+        jax.profiler.stop_trace()
+    assert not tracing.armed()
+    roots = sorted(span_readers.root_of(t)["event"]
+                   for t in tracing.completed_traces())
+    assert roots == ["osd_op profiled-0", "osd_op profiled-1",
+                     "update_to"]
+    # the session's traces are pinned past the active cap ...
+    tracing.set_active_cap(1)
+    assert len(tracing.completed_traces()) == 3
+    # ... and the host plane of the xplane holds the same-thread spans
+    host: dict[str, int] = {}
+    path = trace_mod.find_xplane(str(tmp_path))
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    host[ev.name] = host.get(ev.name, 0) + 1
+    for want, n in (("client submit", 2), ("client complete", 2),
+                    ("ec continuation", 2), ("ec sub-write", 10),
+                    ("bluestore commit", 12), ("bluestore fsync", 24),
+                    ("update_to", 1), ("mapping crush", 1),
+                    ("mapping delta sort", 1)):
+        assert host.get(want) == n, (want, n, host.get(want))
+    # cross-thread spans live in the table only
+    assert not [n for n in host if n.startswith(("msg ", "opq ",
+                                                 "osd_op", "device "))]
+    # writes after the session are not traced
+    _write(io, ["after"])
+    assert len(tracing.trace_ids()) == 3
