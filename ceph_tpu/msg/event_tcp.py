@@ -9,6 +9,13 @@ and nothing scales with connection count.  This stack is its analog on
 * ONE event-loop thread per messenger owns the listener and every
   connection socket: accept, non-blocking connect, the handshake state
   machine, frame reads and buffered writes all run there.
+* A message sent on an open, idle connection does not wait for that
+  thread: ``send_message`` frames it and hands it to the socket with one
+  non-blocking ``send()`` on the CALLER's thread (the reference's
+  AsyncConnection::send_message likewise tries the write in the caller
+  when ``can_write``).  The loop is woken only for what that call could
+  not take, and for messages sent while the connection is dialing,
+  mid-handshake, reconnecting or already has something queued.
 * ONE dispatch thread drains decoded messages in arrival order and walks
   the dispatcher chain — handlers may block or send without stalling
   socket I/O.  (The reference similarly separates the event centers from
@@ -22,6 +29,17 @@ Wire format: byte-for-byte the v1-lite protocol of the threaded stack
 (banner | name | auth mode+nonce | optional HMAC proofs | compression
 byte | [u32 len][u8 comp] frames) — the two stacks interoperate on the
 same cluster, which is also how this one is tested.
+
+Locking: each connection has a write lock (``EventConnection::wlock``)
+that guards ``out_frames``, ``out_off`` and every ``sock.send``; the
+messenger's lock keeps guarding every ``backlog``.  Order: a caller's
+own locks, then the connection's write lock, then ``Messenger::lock``.
+The write lock is never held across a dispatcher call, and a sender
+never waits for it (it tries it, and queues the message if the loop
+thread or another sender is writing).  While a connection is not open
+only the loop thread touches ``out_frames`` (the handshake's bytes), so
+the handshake code appends without the lock; becoming ``open`` is the
+last thing it does.
 
 Policy semantics match msg/Policy.h via the threaded stack: stateful
 dialing connections reconnect with backoff and resend their backlog
@@ -48,7 +66,7 @@ import threading
 import time
 import zlib
 
-from ceph_tpu.common import tracing
+from ceph_tpu.common import lockdep, tracing
 from ceph_tpu.auth.handshake import (
     AUTH_CEPHX_ENTITY, AUTH_CEPHX_TICKET, accept_ticket, entity_proof,
     proof as _sess_proof, ticket_for)
@@ -61,7 +79,9 @@ from .messenger import Connection, ConnectionPolicy, EntityName, Messenger
 
 _LEN = struct.Struct("<I")
 
-from .features import FEAT_FRAME as _FEAT  # noqa: E402
+from .features import (  # noqa: E402
+    FEAT_FRAME as _FEAT, FEATURE_ICI_TOKENS, FEATURE_TRACE,
+    FEATURE_TRACE_SPANS)
 
 # connection states
 _CONNECTING = "connecting"
@@ -71,11 +91,17 @@ _CLOSED = "closed"
 _WAIT_RECONNECT = "wait-reconnect"
 
 _RECONNECT_DELAY = 0.1
+#: the loop walks its whole connection table (handshake deadlines,
+#: reconnect timers) this often, and sleeps no longer than this
+_SCAN_INTERVAL = 0.2
+_NEVER = float("inf")
 
 
 class EventConnection(Connection):
-    """Non-blocking connection state machine; all socket work happens on
-    the owning messenger's event-loop thread."""
+    """Non-blocking connection state machine.  Reads, the handshake and
+    buffered writes happen on the owning messenger's event-loop thread;
+    a message sent while the connection is open and idle is written by
+    the sending thread (``send_message``), under the write lock."""
 
     def __init__(self, messenger: "EventMessenger", peer_addr: str,
                  peer_name: EntityName | None, policy: ConnectionPolicy,
@@ -94,6 +120,11 @@ class EventConnection(Connection):
         #: bytes (regenerated on reconnect, never resent)
         self.out_frames: collections.deque = collections.deque()
         self.out_off = 0
+        #: guards out_frames, out_off and every sock.send once the
+        #: connection is open; taken before Messenger::lock, never held
+        #: across a dispatcher call
+        self._wlock = lockdep.make_lock(
+            f"EventConnection::wlock({messenger.my_name})")
         self.inbuf = bytearray()
         self._down = False
         # handshake scratch
@@ -124,7 +155,6 @@ class EventConnection(Connection):
     def send_message(self, msg: Message) -> None:
         if self._down:
             return
-        from ceph_tpu.msg.features import FEATURE_TRACE, FEATURE_TRACE_SPANS
         if self.features & FEATURE_TRACE:
             # NEVER emit the trace header extension against a peer
             # that did not negotiate it (features.py's invariant)
@@ -134,11 +164,69 @@ class EventConnection(Connection):
                 # extension: fall back to the v1 bare-u64 frame
                 msg.parent_span_id = 0
         m = self.messenger
+        # fast road: nobody else is writing, so try the socket from here
+        if self._wlock.acquire(blocking=False):
+            try:
+                if self._send_inline(msg):
+                    return
+            finally:
+                self._wlock.release()
+        # slow road: the loop thread frames and writes it
         with m._lock:
             if self._down:
                 return
             self.backlog.append(msg)
-        m.wakeup()
+        m.mark_pending(self)
+
+    def _send_inline(self, msg: Message) -> bool:
+        """Sender's thread, write lock held: frame `msg` and hand it to
+        the socket with one non-blocking send().  False = the message
+        has to queue behind the loop thread (not open, something ahead
+        of it, or a frame that may wait on the device)."""
+        # the loop moves messages from backlog to out_frames only under
+        # the write lock, so both empty means nothing can be overtaken
+        if (self.state != _OPEN or self.sock is None or self.out_frames
+                or self.backlog or self._stages_on_device()):
+            return False
+        m = self.messenger
+        frame = self._frame(msg)
+        try:
+            n = self.sock.send(frame)
+        except (BlockingIOError, InterruptedError):
+            n = 0
+        except OSError:
+            # reconnect-and-resend or drop-and-reset live in _close_now,
+            # on the loop thread
+            with m._lock:
+                self.backlog.appendleft(msg)
+            m.defer(self._reset_if_sock, self.sock)
+            return True
+        if n == len(frame):
+            self._flushed(msg, n, "inline")
+        else:
+            # the socket buffer is full: the loop writes the rest
+            self.out_frames.append((frame, msg))
+            self.out_off = n
+            m.mark_pending(self)
+        return True
+
+    def _stages_on_device(self) -> bool:
+        """Framing a message for this peer may stage its payload in a
+        device buffer (msg/ici.maybe_stage), which can wait on the
+        device: such frames are built on the loop thread."""
+        return (self.messenger.ici_wire
+                and bool(self.features & FEATURE_ICI_TOKENS))
+
+    def _flushed(self, msg: Message, nbytes: int, road: str) -> None:
+        """Write lock held: the last byte of `msg`'s frame is written.
+        Counted here and not at frame-build: fault-salvaged messages
+        re-frame on reconnect and must only count per actual wire
+        traversal."""
+        m = self.messenger
+        m.count_sent(nbytes)
+        m.perf.inc(f"msg_send_{road}")
+        tracing.set_attrs(getattr(msg, "_hop_span", None), road=road)
+        tracing.sent(msg)
 
     def mark_down(self) -> None:
         self._down = True
@@ -150,50 +238,83 @@ class EventConnection(Connection):
 
     # -- event-loop side ------------------------------------------------------
 
+    def _reset_if_sock(self, sock: socket.socket) -> None:
+        """Loop thread: a sender's send() failed on `sock`.  The loop
+        may have seen the failure first and redialed since."""
+        if self.sock is sock:
+            self._close_now(reset=True)
+
     def _close_now(self, reset: bool = False) -> None:
         """Loop thread: tear the socket down; maybe schedule reconnect."""
         m = self.messenger
-        if self.sock is not None:
-            try:
-                m.sel.unregister(self.sock)
-            except (KeyError, ValueError):
-                pass
-            try:
-                self.sock.close()
-            except OSError:
-                pass
-            self.sock = None
-        self._cur_want = 0
-        m._accepting.discard(self)
-        self.inbuf.clear()
-        # salvage framed-but-unflushed messages back onto the backlog in
-        # order (the threaded stack's resend granularity: whole frames)
-        salvage = [om for _, om in self.out_frames if om is not None]
-        self.out_frames.clear()
-        self.out_off = 0
-        if salvage:
-            with self.messenger._lock:
-                self.backlog.extendleft(reversed(salvage))
-        self.hs_stage = "banner"
-        self.hs_session = None
-        self.auth_entity = None
-        if self._down:
-            self.state = _CLOSED
-            return
-        if reset and (self.policy.lossy or self.accepted):
-            # lossy/accepted sessions die with their socket
-            self._down = True
-            self.state = _CLOSED
+        died = False
+        with self._wlock:
+            if self.sock is not None:
+                try:
+                    m.sel.unregister(self.sock)
+                except (KeyError, ValueError):
+                    pass
+                try:
+                    self.sock.close()
+                except OSError:
+                    pass
+                self.sock = None
+            self._cur_want = 0
+            m._accepting.discard(self)
+            self.inbuf.clear()
+            # salvage framed-but-unflushed messages back onto the backlog
+            # in order (the threaded stack's resend granularity: whole
+            # frames)
+            salvage = [om for _, om in self.out_frames if om is not None]
+            self.out_frames.clear()
+            self.out_off = 0
+            if salvage:
+                with m._lock:
+                    self.backlog.extendleft(reversed(salvage))
+            self.hs_stage = "banner"
+            self.hs_session = None
+            self.auth_entity = None
+            if self._down:
+                self.state = _CLOSED
+            elif reset and (self.policy.lossy or self.accepted):
+                # lossy/accepted sessions die with their socket
+                self._down = died = True
+                self.state = _CLOSED
+            elif reset:
+                if not self.policy.resend_on_reconnect:
+                    with m._lock:
+                        self.backlog.clear()
+                self.state = _WAIT_RECONNECT
+                self.reconnect_at = time.monotonic() + _RECONNECT_DELAY
+                m._reconnect_due = min(m._reconnect_due, self.reconnect_at)
+            else:
+                self.state = _CLOSED
+        if died:
+            # dispatchers run outside the write lock
             m.notify_reset(self)
             m.reap(self)
-            return
-        if reset:
-            if not self.policy.resend_on_reconnect:
-                self.backlog.clear()
-            self.state = _WAIT_RECONNECT
-            self.reconnect_at = time.monotonic() + _RECONNECT_DELAY
-        else:
-            self.state = _CLOSED
+
+    def _service(self, now: float) -> float:
+        """Loop thread: bring the selector's interest in this
+        connection, its handshake deadline and its reconnect timer up
+        to date.  Returns when it next needs a timer."""
+        if self.sock is not None and self.state in (
+                _OPEN, _HANDSHAKE, _CONNECTING):
+            if self.state == _OPEN or not (now >= self.hs_deadline > 0):
+                self._update_interest()
+                return _NEVER
+            # the threaded stack's handshake timeout: a peer that
+            # stalls mid-handshake must not leak the fd
+            self._close_now(reset=True)
+        if (self.state in (_CLOSED, _WAIT_RECONNECT) and not self._down
+                and not self.accepted):
+            with self.messenger._lock:
+                pending = bool(self.backlog)
+            if pending:
+                if self.state == _WAIT_RECONNECT and now < self.reconnect_at:
+                    return self.reconnect_at
+                self._start_connect()
+        return _NEVER
 
     def _start_connect(self) -> None:
         """Loop thread: begin a non-blocking dial."""
@@ -462,13 +583,11 @@ class EventConnection(Connection):
     # -- frame I/O ------------------------------------------------------------
 
     def _frame(self, msg: Message) -> bytes:
-        if getattr(self.messenger, "ici_wire", False):
-            from ceph_tpu.msg.features import FEATURE_ICI_TOKENS
-            if self.features & FEATURE_ICI_TOKENS:
-                # ici-wire data plane: the bulk payload moves through
-                # the device transfer engine; the frame carries a token
-                from ceph_tpu.msg.ici import maybe_stage
-                maybe_stage(msg, self.peer_name)
+        if self._stages_on_device():
+            # ici-wire data plane: the bulk payload moves through the
+            # device transfer engine; the frame carries a token
+            from ceph_tpu.msg.ici import maybe_stage
+            maybe_stage(msg, self.peer_name)
         payload = msg.encode()
         comp = COMP_NONE
         if self.comp == COMP_ZLIB and len(payload) >= COMP_THRESHOLD:
@@ -493,34 +612,39 @@ class EventConnection(Connection):
         if self.state == _CONNECTING:
             self._on_connected()
             return
+        with self._wlock:
+            ok = self._flush()
+        if ok:
+            self._update_interest()
+        else:
+            self._close_now(reset=True)
+
+    def _flush(self) -> bool:
+        """Loop thread, write lock held: write what is queued until the
+        socket takes no more.  False = the socket failed."""
         if self.state == _OPEN:
             self._fill_out_frames()
         while self.out_frames:
-            head, _msg = self.out_frames[0]
+            head, msg = self.out_frames[0]
             try:
                 n = self.sock.send(head[self.out_off:] if self.out_off
                                    else head)
             except (BlockingIOError, InterruptedError):
                 break
             except OSError:
-                self._close_now(reset=True)
-                return
+                return False
             self.out_off += n
-            if self.out_off >= len(head):
-                self.out_frames.popleft()
-                self.out_off = 0
-                # count at FLUSH, not frame-build: fault-salvaged
-                # messages re-frame on reconnect and must only count
-                # per actual wire traversal (handshake frames carry no
-                # message and are not message traffic)
-                if _msg is not None:
-                    self.messenger.count_sent(len(head))
-                    tracing.sent(_msg)
-            else:
+            if self.out_off < len(head):
                 break
+            self.out_frames.popleft()
+            self.out_off = 0
+            # handshake frames carry no message and are not message
+            # traffic
+            if msg is not None:
+                self._flushed(msg, len(head), "queued")
             if self.state == _OPEN:
                 self._fill_out_frames()
-        self._update_interest()
+        return True
 
     def _on_readable(self) -> None:
         try:
@@ -576,13 +700,15 @@ class EventConnection(Connection):
         if self.sock is None:
             return
         want = selectors.EVENT_READ if not self.messenger.paused else 0
-        with self.messenger._lock:
-            pending = bool(self.backlog)
-        # backlog counts only once OPEN: mid-handshake it cannot be
-        # framed yet, and write interest with nothing to write busy-spins
-        if self.out_frames or self.state == _CONNECTING or (
-                pending and self.state == _OPEN):
+        if self.out_frames or self.state == _CONNECTING:
             want |= selectors.EVENT_WRITE
+        elif self.state == _OPEN:
+            # backlog counts only once OPEN: mid-handshake it cannot be
+            # framed yet, and write interest with nothing to write
+            # busy-spins
+            with self.messenger._lock:
+                if self.backlog:
+                    want |= selectors.EVENT_WRITE
         if want == self._cur_want:
             return
         sel = self.messenger.sel
@@ -604,9 +730,14 @@ class EventConnection(Connection):
 
 
 class EventMessenger(Messenger):
-    """selectors-based messenger: 2 threads total (event loop + dispatch)."""
+    """selectors-based messenger: 2 threads total (event loop + dispatch).
+    Senders write to open, idle connections themselves
+    (EventConnection.send_message) and wake the loop for the rest."""
 
     is_wire = True
+    #: the ici-wire subclass (msg/ici.make_wire_messenger) stages bulk
+    #: payloads on the device while framing
+    ici_wire = False
 
     #: stop reading sockets when this many decoded bytes sit undispatched
     DISPATCH_HIGH = 256 << 20
@@ -629,6 +760,16 @@ class EventMessenger(Messenger):
         #: tracked so deadlines and shutdown reach them
         self._accepting: set = set()
         self._deferred: collections.deque = collections.deque()
+        #: connections whose senders queued something for the loop
+        #: thread (a backlog, the rest of a frame); appended from any
+        #: thread, drained by the loop each tick
+        self._pending: collections.deque = collections.deque()
+        # loop thread's timers: the next walk of the whole table, the
+        # earliest reconnect among waiting connections, and the value
+        # of `paused` the selector's read interests were last set for
+        self._scan_at = 0.0
+        self._reconnect_due = _NEVER
+        self._paused_seen = False
         self._dispatch_q: queue.Queue = queue.Queue()
         self._dispatch_bytes = 0
         self._wake_r, self._wake_w = socket.socketpair()
@@ -666,6 +807,17 @@ class EventMessenger(Messenger):
         """Run fn(*args) on the event-loop thread."""
         self._deferred.append((fn, args))
         self.wakeup()
+
+    def mark_pending(self, con: EventConnection) -> None:
+        """Any thread: `con` has something the loop thread must write."""
+        self._pending.append(con)
+        self.wakeup()
+
+    def _perf_builder(self):
+        # which road a frame took to its socket: written whole by the
+        # thread that sent it, or (wholly or in part) by the loop
+        return (super()._perf_builder()
+                .add_u64("msg_send_inline").add_u64("msg_send_queued"))
 
     def enqueue_dispatch(self, con: EventConnection, data: bytes,
                          wire_len: int = 0) -> None:
@@ -797,7 +949,6 @@ class EventMessenger(Messenger):
                 events = sel.select(timeout)
             except OSError:
                 return
-            now = time.monotonic()
             for skey, mask in events:
                 tag = skey.data
                 if tag == "wake":
@@ -821,44 +972,37 @@ class EventMessenger(Messenger):
                     get_logger("ms").exception(
                         "%s: connection event failed", self.my_name)
                     con._close_now(reset=True)
-            self._run_timers(now)
             self._refresh_writers()
 
     def _refresh_writers(self) -> None:
-        """Pick up messages queued from other threads: any connection
-        with a pending backlog (or newly unpaused reads) re-registers;
-        stalled handshakes are torn down at their deadline."""
+        """Pick up what other threads queued: the connections they
+        marked pending get their write interest (or their redial).  The
+        whole table is walked only when a timer is due or `paused`
+        flipped."""
         now = time.monotonic()
+        pending = self._pending
+        while pending:
+            due = pending.popleft()._service(now)
+            self._reconnect_due = min(self._reconnect_due, due)
+        paused = self.paused
+        if (paused != self._paused_seen
+                or now >= min(self._scan_at, self._reconnect_due)):
+            self._paused_seen = paused
+            self._scan_table(now)
+
+    def _scan_table(self, now: float) -> None:
+        """Every connection: stalled handshakes are torn down at their
+        deadline, due reconnects dial, and read interest follows
+        `paused` (un-pausing re-registers)."""
+        self._scan_at = now + _SCAN_INTERVAL
         with self._lock:
             conns = list(self._conns.values()) + list(self._accepting)
-        for con in conns:
-            if con.sock is not None and con.state in (
-                    _OPEN, _HANDSHAKE, _CONNECTING):
-                if (con.state in (_HANDSHAKE, _CONNECTING)
-                        and now >= con.hs_deadline > 0):
-                    # the threaded stack's handshake timeout: a peer
-                    # that stalls mid-handshake must not leak the fd
-                    con._close_now(reset=True)
-                    continue
-                con._update_interest()
-            elif con.state in (_CLOSED, _WAIT_RECONNECT) and not con._down:
-                with self._lock:
-                    pending = bool(con.backlog)
-                if pending and (con.state == _CLOSED
-                                or now >= con.reconnect_at):
-                    if not con.accepted:
-                        con._start_connect()
+        self._reconnect_due = min(
+            (con._service(now) for con in conns), default=_NEVER)
 
     def _next_timer(self) -> float:
-        with self._lock:
-            waits = [c.reconnect_at for c in self._conns.values()
-                     if c.state == _WAIT_RECONNECT and c.backlog]
-        if not waits:
-            return 0.2
-        return max(0.0, min(min(waits) - time.monotonic(), 0.2))
-
-    def _run_timers(self, now: float) -> None:
-        pass  # reconnects handled by _refresh_writers
+        return max(0.0, min(self._scan_at, self._reconnect_due)
+                   - time.monotonic())
 
     def _accept_ready(self) -> None:
         while True:

@@ -123,11 +123,15 @@ class Messenger:
         self._lock = lockdep.make_lock(f"Messenger::lock({name})")
         # per-messenger wire counters (AsyncMessenger's l_msgr_* set);
         # daemons register this into their context's collection
+        self.perf = self._perf_builder().create_perf_counters()
+
+    def _perf_builder(self):
+        """The stack's counter set; a stack with counters of its own
+        adds them to this."""
         from ceph_tpu.common.perf_counters import PerfCountersBuilder
-        self.perf = (PerfCountersBuilder(f"msgr.{name}")
-                     .add_u64("msg_send").add_u64("msg_recv")
-                     .add_u64("bytes_send").add_u64("bytes_recv")
-                     .create_perf_counters())
+        return (PerfCountersBuilder(f"msgr.{self.my_name}")
+                .add_u64("msg_send").add_u64("msg_recv")
+                .add_u64("bytes_send").add_u64("bytes_recv"))
 
     def count_sent(self, nbytes: int) -> None:
         """Transport send hook: one frame of nbytes left this endpoint."""

@@ -251,3 +251,239 @@ def test_event_and_threaded_stacks_interoperate():
         assert len(got) == 3, f"{srv_type}<-{cli_type}: got {len(got)}"
         cli.shutdown()
         srv.shutdown()
+
+
+# -- which thread writes a frame (event stack) --------------------------------
+
+def _wait_for(cond, timeout=10.0):
+    deadline = time.time() + timeout
+    while not cond() and time.time() < deadline:
+        time.sleep(0.005)
+    return cond()
+
+
+def _subwrite(sender: int, i: int, chunk: bytes = b"c" * 100):
+    return MOSDECSubOpWrite(reqid=(sender, i), pgid=(1, 0),
+                            oid=f"o{sender}.{i}", shard=i % 12, chunk=chunk)
+
+
+class _EventPair:
+    """An event-stack server and a client dialing it, both collecting."""
+
+    def __init__(self, client_policy: ConnectionPolicy | None = None,
+                 prepare=None):
+        self.server = Messenger.create(EntityName("osd", 5), "async")
+        self.client = Messenger.create(EntityName("client", 6), "async")
+        if prepare is not None:
+            prepare(self.server)
+            prepare(self.client)
+        if client_policy is not None:
+            self.client.set_policy("osd", client_policy)
+        self.got, self.client_got = _Collector(), _Collector()
+        self.server.add_dispatcher_tail(self.got)
+        self.client.add_dispatcher_tail(self.client_got)
+        self.server.bind("127.0.0.1:0")
+        self.server.start()
+        self.client.start()
+        self.con = None
+
+    def connect(self):
+        self.con = self.client.connect_to(self.server.my_addr,
+                                          EntityName("osd", 5))
+        return self.con
+
+    def settle(self):
+        """Dial, and send until one message has gone all the way: the
+        handshake's bytes are flushed and both queues are empty."""
+        con = self.con or self.connect()
+        con.send_message(_subwrite(0, 0))
+        assert _wait_for(lambda: self.sent("msg_send") == 1
+                         and len(self.got.got) == 1)
+        assert not con.out_frames and not con.backlog
+        return con
+
+    def sent(self, key: str) -> int:
+        return self.client.perf.value(key)
+
+    def ids(self):
+        return [m.reqid for m in self.got.got]
+
+    def close(self):
+        self.client.shutdown()
+        self.server.shutdown()
+
+
+def test_event_settled_connection_is_written_by_the_sender():
+    p = _EventPair()
+    try:
+        con = p.settle()
+        base = {k: p.sent(k) for k in ("msg_send", "msg_send_inline",
+                                       "msg_send_queued")}
+        n = 64
+        for i in range(1, n + 1):
+            con.send_message(_subwrite(1, i))
+        assert _wait_for(lambda: len(p.got.got) == n + 1)
+        assert p.ids()[1:] == [(1, i) for i in range(1, n + 1)]
+        assert p.sent("msg_send_inline") - base["msg_send_inline"] == n
+        assert p.sent("msg_send_queued") == base["msg_send_queued"]
+        assert p.sent("msg_send") - base["msg_send"] == n
+    finally:
+        p.close()
+
+
+def test_event_senders_interleave_large_and_small_frames():
+    """Frames larger than the socket buffer take the partial-write
+    road (the sender writes what fits, the loop the rest) while other
+    threads keep sending on the same connection: nothing overtakes
+    within a sender and every byte arrives."""
+    import socket
+    import sys
+
+    p = _EventPair()
+    old_switch = sys.getswitchinterval()
+    try:
+        con = p.settle()
+        con.sock.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 64 << 10)
+        senders, per = 6, 12
+        sys.setswitchinterval(1e-5)
+
+        def chunk(s: int, i: int) -> bytes:
+            size = (1 << 20) if i % 2 == 0 else 100
+            return bytes([(s * per + i) % 251]) * size
+
+        def run(s: int):
+            for i in range(per):
+                con.send_message(_subwrite(s, i, chunk(s, i)))
+
+        threads = [threading.Thread(target=run, args=(s,))
+                   for s in range(1, senders + 1)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+            assert not t.is_alive()
+        assert _wait_for(lambda: len(p.got.got) == senders * per + 1, 60)
+        for s in range(1, senders + 1):
+            mine = [m for m in p.got.got if m.reqid[0] == s]
+            assert [m.reqid[1] for m in mine] == list(range(per))
+            assert all(m.chunk == chunk(s, m.reqid[1]) for m in mine)
+        assert p.sent("msg_send_queued") > 1        # 1 = the dial's own
+        assert (p.sent("msg_send_inline") + p.sent("msg_send_queued")
+                == p.sent("msg_send") == senders * per + 1)
+    finally:
+        sys.setswitchinterval(old_switch)
+        p.close()
+
+
+@pytest.mark.parametrize("road", ["fast", "slow"])
+@pytest.mark.parametrize("lossy", [False, True],
+                         ids=["stateful", "lossy"])
+def test_event_peer_socket_closed_mid_stream(lossy, road):
+    """The peer's socket dies while the client keeps sending.  On the
+    fast road the sender's own send() meets the dead socket, on the slow
+    road the loop thread's does; either way a stateful dialing
+    connection redials and delivers the unwritten tail in order, once,
+    and a lossy one resets once and delivers nothing more."""
+    policy = (ConnectionPolicy(lossy=True, resend_on_reconnect=False)
+              if lossy else None)
+    p = _EventPair(client_policy=policy)
+    gate, stalled = threading.Event(), threading.Event()
+    try:
+        con = p.settle()
+        # hold the client's loop thread so that only senders touch the
+        # socket until the gate opens
+        p.client.defer(lambda: (stalled.set(), gate.wait(20)))
+        assert stalled.wait(5)
+        acc = p.server._conns[f"accepted:{p.client.my_name}"]
+        acc.mark_down()
+        assert _wait_for(lambda: acc.sock is None)
+        # TCP takes one write after the peer's close and answers it with
+        # a reset; from then on a write fails
+        con.send_message(_subwrite(9, 0))
+        time.sleep(0.1)
+        inline0 = p.sent("msg_send_inline")
+        tail = [(2, i) for i in range(8)]
+
+        def send_tail():
+            for r in tail:
+                con.send_message(_subwrite(*r))
+
+        if road == "fast":
+            send_tail()
+        else:
+            # another writer holds the connection: senders queue
+            with con._wlock:
+                t = threading.Thread(target=send_tail)
+                t.start()
+                t.join(timeout=10)
+                assert not t.is_alive()
+        assert [m.reqid for m in con.backlog] == tail
+        assert p.sent("msg_send_inline") == inline0
+        gate.set()
+        if lossy:
+            assert _wait_for(lambda: len(p.client_got.resets) == 1)
+            assert not con.is_connected()
+            con.send_message(_subwrite(2, 99))
+            time.sleep(0.3)
+            assert p.client_got.resets == [con]
+            assert not [r for r in p.ids() if r[0] == 2]
+        else:
+            assert _wait_for(
+                lambda: [r for r in p.ids() if r[0] == 2] == tail), p.ids()
+            assert con.is_connected() and not p.client_got.resets
+            # the redialed connection settles back onto the fast road
+            assert _wait_for(lambda: not con.out_frames and not con.backlog)
+            inline1 = p.sent("msg_send_inline")
+            con.send_message(_subwrite(3, 0))
+            assert _wait_for(lambda: p.ids()[-1] == (3, 0))
+            assert p.sent("msg_send_inline") == inline1 + 1
+    finally:
+        gate.set()
+        p.close()
+
+
+def test_event_send_before_handshake_takes_the_loop():
+    p = _EventPair()
+    try:
+        con = p.connect()
+        con.send_message(_subwrite(1, 0))     # still dialing
+        assert _wait_for(lambda: p.ids() == [(1, 0)])
+        assert _wait_for(lambda: p.sent("msg_send_queued") == 1)
+        assert p.sent("msg_send_inline") == 0
+    finally:
+        p.close()
+
+
+def test_event_ici_token_connection_frames_on_the_loop(monkeypatch):
+    """Framing for a peer that negotiated FEATURE_ICI_TOKENS may stage
+    a device buffer, which can wait on the device: never on the
+    sender's thread."""
+    from ceph_tpu.msg.event_tcp import EventConnection
+    from ceph_tpu.msg.features import FEATURE_ICI_TOKENS
+
+    framed_on = []
+    frame = EventConnection._frame
+
+    def spy(self, msg):
+        framed_on.append(threading.current_thread().name)
+        return frame(self, msg)
+
+    monkeypatch.setattr(EventConnection, "_frame", spy)
+
+    def as_ici_wire(m):
+        m.ici_wire = True
+        m.local_features |= FEATURE_ICI_TOKENS
+
+    p = _EventPair(prepare=as_ici_wire)
+    try:
+        con = p.settle()
+        assert con.features & FEATURE_ICI_TOKENS
+        for i in range(1, 9):
+            con.send_message(_subwrite(1, i))
+        assert _wait_for(lambda: len(p.got.got) == 9)
+        assert p.ids()[1:] == [(1, i) for i in range(1, 9)]
+        assert p.sent("msg_send_inline") == 0
+        assert p.sent("msg_send_queued") == 9
+        assert set(framed_on) == {f"ms-ev:{p.client.my_name}"}
+    finally:
+        p.close()
