@@ -144,6 +144,12 @@ LAYERS = {
     "ec fan-out": LAYER_ECB, "ec sub-write": LAYER_ECB,
     "ec sub-write ack": LAYER_ECB, "osd reply": LAYER_ECB,
     "rep op": LAYER_ECB,
+    # the EC read path: the primary's gather (the wait for k shards),
+    # a shard's side of it, the decode engine's submit and continuation
+    "ec read prepare": LAYER_ECB, "ec read gather": LAYER_ECB,
+    "ec sub-read": LAYER_ECB, "ec sub-read reply": LAYER_ECB,
+    "ec decode submit": LAYER_ECB, "ec decode continuation": LAYER_ECB,
+    "ec read finish": LAYER_ECB,
     # ops/dispatch.py: the request and its phases; ops/telemetry.py
     "device": LAYER_ENGINE, "engine": LAYER_ENGINE,
     "kernel": LAYER_KERNELS,
@@ -152,6 +158,8 @@ LAYERS = {
     "bluestore csum settle": LAYER_STORE, "bluestore fsync": LAYER_STORE,
     "bluestore kv commit": LAYER_STORE,
     "bluestore on_commit": LAYER_STORE,
+    "bluestore read": LAYER_STORE, "bluestore read blocks": LAYER_STORE,
+    "bluestore csum verify": LAYER_STORE,
     "objectstore commit": LAYER_STORE,
     # osd/mapping.py
     "update_to": LAYER_MAPPING, "mapping": LAYER_MAPPING,

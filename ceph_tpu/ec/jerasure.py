@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from ceph_tpu.gf.tables import gf_inv, gf_mul, gf_pow
+from ceph_tpu.gf.tables import gf_inv, gf_mul, gf_pow, mul_table
 
 from .base import ErasureCode
 from .registry import register
@@ -48,35 +48,28 @@ def extended_vandermonde_matrix(rows: int, cols: int) -> np.ndarray:
 
 
 def big_vandermonde_distribution_matrix(rows: int, cols: int) -> np.ndarray:
-    """Systematic form of the extended Vandermonde (jerasure
-    reed_sol_big_vandermonde_distribution_matrix semantics): elementary column
-    ops make the top cols x cols block the identity, then coding rows are
-    scaled so their first column is all ones."""
+    """jerasure's reed_sol_big_vandermonde_distribution_matrix, step for
+    step: (1) column operations make the top cols x cols block of the
+    extended Vandermonde the identity; (2) each column is scaled so that row
+    `cols`, the first coding row, is all ones; (3) each later coding row is
+    scaled so that its first element is one.  reed_sol.c swaps rows at a
+    zero pivot; none occurs, since the leading minors are Vandermonde on the
+    distinct points 0..i (`gf_inv` would raise on one)."""
+    mul = mul_table()
     vdm = extended_vandermonde_matrix(rows, cols)
+    for i in range(1, cols):
+        if vdm[i, i] != 1:
+            vdm[:, i] = mul[gf_inv(int(vdm[i, i]))][vdm[:, i]]
+        for j in range(cols):
+            f = int(vdm[i, j])
+            if j != i and f:
+                vdm[:, j] ^= mul[f][vdm[:, i]]
     for j in range(cols):
-        if vdm[j, j] == 0:
-            for j2 in range(j + 1, cols):
-                if vdm[j, j2]:
-                    vdm[:, [j, j2]] = vdm[:, [j2, j]]
-                    break
-            else:
-                raise ValueError("extended Vandermonde unexpectedly singular")
-        d = int(vdm[j, j])
-        if d != 1:
-            dinv = gf_inv(d)
-            for i in range(rows):
-                vdm[i, j] = gf_mul(int(vdm[i, j]), dinv)
-        for j2 in range(cols):
-            f = int(vdm[j, j2])
-            if j2 != j and f:
-                for i in range(rows):
-                    vdm[i, j2] ^= gf_mul(f, int(vdm[i, j]))
-    for i in range(cols, rows):
-        d = int(vdm[i, 0])
-        if d and d != 1:
-            dinv = gf_inv(d)
-            for j in range(cols):
-                vdm[i, j] = gf_mul(int(vdm[i, j]), dinv)
+        if vdm[cols, j] != 1:
+            vdm[cols:, j] = mul[gf_inv(int(vdm[cols, j]))][vdm[cols:, j]]
+    for i in range(cols + 1, rows):
+        if vdm[i, 0] != 1:
+            vdm[i] = mul[gf_inv(int(vdm[i, 0]))][vdm[i]]
     return vdm
 
 

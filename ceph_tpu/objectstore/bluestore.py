@@ -661,17 +661,23 @@ class BlueStoreLite(ObjectStore):
         if eng is None:
             return {}
         stored = []
-        for bi in bis:
-            comp = co[bi] if bi < len(co) else None
-            data = self._read_block(meta["extents"][bi])
-            stored.append(data[:comp[1]] if comp else data)
+        with tracing.span("bluestore read blocks", daemon="bluestore",
+                          blocks=len(bis)):
+            for bi in bis:
+                comp = co[bi] if bi < len(co) else None
+                data = self._read_block(meta["extents"][bi])
+                stored.append(data[:comp[1]] if comp else data)
         from ceph_tpu.ops import telemetry
         from ceph_tpu.ops.dispatch import submit_bluestore_data
         try:
-            dig = submit_bluestore_data(
-                eng, stored, cost_tag=("_bluestore", "read")).result(
-                timeout=float(self._conf("bluestore_data_timeout",
-                                         30.0)))
+            # the digest batch: the engine's `device <kernel>` request
+            # span parents under this one, which waits for it
+            with tracing.span("bluestore csum verify", daemon="bluestore",
+                              blocks=len(bis)):
+                dig = submit_bluestore_data(
+                    eng, stored, cost_tag=("_bluestore", "read")).result(
+                    timeout=float(self._conf("bluestore_data_timeout",
+                                             30.0)))
         except Exception:
             telemetry.bluestore_stats().inc("csum_fallbacks")
             return {}
@@ -1084,7 +1090,11 @@ class BlueStoreLite(ObjectStore):
         return m
 
     def read(self, cid, oid, offset=0, length=None) -> bytes:
-        with self._lock:
+        # read span on the calling op's trace, the commit span's twin:
+        # the store lock's wait, the block reads and the checksum
+        # verification (no-op context when the thread is untraced)
+        with tracing.span("bluestore read", daemon="bluestore"), \
+                self._lock:
             m = self._get_checked(cid, oid)
             if length is None:
                 length = m["size"] - offset

@@ -40,6 +40,7 @@ import time
 from contextlib import contextmanager
 
 from ceph_tpu.common import tracing
+from ceph_tpu.common.allocator import pin_malloc_thresholds
 from ceph_tpu.common.context import CephTpuContext
 from ceph_tpu.common.logging import dout
 from ceph_tpu.common.perf_counters import PerfCountersBuilder
@@ -347,6 +348,8 @@ class OSDDaemon(Dispatcher):
                      .add_u64("ec_dispatch_submits")
                      .add_u64("ec_dispatch_commits")
                      .add_u64("ec_decode_submits")
+                     .add_u64("ec_degraded_reads")
+                     .add_u64("ec_decode_targets")
                      .add_u64("recovery_decode_stripes")
                      .add_u64("map_epochs")
                      .add_u64("map_pgs_scanned")
@@ -672,6 +675,8 @@ class OSDDaemon(Dispatcher):
     # -- lifecycle (OSD::init, ceph_osd.cc main) ------------------------------
 
     def init(self) -> None:
+        # object-sized buffers from here on: see common/allocator.py
+        pin_malloc_thresholds()
         self.store.mkfs_if_needed()
         self.store.mount()
         self._load_pgs()
@@ -3637,31 +3642,36 @@ class OSDDaemon(Dispatcher):
         op carries the byte range; today full shards travel and the
         whole object decodes before slicing (ranged shard reads over
         the wire are a known optimization, not yet done)."""
-        codec = self._codec(pool)
-        k = codec.get_data_chunk_count()
-        n = codec.get_chunk_count()
-        reqid = (msg.client_id, msg.tid)
-        pg = self.pgs.get(msg.pgid)
-        cand = (self._ec_shard_candidates(pg, n) if pg is not None
-                else {s: [up[s]] for s in range(min(n, len(up)))
-                      if up[s] != CEPH_NOSD})
-        if sum(1 for c in cand.values() if c) < k:
-            # fewer than k shards locatable: unreadable this epoch
-            self._reply_err(msg, -5)
-            return
-        entry = pg.log.index.get(msg.oid) if pg is not None else None
-        state = {"kind": "client", "msg": msg, "pool": pool,
-                 "pgid": msg.pgid, "oid": msg.oid,
-                 "off": op.offset if op is not None else 0,
-                 "len": op.length if op is not None else 0,
-                 # the logged version pins the stripe: past-interval
-                 # holders may serve stale chunks that must not be mixed
-                 # into the decode
-                 "need": entry.version if entry is not None
-                 and not entry.is_delete() else None,
-                 "shards": {}, "k": k, "active": set(), "cand": cand}
-        with self._lock:
-            self._ec_reads[reqid] = state
+        with tracing.span("ec read prepare", daemon=self._tname):
+            codec = self._codec(pool)
+            k = codec.get_data_chunk_count()
+            n = codec.get_chunk_count()
+            reqid = (msg.client_id, msg.tid)
+            pg = self.pgs.get(msg.pgid)
+            cand = (self._ec_shard_candidates(pg, n) if pg is not None
+                    else {s: [up[s]] for s in range(min(n, len(up)))
+                          if up[s] != CEPH_NOSD})
+            if sum(1 for c in cand.values() if c) < k:
+                # fewer than k shards locatable: unreadable this epoch
+                self._reply_err(msg, -5)
+                return
+            entry = pg.log.index.get(msg.oid) if pg is not None else None
+            state = {"kind": "client", "msg": msg, "pool": pool,
+                     "pgid": msg.pgid, "oid": msg.oid,
+                     "off": op.offset if op is not None else 0,
+                     "len": op.length if op is not None else 0,
+                     # the logged version pins the stripe: past-interval
+                     # holders may serve stale chunks that must not be
+                     # mixed into the decode
+                     "need": entry.version if entry is not None
+                     and not entry.is_delete() else None,
+                     "shards": {}, "k": k, "active": set(), "cand": cand}
+            with self._lock:
+                self._ec_reads[reqid] = state
+        # the wait for k shards, as a span of the op's tree: opened
+        # here, closed by whichever thread brings the k-th shard in;
+        # every sub-read is sent under it (None on an untraced thread)
+        state["gspan"] = tracing.begin_span("ec read gather", self._tname)
         self._ec_gather(reqid, state)
 
     def _ec_gather(self, reqid, state: dict) -> None:
@@ -3696,11 +3706,20 @@ class OSDDaemon(Dispatcher):
                     osd = state["cand"][pick].pop(0)
                     state["active"].add(pick)
             if give_up:
+                tracing.finish_span(state.get("gspan"))
                 self._ec_read_give_up(state)
                 return
             self._ec_ask(reqid, state, pick, osd)
 
     def _ec_ask(self, reqid, state: dict, shard: int, osd: int) -> None:
+        # a sub-read belongs to the gather that waits for it, whichever
+        # thread asks (the first asks come from the op's handler, a
+        # retry from the thread that saw the failure)
+        gspan = state.get("gspan")
+        if gspan is not None and tracing.current_span() != gspan.span_id:
+            with tracing.joined(gspan.trace_id, gspan.span_id):
+                self._ec_ask(reqid, state, shard, osd)
+            return
         pgid = state["pgid"]
         oid = state["oid"]
         if osd == self.osd_id:
@@ -3750,6 +3769,11 @@ class OSDDaemon(Dispatcher):
         self._ec_read_done(reqid, shard, *got)
 
     def _handle_ec_read(self, msg: MOSDECSubOpRead) -> None:
+        with tracing.span("ec sub-read", daemon=self._tname,
+                          shard=msg.shard):
+            self._do_handle_ec_read(msg)
+
+    def _do_handle_ec_read(self, msg: MOSDECSubOpRead) -> None:
         pool = self.osdmap.pools.get(msg.pgid[0])
         if pool is not None and self._park_subop(
                 self._handle_ec_read, msg, pool):
@@ -3768,12 +3792,14 @@ class OSDDaemon(Dispatcher):
             chunk=chunk + size.to_bytes(8, "little")))
 
     def _handle_ec_read_reply(self, msg: MOSDECSubOpReadReply) -> None:
-        if msg.result != 0:
-            self._ec_read_failed(msg.reqid, msg.shard)
-            return
-        chunk, size = msg.chunk[:-8], int.from_bytes(msg.chunk[-8:],
-                                                     "little")
-        self._ec_read_done(msg.reqid, msg.shard, chunk, size, msg.ver)
+        with tracing.span("ec sub-read reply", daemon=self._tname,
+                          shard=msg.shard):
+            if msg.result != 0:
+                self._ec_read_failed(msg.reqid, msg.shard)
+                return
+            chunk, size = msg.chunk[:-8], int.from_bytes(msg.chunk[-8:],
+                                                         "little")
+            self._ec_read_done(msg.reqid, msg.shard, chunk, size, msg.ver)
 
     def _ec_read_failed(self, reqid, shard: int) -> None:
         with self._lock:
@@ -3845,6 +3871,15 @@ class OSDDaemon(Dispatcher):
         if stale:
             self._ec_gather(reqid, state)
             return
+        # k shards are in: the gather's wait is over (a widened gather
+        # closes its span again, later)
+        tracing.finish_span(state.get("gspan"))
+        if state["kind"] == "client" and not state.get("counted") \
+                and any(s >= state["k"] for s in state["shards"]):
+            # a parity shard stood in for a data shard that no holder
+            # could give
+            state["counted"] = True
+            self.perf.inc("ec_degraded_reads")
         if self._ec_submit_decode(reqid, state):
             # submit-and-continue: the decode rides the decode engine
             # (coalescing with every other in-flight gather's decode —
@@ -3892,23 +3927,27 @@ class OSDDaemon(Dispatcher):
         if all(s < k for s in sorted(state["shards"])[:k]):
             return False
         size = state["size"]
-        chosen, arr, targets, stripes = self._ec_gathered_stripes(
-            si, k, state["shards"], size)
-        # targets cannot be empty here: the pre-check above bailed on
-        # the all-data-shards case, so at least one parity shard is in
-        # `chosen` and at least one data row is missing
-        engine = self.ctx.decode_dispatch_engine()
         if state["kind"] == "recover":
             tag = ("recovery", "recovery")
         else:
             tag = (getattr(state.get("msg"), "qos_tenant", "")
                    or "client", "client")
-        try:
-            fut = codec.submit_decode_chunks(engine, chosen, arr,
-                                             targets, cost_tag=tag)
-        except (ValueError, IOError):
-            return False
+        # the engine's `device <kernel>` request span parents under
+        # this one, and the continuation under its delivery
+        with tracing.span("ec decode submit", daemon=self._tname):
+            chosen, arr, targets, stripes = self._ec_gathered_stripes(
+                si, k, state["shards"], size)
+            # targets cannot be empty here: the pre-check above bailed
+            # on the all-data-shards case, so at least one parity shard
+            # is in `chosen` and at least one data row is missing
+            engine = self.ctx.decode_dispatch_engine()
+            try:
+                fut = codec.submit_decode_chunks(engine, chosen, arr,
+                                                 targets, cost_tag=tag)
+            except (ValueError, IOError):
+                return False
         self.perf.inc("ec_decode_submits")
+        self.perf.inc("ec_decode_targets", len(targets))
         if state["kind"] == "recover":
             self.perf.inc("recovery_decode_stripes", int(arr.shape[0]))
         trk = getattr(state.get("msg"), "_trk", None)
@@ -3936,12 +3975,22 @@ class OSDDaemon(Dispatcher):
             return
         from types import SimpleNamespace
         # straight into the shard queue: no payload to throttle, and the
-        # completion thread must not block on the intake throttle either
+        # completion thread must not block on the intake throttle either.
+        # A traced delivery's queue wait is a span of the op's tree, as
+        # an op's own is (_enqueue_op): the worker runs fn under it
+        qspan = tracing.begin_span("opq wait", self._tname)
         self.opwq.enqueue(pgid, "subop",
-                          (lambda _carrier: fn(), SimpleNamespace(), 0))
+                          (lambda _carrier: fn(), SimpleNamespace(), 0,
+                           qspan))
 
     def _ec_decode_done(self, reqid, state: dict, si, stripes, targets,
                         size: int, fut) -> None:
+        with tracing.span("ec decode continuation", daemon=self._tname):
+            self._do_ec_decode_done(reqid, state, si, stripes, targets,
+                                    size, fut)
+
+    def _do_ec_decode_done(self, reqid, state: dict, si, stripes, targets,
+                           size: int, fut) -> None:
         """Decode-engine completion continuation (handed to an op-queue
         worker by _off_engine_thread): overlay the rebuilt rows and
         finish the gather — client reply, rmw overlay-and-drain, or
@@ -3979,6 +4028,10 @@ class OSDDaemon(Dispatcher):
         self._ec_read_finish(reqid, state, data)
 
     def _ec_read_finish(self, reqid, state: dict, data: bytes) -> None:
+        with tracing.span("ec read finish", daemon=self._tname):
+            self._do_ec_read_finish(reqid, state, data)
+
+    def _do_ec_read_finish(self, reqid, state: dict, data: bytes) -> None:
         """Reconstructed object bytes in hand (synchronous decode or
         decode-engine continuation): complete the gather by kind."""
         if state["kind"] == "rmw":

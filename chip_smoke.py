@@ -10,7 +10,10 @@ deployment's widths, and checks every result by the repo's own means:
               units, driven from the client by tools/rados_bench.ObjBencher
               at `rados bench` defaults (4 MiB objects, 16 in flight):
               seeded objects written, every one read back and compared,
-              then an OSD taken down and every object read back degraded.
+              the parity shards the OSDs stored of a seeded sample compared
+              with jerasure's reed_sol_van as the plain reference builds it
+              (perfbench/reference/rs_plain.py), then an OSD taken down and
+              every object read back degraded.
   placement   65,536 PGs on the 10,000-OSD two-level map through
               BatchMapper.do_rule as tools/crush_test reaches it, compared
               lane for lane with the scalar oracle on a seeded sample and
@@ -67,6 +70,7 @@ class Sizes:
     depth: int = 16                 # rados bench default
     n_objects: int = 64             # 256 MiB of user bytes
     degraded_min: int = 8
+    parity_sample: int = 8          # objects whose stored parity is compared
     hosts: int = 250
     per_host: int = 40
     n_pgs: int = 65536
@@ -147,6 +151,38 @@ class CompileWatch:
 # phase 1: the served EC path
 # ---------------------------------------------------------------------------
 
+def stored_parity(sz: Sizes, seed: int, daemons, name_of, payload_of,
+                  stripe_unit: int) -> dict:
+    """Of a seeded sample of the objects written, the parity shards the
+    OSDs' stores hold (store object ``<name>:<shard>``) against the plain
+    reference's.  A read-back passes on any MDS code that the program
+    decodes as it encodes; only this says the code is the profile's."""
+    from perfbench.reference import rs_plain
+    rng = np.random.default_rng((seed, 0x9a71))
+    sample = sorted(rng.choice(
+        sz.n_objects, min(sz.parity_sample, sz.n_objects),
+        replace=False).tolist())
+    where: dict[str, list] = {}
+    for d in daemons:
+        for cid in d.store.list_collections():
+            for soid in d.store.list_objects(cid):
+                where.setdefault(soid, []).append((d.store, cid))
+    differ = []
+    for i in sample:
+        want = rs_plain.shards_of(payload_of(i), sz.k, sz.m, stripe_unit)
+        for s in range(sz.k, sz.k + sz.m):
+            soid = f"{name_of(i)}:{s}"
+            holders = where.get(soid, [])
+            if not holders or any(store.read(cid, soid) != want[s]
+                                  for store, cid in holders):
+                differ.append(soid)
+    require(not differ,
+            f"{len(differ)} of {len(sample) * sz.m} stored parity shards "
+            f"are missing or not reed_sol_van's: {differ[:4]}")
+    return {"objects": len(sample), "shards_compared": len(sample) * sz.m,
+            "shards_differ": 0}
+
+
 def served_ec(sz: Sizes, seed: int, base_path: str) -> dict:
     from ceph_tpu.ops import telemetry
     from ceph_tpu.tools.rados_bench import ObjBencher
@@ -188,6 +224,11 @@ def served_ec(sz: Sizes, seed: int, base_path: str) -> dict:
         run("write", bench.write_bench, payload_of=payload_of)
         run("read", bench.seq_read_bench, n_objects=sz.n_objects,
             payload_of=payload_of)
+        pg_pool = cluster.mon.osdmap.pools[pool]
+        stripe_unit = daemons[0]._ec_stripe_info(
+            daemons[0]._codec(pg_pool), pg_pool).su
+        facts["parity"] = stored_parity(sz, seed, daemons, bench._obj,
+                                        payload_of, stripe_unit)
 
         # one OSD down: every PG spans all k+m OSDs, so each object
         # lost a shard, and those that lost a data shard are rebuilt by
@@ -211,12 +252,9 @@ def served_ec(sz: Sizes, seed: int, base_path: str) -> dict:
         require(decoded >= min(sz.degraded_min, sz.n_objects),
                 f"only {decoded} reads went through the decode engine")
         facts["user_bytes"] = sz.n_objects * sz.obj_size
-        pg_pool = cluster.mon.osdmap.pools[pool]
-        osd = daemons[0]
         facts["pool"] = {
             "k": sz.k, "m": sz.m, "pg_num": pg_pool.pg_num,
-            "stripe_unit": osd._ec_stripe_info(osd._codec(pg_pool),
-                                               pg_pool).su,
+            "stripe_unit": stripe_unit,
             "osds": sz.n_osds, "store": "bluestore"}
     finally:
         cluster.stop()

@@ -1,0 +1,5 @@
+"""`gf_encode_roofline` for a lone writer's cell: the parked cell holds the
+unsuffixed name, and the arithmetic is its reader's."""
+from perfbench.harness.manifest import load_reader
+
+read = load_reader("gf_encode_roofline")

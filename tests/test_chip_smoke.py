@@ -81,6 +81,8 @@ def test_phases_at_toy_size(on_cpu, capsys):
     assert ec["write"]["total_writes_or_reads"] == TOY.n_objects
     assert ec["read"]["errors"] == ec["degraded_read"]["errors"] == 0
     assert ec["degraded_read"]["decode_submits"] >= 1
+    assert ec["parity"] == {"objects": TOY.n_objects, "shards_differ": 0,
+                            "shards_compared": TOY.n_objects * TOY.m}
     assert facts["placement"]["xla_lanes_equal"] == TOY.n_pgs
     assert facts["map_epochs"]["mapping"]["fused_epochs"] == 4
     proof = json.loads(next(ln for ln in lines
@@ -101,6 +103,18 @@ def test_forced_fallback_exits_nonzero(on_cpu, capsys):
         failpoint.clear("dispatch.launch:ec_encode")
     out = capsys.readouterr().out
     assert "phase served_ec" in out and '"ok"' not in out
+
+
+def test_wrong_parity_exits_nonzero(on_cpu, capsys):
+    """Every object reads back, degraded too, from parity that is not
+    the profile's: the proof may not pass on that."""
+    from perfbench import faults
+    with faults.plant("altered_parity"):
+        with pytest.raises(chip_smoke.SmokeFailure,
+                           match="stored parity shards"):
+            chip_smoke.main([], sizes=TOY)
+    out = capsys.readouterr().out
+    assert "phase served_ec" not in out and '"ok"' not in out
 
 
 def test_mesh_mode_runs_only_the_mesh_phase(on_cpu, capsys):

@@ -1,8 +1,9 @@
 """The program's span trees where the traffic is: ``aio_write_full`` on
-an EC pool and one ``update_to`` epoch give one complete tree each,
-with a span of every layer on it and a critical path that is named end
-to end; a live profiler session arms the roots and receives the
-same-thread spans as annotations; unarmed, nothing is recorded."""
+an EC pool, ``aio_read`` of an object that lost a shard and one
+``update_to`` epoch give one complete tree each, with a span of every
+layer on it and a critical path that is named end to end; a live
+profiler session arms the roots and receives the same-thread spans as
+annotations; unarmed, nothing is recorded."""
 
 from __future__ import annotations
 
@@ -287,3 +288,171 @@ def test_profiler_session_arms_roots_and_receives_annotations(
     # writes after the session are not traced
     _write(io, ["after"])
     assert len(tracing.trace_ids()) == 3
+
+
+# -- the EC read path -----------------------------------------------------------
+
+#: four whole 4+2 stripes of 16 KiB chunks: a shard is 16 blocks, over
+#: `bluestore_batched_read_min`, so a shard read verifies by the digest
+#: batch as a 4 MiB object's shards do
+READ_SIZE = 4 * 4 * 16384
+DOWN_OSD = 1
+
+
+@pytest.fixture(scope="module")
+def degraded_cluster(tmp_path_factory):
+    """A 4+2 pool on six OSDs with eight objects written, then one OSD
+    killed and marked down (and left in): every PG is a shard short.
+    Its own cluster: the kill may not reach the other tests' pool."""
+    from ceph_tpu.tools.vstart import MiniCluster
+    c = MiniCluster(n_osds=6, store_type="bluestore", ms_type="async",
+                    base_path=str(tmp_path_factory.mktemp("degraded"))
+                    ).start()
+    try:
+        c.wait_for_osd_count(6)
+        client = c.client(timeout=30.0)
+        pool = c.create_pool(client, pool_type="erasure", k=4, m=2,
+                             pg_num=8, stripe_unit=16384)
+        io = client.open_ioctx(pool)
+        rng = np.random.default_rng(31)
+        blobs = {f"deg-{i}": rng.bytes(READ_SIZE) for i in range(8)}
+        for name, blob in blobs.items():
+            io.write_full(name, blob)
+        lost_data = {name for name in blobs
+                     if any(f"{name}:{s}" in c.osds[DOWN_OSD].store
+                            .list_objects(cid) for s in range(4)
+                            for cid in c.osds[DOWN_OSD].store
+                            .list_collections())}
+        c.kill_osd(DOWN_OSD)
+        rc, out = client.mon_command({"prefix": "osd down",
+                                      "id": str(DOWN_OSD)})
+        assert rc == 0, out
+        c.wait_for_epoch(c.mon.osdmap.epoch)
+        client.wait_for_epoch(c.mon.osdmap.epoch)
+        for name, blob in blobs.items():    # patterns met, programs compiled
+            assert io.read(name) == blob
+        yield c, io, blobs, lost_data
+    finally:
+        c.stop()
+
+
+def _read(io, blobs):
+    done = {n: io.aio_read(n) for n in blobs}
+    for n, c in done.items():
+        assert c.wait_for_complete(30.0) and c.get_return_value() >= 0
+        assert c.data == blobs[n]
+    time.sleep(0.2)
+
+
+def _counters(cluster) -> dict:
+    return {key: sum(d.perf.value(key) for d in cluster.osds.values())
+            for key in ("ec_decode_submits", "ec_degraded_reads",
+                        "ec_decode_targets")}
+
+
+def test_degraded_aio_read_gives_one_complete_tree_per_op(degraded_cluster):
+    c, io, blobs, lost_data = degraded_cluster
+    assert 0 < len(lost_data) < len(blobs)
+    before = _counters(c)
+    tracing.set_sample_rate(1.0)
+    _read(io, blobs)
+    tracing.set_sample_rate(0.0)
+    # always-on counters: one submit and one rebuilt shard for every
+    # object whose data shard was on the dead OSD
+    after = _counters(c)
+    assert {k: after[k] - before[k] for k in after} == {
+        "ec_decode_submits": len(lost_data),
+        "ec_degraded_reads": len(lost_data),
+        "ec_decode_targets": len(lost_data)}
+    traces = {span_readers.root_of(t)["event"][len("osd_op "):]: t
+              for t in _op_traces()}
+    assert sorted(traces) == sorted(blobs)
+    for name, rows in traces.items():
+        spans = [r for r in rows if r["kind"] == "span"]
+        by_id = {r["span_id"]: r for r in spans}
+        root = span_readers.root_of(rows)
+        # ONE tree, every span closed
+        assert all(r["parent_span_id"] in by_id
+                   for r in spans if r is not root)
+        assert all(r["end_ns"] is not None for r in spans), \
+            [r["event"] for r in spans if r["end_ns"] is None]
+        names = [r["event"] for r in spans]
+        for want in ("client submit", "msg MOSDOp", "rx MOSDOp",
+                     "opq wait", "osd op", "ec read prepare",
+                     "ec read gather", "msg MOSDECSubOpRead",
+                     "ec sub-read", "msg MOSDECSubOpReadReply",
+                     "ec sub-read reply", "bluestore read",
+                     "bluestore read blocks", "bluestore csum verify",
+                     "device bluestore_data", "ec read finish",
+                     "osd reply", "msg MOSDOpReply", "client complete"):
+            assert want in names, (name, want, sorted(set(names)))
+        assert names.count("ec read gather") == 1
+        # k shards: the primary's own and k - 1 asked for over the wire
+        assert names.count("bluestore read") == 4
+        assert names.count("ec sub-read") == 3
+        assert all(r["layer"] == tracing.LAYER_ECB for r in spans
+                   if r["event"].startswith("ec "))
+        assert all(r["layer"] == tracing.LAYER_STORE for r in spans
+                   if r["event"].startswith("bluestore "))
+
+        def parent(r):
+            return by_id[r["parent_span_id"]]["event"]
+
+        def ancestors(r):
+            while r["parent_span_id"] in by_id:
+                r = by_id[r["parent_span_id"]]
+                yield r["event"]
+
+        # asynchronous work hangs under the span that waits for it:
+        # a sub-read under the gather, a digest batch under the verify
+        gather = next(r for r in spans if r["event"] == "ec read gather")
+        for r in spans:
+            if r["event"] in ("msg MOSDECSubOpRead", "ec sub-read",
+                              "ec sub-read reply", "bluestore read"):
+                assert "ec read gather" in ancestors(r), r["event"]
+            if r["event"] == "device bluestore_data":
+                assert parent(r) == "bluestore csum verify"
+            if r["event"] in ("bluestore read blocks",
+                              "bluestore csum verify"):
+                assert parent(r) == "bluestore read"
+        assert gather["end_ns"] >= max(
+            r["start_ns"] for r in spans
+            if r["event"] == "ec sub-read reply")
+        decoded = name in lost_data
+        for want in ("ec decode submit", "device ec_decode",
+                     "ec decode continuation"):
+            assert (want in names) == decoded, (name, want)
+        if decoded:
+            req = next(r for r in spans if r["event"] == "device ec_decode")
+            assert parent(req) == "ec decode submit"
+            cont = next(r for r in spans
+                        if r["event"] == "ec decode continuation")
+            # handed from the engine's delivery to an op-queue worker
+            assert parent(cont) == "opq wait"
+            assert list(ancestors(cont))[1:3] == ["engine deliver",
+                                                  "device ec_decode"]
+            finish = next(r for r in spans
+                          if r["event"] == "ec read finish")
+            assert parent(finish) == "ec decode continuation"
+        # the critical path is named end to end and holds every layer
+        path = span_readers.critical_path(rows)
+        assert OP_LAYERS <= set(path), path
+        assert path["unnamed"] <= UNNAMED_MAX * path["root"], path
+        assert sum(v for k, v in path.items() if k != "root") \
+            == path["root"]
+        on_path = [r["event"] for r, _d in span_readers.path_spans(rows)]
+        # of the gather's sub-reads only the last to arrive is on it
+        assert on_path.count("ec sub-read") <= 1
+        assert {"client submit", "ec read gather", "ec read finish",
+                "osd reply", "client complete"} <= set(on_path)
+        if decoded:
+            assert {"ec decode submit", "device ec_decode",
+                    "engine deliver", "ec decode continuation"} \
+                <= set(on_path)
+
+
+def test_unarmed_degraded_reads_record_nothing(degraded_cluster):
+    _c, io, blobs, _lost = degraded_cluster
+    assert not tracing.armed()
+    _read(io, blobs)
+    assert tracing.trace_ids() == []
