@@ -180,15 +180,21 @@ def test_froot_columns(one_chip, monkeypatch):
 
 # -- checksum, compression, placement ladder ----------------------------------
 
-def test_block_digest(one_chip):
-    """One shard write's BlueStore blocks: 128 x 4 KiB (also the scrub
-    digest; the two channels share the program)."""
-    from ceph_tpu.ops.checksum_kernel import _jit_digest
-    s, w = 128, 4096
-    _jit_digest().lower(
+@pytest.mark.parametrize("s,w", [(128, 4096), (16, 65536), (4, 262144)])
+def test_block_digest(one_chip, s, w):
+    """One shard write's BlueStore blocks, 128 x 4 KiB (also the scrub
+    digest; the two channels share the program), and deep scrub's wide
+    rows, which go through the segment product and the fold.  The
+    compiled program is a matrix product and holds no loop."""
+    from ceph_tpu.ops import checksum_kernel as ck
+    lin = tuple(_spec(one_chip, m.shape, m.dtype)
+                for m in ck.linear_operands(w))
+    text = ck._jit_digest().lower(
         _spec(one_chip, (s, w), jnp.uint8),
         _spec(one_chip, (s, 32), jnp.uint32),
-        _spec(one_chip, (s, 4), jnp.uint8), w=w).compile()
+        _spec(one_chip, (s, 4), jnp.uint8), lin, w=w).compile().as_text()
+    assert "convolution(" in text or " dot(" in text
+    assert "while(" not in text
 
 
 def test_bitplane_transpose(one_chip):
