@@ -25,16 +25,13 @@ from .osd.osdmap import CEPH_NOSD, CRUSH_ITEM_NONE, OSDMap
 
 def _shared_service(osdmap: OSDMap):
     """The default context's shared mapping cache, warmed to this map
-    (osd.mapping.SharedPGMappingService) — None when the
-    osdmap_mapping_shared knob is off or warming fails.  The balancer
-    reads the same epoch-keyed tables every other consumer does; every
-    read still falls back to the scalar oracle on a cache miss."""
+    (osd.mapping.SharedPGMappingService) — None when warming fails.
+    The balancer reads the same epoch-keyed tables every other consumer
+    does; every read still falls back to the scalar oracle on a cache
+    miss."""
     try:
         from .common.context import default_context
-        ctx = default_context()
-        if not ctx.conf.get("osdmap_mapping_shared"):
-            return None
-        svc = ctx.mapping_service()
+        svc = default_context().mapping_service()
         svc.warm(osdmap)
         return svc
     except Exception:
@@ -60,7 +57,8 @@ def _candidate_osds(osdmap: OSDMap) -> list[int]:
 def pool_pg_histogram(osdmap: OSDMap, pool_id: int, service=None
                       ) -> dict[int, list[tuple[int, int]]]:
     """osd -> [(pgid_ps, position)] placements for one pool, read from
-    the shared mapping cache (scalar per-PG pipeline when disabled)."""
+    the shared mapping cache (scalar per-PG pipeline when it could
+    not be warmed)."""
     pool = osdmap.pools[pool_id]
     svc = service if service is not None else _shared_service(osdmap)
     out: dict[int, list[tuple[int, int]]] = {}

@@ -182,13 +182,8 @@ class RadosClient(Dispatcher):
         #: op targeting reads the context's shared epoch-keyed mapping
         #: cache (Objecter-side OSDMapMapping): _calc_target becomes a
         #: cached-raw pipeline tail instead of a scalar crush_do_rule
-        #: per op.  Hot-togglable; any epoch mismatch falls back to the
-        #: scalar oracle, so correctness never depends on the cache.
-        self._map_shared = bool(
-            self.ctx.conf.get("osdmap_mapping_shared"))
-        self.ctx.conf.add_observer(
-            "osdmap_mapping_shared",
-            lambda _n, v: setattr(self, "_map_shared", bool(v)))
+        #: per op.  Any epoch mismatch falls back to the scalar oracle,
+        #: so correctness never depends on the cache.
         #: newest-map slot + single background warm worker: map storms
         #: must neither stall the dispatch thread nor spawn a thread
         #: per epoch (the slot keeps only the latest, matching the
@@ -338,20 +333,19 @@ class RadosClient(Dispatcher):
                 # backfill (it sends the chain or a full map)
                 self._subscribe()
                 return True
-            if self._map_shared:
-                # warm the shared cache in the BACKGROUND: the op path
-                # must never stall behind a table build (a light client
-                # on a many-pool cluster would otherwise pay an
-                # OSD-sized rebuild on its dispatch thread); until the
-                # build lands, targeting falls back to the scalar
-                # oracle per op — exactly the seed's cost
-                with self._lock:
-                    self._warm_latest = newmap
-                    if self._warm_thread is None:
-                        self._warm_thread = threading.Thread(
-                            target=self._warm_worker, daemon=True,
-                            name="rados-map-warm")
-                        self._warm_thread.start()
+            # warm the shared cache in the BACKGROUND: the op path
+            # must never stall behind a table build (a light client
+            # on a many-pool cluster would otherwise pay an
+            # OSD-sized rebuild on its dispatch thread); until the
+            # build lands, targeting falls back to the scalar
+            # oracle per op — exactly the seed's cost
+            with self._lock:
+                self._warm_latest = newmap
+                if self._warm_thread is None:
+                    self._warm_thread = threading.Thread(
+                        target=self._warm_worker, daemon=True,
+                        name="rados-map-warm")
+                    self._warm_thread.start()
             self._map_event.set()
             for w in pending:   # resend on map change (Objecter semantics)
                 self._resend_op(w)
@@ -514,12 +508,10 @@ class RadosClient(Dispatcher):
     def _pg_mapping(self, pool_id: int, pgid: int
                     ) -> tuple[list[int], int, list[int], int]:
         """(up, up_primary, acting, acting_primary) — shared mapping
-        cache when enabled (scalar-oracle fallback on any epoch or
-        object mismatch), else the scalar pipeline."""
-        if self._map_shared:
-            return self.ctx.mapping_service().lookup(
-                self.osdmap, pool_id, pgid)
-        return self.osdmap.pg_to_up_acting_osds(pool_id, pgid)
+        cache (scalar-oracle fallback on any epoch or object
+        mismatch)."""
+        return self.ctx.mapping_service().lookup(
+            self.osdmap, pool_id, pgid)
 
     def _send_op(self, w: _Waiter) -> None:
         if w.fixed_pgid is not None:

@@ -68,30 +68,12 @@ register_options([
            "plugins preloaded at init (options.cc:2197 analog)"),
     Option("erasure_code_runtime", OPT_STR, "tpu",
            "default EC execution runtime: tpu | cpu"),
-    Option("crush_backend", OPT_STR, "tpu",
-           "bulk placement backend: tpu (BatchMapper) | scalar"),
     Option("osdmap_mapping_min_pgs", OPT_INT, 1024,
            "pools with fewer PGs than this rebuild their cached raw "
            "tables with the scalar rule engine instead of a device "
            "call (per-call dispatch + jit-compile overhead dominates "
            "tiny pools); the epoch cache, incremental invalidation "
            "and delta detection are identical either way"),
-    Option("osdmap_mapping_fused", OPT_BOOL, True,
-           "fuse the post-CRUSH placement pipeline tail (upmap -> "
-           "up/state filter -> primary affinity -> pg_temp/"
-           "primary_temp) into one device ladder per epoch "
-           "(ops.placement_kernel): the mapping service publishes "
-           "packed (up, acting, primaries) tables next to the raw "
-           "ones, reads become row slices, and epoch deltas diff the "
-           "fused outputs on device; off (or crush_backend=scalar) = "
-           "the per-PG host pipeline tail of PR 5"),
-    Option("osdmap_mapping_shared", OPT_BOOL, True,
-           "serve PG->OSD mappings from the context's shared "
-           "epoch-keyed mapping cache (osd.mapping."
-           "SharedPGMappingService): OSD map consumption becomes "
-           "O(changed PGs + local PGs), client op targeting and the "
-           "balancer read cached raw placements; off = every consumer "
-           "runs the scalar pg_to_up_acting_osds pipeline per PG"),
     Option("osd_pool_default_size", OPT_INT, 3, "replicas per object"),
     Option("mds_dentry_lease_ttl", OPT_FLOAT, 10.0,
            "seconds a client may trust a leased dentry+attrs without "
@@ -179,18 +161,6 @@ register_options([
            "mesh): 0 = all local devices, 1 = single-device (exact "
            "pre-mesh engine behavior), N = the first N devices; "
            "ignored when the backend exposes one device"),
-    Option("osd_ec_dispatch_async", OPT_BOOL, True,
-           "submit EC write encodes through the dispatch engine and "
-           "run transaction-build + shard fan-out in the completion "
-           "continuation, letting concurrent client writes share one "
-           "device call; off = encode synchronously per op"),
-    Option("osd_ec_decode_async", OPT_BOOL, True,
-           "submit EC decodes (degraded reads, recovery pulls, rmw "
-           "gathers) through the decode dispatch engine and finish "
-           "reply/push/overlay in the completion continuation; "
-           "concurrent decodes coalesce into one device call even "
-           "with different erasure patterns (heterogeneous-matrix "
-           "batched kernel); off = decode synchronously per gather"),
     Option("kernel_failpoints", OPT_STR, "",
            "armed device-runtime failpoints (common/failpoint.py): "
            "'name=mode[;name=mode...]' where name is a boundary site "
@@ -222,12 +192,6 @@ register_options([
            "per engine (in-flight batches re-fan to the replacement); "
            "past the budget the engine is wedged: every waiter gets "
            "a loud EngineWedgedError and flush() raises"),
-    Option("osd_scrub_batched", OPT_BOOL, True,
-           "compute scrub-map digests as one coalesced device batch "
-           "per PG through the scrub_digest dispatch channel (crc32 + "
-           "GF shard digest over stacked object/omap rows); off = the "
-           "seed's per-object host shard_crc loop (always the "
-           "fallback when the channel degrades)"),
     Option("osd_scrub_chunk_timeout", OPT_FLOAT, 15.0,
            "seconds a scrubbing primary waits for replica scrub maps "
            "per gather round; peers the osdmap marks down are "
@@ -317,13 +281,6 @@ register_options([
            "rolling counter samples the mgr slo module retains for "
            "windowed burn evaluation (also time-bounded by the slow "
            "window)"),
-    Option("bluestore_batched_csum", OPT_BOOL, True,
-           "settle each bluestore transaction batch's write-time "
-           "block checksums as ONE coalesced device digest through "
-           "the bluestore_data dispatch channel (the scrub digest "
-           "kernel's crc32 column over stored payloads); off = the "
-           "seed's inline scalar zlib.crc32 per block (always the "
-           "fallback when the channel degrades)"),
     Option("bluestore_batched_csum_min", OPT_INT, 4,
            "minimum pending blocks before a commit's checksum batch "
            "rides the device; smaller batches take the scalar path "
@@ -333,11 +290,6 @@ register_options([
            "bluestore_data digest future before falling back to "
            "scalar crc32 (generous: the engine's own retry/breaker "
            "ladder resolves failures far sooner)"),
-    Option("bluestore_batched_read_verify", OPT_BOOL, True,
-           "verify wide reads' block checksums as one bluestore_data "
-           "digest call instead of per-block scalar crc32; any "
-           "engine failure falls back to the scalar per-block path — "
-           "reads never lose verification, only batching"),
     Option("bluestore_batched_read_min", OPT_INT, 8,
            "minimum checksummed blocks a read must cover before its "
            "verification batches to the device"),

@@ -264,7 +264,7 @@ def build_two_level_map(n_hosts: int, osds_per_host: int,
 def build_skewed_two_level_map(n_hosts: int, osds_per_host: int,
                                seed: int = 42):
     """The deployment-shaped two-level map the bulk-placement runs share
-    (bench.py, chip_smoke.py, the TPU cross-validation tests): per-OSD
+    (chip_smoke.py, the TPU cross-validation tests): per-OSD
     bucket weights skewed over [0.5, 2.0), 10 % of the OSDs reweighted to
     0.5 and 2 % out, so the firstn retry ladder actually fires.  Returns
     (map, chooseleaf_firstn_rule_id, reweight (n_osds,) int64)."""

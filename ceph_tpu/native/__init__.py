@@ -1,8 +1,8 @@
 """Native (C) single-core baseline kernels, compiled on first use.
 
-These are the honest CPU yardsticks bench.py compares the TPU kernels
-against (BASELINE.md rows): an ISA-L-class split-nibble GF(2^8) encode and a
-scalar straw2 ``crush_do_rule`` (semantics of src/crush/mapper.c:900, ported
+These are the CPU references behind the EC profile key ``runtime=native``
+and the yardsticks of BASELINE.md's rows: an ISA-L-class split-nibble
+GF(2^8) encode and a scalar straw2 ``crush_do_rule`` (semantics of src/crush/mapper.c:900, ported
 from the in-repo oracle ``crush.mapper_ref`` and cross-validated in
 tests/test_native.py).
 

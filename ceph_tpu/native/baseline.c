@@ -1,6 +1,7 @@
 /* Single-core CPU baseline kernels: GF(2^8) erasure encode and scalar CRUSH.
  *
- * Purpose: an honest in-repo CPU yardstick for bench.py (BASELINE.md rows).
+ * Purpose: an honest in-repo CPU yardstick (BASELINE.md rows) and the
+ * `runtime=native` reference codec.
  * The GF encode uses the split-nibble table algorithm that ISA-L / jerasure's
  * SIMD paths use (reference semantics: src/erasure-code/isa/ErasureCodeIsa.cc
  * :118-130 ec_encode_data), expressed with GCC vector extensions so -O3

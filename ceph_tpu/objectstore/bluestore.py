@@ -101,7 +101,7 @@ class BlueStoreLite(ObjectStore):
     txcs/stores at the engine), reads above a threshold verify through
     the same channel, and per-pool/global ``compression_mode`` runs
     blocks through a compressor plugin before they hit the block file.
-    Without one (or with the knobs off) every path is the seed's
+    Without one (or under the batch floors) every path is the seed's
     scalar ``zlib.crc32`` loop — which also remains the bit-exact
     oracle the channel's fault ladder falls back to."""
 
@@ -259,15 +259,14 @@ class BlueStoreLite(ObjectStore):
 
     def _batch_engine(self):
         """The engine this batch's ``bluestore_data`` submissions ride
-        — or None for the scalar path.  None when: no context, knob
-        off, or the CALLER is an engine worker thread (store commits
+        — or None for the scalar path.  None when: no context, or
+        the CALLER is an engine worker thread (store commits
         run on completion threads via EC-write and recovery
         continuations; blocking on a future there would starve the
         thread that delivers it).  The channel rides the decode engine
         so store digests coalesce with scrub's — one checksum
         definition, one width-bucketed batch stream."""
-        if self._ctx is None or not bool(
-                self._conf("bluestore_batched_csum", True)):
+        if self._ctx is None:
             return None
         try:
             eng = self._ctx.decode_dispatch_engine()
@@ -647,8 +646,6 @@ class BlueStoreLite(ObjectStore):
         the blocks it verified; {} routes the read through the scalar
         per-block path — including on any engine failure, so reads
         never lose verification, only batching."""
-        if not bool(self._conf("bluestore_batched_read_verify", True)):
-            return {}
         bis = []
         for bi in range(offset // BLOCK, -(-end // BLOCK)):
             if (bi < len(meta["extents"]) and meta["extents"][bi] >= 0

@@ -26,14 +26,13 @@ CephTpuContext import it; ceph_tpu.ops resolves its kernel exports
 lazily for the same reason), and the instrumented call sites pass
 callables for anything device-flavored.
 
-Calls made UNDER an outer jit trace (the bench's chained ``lax.scan``
-loops, any user jit composing our kernels) return tracers: those are
+Calls made UNDER an outer jit trace (any user jit composing our
+kernels) return tracers: those are
 counted as ``traced`` executions but produce no latency/byte samples —
 a tracer has no wall time and fencing it would throw.
 
-Surfaces: ``dump()`` (admin socket ``dump_kernel_stats``), the mgr
-prometheus module (histogram families per kernel), and ``summary()``
-(bench.py's one-line digest: retraces, p50/p99 latency, occupancy).
+Surfaces: ``dump()`` (admin socket ``dump_kernel_stats``) and the mgr
+prometheus module (histogram families per kernel).
 """
 
 from __future__ import annotations
@@ -336,7 +335,7 @@ class PhaseStats:
             }
 
     def summary(self) -> dict:
-        """Compact digest (MMgrReport carriage / bench JSON): per
+        """Compact digest (MMgrReport carriage, perfbench): per
         kernel family the phase totals and shares, plus the compile
         ledger and the utilization gauges.  Ring omitted — digests
         travel the wire every tick."""
@@ -608,7 +607,7 @@ class DispatchStats:
             } | {"faults": self._fault_dict()}
 
     def summary(self) -> dict:
-        """bench.py's digest: amortization in three numbers."""
+        """chip_smoke.py's digest: amortization in three numbers."""
         with self._lock:
             batches = self.batches
             dev_n = self.devices_used.count
@@ -860,7 +859,8 @@ class MappingStats:
                 "unfused_epochs": unfused}
 
     def summary(self) -> dict:
-        """bench.py's digest: incrementality in a few numbers."""
+        """The digest chip_smoke.py and perfbench read:
+        incrementality in a few numbers."""
         with self._lock:
             n = self.update_latency.count
             return {
@@ -889,7 +889,7 @@ class ScrubStats:
     folds its scrub accounting in (the per-daemon copies feed
     ``dump_scrub_stats`` and the ``ceph_scrub_*`` prometheus families
     through the MMgrReport tail), so this sink is the cluster-wide
-    roll-up the thrasher's scrub-storm gate and bench.py poll —
+    roll-up the thrasher's scrub-storm gate polls —
     "every injected corruption detected and repaired" is a claim
     about the whole MiniCluster, not one daemon."""
 
@@ -917,7 +917,7 @@ class ScrubStats:
             return dict(self._counts)
 
     def summary(self) -> dict:
-        """bench.py / thrasher digest: the integrity story in a few
+        """The thrasher's digest: the integrity story in a few
         numbers — how much was checked, how it was digested (batched
         vs scalar), and whether every found inconsistency ended in a
         VERIFIED repair."""
@@ -945,8 +945,8 @@ class BlueStoreStats:
 
     Process-global like the other sinks: every BlueStoreLite in the
     process folds its accounting in; ``bluestore_dump`` and the
-    ``ceph_bluestore_*`` prometheus families read it, and bench.py's
-    objectstore section polls ``summary()``."""
+    ``ceph_bluestore_*`` prometheus families read it, and
+    chip_smoke.py and perfbench poll ``summary()``."""
 
     FIELDS = ("csum_batches", "csum_blocks", "csum_scalar_blocks",
               "csum_fallbacks", "read_verify_batches",
@@ -972,8 +972,8 @@ class BlueStoreStats:
             return dict(self._counts)
 
     def summary(self) -> dict:
-        """bench/test digest: how the store's checksum work was
-        computed (batched device calls vs scalar), what compression
+        """chip_smoke.py / perfbench digest: how the store's checksum
+        work was computed (batched device calls vs scalar), what compression
         did, and whether anything went wrong."""
         with self._lock:
             c = dict(self._counts)
@@ -1104,7 +1104,7 @@ class TenantDeviceStats:
 
     def digest(self) -> dict:
         """Compact ledger (no histogram buckets) — the MMgrReport
-        ``tenant_usage`` tail and bench.py's qos-section carriage."""
+        ``tenant_usage`` tail."""
         with self._lock:
             rows = {k: dict(r) for k, r in self._rows.items()}
         total = sum(r["device_seconds"] for r in rows.values())
@@ -1159,7 +1159,7 @@ class KernelTelemetry:
         return {ks.name: ks.dump() for ks in kernels}
 
     def reset(self) -> None:
-        """Drop all samples (tests/bench isolation).  Signature sets go
+        """Drop all samples (test isolation).  Signature sets go
         too, but jit caches live in jax — miss counting stays a delta
         against the real cache, so reset never fabricates misses."""
         with self._lock:
@@ -1170,26 +1170,6 @@ class KernelTelemetry:
         self.scrub.clear()
         self.bluestore.clear()
         self.tenant.clear()
-
-    def summary(self) -> dict:
-        """Compact digest (bench.py prints this next to its JSON)."""
-        out = {}
-        for name, d in self.dump().items():
-            lat = d["latency_seconds"]
-            bat = d["batch_size"]
-            ks = self.kernel(name)
-            out[name] = {
-                "calls": d["calls"],
-                "retraces": d["jit_misses"],
-                "p50_ms": round(ks.latency.quantile(0.5) * 1e3, 3),
-                "p99_ms": round(ks.latency.quantile(0.99) * 1e3, 3),
-                "mean_batch": (round(bat["sum"] / bat["count"], 1)
-                               if bat["count"] else 0),
-                "gb_in": round(d["bytes_in"] / 1e9, 3),
-                "mean_ms": (round(lat["sum"] / lat["count"] * 1e3, 3)
-                            if lat["count"] else 0.0),
-            }
-        return out
 
 
 _REG = KernelTelemetry()
@@ -1236,15 +1216,11 @@ def decode_dispatch_dump() -> dict:
     return _REG.decode_dispatch.dump()
 
 
-def decode_dispatch_summary() -> dict:
-    return _REG.decode_dispatch.summary()
-
-
 def scrub_stats() -> ScrubStats:
     """The process-global background-integrity counters: every OSD's
     scrub path feeds this alongside its own per-daemon accounting;
-    the thrasher's scrub-storm gate and bench.py's scrub section read
-    the cluster-wide roll-up here."""
+    the thrasher's scrub-storm gate reads the cluster-wide roll-up
+    here."""
     return _REG.scrub
 
 
@@ -1260,7 +1236,7 @@ def bluestore_stats() -> BlueStoreStats:
     """The process-global device-resident-objectstore counters: every
     BlueStoreLite's write/read/compression paths feed this;
     ``dump_bluestore_stats``, the ``ceph_bluestore_*`` prometheus
-    families and bench.py's objectstore section read it."""
+    families, chip_smoke.py and perfbench read it."""
     return _REG.bluestore
 
 
@@ -1285,8 +1261,7 @@ def tenant_dump() -> dict:
 
 
 def tenant_usage_digest() -> dict:
-    """Compact per-tenant ledger digest — the MMgrReport carriage and
-    bench.py's qos-section ``tenant_usage`` key."""
+    """Compact per-tenant ledger digest — the MMgrReport carriage."""
     return _REG.tenant.digest()
 
 
@@ -1330,7 +1305,7 @@ def fault_digest() -> dict:
 
 def pipeline_profile_digest() -> dict:
     """Compact phase-share digest (no histograms, no ring) — the
-    MMgrReport v4 carriage and bench.py's ``profile`` section."""
+    MMgrReport v4 carriage; perfbench reads it too."""
     return {"encode": _REG.dispatch.phases.summary(),
             "decode": _REG.decode_dispatch.phases.summary(),
             "mapping": _REG.mapping.phase_summary()}

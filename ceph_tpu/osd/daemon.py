@@ -279,34 +279,6 @@ class OSDDaemon(Dispatcher):
         self._stop = False
         #: fault injection (reference: OSD.h debug_heartbeat_drops_remaining)
         self.debug_drop_rep_ops = 0
-        #: async EC write dispatch: the encode is SUBMITTED through the
-        #: context's coalescing engine and the transaction-build + shard
-        #: fan-out runs in the completion continuation, so concurrent
-        #: client writes share one device call.  Hot-togglable.
-        self._ec_async = bool(self.ctx.conf.get("osd_ec_dispatch_async"))
-        self.ctx.conf.add_observer(
-            "osd_ec_dispatch_async",
-            lambda _n, v: setattr(self, "_ec_async", bool(v)))
-        #: async EC decode dispatch: degraded reads, recovery pulls and
-        #: rmw gathers SUBMIT the decode through the context's decode
-        #: engine (heterogeneous-matrix batched kernel — mixed erasure
-        #: patterns share one device call) and finish reply/push/
-        #: overlay in the completion continuation.  Hot-togglable.
-        self._ec_decode_async = bool(
-            self.ctx.conf.get("osd_ec_decode_async"))
-        self.ctx.conf.add_observer(
-            "osd_ec_decode_async",
-            lambda _n, v: setattr(self, "_ec_decode_async", bool(v)))
-        #: shared epoch-keyed mapping cache: map consumption rides the
-        #: context's SharedPGMappingService — _scan_pgs walks only the
-        #: changed-PG delta + locally-held PGs, and per-PG reads are
-        #: cached-raw pipeline tails instead of scalar CRUSH.
-        #: Hot-togglable (off = seed's full scalar scan).
-        self._map_shared = bool(
-            self.ctx.conf.get("osdmap_mapping_shared"))
-        self.ctx.conf.add_observer(
-            "osdmap_mapping_shared",
-            lambda _n, v: setattr(self, "_map_shared", bool(v)))
 
         self._auth_key = auth_key
         self._cephx = cephx
@@ -1061,18 +1033,17 @@ class OSDDaemon(Dispatcher):
         self._apply_qos_db(newmap)
         self._apply_pool_compression(newmap)
         self._split_pgs(newmap)
+        # advance the shared cache (daemons on one context share a
+        # single table build; a burst computes only the newest epoch)
+        # and take the exact changed-PG delta from OUR old epoch so
+        # the scan below is O(changed + local)
         upd = None
-        if self._map_shared:
-            # advance the shared cache (daemons on one context share a
-            # single table build; a burst computes only the newest
-            # epoch) and take the exact changed-PG delta from OUR old
-            # epoch so the scan below is O(changed + local)
-            try:
-                upd = self.ctx.mapping_service().update_to(
-                    newmap, from_epoch=oldmap.epoch)
-            except Exception as e:   # cache is an optimization, never a wall
-                dout("osd", 1, "osd.%d mapping service update failed, "
-                     "falling back to scalar scan: %r", self.osd_id, e)
+        try:
+            upd = self.ctx.mapping_service().update_to(
+                newmap, from_epoch=oldmap.epoch)
+        except Exception as e:   # cache is an optimization, never a wall
+            dout("osd", 1, "osd.%d mapping service update failed, "
+                 "falling back to scalar scan: %r", self.osd_id, e)
         del oldmap
         self.perf.inc("map_epochs")
         t_scan = time.time()
@@ -1454,8 +1425,8 @@ class OSDDaemon(Dispatcher):
         strays — their notify/teardown edges depend on OUR state, not
         the map diff) are examined, and each read is a cached-raw
         pipeline tail — O(changed + local) host work instead of
-        O(cluster PGs) scalar CRUSH.  Without a delta (shared cache
-        off, first map, or a chain gap) every PG is walked as before."""
+        O(cluster PGs) scalar CRUSH.  Without a delta (the service's
+        update raised, first map, or a chain gap) every PG is walked."""
         m = self.osdmap
         if upd is not None and not upd.full:
             scan = set(upd.changed)
@@ -1506,12 +1477,10 @@ class OSDDaemon(Dispatcher):
     def _pg_mapping(self, pool_id: int, pgnum: int
                     ) -> tuple[list[int], int, list[int], int]:
         """(up, up_primary, acting, acting_primary) for one PG — from
-        the shared mapping cache when enabled (falls back to the
-        scalar oracle on any epoch/object mismatch), else scalar."""
-        if self._map_shared:
-            return self.ctx.mapping_service().lookup(
-                self.osdmap, pool_id, pgnum)
-        return self.osdmap.pg_to_up_acting_osds(pool_id, pgnum)
+        the shared mapping cache (which falls back to the scalar
+        oracle on any epoch/object mismatch)."""
+        return self.ctx.mapping_service().lookup(
+            self.osdmap, pool_id, pgnum)
 
     def _start_peering(self, pg: PG, up: list[int], primary: int) -> None:
         # interval change: the old interval's recovery slot is void
@@ -2974,7 +2943,9 @@ class OSDDaemon(Dispatcher):
     def _ec_encode_window(codec, si, data: bytes, s0: int,
                           s1: int) -> dict[int, bytes]:
         """Encode stripes [s0, s1) of `data` in one batched device call
-        (the ECUtil::encode batch point): {shard: column bytes}."""
+        (the ECUtil::encode batch point): {shard: column bytes}.  The
+        synchronous form of what the write path submits; tests hold it
+        against the plain reference (tests/test_reed_sol_van.py)."""
         n = codec.get_chunk_count()
         window = np.frombuffer(data[s0 * si.width:s1 * si.width],
                                dtype=np.uint8)
@@ -2982,13 +2953,11 @@ class OSDDaemon(Dispatcher):
         return OSDDaemon._ec_shard_columns(
             si, stripes, codec.encode_chunks(stripes), n)
 
-    def _ec_encode_object(self, codec, si, data: bytes) -> dict[int, bytes]:
-        """Full object -> {shard: shard bytes}."""
-        n = codec.get_chunk_count()
-        if si is None:
-            return codec.encode(set(range(n)), data)
-        return self._ec_encode_window(codec, si, data, 0,
-                                      si.object_stripes(len(data)))
+    @staticmethod
+    def _ec_encode_object(codec, data: bytes) -> dict[int, bytes]:
+        """Whole-object codecs (no StripeInfo): full object ->
+        {shard: shard bytes}, synchronously."""
+        return codec.encode(set(range(codec.get_chunk_count())), data)
 
     def _do_ec_op(self, msg: MOSDOp, pool, pg: PG) -> None:
         cid = self._pg_cid(pg.pgid)
@@ -3207,7 +3176,7 @@ class OSDDaemon(Dispatcher):
     def _ec_apply_write(self, msg: MOSDOp, pool, pg: PG, op,
                         old_data: bytes, replace: bool) -> bytes | None:
         """Start one EC write: overlay, encode, commit, shard fan-out.
-        With the dispatch engine on, the encode is SUBMITTED
+        For a striped codec the encode is SUBMITTED
         (submit-and-continue): this method returns after handing the
         affected stripes to the coalescing engine, and the
         transaction-build + fan-out runs in the completion continuation
@@ -3254,30 +3223,11 @@ class OSDDaemon(Dispatcher):
             s0 = s1 = 0
             shard_off, truncate = 0, True
             shard_len = 0
-        engine = (self.ctx.dispatch_engine()
-                  if self._ec_async and si is not None else None)
-        if engine is None and si is not None:
-            # the async knob was toggled off with commits still in
-            # flight for this object: a synchronous commit here would
-            # log ahead of them and the object would roll back when
-            # their continuations land — ride the engine's per-key
-            # FIFO behind the pending writes instead
-            gid0 = pg.rmw.get(msg.oid)
-            st0 = (self._ec_reads.get(gid0)
-                   if gid0 is not None else None)
-            if (st0 is not None and st0.get("kind") == "wpend"
-                    and st0.get("pending")):
-                engine = self.ctx.dispatch_engine()
-        if engine is None:
-            # synchronous path: whole-object codecs (shec/lrc/clay
-            # encode through their own bespoke layouts) and the async
-            # knob off
-            if si is None:
-                sub = self._ec_encode_object(codec, si, data)
-                shard_len = (len(next(iter(sub.values())))
-                             if sub else 0)
-            else:
-                sub = self._ec_encode_window(codec, si, data, s0, s1)
+        if si is None:
+            # synchronous path: whole-object codecs (shec/lrc/clay)
+            # encode through their own bespoke layouts
+            sub = self._ec_encode_object(codec, data)
+            shard_len = len(next(iter(sub.values()))) if sub else 0
             # device residency on the op's timeline (and, via the trace
             # id, in the cross-daemon span ring): a traced client op
             # shows where its TPU time went
@@ -3305,7 +3255,7 @@ class OSDDaemon(Dispatcher):
         stripes = si.split(window)
         with tracing.span("ec encode submit", daemon=self._tname):
             fut = codec.submit_chunks(
-                engine, stripes,
+                self.ctx.dispatch_engine(), stripes,
                 cost_tag=(getattr(msg, "qos_tenant", "") or "client",
                           "client"))
         self.perf.inc("ec_dispatch_submits")
@@ -3906,11 +3856,9 @@ class OSDDaemon(Dispatcher):
         dispatch engine: True when the completion continuation now owns
         the rest of the read.  False falls back to the synchronous
         path — whole-object codecs (si None), packet-level bitmatrix
-        codecs, the knob off, a widened (non-MDS) gather, no missing
-        data rows, or a singular chosen set (the widen ladder handles
-        that one just like the sync decode's IOError)."""
-        if not self._ec_decode_async:
-            return False
+        codecs, a widened (non-MDS) gather, no missing data rows, or a
+        singular chosen set (the widen ladder handles that one just
+        like the sync decode's IOError)."""
         pool = state["pool"]
         codec = self._codec(pool)
         if not getattr(codec, "supports_submit_decode", False):
@@ -4066,10 +4014,10 @@ class OSDDaemon(Dispatcher):
     @staticmethod
     def _ec_gathered_stripes(si, k: int, shards: dict, size: int):
         """Shared shard-to-array assembly for the sync and async decode
-        paths (they MUST reconstruct identically whatever the
-        osd_ec_decode_async setting): (chosen, arr (S, k_chosen, su) of
-        gathered columns, missing data-row targets, stripes buffer
-        with the surviving data rows scattered in)."""
+        paths (they MUST reconstruct identically): (chosen, arr
+        (S, k_chosen, su) of gathered columns, missing data-row
+        targets, stripes buffer with the surviving data rows scattered
+        in)."""
         shard_len = si.shard_len(size)
         chosen = sorted(shards)[:k]
         cols = []
@@ -4111,7 +4059,7 @@ class OSDDaemon(Dispatcher):
 
     def _ec_recover_done(self, state: dict, data: bytes) -> None:
         """Reconstructed the full object: re-encode and deliver the
-        destination shard's chunk.  With async dispatch on, the
+        destination shard's chunk.  For a striped codec the
         re-encode SUBMITS through the encode engine — the reservation
         window's concurrent in-flight pulls coalesce their re-encodes
         into one device call — and the store/push runs in the
@@ -4119,7 +4067,7 @@ class OSDDaemon(Dispatcher):
         pool = state["pool"]
         codec = self._codec(pool)
         si = self._ec_stripe_info(codec, pool)
-        if self._ec_async and si is not None:
+        if si is not None:
             stripes = si.split(np.frombuffer(data, dtype=np.uint8))
             n = codec.get_chunk_count()
             fut = codec.submit_chunks(self.ctx.dispatch_engine(),
@@ -4130,7 +4078,7 @@ class OSDDaemon(Dispatcher):
                 lambda f, c=(state, data, si, stripes, n):
                 self._ec_recover_encoded(*c, f))
             return
-        chunks = self._ec_encode_object(codec, si, data)
+        chunks = self._ec_encode_object(codec, data)
         self._ec_recover_store(state, data, chunks)
 
     def _ec_recover_encoded(self, state: dict, data: bytes, si,
@@ -4286,11 +4234,11 @@ class OSDDaemon(Dispatcher):
     def _scrub_digest_rows(self, blobs: list) -> "np.ndarray | None":
         """(len(blobs), 2) uint32 digests via ONE coalesced device
         batch on the scrub_digest channel, or None — the caller runs
-        the bit-exact scalar loop (knob off, empty batch, rows wider
+        the bit-exact scalar loop (empty batch, rows wider
         than the kernel cap, or a permanent engine error; transient
         device faults never reach here — the engine's retry ladder and
         host oracle absorb them)."""
-        if not blobs or not bool(self.ctx.conf.get("osd_scrub_batched")):
+        if not blobs:
             return None
         from ceph_tpu.ops import checksum_kernel as ck
         if max(len(b) for b in blobs) > ck.MAX_WIDTH:
@@ -4408,8 +4356,7 @@ class OSDDaemon(Dispatcher):
         ``finish(digs_or_None)`` runs on the engine's completion
         thread (None = take the scalar loop)."""
         blobs = [r[1] for r in rows] + [r[2] for r in rows]
-        if not blobs or not bool(
-                self.ctx.conf.get("osd_scrub_batched")):
+        if not blobs:
             finish(None)
             return
         from ceph_tpu.ops import checksum_kernel as ck
@@ -4973,7 +4920,6 @@ class OSDDaemon(Dispatcher):
             out = dict(self._scrub_stats)
             out["last_sweep"] = dict(self._scrub_stats["last_sweep"])
         out["qos_class"] = BACKGROUND_BEST_EFFORT
-        out["batched"] = bool(self.ctx.conf.get("osd_scrub_batched"))
         out["auto_interval"] = float(
             self.ctx.conf.get("osd_scrub_auto_interval"))
         if self.opwq is not None:

@@ -346,10 +346,10 @@ class OSDMapMapping:
     """Full-map PG->OSD cache, updated per epoch (OSDMapMapping.h:324-332).
 
     ``update()`` recomputes only pools whose placement inputs changed
-    since the cached epoch; see the module docstring.  ``backend``
-    mirrors the ``crush_backend`` option: "tpu" uses the batched
-    device mapper, "scalar" the pure-python oracle (slow, but it keeps
-    the incremental reuse and exists for hosts without a device)."""
+    since the cached epoch; see the module docstring.  ``backend``:
+    "tpu" uses the batched device mapper, "scalar" the pure-python
+    oracle (slow, but it keeps the incremental reuse and exists for
+    hosts without a device)."""
 
     def __init__(self, osdmap: OSDMap | None = None, *,
                  backend: str = "tpu", min_device_pgs: int = 0,
@@ -359,8 +359,7 @@ class OSDMapMapping:
         #: (device dispatch + compile overhead dominates tiny pools);
         #: the osdmap_mapping_min_pgs option
         self.min_device_pgs = min_device_pgs
-        #: fuse the post-CRUSH pipeline tail on device (the
-        #: osdmap_mapping_fused option): publish packed
+        #: fuse the post-CRUSH pipeline tail on device: publish packed
         #: (up, acting, primaries) tables next to the raw ones.
         #: Ignored on the scalar backend.
         self.fused = fused
@@ -635,16 +634,15 @@ class SharedPGMappingService:
     #: at width 3, where the device/mesh diff starts paying)
     FUSED_DIFF_HOST_MAX = 1 << 20
 
-    def __init__(self, ctx=None, backend: str | None = None,
-                 fused: bool | None = None):
+    def __init__(self, ctx=None, backend: str = "tpu",
+                 fused: bool = True):
         self._cv = lockdep.make_condition("SharedPGMappingService::cv")
         self._ctx = ctx
-        #: explicit backend override (tests / engine-less tools);
-        #: None = follow the context's crush_backend option
-        self._backend_override = backend
-        #: explicit fused-ladder override (tests / bench A-B runs);
-        #: None = follow the osdmap_mapping_fused option
-        self._fused_override = fused
+        #: "scalar" for tests and engine-less tools
+        self._backend = backend
+        #: False keeps the per-PG host tail (the fused rows' reference
+        #: in tests)
+        self._fused = fused
         self._mapping: OSDMapMapping | None = None
         self._tables: dict[int, _Tables] = {}     # current + previous epoch
         self._deltas: deque = deque(maxlen=self.DELTA_LOG)
@@ -667,28 +665,8 @@ class SharedPGMappingService:
     def epoch(self) -> int:
         return self._epoch
 
-    def _backend(self) -> str:
-        if self._backend_override is not None:
-            return self._backend_override
-        if self._ctx is None:
-            return "tpu"
-        try:
-            return str(self._ctx.conf.get("crush_backend"))
-        except KeyError:
-            return "tpu"
-
-    def _fused_enabled(self) -> bool:
-        if self._fused_override is not None:
-            return bool(self._fused_override)
-        if self._ctx is None:
-            return True
-        try:
-            return bool(self._ctx.conf.get("osdmap_mapping_fused"))
-        except KeyError:
-            return True
-
     def _engine(self):
-        if self._ctx is None or self._backend() == "scalar":
+        if self._ctx is None or self._backend == "scalar":
             return None
         return self._ctx.dispatch_engine()
 
@@ -709,14 +687,8 @@ class SharedPGMappingService:
 
     def _ensure_mapping(self) -> OSDMapMapping:
         if self._mapping is None:
-            self._mapping = OSDMapMapping(backend=self._backend(),
-                                          fused=self._fused_enabled())
-        else:
-            # the knobs follow the live config (an operator flipping
-            # crush_backend to scalar mid-flight — wedged device —
-            # must take effect on the next update)
-            self._mapping.backend = self._backend()
-            self._mapping.fused = self._fused_enabled()
+            self._mapping = OSDMapMapping(backend=self._backend,
+                                          fused=self._fused)
         if self._ctx is not None:
             try:
                 self._mapping.min_device_pgs = int(

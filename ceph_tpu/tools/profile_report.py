@@ -5,16 +5,15 @@ Accepts any of the profiler's JSON surfaces and normalizes them to one
 view:
 
   * ``dump_pipeline_profile`` admin-socket output (full histograms),
-  * ``telemetry.pipeline_profile_digest()`` (the MMgrReport carriage,
-    also what ``bench.py --sections profile`` embeds under "profile"),
+  * ``telemetry.pipeline_profile_digest()`` (the MMgrReport carriage),
   * the mgr insights module's ``profile phases`` command output
     (cluster-merged), and
-  * a whole bench JSON line (the "profile" key is found and used).
+  * any wrapper document carrying one of these under a "profile" key.
 
 It also accepts the tenant device-time ledger's surfaces — the
 ``dump_tenant_usage`` admin output, the MMgrReport ``tenant_usage``
-digest, the mgr slo module's ``usage top`` merge, or a bench JSON
-line carrying a ``tenant_usage`` key — and renders a per-tenant
+digest, the mgr slo module's ``usage top`` merge, or a wrapper
+document carrying a ``tenant_usage`` key — and renders a per-tenant
 where-did-the-DEVICE-go table (device-seconds, cluster share, and
 the per-engine/channel split) next to the phase table.
 
@@ -55,7 +54,7 @@ def normalize(doc: dict) -> dict:
     "utilization", "mapping"} (the insights ``profile phases``
     shape)."""
     if "profile" in doc and isinstance(doc["profile"], dict):
-        doc = doc["profile"]          # bench JSON line
+        doc = doc["profile"]          # wrapper document
     if "engines" in doc:              # insights profile phases output
         return {"engines": doc.get("engines", {}),
                 "compile": doc.get("compile", {}),
@@ -99,8 +98,7 @@ def normalize_tenant(doc: dict) -> dict | None:
 
     Accepts the admin dump / MMgrReport digest (``tenants`` mapping),
     the slo module's ``usage top`` output (``tenants`` LIST of ranked
-    rows), and any wrapper carrying a ``tenant_usage`` key (a bench
-    JSON line)."""
+    rows), and any wrapper carrying a ``tenant_usage`` key."""
     if isinstance(doc.get("tenant_usage"), dict):
         doc = doc["tenant_usage"]
     tenants = doc.get("tenants")

@@ -585,8 +585,10 @@ def run_soak(duration: float = 25.0, seed: int = 7,
             # fault-free warmup first: on a cold process the first ops
             # PAY the jit compiles (encode kernel, mapper, ladder);
             # arming failpoints before any op has ever succeeded would
-            # storm an empty pipeline and measure nothing
-            wdl = time.time() + 8.0
+            # storm an empty pipeline and measure nothing.  The wait is
+            # compile-sized like the epoch wait above: on a loaded host
+            # the first op of each pool alone outlasts its 6 s timeout
+            wdl = time.time() + ept
             while w1.ops + w2.ops < 6 and time.time() < wdl:
                 time.sleep(0.25)
             chaos = DeviceChaos(random.Random(seed + 3))

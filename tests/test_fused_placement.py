@@ -231,8 +231,9 @@ def test_fused_service_matches_oracle_and_exact_delta():
 
 
 def test_fused_off_knob_restores_host_tail_path():
-    """fused=False (the osdmap_mapping_fused escape hatch) keeps the
-    PR 5 host-tail behavior: identical results, unfused counters."""
+    """fused=False (the constructor argument; the fused rows'
+    reference) keeps the PR 5 host-tail behavior: identical results,
+    unfused counters."""
     rng = np.random.default_rng(5)
     m, rule = _base_map()
     svc = SharedPGMappingService(fused=False)

@@ -372,8 +372,8 @@ def _count_scan_scalar_calls(monkeypatch):
 
 
 def test_scan_pgs_scalar_calls_stay_flat_across_epoch(monkeypatch):
-    """Acceptance gate: with osdmap_mapping_shared on, an epoch advance
-    over a large pool does NOT re-run the scalar pipeline per PG inside
+    """Acceptance gate: an epoch advance over a large pool does NOT
+    re-run the scalar pipeline per PG inside
     _scan_pgs — the OSDs consume the map from the shared cache (changed
     + local PGs, served by cached-raw pipeline tails), where the seed
     walked every PG scalar on every OSD (3 x 64 here)."""
@@ -526,25 +526,3 @@ def test_admin_socket_dump_mapping_stats():
     out = ctx.admin.execute("dump_mapping_stats")
     assert "epoch_updates" in out
     assert "changed_pgs" in out
-
-
-def test_mapping_shared_off_uses_scalar_path(monkeypatch):
-    """The osdmap_mapping_shared=False fallback: consumers run the
-    scalar pipeline exactly as the seed did."""
-    from ceph_tpu.tools.vstart import MiniCluster
-
-    c = MiniCluster(n_osds=2, ms_type="loopback").start()
-    try:
-        c.wait_for_osd_count(2)
-        for osd in c.osds.values():
-            osd.ctx.conf.set("osdmap_mapping_shared", False)
-        client = c.client()
-        client.ctx.conf.set("osdmap_mapping_shared", False)
-        calls = _count_scan_scalar_calls(monkeypatch)
-        pool = c.create_pool(client, pg_num=16, size=2)
-        io = client.open_ioctx(pool)
-        io.write_full("obj", b"scalar")
-        assert io.read("obj") == b"scalar"
-        assert calls["scan"] >= 16   # full scalar scans are back
-    finally:
-        c.stop()

@@ -2,8 +2,8 @@
 the in-repo oracles: GF(2^8) encode vs the numpy reference, and the scalar C
 crush_do_rule vs crush.mapper_ref across map shapes, weights, and rule modes.
 
-These guarantee bench.py's vs_baseline denominators compute the same math the
-TPU kernels do."""
+These guarantee the ``runtime=native`` reference codec and BASELINE.md's C
+yardsticks compute the same math the TPU kernels do."""
 
 from __future__ import annotations
 

@@ -663,7 +663,7 @@ class TestTreeGate:
         assert len(thread_except._thread_roots(idx)) >= 4
         edges = lock_order.build_graph(idx)
         assert len(edges) >= 10
-        assert "osdmap_mapping_shared" in \
+        assert "osdmap_mapping_min_pgs" in \
             registry_lint._option_names(idx)
         assert "ec_dispatch_submits" in \
             registry_lint._registered_counters(idx)
