@@ -19,10 +19,14 @@ the object's extent map flips to it only inside the KV commit), data
 is fsync'd before the ONE KV transaction that references it, and the
 displaced blocks return to the allocator only after that commit
 succeeds.  A crash anywhere leaves the old metadata pointing at
-untouched old blocks.  The allocator itself is never trusted from a
-snapshot: mount rebuilds the free list from the committed extent maps
-(BlueStore fsck/allocation-recovery analog), so a hard kill can never
-resurrect in-use blocks as free.
+untouched old blocks.  Whole, block-aligned, uncompressed blocks move
+between an object and the block file by extent run: one allocation and
+one positioned write per run of fresh blocks for a write's body, one
+positioned read per run of consecutive extents for a wide read.  The
+allocator itself is never trusted from a snapshot: mount rebuilds the
+free list from the committed extent maps (BlueStore fsck/allocation-
+recovery analog), so a hard kill can never resurrect in-use blocks as
+free.
 """
 
 from __future__ import annotations
@@ -87,6 +91,19 @@ def _okey(cid: str, oid: str) -> str:
     return f"{cid}\x00{oid}"
 
 
+def _runs(blocks: list[int]):
+    """Cut a list of block numbers into its runs of consecutive
+    numbers: yields (i, j) with ``blocks[i:j]`` one contiguous extent
+    of the block file."""
+    i, n = 0, len(blocks)
+    while i < n:
+        j = i + 1
+        while j < n and blocks[j] == blocks[j - 1] + 1:
+            j += 1
+        yield i, j
+        i = j
+
+
 #: compression_mode values that compress (the reference's "passive"
 #: compresses only on client hints, which this stack does not carry)
 _COMP_MODES_ON = ("aggressive", "force")
@@ -130,6 +147,10 @@ class BlueStoreLite(ObjectStore):
                      .add_u64("csum_fallbacks")
                      .add_u64("read_verify_batches")
                      .add_u64("read_verify_blocks")
+                     .add_u64("write_runs")
+                     .add_u64("write_run_blocks")
+                     .add_u64("read_runs")
+                     .add_u64("read_run_blocks")
                      .add_u64("compress_blocks")
                      .add_u64("compress_rejected")
                      .add_u64("compress_roundtrip_failures")
@@ -141,10 +162,11 @@ class BlueStoreLite(ObjectStore):
         #: to the allocator only after its KV commit lands
         self._freed: list[int] = []
         #: freshly allocated block -> STORED payload whose crc32 the
-        #: in-flight batch still owes; ONE coalesced device call at
-        #: commit fills them (scalar zlib on any failure — a csum is
-        #: never committed unset)
-        self._pending_csum: dict[int, bytes] = {}
+        #: in-flight batch still owes (bytes, or for a block written by
+        #: run a 4,096-byte view of the caller's buffer); ONE coalesced
+        #: device call at commit fills them (scalar zlib on any failure
+        #: — a csum is never committed unset)
+        self._pending_csum: dict[int, bytes | memoryview] = {}
         #: engine the in-flight batch rides (None = scalar batch)
         self._batch_eng = None
         #: cid -> resolved compression policy, cached per batch so the
@@ -160,6 +182,9 @@ class BlueStoreLite(ObjectStore):
         #: write batch skips the block-file fsync entirely — the whole
         #: point of the WAL path: one KV commit, no data syncs)
         self._block_dirty = False
+        #: [runs, blocks] the in-flight batch wrote to the block file
+        #: (the ``bluestore apply`` span's attributes)
+        self._wrote = [0, 0]
         #: deferred-write entries of the in-flight batch, per object key:
         #: committed as individual "wal" column keys alongside the meta
         #: (RocksDB deferred-write keys in the reference) — NOT inlined
@@ -205,7 +230,10 @@ class BlueStoreLite(ObjectStore):
             telemetry.bluestore_stats().inc(
                 "kv_journal_lost_bytes",
                 getattr(self._db, "truncated_bytes", 0))
-        self._f = open(self._block_path, "r+b")
+        # unbuffered: all block I/O is positioned (_read_run /
+        # _write_run), so there is no file position or buffer to keep
+        # coherent with it
+        self._f = open(self._block_path, "r+b", buffering=0)
         # rebuild the allocator from the committed extent maps — the
         # only crash-safe source of truth (fsck-style recovery; a
         # snapshot written at umount would be stale after a hard kill
@@ -224,10 +252,15 @@ class BlueStoreLite(ObjectStore):
             self._wal_seq = max(self._wal_seq, int(seq))
 
     def umount(self) -> None:
-        if self._f is not None:
-            self._f.close()
-            self._f = None
-        self._db.close()
+        # under the store lock: block I/O is positioned I/O on the
+        # file's descriptor, and a descriptor closed under a commit
+        # still in flight may name another file by the time its write
+        # is issued
+        with self._lock:
+            if self._f is not None:
+                self._f.close()
+                self._f = None
+            self._db.close()
 
     # -- metadata helpers -----------------------------------------------------
 
@@ -458,10 +491,49 @@ class BlueStoreLite(ObjectStore):
 
     # -- block I/O ------------------------------------------------------------
 
+    def _read_run(self, first_block: int, n_blocks: int) -> bytes:
+        """``n_blocks`` consecutive blocks of the block file in one
+        positioned read — with ``_write_run`` the one place that
+        touches the file's data.  Zero-filled past a short file tail
+        (a compressed last block is written at its stored length)."""
+        want = n_blocks * BLOCK
+        off = first_block * BLOCK
+        fd = self._f.fileno()
+        data = os.pread(fd, want, off)
+        while len(data) < want:
+            more = os.pread(fd, want - len(data), off + len(data))
+            if not more:    # end of file
+                data += bytes(want - len(data))
+                break
+            data += more
+        self._count_run("read", n_blocks)
+        return data
+
+    def _write_run(self, first_block: int, view) -> None:
+        """The STORED bytes of one run of consecutive blocks (whole
+        blocks, or one block's compressed body: its tail keeps whatever
+        it held, and reads slice to the comp entry's stored length
+        before verifying) in one positioned write, straight from the
+        caller's buffer."""
+        off = first_block * BLOCK
+        fd = self._f.fileno()
+        done = os.pwrite(fd, view, off)
+        while done < len(view):     # a short write: finish it
+            done += os.pwrite(fd, memoryview(view)[done:], off + done)
+        self._block_dirty = True
+        n_blocks = -(-len(view) // BLOCK)
+        self._wrote[0] += 1
+        self._wrote[1] += n_blocks
+        self._count_run("write", n_blocks)
+
+    def _count_run(self, kind: str, n_blocks: int) -> None:
+        from ceph_tpu.ops import telemetry
+        for sink in (self.perf, telemetry.bluestore_stats()):
+            sink.inc(f"{kind}_runs")
+            sink.inc(f"{kind}_run_blocks", n_blocks)
+
     def _read_block(self, block: int) -> bytes:
-        self._f.seek(block * BLOCK)
-        data = self._f.read(BLOCK)
-        return data + bytes(BLOCK - len(data))
+        return self._read_run(block, 1)
 
     def _stored_read(self, block: int, crc, comp=None) -> bytes:
         """The STORED payload of a block — compressed body or raw
@@ -470,7 +542,7 @@ class BlueStoreLite(ObjectStore):
         the commit's coalesced flush)."""
         pend = self._pending_csum.get(block)
         if pend is not None:
-            return pend
+            return bytes(pend)
         data = self._read_block(block)
         stored = data[:comp[1]] if comp else data
         if crc is not None and zlib.crc32(stored) != crc:
@@ -527,9 +599,10 @@ class BlueStoreLite(ObjectStore):
             co.append(None)
         return co
 
-    def _stage_csum(self, nb: int, stored: bytes, cs: list,
+    def _stage_csum(self, nb: int, stored, cs: list,
                     bi: int) -> None:
-        """Record a freshly written block's checksum obligation: into
+        """Record a freshly written block's checksum obligation
+        (``stored``: its payload, bytes or a view of them): into
         the batch's pending map when this batch rides the engine (one
         coalesced device call at commit), else the scalar crc32 the
         seed computed inline — which is also the flush's fallback, so
@@ -568,7 +641,7 @@ class BlueStoreLite(ObjectStore):
             stored, centry = self._compress_block(
                 padded, self._comp_policy(okey))
         nb = self._alloc.allocate(1)[0]
-        self._write_block(nb, stored, pad=centry is None)
+        self._write_run(nb, stored)
         meta["extents"][bi] = nb
         co[bi] = centry
         self._stage_csum(nb, stored, cs, bi)
@@ -627,22 +700,12 @@ class BlueStoreLite(ObjectStore):
         self._wal_pending.pop(okey, None)
         meta["wal_n"] = 0
 
-    def _write_block(self, block: int, data: bytes,
-                     pad: bool = True) -> None:
-        """Write a block's STORED payload.  ``pad=False`` (compressed
-        payloads) writes only the stored bytes — the block's tail
-        keeps whatever it held, and reads slice to the comp entry's
-        stored length before verifying."""
-        self._f.seek(block * BLOCK)
-        self._f.write(data[:BLOCK].ljust(BLOCK, b"\x00") if pad
-                      else data[:BLOCK])
-        self._block_dirty = True
-
     def _batch_read_verify(self, meta: dict, offset: int, end: int,
-                           cs: list, co: list) -> dict[int, bytes]:
+                           cs: list, co: list) -> dict:
         """Verify a wide read's block checksums in ONE device digest
         call (the same ``bluestore_data`` channel write commits use,
-        cost-tagged as read work).  Returns {bi: logical bytes} for
+        cost-tagged as read work).  Returns {bi: logical bytes (for an
+        uncompressed block a view into its run's buffer)} for
         the blocks it verified; {} routes the read through the scalar
         per-block path — including on any engine failure, so reads
         never lose verification, only batching."""
@@ -657,13 +720,21 @@ class BlueStoreLite(ObjectStore):
         eng = self._batch_engine()
         if eng is None:
             return {}
+        # one read per run of consecutive extents; the stored
+        # payloads are views into the runs' buffers
+        exts = [meta["extents"][bi] for bi in bis]
+        comps = [co[bi] if bi < len(co) else None for bi in bis]
         stored = []
         with tracing.span("bluestore read blocks", daemon="bluestore",
-                          blocks=len(bis)):
-            for bi in bis:
-                comp = co[bi] if bi < len(co) else None
-                data = self._read_block(meta["extents"][bi])
-                stored.append(data[:comp[1]] if comp else data)
+                          blocks=len(bis)) as sp:
+            runs = list(_runs(exts))
+            for i, j in runs:
+                buf = memoryview(self._read_run(exts[i], j - i))
+                for k in range(i, j):
+                    lo = (k - i) * BLOCK
+                    stored.append(buf[lo:lo + (comps[k][1] if comps[k]
+                                               else BLOCK)])
+            tracing.set_attrs(sp, runs=len(runs))
         from ceph_tpu.ops import telemetry
         from ceph_tpu.ops.dispatch import submit_bluestore_data
         try:
@@ -685,11 +756,11 @@ class BlueStoreLite(ObjectStore):
                 telemetry.bluestore_stats().inc("csum_errors")
                 raise IOError(
                     f"bluestore checksum mismatch on block "
-                    f"{meta['extents'][bi]}: stored {cs[bi]:#x}, "
+                    f"{exts[i]}: stored {cs[bi]:#x}, "
                     f"computed {crc:#x}")
             out[bi] = self._decompress_stored(
-                meta["extents"][bi], stored[i],
-                co[bi] if bi < len(co) else None)
+                exts[i], bytes(stored[i]) if comps[i] else stored[i],
+                comps[i])
         bs = telemetry.bluestore_stats()
         bs.inc("read_verify_batches")
         bs.inc("read_verify_blocks", len(bis))
@@ -765,19 +836,46 @@ class BlueStoreLite(ObjectStore):
                     (bi for bi, _ in full),
                     self._compress_blocks([c for _, c in full],
                                           policy)))
+        view = memoryview(data)
         pos = offset
-        di = 0
         while pos < end:
-            bi = pos // BLOCK
-            boff = pos % BLOCK
-            n = min(BLOCK - boff, end - pos)
-            # COW via the checksum-maintaining patcher: the old extent
-            # stays valid until the KV commit flips the map
-            self._patch_block(meta, bi, boff, data[di:di + n],
-                              okey=okey, pre=pres.get(bi))
+            bi, boff = divmod(pos, BLOCK)
+            di = pos - offset
+            if boff == 0 and end - pos >= BLOCK and policy is None:
+                # the body: every whole block of the write, by run
+                n = (end - pos) // BLOCK * BLOCK
+                self._write_body(meta, bi, view[di:di + n])
+            else:
+                # unaligned head or tail (and, under a compression
+                # policy, each block: stored lengths differ).  COW via
+                # the checksum-maintaining patcher: the old extent
+                # stays valid until the KV commit flips the map
+                n = min(BLOCK - boff, end - pos)
+                self._patch_block(meta, bi, boff, data[di:di + n],
+                                  okey=okey, pre=pres.get(bi))
             pos += n
-            di += n
         meta["size"] = max(meta["size"], end)
+
+    def _write_body(self, meta: dict, bi: int, view) -> None:
+        """COW-write a span of whole, uncompressed blocks starting at
+        block index ``bi``: ONE allocation, one block-file write per
+        run of consecutive new blocks (a fresh object's are one run;
+        a fragmented free set gives more, down to a block each), the
+        extent map flipped by slice.  The caller has grown the map to
+        cover the span."""
+        n = len(view) // BLOCK
+        cs = self._csums(meta)
+        co = self._comps(meta)
+        new = self._alloc.allocate(n)
+        for i, j in _runs(new):
+            self._write_run(new[i], view[i * BLOCK:j * BLOCK])
+        self._freed.extend(b for b in meta["extents"][bi:bi + n]
+                           if b >= 0)
+        meta["extents"][bi:bi + n] = new
+        co[bi:bi + n] = [None] * n
+        for k, nb in enumerate(new):
+            self._stage_csum(nb, view[k * BLOCK:(k + 1) * BLOCK],
+                             cs, bi + k)
 
     def _obj_zero(self, okey: str, meta: dict, offset: int,
                   length: int) -> None:
@@ -916,7 +1014,7 @@ class BlueStoreLite(ObjectStore):
                 # compressed — no decode/re-encode round-trip)
                 stored = self._stored_read(src, cs[bi], co[bi])
                 nb = self._alloc.allocate(1)[0]
-                self._write_block(nb, stored, pad=co[bi] is None)
+                self._write_run(nb, stored)
                 dst["extents"].append(nb)
                 dst["comp"].append(co[bi])
                 if src in self._pending_csum:
@@ -951,6 +1049,7 @@ class BlueStoreLite(ObjectStore):
             self._wal_pending = {}
             self._wal_rms = []
             self._pending_csum = {}
+            self._wrote = [0, 0]
             self._comp_cache.clear()
             # bind the batch's engine once: every block this batch
             # stages rides (or skips) the channel consistently, and
@@ -993,8 +1092,11 @@ class BlueStoreLite(ObjectStore):
 
             try:
                 t_apply = _time.perf_counter()
-                with tracing.span("bluestore apply", daemon="bluestore"):
+                with tracing.span("bluestore apply",
+                                  daemon="bluestore") as sp:
                     apply_ops()
+                    tracing.set_attrs(sp, runs=self._wrote[0],
+                                      blocks=self._wrote[1])
                 t_csum = _time.perf_counter()
                 self.perf.tinc("apply_lat", t_csum - t_apply)
                 # settle the batch's checksum debt (one coalesced

@@ -950,7 +950,8 @@ class BlueStoreStats:
 
     FIELDS = ("csum_batches", "csum_blocks", "csum_scalar_blocks",
               "csum_fallbacks", "read_verify_batches",
-              "read_verify_blocks", "compress_blocks",
+              "read_verify_blocks", "write_runs", "write_run_blocks",
+              "read_runs", "read_run_blocks", "compress_blocks",
               "compress_rejected", "compress_roundtrip_failures",
               "decompress_errors", "csum_errors",
               "kv_journal_truncated", "kv_journal_lost_bytes")
@@ -984,6 +985,12 @@ class BlueStoreStats:
             "csum_fallbacks": c.get("csum_fallbacks", 0),
             "read_verify_batches": c.get("read_verify_batches", 0),
             "read_verify_blocks": c.get("read_verify_blocks", 0),
+            # block-file I/O by extent run: blocks / runs is the
+            # blocks a positioned read or write moved
+            "write_runs": c.get("write_runs", 0),
+            "write_run_blocks": c.get("write_run_blocks", 0),
+            "read_runs": c.get("read_runs", 0),
+            "read_run_blocks": c.get("read_run_blocks", 0),
             "compress_blocks": c.get("compress_blocks", 0),
             "compress_rejected": c.get("compress_rejected", 0),
             "csum_errors": c.get("csum_errors", 0),

@@ -108,6 +108,12 @@ def test_aio_write_gives_one_complete_tree_per_op(ec_cluster):
         q = [r for r in spans if r["event"] == "opq wait"]
         assert len(q) == 6
         assert all({"klass", "phase"} <= set(r["attrs"]) for r in q)
+        # what each commit moved to the block file, and in how many
+        # runs: one block a shard here
+        applies = [r["attrs"] for r in spans
+                   if r["event"] == "bluestore apply"]
+        assert len(applies) == 6
+        assert all(a["blocks"] == a["runs"] == 1 for a in applies), applies
         # the critical path holds a span of every layer and is named
         path = span_readers.critical_path(rows)
         assert OP_LAYERS <= set(path), path
@@ -415,6 +421,11 @@ def test_degraded_aio_read_gives_one_complete_tree_per_op(degraded_cluster):
             if r["event"] in ("bluestore read blocks",
                               "bluestore csum verify"):
                 assert parent(r) == "bluestore read"
+            if r["event"] == "bluestore read blocks":
+                # a shard of 16 blocks, written whole into a fresh
+                # object: one extent run, one read of the block file
+                assert (r["attrs"]["blocks"], r["attrs"]["runs"]) \
+                    == (READ_SIZE // 4 // 4096, 1), r["attrs"]
         assert gather["end_ns"] >= max(
             r["start_ns"] for r in spans
             if r["event"] == "ec sub-read reply")
