@@ -667,6 +667,17 @@ class Module(MgrModule):
                     "mapping reads answered by a packed fused-row "
                     "slice (subset of the cache lookups)",
                     d.get("fused_lookups", 0))
+        exp.counter(f"{p}_delta_device_diffs_total",
+                    "epoch table diffs served by the device "
+                    "(mapping_delta_diff)",
+                    d.get("delta_device_diffs", 0))
+        exp.counter(f"{p}_delta_host_diffs_total",
+                    "epoch table diffs computed on the host (small "
+                    "tables, changed layout, no device)",
+                    d.get("delta_host_diffs", 0))
+        exp.counter(f"{p}_delta_upload_bytes_total",
+                    "bytes of packed tables uploaded for device diffs",
+                    d.get("delta_upload_bytes", 0))
         exp.gauge(f"{p}_host_tail_share",
                   "host-tail share of the total mapping epoch cost "
                   "(device + delta + host_tail) — collapses toward 0 "

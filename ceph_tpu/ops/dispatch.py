@@ -557,6 +557,13 @@ class DeviceDispatchEngine:
                 raise self._wedge_exc
         return True
 
+    def building(self) -> bool:
+        """True while the dispatch thread builds and launches a batch —
+        where a request's first shape traces and compiles, for as long
+        as that takes (a waiter that would give up asks this first)."""
+        with self._cv:
+            return self._building > 0
+
     def owns_current_thread(self) -> bool:
         """True when the caller IS one of this engine's own worker
         threads (dispatch/completion).  A submitter that would BLOCK on

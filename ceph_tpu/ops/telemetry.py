@@ -716,6 +716,13 @@ class MappingStats:
     slice (a subset of ``lookups``), and the ``host_tail_share`` gauge
     is the host-tail phase's share of the total epoch cost — the
     number ``profile phases`` watches collapse on a fused cluster.
+
+    The DELTA counters say who compared an epoch's tables with the
+    previous epoch's: ``delta_device_diffs`` counts table diffs the
+    device served (``mapping_delta_diff``), ``delta_upload_bytes`` the
+    bytes of both tables uploaded for them, and ``delta_host_diffs``
+    the diffs the host computed (tables at or under
+    ``FUSED_DIFF_HOST_MAX``, a changed layout, or no device).
     """
 
     __slots__ = ("_lock", "epoch_updates", "epoch_skips",
@@ -723,7 +730,9 @@ class MappingStats:
                  "lookups", "lookup_fallbacks", "update_latency",
                  "changed_pgs", "cached_pgs", "cached_pools",
                  "phase_device", "phase_delta", "phase_host_tail",
-                 "fused_epochs", "unfused_epochs", "fused_lookups")
+                 "fused_epochs", "unfused_epochs", "fused_lookups",
+                 "delta_device_diffs", "delta_host_diffs",
+                 "delta_upload_bytes")
 
     def __init__(self):
         self._lock = lockdep.make_lock("MappingStats::lock")
@@ -746,6 +755,10 @@ class MappingStats:
         self.fused_epochs = 0
         self.unfused_epochs = 0
         self.fused_lookups = 0
+        # who diffed the epochs' tables (see class docstring)
+        self.delta_device_diffs = 0
+        self.delta_host_diffs = 0
+        self.delta_upload_bytes = 0
 
     def clear(self) -> None:
         with self._lock:
@@ -762,6 +775,19 @@ class MappingStats:
             self.phase_host_tail = Histogram(LATENCY_BOUNDS)
             self.fused_epochs = self.unfused_epochs = 0
             self.fused_lookups = 0
+            self.delta_device_diffs = self.delta_host_diffs = 0
+            self.delta_upload_bytes = 0
+
+    def record_delta_diff(self, device: bool,
+                          upload_bytes: int = 0) -> None:
+        """One table diff of an epoch's delta: served by the device
+        (with the bytes uploaded for it) or computed on the host."""
+        with self._lock:
+            if device:
+                self.delta_device_diffs += 1
+                self.delta_upload_bytes += int(upload_bytes)
+            else:
+                self.delta_host_diffs += 1
 
     def record_phases(self, *, device_s: float, delta_s: float,
                       host_tail_s: float) -> None:
@@ -833,6 +859,9 @@ class MappingStats:
                 "fused_epochs": self.fused_epochs,
                 "unfused_epochs": self.unfused_epochs,
                 "fused_lookups": self.fused_lookups,
+                "delta_device_diffs": self.delta_device_diffs,
+                "delta_host_diffs": self.delta_host_diffs,
+                "delta_upload_bytes": self.delta_upload_bytes,
                 "host_tail_share": round(self._host_tail_share(), 6),
                 "phase_seconds": {
                     "device": self.phase_device.dump(),
@@ -878,6 +907,9 @@ class MappingStats:
                 "fused_epochs": self.fused_epochs,
                 "unfused_epochs": self.unfused_epochs,
                 "fused_lookups": self.fused_lookups,
+                "delta_device_diffs": self.delta_device_diffs,
+                "delta_host_diffs": self.delta_host_diffs,
+                "delta_upload_bytes": self.delta_upload_bytes,
                 "host_tail_share": round(self._host_tail_share(), 6),
             }
 

@@ -44,6 +44,7 @@ published — advance by building a NEW OSDMap with a higher epoch (OSDMap.copy
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from collections import deque
@@ -124,19 +125,37 @@ def rule_devices(crush: CrushMap, ruleno: int) -> tuple[int, ...]:
     return tuple(sorted(devs))
 
 
+@functools.lru_cache(maxsize=None)
+def _delta_diff_program():
+    """The epoch diff as one jitted program with a name of its own, so
+    that a device trace shows it as ``jit_mapping_delta_diff``.  Built
+    on first use: the scalar backend never imports jax."""
+    import jax
+    import jax.numpy as jnp
+
+    def mapping_delta_diff(old, new):
+        return jnp.any(old != new, axis=1)
+
+    return jax.jit(mapping_delta_diff)
+
+
 def _changed_rows(old: np.ndarray, new: np.ndarray,
                   mesh=None) -> np.ndarray:
     """Row indices where the two (pg_num, size) raw tables differ.
-    The elementwise compare + row reduce runs on device; only the
-    boolean row mask comes back to host.  With a ``mesh`` (the
-    context's kernel mesh) and a PG axis the mesh size divides —
-    pg_num is a power of two in practice — both tables split their PG
-    axis across the mesh, so the epoch diff fans out with the rest of
-    the mapping pipeline instead of serializing on one chip."""
+    The elementwise compare + row reduce runs on device
+    (``mapping_delta_diff``); only the boolean row mask comes back to
+    host.  With a ``mesh`` (the context's kernel mesh) and a PG axis
+    the mesh size divides — pg_num is a power of two in practice — both
+    tables split their PG axis across the mesh, so the epoch diff fans
+    out with the rest of the mapping pipeline instead of serializing on
+    one chip.  Counted in MappingStats (delta_device_diffs and the
+    bytes uploaded for them, or delta_host_diffs where no device
+    answered)."""
     if old.shape != new.shape:
         return np.arange(new.shape[0])
     if new.size == 0:
         return np.zeros(0, dtype=np.int64)
+    stats = telemetry.mapping_stats()
     try:
         import jax.numpy as jnp
         with tracing.span("mapping delta upload", daemon="mapping"):
@@ -154,14 +173,38 @@ def _changed_rows(old: np.ndarray, new: np.ndarray,
             else:
                 o, n = jnp.asarray(old), jnp.asarray(new)
         with tracing.span("mapping delta diff", daemon="mapping"):
-            dev_mask = jnp.any(o != n, axis=1)      # async dispatch
+            dev_mask = _delta_diff_program()(o, n)  # async dispatch
         # the host's wait for the device's answer, and its copy back
         with tracing.span("mapping delta read-back", daemon="mapping",
                           device_wait=True):
             mask = np.asarray(dev_mask)
+        stats.record_delta_diff(device=True,
+                                upload_bytes=old.nbytes + new.nbytes)
     except Exception:   # scalar backend / no device: host diff
         mask = (old != new).any(axis=1)
+        stats.record_delta_diff(device=False)
     return np.flatnonzero(mask)
+
+
+#: seconds an epoch waits for one of its engine requests before it
+#: asks whether the engine is still launching (_engine_result)
+ENGINE_WAIT_S = 120.0
+
+
+def _engine_result(engine, fut) -> np.ndarray:
+    """The engine's answer to one of an epoch's requests.  The engine's
+    completion thread has already copied it to the host (its
+    `materialize` phase), so np.asarray is free.  The wait gives up
+    after ENGINE_WAIT_S unless the engine is then launching a batch: a
+    request's first shape compiles inside the launch, and the programs
+    of a 1 Mi-PG pool's first build compile for well over a minute —
+    a cold first build must not read as a dead engine."""
+    while True:
+        try:
+            return np.asarray(fut.result(timeout=ENGINE_WAIT_S))
+        except TimeoutError:
+            if not engine.building():
+                raise
 
 
 def pool_signatures(m: OSDMap, reach: dict | None = None
@@ -480,11 +523,8 @@ class OSDMapMapping:
                 else:
                     raw[pool_id] = np.asarray(bm.do_rule(
                         pool.crush_rule, pps, pool.size, weights))
-            # the engine's completion thread has already copied the
-            # result to the host (its `materialize` phase): this is the
-            # wait for the engine, and np.asarray is free
             for pool_id, fut in futures:
-                raw[pool_id] = np.asarray(fut.result(timeout=120.0))
+                raw[pool_id] = _engine_result(engine, fut)
         fused: dict[int, np.ndarray] = {}
         fused_w: dict[int, int] = {}
         tail_sigs: dict[int, tuple] = {}
@@ -572,7 +612,7 @@ class OSDMapMapping:
                     cost_tag=("system", BACKGROUND_BEST_EFFORT)))
                         for pid, op in jobs]
                 for pid, fut in futs:
-                    fused[pid] = np.asarray(fut.result(timeout=120.0))
+                    fused[pid] = _engine_result(engine, fut)
                     fused_w[pid] = width
         else:
             # per-pool direct calls, NOT a concatenated group: pool
@@ -929,6 +969,7 @@ class SharedPGMappingService:
                     # production pool sizes take the device diff below
                     mask = np.flatnonzero((oldp != newp).any(axis=1))
                     changed.extend((pool_id, int(pg)) for pg in mask)
+                    self.stats.record_delta_diff(device=False)
                     continue
                 rows = _changed_rows(oldp, newp, mesh=mesh)
                 with tracing.span("mapping delta list", daemon="mapping",
@@ -946,6 +987,7 @@ class SharedPGMappingService:
             if k:
                 for pg in np.flatnonzero((a[:k] != b[:k]).any(axis=1)):
                     changed.append((pool_id, int(pg)))
+                self.stats.record_delta_diff(device=False)
             changed.extend((pool_id, pg)
                            for pg in range(k, newp.shape[0]))
         with tracing.span("mapping delta sort", daemon="mapping"):
@@ -1205,7 +1247,7 @@ class SharedPGMappingService:
                 packed = np.asarray(submit_finish_ladder(
                     engine, ops_,
                     cost_tag=("system", BACKGROUND_BEST_EFFORT),
-                ).result(timeout=120.0))
+                ).result(timeout=ENGINE_WAIT_S))
             else:
                 packed = pk.run_ladder(ops_)
         except Exception:
@@ -1240,5 +1282,5 @@ class SharedPGMappingService:
             return np.asarray(submit_do_rule(
                 engine, bm, ruleno, xs, numrep, reweight,
                 cost_tag=("system", BACKGROUND_BEST_EFFORT),
-            ).result(timeout=120.0))
+            ).result(timeout=ENGINE_WAIT_S))
         return np.asarray(bm.do_rule(ruleno, xs, numrep, reweight))

@@ -234,9 +234,15 @@ def test_two_stage_pallas_schedule_interpret():
 
     # cap overflow guard: capacity 8 certainly overflows -> whole-batch
     # recompute path, still exact
-    fm.STAGE2_CAP = 8
+    fm.STAGE2_CAP, fm.STAGE2_SHARE = 8, 1 << 20
     res_cap = np.asarray(fm.run(xs, rw, 3))
     np.testing.assert_array_equal(res_cap, res_xla)
+
+    # the capacity grows with the batch: one lane in four of 1,024
+    # holds what 8 could not, through the merge and not the guard
+    fm.STAGE2_SHARE = 4
+    res_share = np.asarray(fm.run(xs, rw, 3))
+    np.testing.assert_array_equal(res_share, res_xla)
 
 
 # -- tree buckets (batched descent vs the scalar oracle) ---------------------

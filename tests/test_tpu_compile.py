@@ -130,16 +130,20 @@ def columns():
 #: R = numrep + 1 columns, then FastMapper.STAGE2_CAP overflowing lanes
 #: get R = numrep + DEFAULT_BLOCK
 STAGES = [(N_PGS, 4), (4096, 9)]
+#: ... and of a pool of 1,048,576 PGs in one batch (the cell
+#: crush10k.weight_churn_1m): stage 2 holds one lane in
+#: FastMapper.STAGE2_SHARE of the batch
+STAGES_1M = [(1 << 20, 4), ((1 << 20) // 16, 9)]
 
 
-@pytest.mark.parametrize("n,R", STAGES)
+@pytest.mark.parametrize("n,R", STAGES + STAGES_1M)
 def test_root_columns(one_chip, columns, n, R):
     text = _compile(lambda xs: columns.root_columns(xs, None, R),
                     _spec(one_chip, (n,), jnp.uint32))
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("n,R", STAGES)
+@pytest.mark.parametrize("n,R", STAGES + STAGES_1M)
 def test_leaf_columns(one_chip, columns, n, R):
     text = _compile(lambda xs, pos: columns.leaf_columns(xs, pos, R),
                     _spec(one_chip, (n,), jnp.uint32),
@@ -148,7 +152,7 @@ def test_leaf_columns(one_chip, columns, n, R):
 
 
 # R = tries + numrep is the cannot-overflow recompute
-@pytest.mark.parametrize("n,R", STAGES + [(N_PGS, 54)])
+@pytest.mark.parametrize("n,R", STAGES + [(N_PGS, 54)] + STAGES_1M)
 def test_consume_columns(one_chip, n, R):
     col = _spec(one_chip, (R, n), jnp.int32)
     text = _compile(
@@ -202,10 +206,11 @@ def test_bitplane_transpose(one_chip):
     _jit_planes().lower(_spec(one_chip, (128, 4096), jnp.uint8)).compile()
 
 
-@pytest.mark.parametrize("erasure", [False, True])
-def test_placement_ladder(one_chip, erasure):
+@pytest.mark.parametrize("erasure,n_pgs", [(False, 1024), (True, 1024),
+                                            (False, 1 << 20)])
+def test_placement_ladder(one_chip, erasure, n_pgs):
     from ceph_tpu.ops.placement_kernel import _ladder_jit
-    n_pgs, w, pairs, n_osds = 1024, (12 if erasure else 3), 1, 10000
+    w, pairs, n_osds = (12 if erasure else 3), 1, 10000
     i32 = functools.partial(_spec, one_chip, dtype=jnp.int32)
     _ladder_jit(erasure).lower(
         i32((n_pgs, w)),                                   # raw
@@ -216,3 +221,14 @@ def test_placement_ladder(one_chip, erasure):
         i32((n_osds,)),                                    # state
         _spec(one_chip, (n_osds,), jnp.int64),             # weight
         i32((n_osds,))).compile()                          # affinity
+
+
+def test_mapping_delta_diff_of_two_1m_row_tables(one_chip):
+    """The epoch diff of two packed tables of a 1,048,576-PG pool of
+    size 3 (2 * 3 + 4 words a row), under its own name."""
+    from ceph_tpu.osd.mapping import _delta_diff_program
+    table = _spec(one_chip, (1 << 20, 10), jnp.int32)
+    compiled = _delta_diff_program().lower(table, table).compile()
+    assert "mapping_delta_diff" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == 1 << 20          # a byte a row
