@@ -167,6 +167,9 @@ class BlueStoreLite(ObjectStore):
         #: device call at commit fills them (scalar zlib on any failure
         #: — a csum is never committed unset)
         self._pending_csum: dict[int, bytes | memoryview] = {}
+        #: the same obligations as they were staged: ([blocks], the
+        #: buffer their payloads are consecutive slices of) per run
+        self._pending_runs: list[tuple[list[int], bytes | memoryview]] = []
         #: engine the in-flight batch rides (None = scalar batch)
         self._batch_eng = None
         #: cid -> resolved compression policy, cached per batch so the
@@ -444,9 +447,16 @@ class BlueStoreLite(ObjectStore):
         for b in self._freed:
             self._pending_csum.pop(b, None)
         pending, self._pending_csum = self._pending_csum, {}
+        staged, self._pending_runs = self._pending_runs, []
         if not pending:
             return
-        blocks = sorted(pending)
+        # in staging order, so that the engine can take each run's
+        # buffer whole; a block displaced within the batch breaks its
+        # run: then block by block, with no runs to offer
+        blocks = [b for run, _buf in staged for b in run]
+        runs = [buf for _run, buf in staged]
+        if len(blocks) != len(pending) or pending.keys() != set(blocks):
+            blocks, runs = sorted(pending), None
         blobs = [pending[b] for b in blocks]
         from ceph_tpu.ops import telemetry
         bs = telemetry.bluestore_stats()
@@ -456,9 +466,11 @@ class BlueStoreLite(ObjectStore):
                 self._conf("bluestore_batched_csum_min", 4)):
             from ceph_tpu.ops.dispatch import submit_bluestore_data
             try:
+                # `pending` keeps the runs' buffers alive, and this
+                # thread waits: the batch may alias them
                 dig = submit_bluestore_data(
-                    eng, blobs,
-                    cost_tag=("_bluestore", "client")).result(
+                    eng, blobs, cost_tag=("_bluestore", "client"),
+                    runs=runs).result(
                     timeout=float(
                         self._conf("bluestore_data_timeout", 30.0)))
                 crc_map = {b: int(dig[i, 0]) & 0xFFFFFFFF
@@ -607,11 +619,21 @@ class BlueStoreLite(ObjectStore):
         coalesced device call at commit), else the scalar crc32 the
         seed computed inline — which is also the flush's fallback, so
         a csum slot is never committed unset."""
-        if self._batch_eng is not None:
-            self._pending_csum[nb] = stored
-            cs[bi] = None
-        else:
-            cs[bi] = zlib.crc32(stored)
+        self._stage_run([nb], stored, cs, bi)
+
+    def _stage_run(self, new: list, view, cs: list, bi: int) -> None:
+        """``_stage_csum`` for a run: ``view`` holds the payloads of
+        the blocks ``new``, one BLOCK each (a lone block's may be
+        shorter: a compressed body), for the csum slots from ``bi``.
+        The flush offers the engine the run's buffer whole."""
+        parts = [view] if len(new) == 1 else [
+            view[k * BLOCK:(k + 1) * BLOCK] for k in range(len(new))]
+        if self._batch_eng is None:
+            cs[bi:bi + len(new)] = [zlib.crc32(p) for p in parts]
+            return
+        self._pending_csum.update(zip(new, parts))
+        self._pending_runs.append((new, view))
+        cs[bi:bi + len(new)] = [None] * len(new)
 
     def _patch_block(self, meta: dict, bi: int, boff: int,
                      chunk: bytes, okey: str | None = None,
@@ -724,12 +746,13 @@ class BlueStoreLite(ObjectStore):
         # payloads are views into the runs' buffers
         exts = [meta["extents"][bi] for bi in bis]
         comps = [co[bi] if bi < len(co) else None for bi in bis]
-        stored = []
+        stored, bufs = [], []
         with tracing.span("bluestore read blocks", daemon="bluestore",
                           blocks=len(bis)) as sp:
             runs = list(_runs(exts))
             for i, j in runs:
                 buf = memoryview(self._read_run(exts[i], j - i))
+                bufs.append(buf)
                 for k in range(i, j):
                     lo = (k - i) * BLOCK
                     stored.append(buf[lo:lo + (comps[k][1] if comps[k]
@@ -742,8 +765,11 @@ class BlueStoreLite(ObjectStore):
             # span parents under this one, which waits for it
             with tracing.span("bluestore csum verify", daemon="bluestore",
                               blocks=len(bis)):
+                # whole stored blocks are their runs' buffers, which
+                # `bufs` holds while this thread waits
                 dig = submit_bluestore_data(
-                    eng, stored, cost_tag=("_bluestore", "read")).result(
+                    eng, stored, cost_tag=("_bluestore", "read"),
+                    runs=None if any(comps) else bufs).result(
                     timeout=float(self._conf("bluestore_data_timeout",
                                              30.0)))
         except Exception:
@@ -873,9 +899,7 @@ class BlueStoreLite(ObjectStore):
                            if b >= 0)
         meta["extents"][bi:bi + n] = new
         co[bi:bi + n] = [None] * n
-        for k, nb in enumerate(new):
-            self._stage_csum(nb, view[k * BLOCK:(k + 1) * BLOCK],
-                             cs, bi + k)
+        self._stage_run(new, view, cs, bi)
 
     def _obj_zero(self, okey: str, meta: dict, offset: int,
                   length: int) -> None:
@@ -1021,6 +1045,7 @@ class BlueStoreLite(ObjectStore):
                     # source was written THIS batch: its crc is still
                     # pending; the clone owes the same digest
                     self._pending_csum[nb] = stored
+                    self._pending_runs.append(([nb], stored))
                     dst["csum"].append(None)
                 else:
                     dst["csum"].append(cs[bi])
@@ -1049,6 +1074,7 @@ class BlueStoreLite(ObjectStore):
             self._wal_pending = {}
             self._wal_rms = []
             self._pending_csum = {}
+            self._pending_runs = []
             self._wrote = [0, 0]
             self._comp_cache.clear()
             # bind the batch's engine once: every block this batch
@@ -1111,6 +1137,7 @@ class BlueStoreLite(ObjectStore):
                 self._wal_pending = {}
                 self._wal_rms = []
                 self._pending_csum = {}
+                self._pending_runs = []
                 self._comp_cache.clear()
                 self._block_dirty = False
                 raise

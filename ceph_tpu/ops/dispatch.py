@@ -1582,8 +1582,29 @@ def submit_scrub_digest(engine: DeviceDispatchEngine, blobs,
                                BACKGROUND_BEST_EFFORT))
 
 
+def _whole_block_batch(blobs, runs):
+    """The (len(blobs), w) uint8 batch as a VIEW of the caller's own
+    bytes, when ``runs`` are the buffers the blobs are consecutive
+    whole slices of and every blob is one full row (w a digest width:
+    no padding, so no unpad); else None.  One run is not copied at
+    all; several are concatenated once."""
+    from ceph_tpu.ops import checksum_kernel as ck
+    n = len(blobs)
+    if not runs or not n:
+        return None
+    w = len(blobs[0])
+    if (w != ck.row_width(w) or len(blobs[-1]) != w
+            or any(len(r) % w for r in runs)
+            or sum(len(r) for r in runs) != n * w):
+        return None
+    parts = [np.frombuffer(r, dtype=np.uint8) for r in runs]
+    flat = parts[0] if len(parts) == 1 else np.concatenate(parts)
+    return flat.reshape(n, w)
+
+
 def submit_bluestore_data(engine: DeviceDispatchEngine, blobs,
-                          key=None, cost_tag=None) -> DispatchFuture:
+                          key=None, cost_tag=None, *,
+                          runs=None) -> DispatchFuture:
     """Submit a batch of STORED block payloads (raw padded blocks or
     compressed bodies — lengths vary, which is exactly what the unpad
     epilogue absorbs) for checksumming through the engine — the SIXTH
@@ -1596,6 +1617,18 @@ def submit_bluestore_data(engine: DeviceDispatchEngine, blobs,
     (``dispatch.launch:bluestore_data``), the bounded retry ladder and
     a per-channel circuit breaker.
 
+    ``runs``: the buffers the blobs were cut from, when the caller
+    knows them — in order, ``b"".join(runs) == b"".join(blobs)``.
+    BlueStore stages a write's body and reads a shard by extent run,
+    so its blobs are consecutive 4,096-byte views of a few buffers
+    (of one, for a fresh object): when every blob is one full row the
+    batch is then a reshaped VIEW of those bytes, not a padded copy
+    built blob by blob.  The view aliases the caller's buffers and
+    the upload is asynchronous: nothing may change or free them
+    before the result is delivered (both store call sites hold them
+    and wait).  Blobs of unequal or short length (a compressed body,
+    a last partial block), or no ``runs``, take the padded build.
+
     The key is just the padded width, so concurrent transaction
     batches — different stores, different daemons on one context —
     coalesce into one device call, like every other channel.  The
@@ -1604,11 +1637,15 @@ def submit_bluestore_data(engine: DeviceDispatchEngine, blobs,
     differ, so the store path's health is observable on its own."""
     from ceph_tpu.ops import checksum_kernel as ck
     lengths = np.array([len(b) for b in blobs], dtype=np.int64)
-    w = ck.row_width(int(lengths.max()) if len(blobs) else 0)
-    data = np.zeros((len(blobs), w), dtype=np.uint8)
-    for i, b in enumerate(blobs):
-        if len(b):
-            data[i, :len(b)] = np.frombuffer(b, dtype=np.uint8)
+    data = _whole_block_batch(blobs, runs)
+    if data is not None:
+        w = data.shape[1]
+    else:
+        w = ck.row_width(int(lengths.max()) if len(blobs) else 0)
+        data = np.zeros((len(blobs), w), dtype=np.uint8)
+        for i, b in enumerate(blobs):
+            if len(b):
+                data[i, :len(b)] = np.frombuffer(b, dtype=np.uint8)
     mats, invp = ck.digest_operands(lengths, w)
     if key is None:
         key = ("bluestore_data", w)

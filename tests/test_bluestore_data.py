@@ -81,6 +81,63 @@ class TestBluestoreDataChannel:
         finally:
             eng.stop()
 
+    #: case -> (blocks per run, bytes before each run's view in its
+    #: buffer, bytes cut off the last blob)
+    RUN_CASES = {
+        "run_of_1": ([1], 0, 0), "run_of_3": ([3], 0, 0),
+        "run_of_128": ([128], 0, 0), "run_of_129": ([129], 0, 0),
+        "view_at_offset": ([5], 1234, 0), "two_runs": ([3, 2], 7, 0),
+        "mixed_short_last": ([4], 0, BLOCK - 100),
+    }
+
+    @pytest.mark.parametrize("case", sorted(RUN_CASES))
+    def test_runs_passed_as_buffers_bit_exact(self, case):
+        """A request whose blobs are whole consecutive slices of the
+        buffers passed as ``runs`` is built as a view of them (one
+        run: the engine is handed the caller's own bytes) and column
+        0 is zlib.crc32 per block; a short last blob takes the padded
+        build, with the same answer."""
+        from ceph_tpu.ops import dispatch
+        rng = np.random.default_rng(23)
+        counts, lead, cut = self.RUN_CASES[case]
+        runs = []
+        for n in counts:
+            raw = rng.integers(0, 256, lead + n * BLOCK - cut,
+                               dtype=np.uint8).tobytes()
+            runs.append(memoryview(raw)[lead:])
+        blobs = [r[lo:lo + BLOCK] for r in runs
+                 for lo in range(0, len(r), BLOCK)]
+        assert len(blobs) == sum(counts)
+        eng = _engine()
+        seen = []
+        real = eng.submit
+
+        def spy(key, fn, data, **kw):
+            seen.append(data)
+            return real(key, fn, data, **kw)
+
+        eng.submit = spy
+        try:
+            got = np.asarray(submit_bluestore_data(
+                eng, blobs, runs=runs).result(60))
+            # plain lists of bytes, no runs: the padded build
+            plain = np.asarray(submit_bluestore_data(
+                eng, [bytes(b) for b in blobs]).result(60))
+        finally:
+            eng.stop()
+        assert np.array_equal(plain, got)
+        assert got.shape == (len(blobs), 2)
+        for i, b in enumerate(blobs):
+            assert int(got[i, 0]) == zlib.crc32(b) & 0xFFFFFFFF, i
+        view = dispatch._whole_block_batch(blobs, runs)
+        if cut:
+            assert view is None
+            assert seen[0].flags.owndata        # the padded build's
+        else:
+            assert view.shape == (len(blobs), BLOCK)
+            assert np.shares_memory(seen[0], np.frombuffer(
+                runs[0], dtype=np.uint8)) == (len(runs) == 1)
+
     def test_shares_scrub_jit_executable(self):
         """bluestore_digest_batched delegates to the SAME jitted entry
         point scrub uses: digesting through both names at one width
