@@ -1629,15 +1629,33 @@ def submit_bluestore_data(engine: DeviceDispatchEngine, blobs,
     and wait).  Blobs of unequal or short length (a compressed body,
     a last partial block), or no ``runs``, take the padded build.
 
-    The key is just the padded width, so concurrent transaction
-    batches — different stores, different daemons on one context —
-    coalesce into one device call, like every other channel.  The
-    digest math IS the scrub kernel's (one checksum definition for
+    Whole-row requests carry no per-row operands: the unpad operands
+    of a shape whose every row is full depend on nothing but the
+    shape, and stay on the device (``checksum_kernel.
+    whole_row_operands``), so such a request uploads its data alone.
+    With a mesh on the engine the padded rows and their operands are
+    placed in lockstep, as before.
+
+    The key is the padded width and, for whole-row requests, their
+    kind, so concurrent transaction batches — different stores,
+    different daemons on one context — coalesce into one device call,
+    like every other channel, each kind with its own.  The digest
+    math IS the scrub kernel's (one checksum definition for
     store and scrub); only the channel label and telemetry family
     differ, so the store path's health is observable on its own."""
     from ceph_tpu.ops import checksum_kernel as ck
-    lengths = np.array([len(b) for b in blobs], dtype=np.int64)
+    submit_kw = dict(
+        label="bluestore_data", cache_entries=ck.digest_jit_entries,
+        cost_tag=cost_tag if cost_tag is not None
+        else ("_bluestore", "client"))
     data = _whole_block_batch(blobs, runs)
+    if data is not None and engine.placement_mesh() is None:
+        return engine.submit(
+            key if key is not None
+            else ("bluestore_data", data.shape[1], "whole"),
+            _digest_whole_rows, data, fallback=_whole_rows_oracle,
+            **submit_kw)
+    lengths = np.array([len(b) for b in blobs], dtype=np.int64)
     if data is not None:
         w = data.shape[1]
     else:
@@ -1659,8 +1677,17 @@ def submit_bluestore_data(engine: DeviceDispatchEngine, blobs,
         return scrub_digest_ref(batch, lens)
 
     return engine.submit(key, fn, data, aux=(lengths, mats, invp),
-                         label="bluestore_data",
-                         cache_entries=ck.digest_jit_entries,
-                         fallback=host_oracle,
-                         cost_tag=cost_tag if cost_tag is not None
-                         else ("_bluestore", "client"))
+                         fallback=host_oracle, **submit_kw)
+
+
+def _digest_whole_rows(batch):
+    """A batch of whole rows (zero rows of padding among them: they
+    digest as rows of zeros and are sliced off): the epilogue operands
+    are resident on the device, only the batch is uploaded."""
+    from ceph_tpu.ops.checksum_kernel import bluestore_digest_batched
+    return bluestore_digest_batched(batch)
+
+
+def _whole_rows_oracle(batch):
+    from ceph_tpu.ops.checksum_kernel import scrub_digest_ref
+    return scrub_digest_ref(batch, [batch.shape[1]] * batch.shape[0])

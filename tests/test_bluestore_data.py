@@ -138,6 +138,63 @@ class TestBluestoreDataChannel:
             assert np.shares_memory(seen[0], np.frombuffer(
                 runs[0], dtype=np.uint8)) == (len(runs) == 1)
 
+    @pytest.mark.parametrize("rows,width", [(1, BLOCK), (4, BLOCK),
+                                            (128, BLOCK), (8, 64)])
+    def test_whole_row_operands_equal_digest_operands(self, rows, width):
+        """The resident epilogue operands of a whole-row shape are
+        ``digest_operands([w] * n, w)``, dtypes included, and one
+        object a shape for the life of the process."""
+        mats, invp = ck.whole_row_operands(rows, width)
+        want_m, want_p = ck.digest_operands([width] * rows, width)
+        assert mats.dtype == np.uint32 and invp.dtype == np.uint8
+        assert np.array_equal(np.asarray(mats), want_m)
+        assert np.array_equal(np.asarray(invp), want_p)
+        again = ck.whole_row_operands(rows, width)
+        assert again[0] is mats and again[1] is invp
+
+    def test_second_whole_row_request_uploads_data_alone(self):
+        """The second request of a whole-row shape uploads one operand
+        (the kernel's ``bytes_in`` grows by the batch's bytes), builds
+        no operands and compiles nothing — and the padded build of the
+        same rows runs the same executable."""
+        rng = np.random.default_rng(29)
+        n = 16
+        buf = rng.integers(0, 256, n * BLOCK, dtype=np.uint8).tobytes()
+        blobs = [buf[i * BLOCK:(i + 1) * BLOCK] for i in range(n)]
+        ks = telemetry.registry().kernel("bluestore_data")
+        eng = _engine()
+        seen = []
+        real = eng.submit
+
+        def spy(key, fn, data, **kw):
+            seen.append((key, kw.get("aux")))
+            return real(key, fn, data, **kw)
+
+        eng.submit = spy
+        try:
+            first = np.asarray(submit_bluestore_data(
+                eng, blobs, runs=[buf]).result(60))
+            entries = ck.digest_jit_entries()
+            built = ck.whole_row_operands.cache_info().misses
+            bytes_in = ks.dump()["bytes_in"]
+            second = np.asarray(submit_bluestore_data(
+                eng, blobs, runs=[buf]).result(60))
+            assert ks.dump()["bytes_in"] - bytes_in == n * BLOCK
+            assert ck.whole_row_operands.cache_info().misses == built
+            assert ck.digest_jit_entries() == entries
+            padded = np.asarray(submit_bluestore_data(
+                eng, blobs).result(60))
+            assert ck.digest_jit_entries() == entries
+        finally:
+            eng.stop()
+        assert np.array_equal(first, second)
+        assert np.array_equal(first, padded)
+        # whole-row requests coalesce with their own kind only
+        assert [k for k, _aux in seen] == [
+            ("bluestore_data", BLOCK, "whole")] * 2 + [
+            ("bluestore_data", BLOCK)]
+        assert [aux is None for _k, aux in seen] == [True, True, False]
+
     def test_shares_scrub_jit_executable(self):
         """bluestore_digest_batched delegates to the SAME jitted entry
         point scrub uses: digesting through both names at one width
