@@ -752,6 +752,11 @@ class Module(MgrModule):
                     d["submits"])
         exp.counter(f"{p}_device_calls_total",
                     "coalesced device calls dispatched", d["batches"])
+        exp.counter(f"{p}_caller_thread_calls_total",
+                    "device calls a waiting submitter's own thread ran "
+                    "(idle engine, lone request: no hand-over to the "
+                    "engine's threads), of the device calls",
+                    d.get("caller_batches", 0))
         exp.counter(f"{p}_completed_total",
                     "requests delivered by the completion thread",
                     d["completed"])

@@ -299,9 +299,10 @@ class BlueStoreLite(ObjectStore):
         the CALLER is an engine worker thread (store commits
         run on completion threads via EC-write and recovery
         continuations; blocking on a future there would starve the
-        thread that delivers it).  The channel rides the decode engine
-        so store digests coalesce with scrub's — one checksum
-        definition, one width-bucketed batch stream."""
+        thread that delivers it — and there the waiting entry
+        would not run the request on the caller's thread either).  The
+        channel rides the decode engine, beside scrub's digests: one
+        checksum definition, one executable a shape."""
         if self._ctx is None:
             return None
         try:
@@ -432,11 +433,16 @@ class BlueStoreLite(ObjectStore):
 
     def _flush_pending_csums(self, cache) -> None:
         """Fill every csum slot the batch left pending with ONE
-        coalesced device digest over the stored payloads — the
+        device digest over the stored payloads — the
         ``bluestore_data`` channel, reusing the scrub digest kernel
-        (crc32 column).  The engine coalesces this call with scrub
-        digests and other stores' batches at equal width buckets.  Any
-        channel failure (breaker open, timeout, device fault) drops to
+        (crc32 column).  The request is offered by run (the buffers
+        the staged blocks are slices of: whole blocks become a view,
+        not a copy) and as a caller that waits: while this OSD's
+        decode engine is idle — one op in flight — this thread runs
+        it itself (``DeviceDispatchEngine.submit_waiting``); while the
+        engine is busy it queues, and coalesces with whole-block
+        batches of other stores on the context (padded ones coalesce
+        with their own kind).  Any channel failure (breaker open, timeout, device fault) drops to
         the scalar ``zlib.crc32`` oracle, so a csum slot is never
         committed unset.  Runs after apply, before the fsync/KV build,
         so the final metas carry real checksums."""
@@ -470,7 +476,7 @@ class BlueStoreLite(ObjectStore):
                 # thread waits: the batch may alias them
                 dig = submit_bluestore_data(
                     eng, blobs, cost_tag=("_bluestore", "client"),
-                    runs=runs).result(
+                    runs=runs, wait=True).result(
                     timeout=float(
                         self._conf("bluestore_data_timeout", 30.0)))
                 crc_map = {b: int(dig[i, 0]) & 0xFFFFFFFF
@@ -726,7 +732,10 @@ class BlueStoreLite(ObjectStore):
                            cs: list, co: list) -> dict:
         """Verify a wide read's block checksums in ONE device digest
         call (the same ``bluestore_data`` channel write commits use,
-        cost-tagged as read work).  Returns {bi: logical bytes (for an
+        cost-tagged as read work; offered by run and as a waiting
+        caller, like ``_flush_pending_csums``: this thread runs the
+        request while the engine is idle, and it queues and coalesces
+        while the engine is busy).  Returns {bi: logical bytes (for an
         uncompressed block a view into its run's buffer)} for
         the blocks it verified; {} routes the read through the scalar
         per-block path — including on any engine failure, so reads
@@ -769,7 +778,8 @@ class BlueStoreLite(ObjectStore):
                 # `bufs` holds while this thread waits
                 dig = submit_bluestore_data(
                     eng, stored, cost_tag=("_bluestore", "read"),
-                    runs=None if any(comps) else bufs).result(
+                    runs=None if any(comps) else bufs,
+                    wait=True).result(
                     timeout=float(self._conf("bluestore_data_timeout",
                                              30.0)))
         except Exception:

@@ -184,10 +184,11 @@ class _Request:
 
 class _Batch:
     __slots__ = ("out", "reqs", "slices", "exc", "t_dispatch", "misses",
-                 "profile", "via_fallback")
+                 "profile", "via_fallback", "on_caller")
 
     def __init__(self, out, reqs, slices, exc=None, t_dispatch=0.0,
-                 misses=None, profile=None, via_fallback=False):
+                 misses=None, profile=None, via_fallback=False,
+                 on_caller=False):
         self.out = out
         self.reqs = reqs
         self.slices = slices
@@ -198,6 +199,10 @@ class _Batch:
         #: oracle (open breaker): completion must not re-enter the
         #: device-retry ladder on its error
         self.via_fallback = via_fallback
+        #: the submitter's own thread built this batch and completes it
+        #: (submit_waiting): it holds its place in _inflight like any
+        #: other, and the completion thread leaves it alone
+        self.on_caller = on_caller
         #: dispatch-side half of the phase ledger (telemetry.PHASES):
         #: monotonic anchors + build/place/launch durations; the
         #: completion thread closes compute/materialize/deliver and
@@ -336,6 +341,8 @@ class DeviceDispatchEngine:
         self._key_totals: dict = {}
         self._inflight: deque[_Batch] = deque()
         self._building = 0          # batches being built/dispatched
+        self._caller_runs = 0       # requests running on their caller
+        self._caller_launching = False  # ... one of them not yet in flight
         self._stop = False
         #: role -> live thread ("submit" dispatches, "complete"
         #: materializes); supervised — see _thread_main
@@ -527,6 +534,12 @@ class DeviceDispatchEngine:
         with self._cv:
             self._stop = True
             self._cv.notify_all()
+            # a request running on its caller's thread is in flight
+            # like any other (and may be all there is: no thread to
+            # join): wait it out, bounded like the joins below
+            deadline = time.monotonic() + 5.0
+            while self._caller_runs and time.monotonic() < deadline:
+                self._cv.wait(0.05)
         self._probe_wake.set()
         for t in list(self._threads.values()):
             t.join(timeout=5.0)
@@ -613,6 +626,78 @@ class DeviceDispatchEngine:
         ``telemetry.TenantDeviceStats``.  Untagged requests land in
         the visible ``_untagged`` bucket — never dropped, so the
         ledger's tenant sum conserves the engine's busy-seconds."""
+        return self._enqueue(self._make_request(
+            key, fn, data, label=label, cache_entries=cache_entries,
+            aux=aux, place=place, fallback=fallback, cost_tag=cost_tag))
+
+    def submit_waiting(self, key, fn, data, **kw) -> DispatchFuture:
+        """``submit`` for a caller that will block on the result at
+        once (BlueStore's commit and wide read, under the store's
+        lock).  The engine picks the road by what it observes, by the
+        rule ``_dispatch_loop`` states — an idle engine flushes
+        immediately, lone ops never wait:
+
+        * nothing pending, building or in flight, the channel's
+          breaker closed, the engine running, the caller not one of
+          its threads: the CALLING thread does for a batch of one what
+          the dispatch and completion threads would — ``_dispatch_batch``
+          then ``_complete_batch``, so the same failpoint sites, stats,
+          phase ledger (``queue_wait`` 0), tenant ledger, spans, retry
+          ladder and breaker accounting — and the future comes back
+          resolved.  Two thread hand-overs out and one back are not
+          made.  While it runs the batch holds the launch and then
+          a place in ``_inflight`` like any other: a concurrent
+          ``submit`` queues, is launched after it and completes behind
+          it (per-key order holds), ``flush()`` and ``stop()`` wait
+          for it.  The wait for the device has no timeout here:
+          a caller that must not hang on a wedged device call keeps to
+          ``submit`` and ``result(timeout=)``.
+        * otherwise — engine busy, breaker open or half-open, engine
+          stopped or wedged, or an engine thread calling — the request
+          queues exactly as ``submit`` would, and coalesces.
+
+        Counted in ``stats.caller_batches`` (of ``batches``)."""
+        req = self._make_request(key, fn, data, **kw)
+        with self._cv:
+            breaker = self._breakers.get(req.label)
+            lone = not (self._stop or self._wedged or self._pending
+                        or self._building or self._inflight
+                        or (breaker is not None and breaker.state
+                            != telemetry.BREAKER_CLOSED)
+                        or threading.current_thread()
+                        in self._threads.values())
+            if lone:
+                self._building += 1
+                self._caller_runs += 1
+                self._caller_launching = True
+                self.stats.record_submit(req.stripes)
+        if not lone:
+            return self._enqueue(req)
+        batch = None
+        try:
+            batch = self._dispatch_batch([req], req.stripes, "idle", 1,
+                                         on_caller=True)
+            if batch is not None:
+                self._complete_batch(batch)
+        finally:
+            with self._cv:
+                self._caller_runs -= 1
+                self._caller_launching = False
+                if batch is not None and batch in self._inflight:
+                    # _complete_batch raised before its pop: an
+                    # on_caller head nobody else completes would stall
+                    # every batch behind it
+                    self._inflight.remove(batch)
+                self._cv.notify_all()
+            if not req.future.done():
+                req.future._deliver(None, RuntimeError(
+                    f"{self.name}: {req.label} request on its "
+                    f"caller's thread ended without a result"))
+        return req.future
+
+    def _make_request(self, key, fn, data, *, label=None,
+                      cache_entries=None, aux=None, place: bool = True,
+                      fallback=None, cost_tag=None) -> _Request:
         # analysis: allow[blocking] -- caller-input normalization: submit() receives host arrays (numpy/bytes), not device values
         data = np.asarray(data)
         stripes = int(data.shape[0]) if data.ndim else 1
@@ -623,16 +708,19 @@ class DeviceDispatchEngine:
                 if not a.ndim or a.shape[0] != stripes:
                     raise ValueError(
                         f"aux leading axis {a.shape} != stripes {stripes}")
-        req = _Request(key, fn, data, stripes, label=label,
-                       cache_entries=cache_entries, aux=aux, place=place,
-                       fallback=fallback, cost_tag=cost_tag)
+        return _Request(key, fn, data, stripes, label=label,
+                        cache_entries=cache_entries, aux=aux, place=place,
+                        fallback=fallback, cost_tag=cost_tag)
+
+    def _enqueue(self, req: _Request) -> DispatchFuture:
+        fn, data, aux, fallback = req.fn, req.data, req.aux, req.fallback
         with self._cv:
             if not self._stop and not self._wedged:
                 self._ensure_threads()
                 self._pending.append(req)
                 self._key_totals[req.key] = (
-                    self._key_totals.get(req.key, 0) + stripes)
-                self.stats.record_submit(stripes)
+                    self._key_totals.get(req.key, 0) + req.stripes)
+                self.stats.record_submit(req.stripes)
                 self._cv.notify_all()
                 return req.future
         # engine stopped: run inline so callers never hang.  First wait
@@ -718,6 +806,12 @@ class DeviceDispatchEngine:
                         break              # ripe + slot free
                     self._cv.wait(max(1e-4, min(deadline - now, 0.05))
                                   if now < deadline else 0.05)
+                # one launch at a time: a request its caller's thread
+                # is launching (submit_waiting) enters _inflight before
+                # anything dispatched from here — the order of
+                # admission, which the completion side delivers in
+                while self._caller_launching:
+                    self._cv.wait(0.05)
                 # collect the batch in ONE pass, partitioning the
                 # oldest request's key out of the deque: per-key FIFO
                 # is preserved (once size-capped, no later same-key
@@ -755,9 +849,14 @@ class DeviceDispatchEngine:
             self._dispatch_batch(reqs, total, reason, depth)
 
     def _dispatch_batch(self, reqs: list[_Request], total: int,
-                        reason: str, depth: int) -> None:
+                        reason: str, depth: int,
+                        on_caller: bool = False) -> _Batch | None:
         """Build the padded batch and issue the device call (runs
-        OUTSIDE the engine lock: a first-shape call traces+compiles)."""
+        OUTSIDE the engine lock: a first-shape call traces+compiles).
+        Returns the batch it put in flight (None: the engine wedged
+        meanwhile and the futures were failed); ``on_caller``: the
+        submitter's thread runs this and will complete the batch
+        itself (submit_waiting)."""
         now = time.monotonic()
         # slices first (pure arithmetic, cannot fail): the completion
         # thread zips reqs against slices, so every request must have
@@ -774,6 +873,7 @@ class DeviceDispatchEngine:
         devices = 1
         bucket, pad = total, 0
         via_fallback = False
+        batch = None
         channel = reqs[0].label
         try:
             # EVERYTHING fallible sits inside this try — mesh lookup,
@@ -874,7 +974,7 @@ class DeviceDispatchEngine:
                     reason=reason, delays=[now - r.t_submit for r in reqs],
                     depth=depth, devices=devices,
                     shard_stripes=(bucket // devices if devices > 1
-                                   else 0))
+                                   else 0), on_caller=on_caller)
             except Exception:
                 pass
             victims = None
@@ -886,18 +986,26 @@ class DeviceDispatchEngine:
                     # behind a thread that will never come back
                     victims = [r.future for r in reqs]
                 else:
-                    self._inflight.append(
-                        _Batch(out, reqs, slices, exc,
-                               t_dispatch=time.monotonic(),
-                               misses=misses, profile=profile,
-                               via_fallback=via_fallback))
+                    batch = _Batch(out, reqs, slices, exc,
+                                   t_dispatch=time.monotonic(),
+                                   misses=misses, profile=profile,
+                                   via_fallback=via_fallback,
+                                   on_caller=on_caller)
+                    self._inflight.append(batch)
                 self.stats.set_in_flight(len(self._inflight)
                                          + self._building)
-                self._cv.notify_all()
+                if on_caller:
+                    self._caller_launching = False
+                if not on_caller or self._pending:
+                    # (the caller's thread goes on to complete its own
+                    # batch: unless a request queued behind its launch,
+                    # no thread has anything to do with it)
+                    self._cv.notify_all()
             if victims is not None:
                 for fut in victims:
                     if not fut.done():
                         fut._deliver(None, self._wedge_exc)
+        return batch
 
     # -- completion thread ----------------------------------------------------
 
@@ -909,141 +1017,157 @@ class DeviceDispatchEngine:
             # into silent timeouts with every waiter stranded
             failpoint.hit("dispatch.complete_thread_death")
             with self._cv:
-                while not self._inflight:
-                    if (self._stop and not self._pending
-                            and not self._building):
+                # the head of the FIFO is this thread's, unless its
+                # submitter's own thread is completing it
+                # (submit_waiting): then whatever was dispatched
+                # behind it waits here for its pop
+                while not self._inflight or self._inflight[0].on_caller:
+                    if (not self._inflight and self._stop
+                            and not self._pending and not self._building):
                         return
-                    self._cv.wait(0.05 if self._stop else None)
+                    self._cv.wait(0.05 if self._stop or self._inflight
+                                  else None)
                 batch = self._inflight[0]
-            channel = batch.reqs[0].label
-            host, exc = None, batch.exc
-            t_ready = t_mat = 0.0
+            self._complete_batch(batch)
+
+    def _complete_batch(self, batch: _Batch) -> None:
+        """Materialize one in-flight batch, recover it if its device
+        path failed, pop it and fan its results out — on the
+        completion thread, or for an ``on_caller`` batch on its
+        submitter's."""
+        channel = batch.reqs[0].label
+        host, exc = None, batch.exc
+        t_ready = t_mat = 0.0
+        if exc is None:
+            try:
+                # split device compute from d2h: waiting out the
+                # async execution first (free — the work is already
+                # in flight) leaves np.asarray measuring only the
+                # materialize copy.  compute is anchored at launch
+                # end, so completion-thread pickup wait (which
+                # overlaps execution under double buffering) is
+                # attributed to compute, keeping the ledger gapless.
+                if not batch.via_fallback:
+                    failpoint.hit("dispatch.block_until_ready",
+                                  tag=channel)
+                wait = getattr(batch.out, "block_until_ready", None)
+                if wait is not None:
+                    try:
+                        wait()
+                    except Exception:
+                        pass   # np.asarray below surfaces the error
+                t_ready = time.monotonic()
+                host = np.asarray(batch.out)   # d2h materialize
+                t_mat = time.monotonic()
+            except BaseException as e:         # noqa: BLE001
+                exc = e
+        # supervised recovery: a failed device-path batch walks the
+        # bounded retry ladder, then the channel's host oracle; a
+        # batch the dispatch thread already served via the oracle
+        # never re-enters (its error is final)
+        if batch.via_fallback:
+            # same rule as the recovery ladder below: the "launch"
+            # anchor timed the host oracle, not a device call —
+            # recording it would let an outage dominate the steady
+            # device phase histograms with host-path runtimes
+            batch.profile = None
             if exc is None:
-                try:
-                    # split device compute from d2h: waiting out the
-                    # async execution first (free — the work is already
-                    # in flight) leaves np.asarray measuring only the
-                    # materialize copy.  compute is anchored at launch
-                    # end, so completion-thread pickup wait (which
-                    # overlaps execution under double buffering) is
-                    # attributed to compute, keeping the ledger gapless.
-                    if not batch.via_fallback:
-                        failpoint.hit("dispatch.block_until_ready",
-                                      tag=channel)
-                    wait = getattr(batch.out, "block_until_ready", None)
-                    if wait is not None:
-                        try:
-                            wait()
-                        except Exception:
-                            pass   # np.asarray below surfaces the error
-                    t_ready = time.monotonic()
-                    host = np.asarray(batch.out)   # d2h materialize
-                    t_mat = time.monotonic()
-                except BaseException as e:         # noqa: BLE001
-                    exc = e
-            # supervised recovery: a failed device-path batch walks the
-            # bounded retry ladder, then the channel's host oracle; a
-            # batch the dispatch thread already served via the oracle
-            # never re-enters (its error is final)
-            if batch.via_fallback:
-                # same rule as the recovery ladder below: the "launch"
-                # anchor timed the host oracle, not a device call —
-                # recording it would let an outage dominate the steady
-                # device phase histograms with host-path runtimes
-                batch.profile = None
-                if exc is None:
-                    total = batch.slices[-1][1] if batch.slices else 0
-                    self.stats.record_fallback(total)
-            elif exc is not None:
-                host, exc, how = self._recover_batch(batch, exc)
-                if how is not None:
-                    batch.profile = None   # phase anchors now span the
-                    # recovery ladder: keep the steady-state ledger
-                    # clean rather than record a fabricated profile
-                    t_ready = t_mat = time.monotonic()
-            else:
-                self._record_device_ok(channel)
-            with self._cv:
-                if self._inflight and self._inflight[0] is batch:
-                    self._inflight.popleft()
-                self.stats.set_in_flight(len(self._inflight)
-                                         + self._building)
+                total = batch.slices[-1][1] if batch.slices else 0
+                self.stats.record_fallback(total)
+        elif exc is not None:
+            host, exc, how = self._recover_batch(batch, exc)
+            if how is not None:
+                batch.profile = None   # phase anchors now span the
+                # recovery ladder: keep the steady-state ledger
+                # clean rather than record a fabricated profile
+                t_ready = t_mat = time.monotonic()
+        else:
+            self._record_device_ok(channel)
+        with self._cv:
+            if self._inflight and self._inflight[0] is batch:
+                self._inflight.popleft()
+            self.stats.set_in_flight(len(self._inflight)
+                                     + self._building)
+            if not batch.on_caller or self._pending or self._inflight:
+                # (a lone request on its caller's thread leaves nobody
+                # to wake: flush() and stop() poll)
                 self._cv.notify_all()
-            for req, (a, b) in zip(batch.reqs, batch.slices):
-                try:
-                    value = None if exc is not None else host[a:b]
-                    if req.trace is not None:
-                        self._deliver_traced(req, value, exc, batch,
-                                             t_ready, t_mat)
+        for req, (a, b) in zip(batch.reqs, batch.slices):
+            try:
+                value = None if exc is not None else host[a:b]
+                if req.trace is not None:
+                    self._deliver_traced(req, value, exc, batch,
+                                         t_ready, t_mat)
+                else:
+                    req.future._deliver(value, exc)
+            except BaseException as e:  # noqa: BLE001 — see below
+                # _deliver shields continuations with `except
+                # Exception` only; one raising past that (SystemExit
+                # in a done-callback) would escape here AFTER the
+                # batch was popped — the supervisor would revive the
+                # loop, but nothing could ever re-fan this batch, so
+                # its remaining futures would hang forever.  The
+                # future itself is already resolved (value set
+                # before callbacks run): log loudly and keep fanning.
+                from ceph_tpu.common.logging import dout
+                dout("dispatch", 0,
+                     "%s: continuation for %s raised past Exception"
+                     " (swallowed to protect the batch fan-out): %r",
+                     self.name, req.label, e)
+        self.stats.record_complete(len(batch.reqs))
+        if exc is None and batch.profile is not None:
+            pr = batch.profile
+            t_end = time.monotonic()
+            try:
+                self.stats.phases.record_batch(
+                    pr["family"],
+                    phases={"queue_wait": pr["t0"] - pr["t_submit0"],
+                            "build": pr["build"],
+                            "place": pr["place"],
+                            "launch": pr["launch"],
+                            "compute": t_ready - pr["t_launch_end"],
+                            "materialize": t_mat - t_ready,
+                            "deliver": t_end - t_mat},
+                    e2e_s=t_end - pr["t_submit0"],
+                    requests=len(batch.reqs),
+                    stripes=pr["stripes"], bucket=pr["bucket"],
+                    devices=pr["devices"], misses=batch.misses,
+                    on_caller=batch.on_caller)
+            except Exception:
+                pass   # profiling must never wedge completions
+            try:
+                # tenant apportionment: the SAME busy integral the
+                # phase ledger just accumulated (compute × devices),
+                # split across the batch's requests by stripe share
+                # — shares sum to 1 over the real stripes (padding
+                # carries no tag and no share), so the per-tenant
+                # ledger conserves busy_seconds exactly
+                busy = (t_ready - pr["t_launch_end"]) * pr["devices"]
+                total = max(1, pr["stripes"])
+                groups: dict = {}
+                for req in batch.reqs:
+                    tag = req.cost_tag
+                    if tag is None:
+                        tenant, klass = None, ""
+                    elif isinstance(tag, str):
+                        tenant, klass = tag, ""
                     else:
-                        req.future._deliver(value, exc)
-                except BaseException as e:  # noqa: BLE001 — see below
-                    # _deliver shields continuations with `except
-                    # Exception` only; one raising past that (SystemExit
-                    # in a done-callback) would escape here AFTER the
-                    # batch was popped — the supervisor would revive the
-                    # loop, but nothing could ever re-fan this batch, so
-                    # its remaining futures would hang forever.  The
-                    # future itself is already resolved (value set
-                    # before callbacks run): log loudly and keep fanning.
-                    from ceph_tpu.common.logging import dout
-                    dout("dispatch", 0,
-                         "%s: continuation for %s raised past Exception"
-                         " (swallowed to protect the batch fan-out): %r",
-                         self.name, req.label, e)
-            self.stats.record_complete(len(batch.reqs))
-            if exc is None and batch.profile is not None:
-                pr = batch.profile
-                t_end = time.monotonic()
-                try:
-                    self.stats.phases.record_batch(
-                        pr["family"],
-                        phases={"queue_wait": pr["t0"] - pr["t_submit0"],
-                                "build": pr["build"],
-                                "place": pr["place"],
-                                "launch": pr["launch"],
-                                "compute": t_ready - pr["t_launch_end"],
-                                "materialize": t_mat - t_ready,
-                                "deliver": t_end - t_mat},
-                        e2e_s=t_end - pr["t_submit0"],
-                        requests=len(batch.reqs),
-                        stripes=pr["stripes"], bucket=pr["bucket"],
-                        devices=pr["devices"], misses=batch.misses)
-                except Exception:
-                    pass   # profiling must never wedge completions
-                try:
-                    # tenant apportionment: the SAME busy integral the
-                    # phase ledger just accumulated (compute × devices),
-                    # split across the batch's requests by stripe share
-                    # — shares sum to 1 over the real stripes (padding
-                    # carries no tag and no share), so the per-tenant
-                    # ledger conserves busy_seconds exactly
-                    busy = (t_ready - pr["t_launch_end"]) * pr["devices"]
-                    total = max(1, pr["stripes"])
-                    groups: dict = {}
-                    for req in batch.reqs:
-                        tag = req.cost_tag
-                        if tag is None:
-                            tenant, klass = None, ""
-                        elif isinstance(tag, str):
-                            tenant, klass = tag, ""
-                        else:
-                            tenant, klass = tag[0], tag[1]
-                        g = groups.setdefault(
-                            (tenant, klass, req.label), [0, 0, []])
-                        g[0] += req.stripes
-                        g[1] += 1
-                        g[2].append(pr["t0"] - req.t_submit)
-                    ledger = telemetry.tenant_stats()
-                    for (tenant, klass, chan), (s, n, waits) \
-                            in groups.items():
-                        ledger.record_batch(
-                            tenant, klass,
-                            engine=self._ledger_engine, channel=chan,
-                            device_seconds=busy * (s / total),
-                            requests=n, stripes=s, queue_waits=waits)
-                except Exception:
-                    pass   # the ledger must never wedge completions
+                        tenant, klass = tag[0], tag[1]
+                    g = groups.setdefault(
+                        (tenant, klass, req.label), [0, 0, []])
+                    g[0] += req.stripes
+                    g[1] += 1
+                    g[2].append(pr["t0"] - req.t_submit)
+                ledger = telemetry.tenant_stats()
+                for (tenant, klass, chan), (s, n, waits) \
+                        in groups.items():
+                    ledger.record_batch(
+                        tenant, klass,
+                        engine=self._ledger_engine, channel=chan,
+                        device_seconds=busy * (s / total),
+                        requests=n, stripes=s, queue_waits=waits)
+            except Exception:
+                pass   # the ledger must never wedge completions
 
 
     @staticmethod
@@ -1065,6 +1189,7 @@ class DeviceDispatchEngine:
             attrs = {"kernel": req.label, "batch": len(batch.reqs),
                      "coalesced": len(batch.reqs) > 1,
                      "error": exc is not None,
+                     "caller_thread": batch.on_caller,
                      "h2d_bytes": int(req.data.nbytes)}
             if value is not None:
                 attrs["d2h_bytes"] = int(value.nbytes)
@@ -1604,7 +1729,7 @@ def _whole_block_batch(blobs, runs):
 
 def submit_bluestore_data(engine: DeviceDispatchEngine, blobs,
                           key=None, cost_tag=None, *,
-                          runs=None) -> DispatchFuture:
+                          runs=None, wait: bool = False) -> DispatchFuture:
     """Submit a batch of STORED block payloads (raw padded blocks or
     compressed bodies — lengths vary, which is exactly what the unpad
     epilogue absorbs) for checksumming through the engine — the SIXTH
@@ -1629,6 +1754,14 @@ def submit_bluestore_data(engine: DeviceDispatchEngine, blobs,
     and wait).  Blobs of unequal or short length (a compressed body,
     a last partial block), or no ``runs``, take the padded build.
 
+    ``wait``: the caller blocks on ``.result()`` at once, as both
+    store call sites do under the store's lock: the request goes
+    through ``engine.submit_waiting``, which runs it on the calling
+    thread while the engine is idle (a lone op: two thread hand-overs
+    out and one back are not made) and queues it like ``submit``
+    whenever the engine is busy, its breaker is not closed, or it is
+    stopped — so at depth requests still queue and coalesce.
+
     Whole-row requests carry no per-row operands: the unpad operands
     of a shape whose every row is full depend on nothing but the
     shape, and stay on the device (``checksum_kernel.
@@ -1648,9 +1781,10 @@ def submit_bluestore_data(engine: DeviceDispatchEngine, blobs,
         label="bluestore_data", cache_entries=ck.digest_jit_entries,
         cost_tag=cost_tag if cost_tag is not None
         else ("_bluestore", "client"))
+    submit = engine.submit_waiting if wait else engine.submit
     data = _whole_block_batch(blobs, runs)
     if data is not None and engine.placement_mesh() is None:
-        return engine.submit(
+        return submit(
             key if key is not None
             else ("bluestore_data", data.shape[1], "whole"),
             _digest_whole_rows, data, fallback=_whole_rows_oracle,
@@ -1676,8 +1810,8 @@ def submit_bluestore_data(engine: DeviceDispatchEngine, blobs,
         from ceph_tpu.ops.checksum_kernel import scrub_digest_ref
         return scrub_digest_ref(batch, lens)
 
-    return engine.submit(key, fn, data, aux=(lengths, mats, invp),
-                         fallback=host_oracle, **submit_kw)
+    return submit(key, fn, data, aux=(lengths, mats, invp),
+                  fallback=host_oracle, **submit_kw)
 
 
 def _digest_whole_rows(batch):

@@ -216,6 +216,7 @@ class PhaseStats:
     """
 
     __slots__ = ("_lock", "phase", "compile_seconds", "compile_events",
+                 "caller_batches",
                  "_compiled_keys", "busy_seconds", "devices_seen",
                  "shard_imbalance", "last_shard_imbalance", "records",
                  "_anchor")
@@ -226,6 +227,9 @@ class PhaseStats:
         self.phase: dict[tuple, Histogram] = {}
         self.compile_seconds: dict[str, float] = {}
         self.compile_events: dict[str, int] = {}
+        #: family -> batches its submitter's own thread ran
+        #: (dispatch.submit_waiting), of the family's batches
+        self.caller_batches: dict[str, int] = {}
         #: (family, bucket, devices) combos already charged a compile
         self._compiled_keys: set = set()
         self.busy_seconds = 0.0     # sum of compute_s * devices
@@ -240,6 +244,7 @@ class PhaseStats:
             self.phase = {}
             self.compile_seconds = {}
             self.compile_events = {}
+            self.caller_batches = {}
             self._compiled_keys = set()
             self.busy_seconds = 0.0
             self.devices_seen = 1
@@ -254,13 +259,18 @@ class PhaseStats:
 
     def record_batch(self, family: str, *, phases: dict, e2e_s: float,
                      requests: int, stripes: int, bucket: int,
-                     devices: int, misses=None) -> None:
+                     devices: int, misses=None,
+                     on_caller: bool = False) -> None:
         """One flushed batch's full ledger.  ``phases`` maps PHASES
         names to seconds (missing = 0); ``misses`` is the submitter's
         jit-cache delta when probed (None = not probed — first-call
-        detection falls back to the (family, bucket, devices) set)."""
+        detection falls back to the (family, bucket, devices) set);
+        ``on_caller``: the submitter's thread ran the batch."""
         d = max(1, int(devices))
         with self._lock:
+            if on_caller:
+                self.caller_batches[family] = \
+                    self.caller_batches.get(family, 0) + 1
             key = (family, int(bucket), d)
             first = key not in self._compiled_keys
             if first:
@@ -293,6 +303,7 @@ class PhaseStats:
                 "requests": int(requests), "stripes": int(stripes),
                 "bucket": int(bucket), "devices": d,
                 "compiled": bool(compiled), "e2e_s": float(e2e_s),
+                "caller_thread": bool(on_caller),
                 "phases": {ph: float(phases.get(ph, 0.0))
                            for ph in PHASES}})
 
@@ -323,6 +334,7 @@ class PhaseStats:
                 "compile": {f: {"seconds": self.compile_seconds[f],
                                 "events": self.compile_events.get(f, 0)}
                             for f in self.compile_seconds},
+                "caller_batches": dict(self.caller_batches),
                 "busy_seconds": self.busy_seconds,
                 "utilization": round(util, 4),
                 "devices_seen": self.devices_seen,
@@ -356,6 +368,7 @@ class PhaseStats:
                                     for ph in PHASES
                                     if (family, ph) in self.phase),
                                    default=0),
+                    "caller_batches": self.caller_batches.get(family, 0),
                 }
             return {
                 "kernels": out_f,
@@ -398,6 +411,7 @@ class DispatchStats:
     """
 
     __slots__ = ("_lock", "submits", "stripes_in", "batches",
+                 "caller_batches",
                  "stripes_out", "padded_stripes", "completed",
                  "coalesce", "queue_delay", "queue_depth",
                  "flush_reasons", "in_flight", "max_in_flight_seen",
@@ -417,6 +431,7 @@ class DispatchStats:
         self.submits = 0          # requests submitted
         self.stripes_in = 0       # stripes submitted
         self.batches = 0          # device calls dispatched
+        self.caller_batches = 0   # ... of them on the submitter's thread
         self.stripes_out = 0      # stripes dispatched (pre-padding)
         self.padded_stripes = 0   # zero rows added by shape bucketing
         self.completed = 0        # requests delivered
@@ -454,6 +469,7 @@ class DispatchStats:
         with self._lock:
             self.submits = self.stripes_in = 0
             self.batches = self.stripes_out = self.padded_stripes = 0
+            self.caller_batches = 0
             self.completed = 0
             self.coalesce = Histogram(COALESCE_BOUNDS)
             self.queue_delay = Histogram(LATENCY_BOUNDS)
@@ -481,9 +497,14 @@ class DispatchStats:
 
     def record_batch(self, *, requests: int, stripes: int, padded: int,
                      reason: str, delays, depth: int,
-                     devices: int = 1, shard_stripes: int = 0) -> None:
+                     devices: int = 1, shard_stripes: int = 0,
+                     on_caller: bool = False) -> None:
+        """``on_caller``: a waiting submitter's own thread ran the
+        batch (dispatch.submit_waiting), no engine thread did."""
         with self._lock:
             self.batches += 1
+            if on_caller:
+                self.caller_batches += 1
             self.stripes_out += stripes
             self.padded_stripes += padded
             self.coalesce.add(requests)
@@ -589,6 +610,7 @@ class DispatchStats:
                 "submits": self.submits,
                 "stripes_in": self.stripes_in,
                 "batches": self.batches,
+                "caller_batches": self.caller_batches,
                 "stripes_out": self.stripes_out,
                 "padded_stripes": self.padded_stripes,
                 "completed": self.completed,
@@ -614,6 +636,7 @@ class DispatchStats:
             return {
                 "submits": self.submits,
                 "device_calls": batches,
+                "caller_thread_calls": self.caller_batches,
                 "mean_coalesce": (round(self.coalesce.sum / batches, 2)
                                   if batches else 0.0),
                 "p99_queue_delay_ms": round(

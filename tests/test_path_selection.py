@@ -1,4 +1,5 @@
-"""The old paths that remain, reached the way the code reaches them.
+"""The paths the code picks between, reached the way the code reaches
+them.
 
 No option selects between an old and a new path any more: the scalar
 scan, the host ``zlib.crc32`` loop, the per-block read verify, the
@@ -7,12 +8,20 @@ taken when the code OBSERVES a reason (an exception from the service, a
 store with no context, a caller that is an engine thread, a batch under
 a floor, a row over the kernel's width cap, a whole-object codec).  Each
 case below produces one such reason and checks that the old path ran
-and that its answer is bit-equal to the new path's."""
+and that its answer is bit-equal to the new path's.
+
+The same holds for the one choice between two live paths: a digest
+request whose caller waits (``submit_bluestore_data(..., wait=True)``
+-> ``DeviceDispatchEngine.submit_waiting``) runs on the caller's own
+thread while the engine is idle and queues like any ``submit``
+otherwise.  The ``waiting_caller_*`` cases produce each observed
+condition and check the road taken by the engine's counters."""
 
 from __future__ import annotations
 
 import json
 import threading
+import time
 import zlib
 
 import numpy as np
@@ -20,8 +29,10 @@ import pytest
 
 from ceph_tpu.objectstore import Transaction
 from ceph_tpu.objectstore.bluestore import BLOCK, BlueStoreLite
+from ceph_tpu.common import failpoint
 from ceph_tpu.ops import checksum_kernel as ck
-from ceph_tpu.ops.dispatch import submit_bluestore_data
+from ceph_tpu.ops import telemetry
+from ceph_tpu.ops.dispatch import DeviceDispatchEngine, submit_bluestore_data
 from ceph_tpu.osd.daemon import OSDDaemon
 from ceph_tpu.osd.mapping import SharedPGMappingService
 from ceph_tpu.tools.vstart import MiniCluster
@@ -321,6 +332,278 @@ def _case_balancer_warm_fails_scalar_histogram(tmp_path, monkeypatch):
     assert balancer.pool_pg_histogram(m, 1) == cached
 
 
+# -- a waiting caller: its own thread, or the queue ----------------------------
+
+def _engine() -> DeviceDispatchEngine:
+    eng = DeviceDispatchEngine(stats=telemetry.DispatchStats(),
+                               name="path-sel")
+    eng.fault_backoff_ms = 1.0
+    eng.fault_backoff_max_ms = 5.0
+    eng.probe_interval = 30.0       # no probe re-closes a breaker here
+    return eng
+
+
+def _blocks(seed: int, n: int):
+    buf = _payload(seed, n * BLOCK)
+    return buf, [buf[i * BLOCK:(i + 1) * BLOCK] for i in range(n)]
+
+
+def _crcs(blobs) -> list:
+    return [zlib.crc32(b) & 0xFFFFFFFF for b in blobs]
+
+
+def _col0(fut) -> list:
+    return [int(v) for v in np.asarray(fut.result(60))[:, 0]]
+
+
+def _gated(gate: threading.Event, entered: threading.Event):
+    def fn(batch):
+        entered.set()
+        assert gate.wait(60)
+        return batch
+    return fn
+
+
+def _case_waiting_caller_idle_engine_runs_on_its_thread(tmp_path,
+                                                        monkeypatch):
+    """Idle engine, waiting caller: the caller's thread builds,
+    launches and completes the batch — the new counter and ``batches``
+    step together, the engine's threads are never started, the future
+    comes back resolved and the phase ledger records no queue wait.
+    A store's commit and wide read take that road by themselves."""
+    eng = _engine()
+    try:
+        buf, blobs = _blocks(31, 8)
+        fut = submit_bluestore_data(eng, blobs, runs=[buf], wait=True)
+        assert fut.done()
+        assert _col0(fut) == _crcs(blobs)
+        d = eng.stats.dump()
+        assert (d["submits"], d["batches"], d["caller_batches"],
+                d["completed"]) == (1, 1, 1, 1)
+        assert d["flush_reasons"]["idle"] == 1
+        assert not eng._threads                 # no hand-over, ever
+        rec = eng.stats.phases.dump()["recent"][-1]
+        assert rec["caller_thread"] and rec["kernel"] == "bluestore_data"
+        assert rec["phases"]["queue_wait"] < 1e-3
+        assert eng.stats.phases.summary()["kernels"]["bluestore_data"][
+            "caller_batches"] == 1
+        # the plain entry still queues: engine threads, no new count
+        assert _col0(submit_bluestore_data(eng, blobs)) == _crcs(blobs)
+        d = eng.stats.dump()
+        assert (d["batches"], d["caller_batches"]) == (2, 1)
+        assert sorted(eng._threads) == ["complete", "submit"]
+        assert eng.flush()
+    finally:
+        eng.stop()
+    ctx = _ctx("path-sel-caller", bluestore_batched_csum_min=1)
+    s = _store(tmp_path, ctx, "s")
+    try:
+        stats = ctx.decode_dispatch_engine().stats
+        n0 = stats.dump()["caller_batches"]
+        payload = _payload(32, 16 * BLOCK)
+        _write(s, "o", payload)
+        assert stats.dump()["caller_batches"] == n0 + 1     # the commit
+        assert s.read("2.0", "o") == payload
+        assert stats.dump()["caller_batches"] == n0 + 2     # the read
+        assert _csums(s, "o") == _crcs(
+            [payload[i:i + BLOCK] for i in range(0, len(payload), BLOCK)])
+    finally:
+        s.umount()
+        _stop_engines(ctx)
+
+
+def _case_waiting_caller_behind_a_request_in_flight_queues(tmp_path,
+                                                           monkeypatch):
+    """A request already in flight: the waiting caller's is queued and
+    delivered after it; and the other way round — while a request runs
+    on its caller's thread a plain ``submit`` queues and completes
+    behind it."""
+    eng = _engine()
+    order = []
+    try:
+        data = np.arange(8, dtype=np.uint8).reshape(2, 4)
+        gate, entered = threading.Event(), threading.Event()
+        first = eng.submit(("k", 4), _gated(gate, entered), data)
+        first.add_done_callback(lambda _f: order.append("first"))
+        assert entered.wait(60)
+        waiting = eng.submit_waiting(("k", 4), lambda b: b + 1, data)
+        waiting.add_done_callback(lambda _f: order.append("waiting"))
+        time.sleep(0.1)
+        assert not first.done() and not waiting.done()
+        assert eng.stats.dump()["caller_batches"] == 0
+        gate.set()
+        assert np.array_equal(waiting.result(60), data + 1)
+        assert order == ["first", "waiting"]
+        assert eng.flush()
+
+        gate, entered = threading.Event(), threading.Event()
+        box = {}
+
+        def on_caller():
+            box["fut"] = eng.submit_waiting(
+                ("k", 4), _gated(gate, entered), data)
+            order.append("caller")
+
+        t = threading.Thread(target=on_caller)
+        t.start()
+        assert entered.wait(60)
+        behind = eng.submit(("k", 4), lambda b: b + 2, data)
+        behind.add_done_callback(lambda _f: order.append("behind"))
+        time.sleep(0.3)         # past the flush deadline: dispatched
+        assert not behind.done()
+        gate.set()
+        t.join(60)
+        assert np.array_equal(behind.result(60), data + 2)
+        assert np.array_equal(box["fut"].result(0), data)
+        assert order[2:] == ["caller", "behind"]
+        assert eng.stats.dump()["caller_batches"] == 1
+    finally:
+        gate.set()
+        eng.stop()
+
+
+def _case_waiting_caller_breaker_open_host_oracle_via_queue(tmp_path,
+                                                            monkeypatch):
+    """The channel's breaker is open: the request is queued and the
+    dispatch thread serves it from the host oracle, as for any
+    ``submit`` — no attempt at the device from the caller's thread."""
+    eng = _engine()
+    eng.breaker_threshold = 1
+    try:
+        buf, blobs = _blocks(33, 4)
+        failpoint.set("dispatch.launch:bluestore_data", "always")
+        opener = submit_bluestore_data(eng, blobs, runs=[buf], wait=True)
+        assert _col0(opener) == _crcs(blobs)
+        assert eng.breaker_states()["bluestore_data"] \
+            == telemetry.BREAKER_OPEN
+        d0 = eng.stats.dump()
+        assert d0["caller_batches"] == 1
+        fut = submit_bluestore_data(eng, blobs, runs=[buf], wait=True)
+        assert _col0(fut) == _crcs(blobs)
+        d = eng.stats.dump()
+        assert d["caller_batches"] == 1         # queued
+        assert d["batches"] == d0["batches"] + 1
+        assert d["faults"]["fallback_batches"] \
+            == d0["faults"]["fallback_batches"] + 1
+        assert d["faults"]["retries"] == d0["faults"]["retries"]
+        assert "submit" in eng._threads
+    finally:
+        failpoint.clear()
+        eng.stop()
+
+
+def _case_waiting_caller_on_engine_thread_always_queues(tmp_path,
+                                                        monkeypatch):
+    """A continuation on the engine's completion thread that asks for
+    the waiting entry — the engine is idle at that moment, its batch
+    just popped — is queued: an engine thread never runs a request
+    itself."""
+    eng = _engine()
+    try:
+        buf, blobs = _blocks(34, 4)
+
+        def ask():
+            fut = submit_bluestore_data(eng, blobs, runs=[buf], wait=True)
+            return fut, fut.done(), eng.stats.dump()["caller_batches"]
+
+        fut, done_at_once, counted = _on_engine_thread(eng, ask)
+        assert not done_at_once and counted == 0
+        assert _col0(fut) == _crcs(blobs)
+        assert eng.stats.dump()["caller_batches"] == 0
+    finally:
+        eng.stop()
+
+
+def _case_waiting_caller_launch_fault_retry_then_oracle(tmp_path,
+                                                        monkeypatch):
+    """``dispatch.launch:bluestore_data`` injected on the caller's
+    thread: one fault is retried there and heals; a lasting one walks
+    the ladder to the host oracle and opens the breaker at its
+    threshold, with the counters of the queued path; a store above it
+    commits exact checksums with no ``csum_fallbacks``."""
+    eng = _engine()
+    eng.breaker_threshold = 2
+    try:
+        buf, blobs = _blocks(35, 4)
+        failpoint.set("dispatch.launch:bluestore_data", "nth:1")
+        assert _col0(submit_bluestore_data(
+            eng, blobs, runs=[buf], wait=True)) == _crcs(blobs)
+        f = eng.stats.fault_dump()
+        assert (f["retries"], f["retry_successes"],
+                f["fallback_batches"]) == (1, 1, 0)
+        failpoint.set("dispatch.launch:bluestore_data", "always")
+        for n in (1, 2):
+            assert _col0(submit_bluestore_data(
+                eng, blobs, runs=[buf], wait=True)) == _crcs(blobs)
+            f = eng.stats.fault_dump()
+            assert f["fallback_batches"] == n
+            assert f["breaker_opens"] == (n == 2)
+        assert f["retries"] == 1 + 2 * eng.fault_max_retries
+        d = eng.stats.dump()
+        assert (d["batches"], d["caller_batches"]) == (3, 3)
+        assert not {"submit", "complete"} & set(eng._threads)
+    finally:
+        failpoint.clear()
+        eng.stop()
+    ctx = _ctx("path-sel-caller-fault", bluestore_batched_csum_min=1)
+    s = _store(tmp_path, ctx, "s")
+    try:
+        ctx.decode_dispatch_engine().fault_backoff_ms = 1.0
+        fb0 = telemetry.bluestore_summary()["csum_fallbacks"]
+        failpoint.set("dispatch.launch:bluestore_data", "always")
+        payload = _payload(36, 6 * BLOCK)
+        _write(s, "o", payload)
+        failpoint.clear()
+        assert _csums(s, "o") == _crcs(
+            [payload[i:i + BLOCK] for i in range(0, len(payload), BLOCK)])
+        assert telemetry.bluestore_summary()["csum_fallbacks"] == fb0
+        assert s.perf.value("csum_batches") == 1
+    finally:
+        failpoint.clear()
+        s.umount()
+        _stop_engines(ctx)
+
+
+def _case_waiting_caller_flush_and_stop_wait_for_its_request(tmp_path,
+                                                             monkeypatch):
+    """``flush()`` and ``stop()`` during a request that runs on its
+    caller's thread return only after it, though the engine has no
+    thread of its own to join."""
+    eng = _engine()
+    gate, entered = threading.Event(), threading.Event()
+    data = np.zeros((2, 4), dtype=np.uint8)
+    t_done = {}
+
+    def on_caller():
+        eng.submit_waiting(("k", 4), _gated(gate, entered), data)
+        t_done["request"] = time.monotonic()
+
+    def stopper():
+        t_done["stop_ok"] = eng.stop()
+        t_done["stop"] = time.monotonic()
+
+    t = threading.Thread(target=on_caller)
+    t.start()
+    try:
+        assert entered.wait(60)
+        assert eng.building() or eng._inflight
+        assert eng.flush(timeout=0.2) is False
+        s = threading.Thread(target=stopper)
+        s.start()
+        time.sleep(0.3)
+        assert s.is_alive() and "stop" not in t_done
+        gate.set()
+        t.join(60)
+        s.join(60)
+        assert t_done["stop_ok"] is True
+        assert t_done["stop"] >= t_done["request"]
+        assert eng.flush(timeout=1.0)
+        assert eng.stats.dump()["caller_batches"] == 1
+    finally:
+        gate.set()
+        eng.stop()
+
+
 CASES = {
     "update_to_raises_full_scalar_scan":
         _case_update_to_raises_full_scalar_scan,
@@ -336,6 +619,18 @@ CASES = {
         _case_whole_object_codec_sync_encode_and_decode,
     "balancer_warm_fails_scalar_histogram":
         _case_balancer_warm_fails_scalar_histogram,
+    "waiting_caller_idle_engine_runs_on_its_thread":
+        _case_waiting_caller_idle_engine_runs_on_its_thread,
+    "waiting_caller_behind_a_request_in_flight_queues":
+        _case_waiting_caller_behind_a_request_in_flight_queues,
+    "waiting_caller_breaker_open_host_oracle_via_queue":
+        _case_waiting_caller_breaker_open_host_oracle_via_queue,
+    "waiting_caller_on_engine_thread_always_queues":
+        _case_waiting_caller_on_engine_thread_always_queues,
+    "waiting_caller_launch_fault_retry_then_oracle":
+        _case_waiting_caller_launch_fault_retry_then_oracle,
+    "waiting_caller_flush_and_stop_wait_for_its_request":
+        _case_waiting_caller_flush_and_stop_wait_for_its_request,
 }
 
 

@@ -462,6 +462,70 @@ def test_degraded_aio_read_gives_one_complete_tree_per_op(degraded_cluster):
                 <= set(on_path)
 
 
+def _digest_requests_keep_their_spans(rows, waiter: str) -> int:
+    """Every ``device bluestore_data`` request of the trace hangs under
+    the store span that waits for it and carries the engine's seven
+    phases, gapless and in order, whichever thread ran it; one that
+    its caller's own thread ran (``caller_thread``) queued for nothing.
+    Returns how many ran on their caller's thread."""
+    spans = [r for r in rows if r["kind"] == "span"]
+    by_id = {r["span_id"]: r for r in spans}
+    reqs = [r for r in spans if r["event"] == "device bluestore_data"]
+    assert reqs
+    for req in reqs:
+        assert by_id[req["parent_span_id"]]["event"] == waiter
+        assert req["layer"] == tracing.LAYER_ENGINE
+        phases = [r for r in spans if r["parent_span_id"] == req["span_id"]]
+        assert [r["event"] for r in phases] \
+            == [f"engine {p}" for p in tracing.ENGINE_PHASES]
+        assert phases[0]["start_ns"] == req["start_ns"]
+        for a, b in zip(phases, phases[1:]):
+            assert a["end_ns"] == b["start_ns"]
+        assert phases[-1]["end_ns"] <= req["end_ns"]
+        assert {"h2d_bytes", "d2h_bytes", "retrace", "batch",
+                "caller_thread"} <= set(req["attrs"])
+        if req["attrs"]["caller_thread"]:
+            wait = phases[0]
+            assert wait["end_ns"] - wait["start_ns"] < 1_000_000
+            assert req["attrs"]["batch"] == 1
+        # the store's span waits for the request: it ends no earlier
+        assert by_id[req["parent_span_id"]]["end_ns"] >= req["end_ns"]
+    return sum(bool(r["attrs"]["caller_thread"]) for r in reqs)
+
+
+def test_traced_commit_keeps_digest_spans_on_the_callers_thread(ec_cluster):
+    """A write of eight blocks a shard: each replica shard's commit
+    settles its checksums by one ``bluestore_data`` request, which at
+    one op in flight its committing thread runs itself — under
+    ``bluestore csum settle`` all the same, phases and all."""
+    _c, io = ec_cluster
+    _write(io, ["settle-warm"], size=4 * 8 * 4096)  # the shape compiled
+    tracing.set_sample_rate(1.0)
+    _write(io, ["settle"], size=4 * 8 * 4096)
+    tracing.set_sample_rate(0.0)
+    (rows,) = _op_traces()
+    assert _digest_requests_keep_their_spans(
+        rows, "bluestore csum settle") >= 1
+    path = span_readers.critical_path(rows)
+    assert path["unnamed"] <= UNNAMED_MAX * path["root"], path
+
+
+def test_traced_wide_read_keeps_digest_spans_on_the_callers_thread(
+        degraded_cluster):
+    """A shard read of sixteen blocks verifies them by one
+    ``bluestore_data`` request on the reading thread, under
+    ``bluestore csum verify``."""
+    _c, io, blobs, _lost = degraded_cluster
+    tracing.set_sample_rate(1.0)
+    _read(io, blobs)
+    tracing.set_sample_rate(0.0)
+    on_caller = 0
+    for rows in _op_traces():
+        on_caller += _digest_requests_keep_their_spans(
+            rows, "bluestore csum verify")
+    assert on_caller >= 1
+
+
 def test_unarmed_degraded_reads_record_nothing(degraded_cluster):
     _c, io, blobs, _lost = degraded_cluster
     assert not tracing.armed()
