@@ -756,7 +756,7 @@ class Module(MgrModule):
                     "device calls a waiting submitter's own thread ran "
                     "(idle engine, lone request: no hand-over to the "
                     "engine's threads), of the device calls",
-                    d.get("caller_batches", 0))
+                    d["caller_batches"])
         exp.counter(f"{p}_completed_total",
                     "requests delivered by the completion thread",
                     d["completed"])

@@ -442,9 +442,9 @@ class BlueStoreLite(ObjectStore):
         it itself (``DeviceDispatchEngine.submit_waiting``); while the
         engine is busy it queues, and coalesces with whole-block
         batches of other stores on the context (padded ones coalesce
-        with their own kind).  Any channel failure (breaker open, timeout, device fault) drops to
-        the scalar ``zlib.crc32`` oracle, so a csum slot is never
-        committed unset.  Runs after apply, before the fsync/KV build,
+        with their own kind).  Any channel failure (breaker open,
+        timeout, device fault) drops to the scalar ``zlib.crc32``
+        oracle, so a csum slot is never committed unset.  Runs after apply, before the fsync/KV build,
         so the final metas carry real checksums."""
         if not self._pending_csum:
             return

@@ -688,7 +688,7 @@ class DeviceDispatchEngine:
                     # on_caller head nobody else completes would stall
                     # every batch behind it
                     self._inflight.remove(batch)
-                self._cv.notify_all()
+                    self._cv.notify_all()
             if not req.future.done():
                 req.future._deliver(None, RuntimeError(
                     f"{self.name}: {req.label} request on its "
@@ -1766,8 +1766,8 @@ def submit_bluestore_data(engine: DeviceDispatchEngine, blobs,
     of a shape whose every row is full depend on nothing but the
     shape, and stay on the device (``checksum_kernel.
     whole_row_operands``), so such a request uploads its data alone.
-    With a mesh on the engine the padded rows and their operands are
-    placed in lockstep, as before.
+    With a mesh on the engine every request takes the padded build,
+    whose rows and operands are placed in lockstep, as before.
 
     The key is the padded width and, for whole-row requests, their
     kind, so concurrent transaction batches — different stores,
@@ -1782,22 +1782,20 @@ def submit_bluestore_data(engine: DeviceDispatchEngine, blobs,
         cost_tag=cost_tag if cost_tag is not None
         else ("_bluestore", "client"))
     submit = engine.submit_waiting if wait else engine.submit
-    data = _whole_block_batch(blobs, runs)
-    if data is not None and engine.placement_mesh() is None:
+    data = (_whole_block_batch(blobs, runs)
+            if engine.placement_mesh() is None else None)
+    if data is not None:
         return submit(
             key if key is not None
             else ("bluestore_data", data.shape[1], "whole"),
             _digest_whole_rows, data, fallback=_whole_rows_oracle,
             **submit_kw)
     lengths = np.array([len(b) for b in blobs], dtype=np.int64)
-    if data is not None:
-        w = data.shape[1]
-    else:
-        w = ck.row_width(int(lengths.max()) if len(blobs) else 0)
-        data = np.zeros((len(blobs), w), dtype=np.uint8)
-        for i, b in enumerate(blobs):
-            if len(b):
-                data[i, :len(b)] = np.frombuffer(b, dtype=np.uint8)
+    w = ck.row_width(int(lengths.max()) if len(blobs) else 0)
+    data = np.zeros((len(blobs), w), dtype=np.uint8)
+    for i, b in enumerate(blobs):
+        if len(b):
+            data[i, :len(b)] = np.frombuffer(b, dtype=np.uint8)
     mats, invp = ck.digest_operands(lengths, w)
     if key is None:
         key = ("bluestore_data", w)

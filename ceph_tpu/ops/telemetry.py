@@ -636,7 +636,6 @@ class DispatchStats:
             return {
                 "submits": self.submits,
                 "device_calls": batches,
-                "caller_thread_calls": self.caller_batches,
                 "mean_coalesce": (round(self.coalesce.sum / batches, 2)
                                   if batches else 0.0),
                 "p99_queue_delay_ms": round(
