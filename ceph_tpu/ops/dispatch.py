@@ -18,7 +18,11 @@ Three mechanisms, one engine:
   op never waits — single-op latency cannot regress), else accumulate
   until ``max_stripes`` or ``max_delay_us``, whichever first.  The
   batch is self-clocking: while batch N computes, batch N+1's requests
-  pile up, exactly the adaptive-batching feedback loop.
+  pile up, exactly the adaptive-batching feedback loop.  The same
+  rule one step further: a submitter that will block on its result at
+  once (``submit_waiting``) runs a lone request on its OWN thread while
+  the engine is idle — no hand-over to the two threads below and back
+  — and queues like everyone else as soon as the engine is busy.
 
 * **shape bucketing** — the coalesced batch rounds UP to a power-of-two
   stripe count with all-zero padding rows (bit-exact for every kernel
