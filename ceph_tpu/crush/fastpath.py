@@ -41,16 +41,27 @@ full per-item draws handles ties for free.)
 Bit-exactness: validated against the scalar oracle (crush.mapper_ref) in
 tests/test_mapper_jax.py::test_fastpath_* across skewed weights, reweights,
 out OSDs, uneven host sizes, and forced-fallback configurations.
+
+What is compiled and what is data.  A program depends on a rule's
+*shape class* (``FastShape``: kind, numrep, tries, vary_r and the padded
+table shapes) and on nothing of a map's content: the bucket tables —
+ids, weights, magic divisors, the leaf kernel's packed rows — are
+operands (``build_tables``, held with their device copies by
+``FastTables``).  A host added, a host removed, an item reweighted is a
+host-side table build and an upload; a program is built only when an
+edit crosses a padded class (tests/test_crush_reshape.py).
 """
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ceph_tpu.ops import telemetry
 from ceph_tpu.ops.crush_kernel import is_out
 from ceph_tpu.ops.straw2_u32 import (
     _ln_f32_error_bound, magic_tables, straw2_choose_index_approx)
@@ -239,56 +250,170 @@ def _compact_rows(rows):
     return jnp.take_along_axis(rows, order, axis=1)
 
 
+#: lanes the XLA path pads a bucket to (the Pallas kernels pad to
+#: their 128-lane vreg, ``pallas_straw2._pad_lanes``): a shape class
+#: holds every map whose root and widest host round up to the same
+#: multiples, so a host or an OSD added inside the padding reuses the
+#: class's compiled program
+XLA_LANES = 8
+
+
+def _on_tpu() -> bool:
+    """Whether programs built now run on a TPU.  Honors
+    jax.default_device(<tpu>) too: a multi-platform process (cpu
+    default + tpu reachable) running under that context IS on the tpu
+    even though default_backend() still says cpu."""
+    dd = getattr(jax.config, "jax_default_device", None)
+    if dd is not None:
+        # jax.default_device accepts a Device OR a platform string
+        return getattr(dd, "platform", str(dd)) == "tpu"
+    return jax.default_backend() == "tpu"
+
+
+@dataclass(frozen=True)
+class FastShape:
+    """What a fast-path program is traced for, and nothing of a map's
+    content: the rule's kind and retry constants and the padded table
+    shapes.  Maps of one FastShape share one FastMapper and its
+    compiled programs."""
+
+    kind: str                 # "chooseleaf" | "choose_flat"
+    numrep_arg: int
+    tries: int
+    vary_r: int
+    root_lanes: int           # padded root items (= leaf-table rows)
+    leaf_lanes: int           # padded items a host; 0 for a flat rule
+    #: the fused Pallas column kernels (2.5x the XLA path on a TPU);
+    #: the CPU mesh tests keep the XLA path
+    pallas: bool
+    interpret: bool = False   # Pallas in interpret mode (CPU tests)
+
+
+def shape_of(fr: FastRule, pallas: bool | None = None,
+             interpret: bool = False) -> FastShape:
+    """The shape class of a rule on this backend (``pallas`` None: the
+    Pallas kernels on a TPU, the XLA path elsewhere)."""
+    if pallas is None:
+        pallas = _on_tpu()
+    if pallas:
+        from ceph_tpu.ops.pallas_straw2 import _pad_lanes as pad
+    else:
+        def pad(n):
+            return -(-n // XLA_LANES) * XLA_LANES
+    leaf = 0 if fr.leaf_ids is None else pad(fr.leaf_ids.shape[1])
+    return FastShape(fr.kind, fr.numrep_arg, fr.tries, fr.vary_r,
+                     pad(len(fr.root_ids)), leaf, bool(pallas),
+                     bool(interpret))
+
+
+def build_tables(fr: FastRule, shape: FastShape) -> tuple:
+    """One map's bucket tables as the class's program takes them: host
+    arrays, zero-padded to the class (a padded item has weight 0 and
+    never wins; a padded host row is never selected).  The XLA path's
+    order is root ids, weights, magic limbs, limb offsets, then the
+    same four a host row; the Pallas kernels' is
+    ``pallas_straw2.pack_tables``."""
+    S, L = shape.root_lanes, shape.leaf_lanes
+    ids = np.zeros(S, dtype=np.int32)
+    ids[:len(fr.root_ids)] = fr.root_ids
+    w = np.zeros(S, dtype=np.int64)
+    w[:len(fr.root_w)] = fr.root_w
+    lids = lw = None
+    if fr.leaf_ids is not None:
+        H, S_l = fr.leaf_ids.shape
+        lids = np.zeros((S, L), dtype=np.int32)
+        lids[:H, :S_l] = fr.leaf_ids
+        lw = np.zeros((S, L), dtype=np.int64)
+        lw[:H, :S_l] = fr.leaf_w
+    if shape.pallas:
+        from ceph_tpu.ops.pallas_straw2 import pack_tables
+        return pack_tables(ids, w, lids, lw)
+    tables = [ids, w, *magic_tables(w)]
+    if lids is not None:
+        tables += [lids, lw, *magic_tables(lw)]
+    return tuple(tables)
+
+
+class FastTables:
+    """One (map, rule)'s bucket tables: the host arrays of
+    ``build_tables`` and their device copies, one per placement (the
+    default device, or replicated over a mesh for mesh-sharded
+    batches).  The content of a map lives here and nowhere in a
+    program."""
+
+    def __init__(self, shape: FastShape, host: tuple):
+        self.shape = shape
+        self.host = host
+        self.nbytes = sum(int(a.nbytes) for a in host)
+        self._placed: dict = {}
+
+    def placed(self, mesh=None) -> bool:
+        """Whether ``on(mesh)`` has uploaded already."""
+        return mesh in self._placed
+
+    def on(self, mesh=None) -> tuple:
+        """The tables on the default device (``mesh`` None) or
+        replicated over ``mesh``, uploaded on first use; the bytes are
+        counted in MappingStats (``crush_table_upload_bytes``)."""
+        dev = self._placed.get(mesh)
+        if dev is None:
+            if mesh is None:
+                dev = tuple(jnp.asarray(a) for a in self.host)
+                copies = 1
+            else:
+                from jax.sharding import NamedSharding, PartitionSpec
+                dev = jax.device_put(
+                    self.host, NamedSharding(mesh, PartitionSpec()))
+                copies = mesh.size
+            self._placed[mesh] = dev
+            telemetry.mapping_stats().record_crush_table_upload(
+                self.nbytes * copies)
+        return dev
+
+
+def tables_of(fr: FastRule, pallas: bool | None = None,
+              interpret: bool = False) -> FastTables:
+    """A rule's tables, built for its shape class on this backend (or
+    on the path named: the chip's cross-validation runs both)."""
+    shape = shape_of(fr, pallas, interpret)
+    return FastTables(shape, build_tables(fr, shape))
+
+
+@functools.lru_cache(maxsize=None)
+def mapper_for(shape: FastShape) -> "FastMapper":
+    """The process's one FastMapper of a shape class."""
+    return FastMapper(shape)
+
+
 class FastMapper:
-    """Compiled fast path for one (map, rule)."""
+    """The fast path of one shape class (``FastShape``): every method
+    takes the bucket tables (``build_tables``) as an argument, so a
+    program traced from ``run`` serves any map of the class — a host
+    added, an item reweighted — with new operands and no compile."""
 
-    def __init__(self, fr: FastRule):
-        self.fr = fr
+    def __init__(self, shape: FastShape):
+        self.shape = shape
         _ln_f32_error_bound()   # measure eagerly: must be concrete by
-        self.root_ids = jnp.asarray(fr.root_ids)   # the time jit traces
-        self.root_w = jnp.asarray(fr.root_w)
-        rm, ro = magic_tables(fr.root_w)
-        self.root_magic = jnp.asarray(rm)
-        self.root_off = jnp.asarray(ro)
-        if fr.leaf_ids is not None:
-            self.leaf_ids = jnp.asarray(fr.leaf_ids)
-            self.leaf_w = jnp.asarray(fr.leaf_w)
-            lm, lo = magic_tables(fr.leaf_w)
-            self.leaf_magic = jnp.asarray(lm)
-            self.leaf_off = jnp.asarray(lo)
-        # the fused Pallas column kernels (2.5x the XLA path on this
-        # backend); TPU-only — the CPU mesh tests keep the XLA path.
-        # Mesh-sharded batches reach these kernels through the
-        # shard_map wrapper in BatchMapper._fast_sharded_fn (a
-        # pallas_call is an opaque custom call GSPMD cannot split, so
-        # the batch splits BEFORE the kernel; run() itself is
-        # row-independent along x by the oracle-equivalence contract).
-        # The gate honors jax.default_device(<tpu>) too: a multi-
-        # platform process (cpu default + tpu reachable) running under
-        # that context IS on the tpu even though default_backend()
-        # still says cpu
-        self._pallas = None
-        _dd = getattr(jax.config, "jax_default_device", None)
-        if _dd is not None:
-            # jax.default_device accepts a Device OR a platform string
-            on_tpu = getattr(_dd, "platform", str(_dd)) == "tpu"
-        else:
-            on_tpu = jax.default_backend() == "tpu"
-        if on_tpu:
-            try:
-                from ceph_tpu.ops.pallas_straw2 import PallasColumns
-            except ImportError:   # pragma: no cover
-                PallasColumns = None
-            if PallasColumns is not None:
-                # construction failures must surface, not silently
-                # degrade to the slower XLA path
-                self._pallas = PallasColumns(fr)
+        self._pallas = None     # the time jit traces
+        if shape.pallas:
+            # Mesh-sharded batches reach these kernels through the
+            # shard_map wrapper in mapper_jax._fast_program (a
+            # pallas_call is an opaque custom call GSPMD cannot split,
+            # so the batch splits BEFORE the kernel; run() itself is
+            # row-independent along x by the oracle-equivalence
+            # contract).  Construction failures must surface, not
+            # silently degrade to the slower XLA path
+            from ceph_tpu.ops.pallas_straw2 import PallasColumns
+            self._pallas = PallasColumns(
+                shape.root_lanes, shape.leaf_lanes, shape.vary_r,
+                interpret=shape.interpret)
 
-    def _winners(self, xs, reweight, R: int):
+    def _winners(self, xs, reweight, tables, R: int):
         """host_win/leaf_win/leaf_bad for r in [0, R): a fori_loop producing
         one r column per step (bounds the (N, H) ln-matmul intermediates to a
         single r; an unrolled R-wide block OOMs HBM at bulk batch sizes)."""
-        fr = self.fr
+        shape = self.shape
+        root_ids, root_w, root_magic, root_off = tables[:4]
         n = xs.shape[0]
         hw0 = jnp.full((n, R), NONE, dtype=jnp.int32)
         lw0 = jnp.full((n, R), NONE, dtype=jnp.int32)
@@ -297,22 +422,22 @@ class FastMapper:
         def body(i, bufs):
             hw, lw, lb = bufs
             r = i.astype(jnp.uint32)
-            pos = _draw_argmax(xs, self.root_ids, self.root_w, r,
-                               self.root_magic, self.root_off)
-            first = self.root_ids[pos]                         # (N,)
-            if fr.kind == "choose_flat":
+            pos = _draw_argmax(xs, root_ids, root_w, r,
+                               root_magic, root_off)
+            first = root_ids[pos]                              # (N,)
+            if shape.kind == "choose_flat":
                 leaf = first
             else:
+                leaf_ids, leaf_w, leaf_magic, leaf_off = tables[4:]
                 # r_leaf = vary_r ? r >> (vary_r-1) : 0 (mapper.c:578)
-                if fr.vary_r:
-                    r_leaf = r >> jnp.uint32(fr.vary_r - 1)
+                if shape.vary_r:
+                    r_leaf = r >> jnp.uint32(shape.vary_r - 1)
                 else:
                     r_leaf = jnp.uint32(0)
-                ids = self.leaf_ids[pos]                       # (N, S)
-                w = self.leaf_w[pos]                           # (N, S)
+                ids = leaf_ids[pos]                            # (N, S)
+                w = leaf_w[pos]                                # (N, S)
                 lpos = _draw_argmax(xs, ids, w, r_leaf,
-                                    self.leaf_magic[pos],
-                                    self.leaf_off[pos])
+                                    leaf_magic[pos], leaf_off[pos])
                 leaf = jnp.take_along_axis(ids, lpos[:, None], 1)[:, 0]
             bad = is_out(reweight, leaf, xs)
             hw = jax.lax.dynamic_update_slice(hw, first[:, None], (0, i))
@@ -322,7 +447,7 @@ class FastMapper:
 
         return jax.lax.fori_loop(0, R, body, (hw0, lw0, lb0))
 
-    def _winners_cols(self, xs, reweight, R: int):
+    def _winners_cols(self, xs, reweight, tables, R: int):
         """(host_win, leaf_win, leaf_bad) in the native (R, n_padded)
         column layout of the Pallas kernels (no transposes).
 
@@ -342,19 +467,19 @@ class FastMapper:
             # bits (pallas_straw2._extract_candidates), so past 1024
             # items the certificate would fire on every batch and the
             # filter pass would be pure overhead
-            pos, ids, ovf = pc.froot_columns(xs, reweight, R)
+            pos, ids, ovf = pc.froot_columns(xs, tables, R)
             pos, ids = jax.lax.cond(
                 jnp.any(ovf != 0),
-                lambda _: pc.root_columns(xs, reweight, R),
+                lambda _: pc.root_columns(xs, tables, R),
                 lambda _: (pos, ids), None)
         else:
-            pos, ids = pc.root_columns(xs, reweight, R)
+            pos, ids = pc.root_columns(xs, tables, R)
         # the winner columns come back padded to the kernel block quantum
         n_pad = ids.shape[1]
         xs_pad = jnp.concatenate(
             [xs, jnp.zeros((n_pad - xs.shape[0],), dtype=xs.dtype)]) \
             if n_pad > xs.shape[0] else xs
-        if self.fr.kind == "choose_flat":
+        if self.shape.kind == "choose_flat":
             # is_out runs OUTSIDE the kernels: it is elementwise in
             # (winner, x), one cheap XLA op over the columns — and the
             # in-kernel variant hit a Mosaic miscompile (hash32_2 fed
@@ -363,7 +488,7 @@ class FastMapper:
             # cross-validation in round 3)
             bad = is_out(reweight, ids, xs_pad[None, :])
             return ids, ids, bad
-        lid = self._pallas.leaf_columns(xs, pos, R)
+        lid = pc.leaf_columns(xs, pos, tables, R)
         lbad = is_out(reweight, lid, xs_pad[None, :])
         return ids, lid, lbad
 
@@ -383,7 +508,8 @@ class FastMapper:
     #: is the 4096 above.
     STAGE2_SHARE = 16
 
-    def _run_pallas(self, xs, reweight, result_max, numrep, R0, Rf):
+    def _run_pallas(self, xs, reweight, tables, result_max, numrep,
+                    R0, Rf):
         """Winner columns and the consume ladder both on-device in their
         native (R, N) layout — no transposes, no XLA while_loops.
 
@@ -396,15 +522,16 @@ class FastMapper:
         deterministic in (x, columns) — so this is pure scheduling, the
         oracle-equivalence property is untouched."""
         from ceph_tpu.ops.pallas_straw2 import consume_columns
-        fr = self.fr
+        shape = self.shape
         n = xs.shape[0]
         interp = self._pallas.interpret
 
         def attempt(xv, R):
             m = xv.shape[0]
-            hw, lw, lb = self._winners_cols(xv, reweight, R)
+            hw, lw, lb = self._winners_cols(xv, reweight, tables, R)
             oh, ol, ovf = consume_columns(
-                hw, lw, lb, numrep=numrep, tries=fr.tries, interpret=interp)
+                hw, lw, lb, numrep=numrep, tries=shape.tries,
+                interpret=interp)
             return oh[:, :m], ol[:, :m], ovf[:m]
 
         def attempt_full(xv, R):
@@ -439,7 +566,7 @@ class FastMapper:
                 jnp.sum(need) > cap,
                 lambda _: attempt_full(xs, R0),
                 merged, None)
-        res = out_l if fr.kind == "chooseleaf" else out_h
+        res = out_l if shape.kind == "chooseleaf" else out_h
         res = _compact_rows(res.T)
         if numrep < result_max:
             res = jnp.concatenate(
@@ -447,33 +574,41 @@ class FastMapper:
                                dtype=jnp.int32)], axis=1)
         return res[:, :result_max]
 
-    def run(self, xs, reweight, result_max: int,
+    def run(self, xs, reweight, tables, result_max: int,
             block: int = DEFAULT_BLOCK):
-        """Full do_rule: returns (N, result_max) NONE-compacted placements."""
-        fr = self.fr
-        numrep = fr.numrep_arg
+        """Full do_rule: returns (N, result_max) NONE-compacted placements.
+        ``tables`` are the map's (``build_tables``); ``reweight`` may be
+        zero-padded past max_osd (weight 0 is out, which is is_out's
+        verdict for an id past the vector already)."""
+        shape = self.shape
+        if isinstance(xs, jax.core.Tracer):
+            # a trace of this function is a program built: a compile
+            # or a cache load, on whatever path asked for it
+            telemetry.mapping_stats().record_program_build()
+        numrep = shape.numrep_arg
         if numrep <= 0:
             numrep += result_max
         n = xs.shape[0]
         if numrep <= 0:
             return jnp.full((n, result_max), NONE, dtype=jnp.int32)
-        Rf = fr.tries + numrep
+        Rf = shape.tries + numrep
         R0 = min(numrep + block, Rf)
 
         if self._pallas is not None:
-            return self._run_pallas(xs, reweight, result_max, numrep, R0, Rf)
+            return self._run_pallas(xs, reweight, tables, result_max,
+                                    numrep, R0, Rf)
 
-        hw, lw, lb = self._winners(xs, reweight, R0)
-        out_h, out_l, ovf = _consume(hw, lw, lb, numrep, fr.tries, R0, n)
+        hw, lw, lb = self._winners(xs, reweight, tables, R0)
+        out_h, out_l, ovf = _consume(hw, lw, lb, numrep, shape.tries, R0, n)
 
         def slow(_):
-            hw2, lw2, lb2 = self._winners(xs, reweight, Rf)
-            oh, ol, _ = _consume(hw2, lw2, lb2, numrep, fr.tries, Rf, n)
+            hw2, lw2, lb2 = self._winners(xs, reweight, tables, Rf)
+            oh, ol, _ = _consume(hw2, lw2, lb2, numrep, shape.tries, Rf, n)
             return oh, ol
 
         out_h, out_l = jax.lax.cond(
             jnp.any(ovf), slow, lambda _: (out_h, out_l), None)
-        res = out_l if fr.kind == "chooseleaf" else out_h
+        res = out_l if shape.kind == "chooseleaf" else out_h
         res = _compact_rows(res)
         if numrep < result_max:
             res = jnp.concatenate(

@@ -49,6 +49,7 @@ from .types import (
     RULE_SET_CHOOSELEAF_VARY_R,
     RULE_TAKE,
     CrushMap,
+    padded_osds,
 )
 
 NONE = jnp.int32(CRUSH_ITEM_NONE)
@@ -425,8 +426,40 @@ def _compact_rows(rows: jax.Array) -> jax.Array:
     return jnp.take_along_axis(rows, order, axis=1)
 
 
+#: the process's fast-path programs, keyed by what their trace depends
+#: on and nothing of a map's content: (fastpath.FastShape, result_max,
+#: the batch's row sharding or None).  The bucket tables are operands
+#: (fastpath.FastTables), so every map of a shape class — a host added,
+#: an item reweighted — runs the program the first one built.
+_FAST_PROGRAMS: dict = {}
+
+
+def _fast_program(shape, result_max: int, sh=None):
+    """The jitted ``FastMapper.run`` of a shape class; with a row
+    sharding ``sh``, its shard_map wrapper: the Pallas column kernels
+    are opaque custom calls GSPMD cannot split, so each device runs the
+    full fused ladder on its local rows (row-independent by the
+    oracle-equivalence contract) with the reweight vector and the
+    bucket tables replicated."""
+    key = (shape, result_max, sh)
+    fn = _FAST_PROGRAMS.get(key)
+    if fn is None:
+        from . import fastpath
+        run = functools.partial(fastpath.mapper_for(shape).run,
+                                result_max=result_max)
+        if sh is None:
+            fn = jax.jit(run)
+        else:
+            from ceph_tpu.ops.gf_kernel import build_sharded_rows_fn
+            # two replicated operands: reweight, and the tuple of
+            # tables (one spec covers its leaves)
+            fn = build_sharded_rows_fn(run, sh, n_replicated=2)
+        _FAST_PROGRAMS[key] = fn
+    return fn
+
+
 class BatchMapper:
-    """Batched crush_do_rule over a compiled map.
+    """Batched crush_do_rule over one crush map.
 
     >>> bm = BatchMapper(crush_map)
     >>> out = bm.do_rule(ruleno, xs, result_max, reweight)   # (N, result_max)
@@ -434,89 +467,114 @@ class BatchMapper:
     firstn rules return NONE-compacted rows (dense prefix, NONE tail); indep
     rules return positionally-stable rows with NONE holes — matching the
     scalar crush_do_rule's list semantics in both cases.
+
+    What is per map here is content only.  A rule the fused fast path
+    takes (crush.fastpath: two levels, firstn) holds the map's bucket
+    tables (``fast_tables``) and runs the process-wide program of its
+    shape class (``_fast_program``): building a BatchMapper for an
+    edited map costs a host-side table build and an upload, no
+    compile.  The generic interpreter (three levels, indep rules, tree
+    and uniform buckets) still closes over the compiled map, so its
+    programs are per content (``_jit_cache``).
     """
 
     def __init__(self, m: CrushMap, compiled: CompiledCrushMap | None = None):
         self.map = m
+        # host work of a millisecond, and the batchability check
+        # (ValueError for list / legacy straw buckets)
         self.compiled = compiled or compile_map(m)
-        self.arrays = _Arrays(self.compiled)
+        self._arrays: _Arrays | None = None
         self._jit_cache: dict = {}
         self._fast_cache: dict = {}
 
-    def _fastpath(self, ruleno: int):
-        """Fused two-level kernel if the rule fits (crush.fastpath)."""
+    @property
+    def arrays(self) -> _Arrays:
+        """The generic interpreter's device-resident compiled map,
+        uploaded when a rule first needs it (the fast path never
+        does)."""
+        if self._arrays is None:
+            self._arrays = _Arrays(self.compiled)
+        return self._arrays
+
+    def fast_tables(self, ruleno: int):
+        """This map's bucket tables for the fused two-level kernel
+        (fastpath.FastTables), built on first use; None if the rule
+        does not fit it."""
         if ruleno not in self._fast_cache:
             from . import fastpath
+            ft = None
             fr = fastpath.detect(self.map, ruleno)
-            self._fast_cache[ruleno] = (
-                fastpath.FastMapper(fr) if fr is not None else None)
+            if fr is not None:
+                ft = fastpath.tables_of(fr)
+                from ceph_tpu.ops import telemetry
+                telemetry.mapping_stats().record_crush_table_build()
+            self._fast_cache[ruleno] = ft
         return self._fast_cache[ruleno]
 
-    def _jit_entries(self) -> int:
-        """Compile-cache entries across every jitted rule evaluator —
-        the telemetry retrace counter differences this per call."""
-        return sum(f._cache_size() for f in self._jit_cache.values())
+    def has_fast_tables(self, ruleno: int) -> bool:
+        """Whether ``fast_tables(ruleno)`` has been settled (built, or
+        found not to fit): the mapping service spans the build."""
+        return ruleno in self._fast_cache
 
-    def _fast_sharded_fn(self, fast, ruleno: int, result_max: int, xs):
-        """The shard_map-wrapped fast path for a mesh-sharded batch:
-        the Pallas column kernels are opaque custom calls GSPMD cannot
-        split, so each device runs the full fused ladder on its local
-        rows (row-independent by the oracle-equivalence contract) with
-        the reweight vector replicated — PR 7's XLA-only routing guard
-        for sharded fastpath batches, lifted.  Returns the jit-cache
-        KEY, or None when the batch is not row-sharded (or
-        single-device)."""
-        from ceph_tpu.ops.gf_kernel import _multi_device, _row_sharding
-        if not _multi_device(xs):
-            return None
-        sh = _row_sharding(xs)
-        if sh is None:
-            return None
-        key = ("fast_sh", ruleno, result_max, sh)
-        if key not in self._jit_cache:
-            from ceph_tpu.ops.gf_kernel import build_sharded_rows_fn
-            self._jit_cache[key] = build_sharded_rows_fn(
-                functools.partial(fast.run, result_max=result_max),
-                sh, n_replicated=1)
-        return key
+    def _jit_entries(self) -> int:
+        """Compile-cache entries across every jitted rule evaluator
+        this mapper can call — the telemetry retrace counter
+        differences this per call."""
+        return (sum(f._cache_size() for f in self._jit_cache.values())
+                + sum(f._cache_size()
+                      for f in list(_FAST_PROGRAMS.values())))
 
     def do_rule(self, ruleno: int, xs, result_max: int, reweight) -> jax.Array:
         xs = jnp.asarray(xs, dtype=jnp.uint32)
+        if not isinstance(reweight, jax.Array):
+            # a host vector is zero-padded to the OSD axis quantum
+            # (weight 0 is out, is_out's verdict for an id past the
+            # vector already): maps whose max_osd differs inside the
+            # quantum then share a program
+            rw = reweight = np.asarray(reweight, dtype=np.int64)
+            if len(rw) != padded_osds(len(rw)):
+                reweight = np.zeros(padded_osds(len(rw)), dtype=np.int64)
+                reweight[:len(rw)] = rw
         reweight = jnp.asarray(reweight, dtype=jnp.int64)
         if (ruleno < 0 or ruleno >= self.map.max_rules
                 or self.map.rules[ruleno] is None):
             # crush_do_rule returns empty for unknown rules (mapper.c:902-904)
             return jnp.full((xs.shape[0], result_max), NONE, dtype=jnp.int32)
-        fast = self._fastpath(ruleno)
-        if fast is not None:
-            key = None
-            if fast._pallas is not None:
-                key = self._fast_sharded_fn(fast, ruleno, result_max, xs)
-            if key is None:
-                key = ("fast", ruleno, result_max)
-                if key not in self._jit_cache:
-                    self._jit_cache[key] = jax.jit(
-                        functools.partial(fast.run,
-                                          result_max=result_max))
+        ft = self.fast_tables(ruleno)
+        if ft is not None:
+            from ceph_tpu.ops.gf_kernel import _multi_device, _row_sharding
+            # a mesh-sharded batch (the engine's placement) takes the
+            # tables replicated over its mesh; only the Pallas kernels
+            # need the shard_map wrapper, GSPMD splits the XLA path
+            mesh = (getattr(xs.sharding, "mesh", None)
+                    if _multi_device(xs) else None)
+            sh = (_row_sharding(xs)
+                  if mesh is not None and ft.shape.pallas else None)
+            key = (ft.shape, result_max, sh)
+            fn = functools.partial(_fast_program(*key), xs, reweight,
+                                   ft.on(mesh))
         else:
             key = (ruleno, result_max)
             if key not in self._jit_cache:
                 self._jit_cache[key] = jax.jit(
                     functools.partial(self._run, ruleno, result_max))
-        fn = self._jit_cache[key]
+            fn = functools.partial(self._jit_cache[key], xs, reweight)
         n = xs.shape[0]
         from ceph_tpu.ops import telemetry
         return telemetry.timed_kernel(
-            "crush_map",
-            lambda: fn(xs, reweight),
+            "crush_map", fn,
             batch=n, bytes_in=n * 4 + reweight.shape[0] * 8,
             bytes_out=n * result_max * 4,
             cache_entries=self._jit_entries,
-            signature=("crush", id(self), key, n))
+            signature=("crush", key, n))
 
     # -- the rule interpreter (mapper.c:900-1105) -----------------------------
 
     def _run(self, ruleno: int, result_max: int, xs, reweight):
+        if isinstance(xs, jax.core.Tracer):
+            # a trace of the interpreter is a program built
+            from ceph_tpu.ops import telemetry
+            telemetry.mapping_stats().record_program_build()
         a = self.arrays
         rule = self.map.rules[ruleno]
         n = xs.shape[0]

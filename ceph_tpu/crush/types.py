@@ -61,6 +61,20 @@ class Tunables:
                    chooseleaf_vary_r=0, chooseleaf_stable=0, straw_calc_version=0)
 
 
+#: the OSD axis of the device programs' per-OSD operands (CRUSH's
+#: reweight vector, the placement ladder's state / weight / affinity)
+#: is padded to a multiple of this, so that max_osd may grow — a host
+#: racked, 10,000 -> 10,040 — without a new shape and its compile.
+#: 1,024 is 25 hosts of 40 and 8 KB of int64 zeros; entries past
+#: max_osd weigh nothing (weight 0 is out, state 0 does not exist).
+OSD_AXIS_QUANTUM = 1024
+
+
+def padded_osds(max_osd: int) -> int:
+    """Length of the per-OSD device operands for ``max_osd`` OSDs."""
+    return max(-(-max_osd // OSD_AXIS_QUANTUM), 1) * OSD_AXIS_QUANTUM
+
+
 @dataclass
 class Bucket:
     id: int                      # negative

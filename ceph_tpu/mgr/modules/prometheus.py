@@ -678,6 +678,17 @@ class Module(MgrModule):
         exp.counter(f"{p}_delta_upload_bytes_total",
                     "bytes of packed tables uploaded for device diffs",
                     d.get("delta_upload_bytes", 0))
+        exp.counter(f"{p}_crush_table_builds_total",
+                    "host builds of a crush map's bucket tables "
+                    "(new crush content)",
+                    d.get("crush_table_builds", 0))
+        exp.counter(f"{p}_crush_table_upload_bytes_total",
+                    "bytes of crush bucket tables put on devices",
+                    d.get("crush_table_upload_bytes", 0))
+        exp.counter(f"{p}_crush_program_builds_total",
+                    "CRUSH and ladder programs traced anew (stands "
+                    "still while map edits stay inside a shape class)",
+                    d.get("crush_program_builds", 0))
         exp.gauge(f"{p}_host_tail_share",
                   "host-tail share of the total mapping epoch cost "
                   "(device + delta + host_tail) — collapses toward 0 "

@@ -1575,9 +1575,9 @@ def submit_do_rule(engine: DeviceDispatchEngine, mapper, ruleno: int,
             # host-side placement scaffolding (see submit_flat_firstn):
             # replicate the reweight vector over the batch's mesh so
             # do_rule's jitted evaluator sees consistent shardings (the
-            # mapper's compiled-map arrays are uncommitted and follow);
-            # cached per (mesh, key) — the key digests mapper identity,
-            # rule and reweight content
+            # mapper replicates its bucket tables over the same mesh,
+            # fastpath.FastTables.on); cached per (mesh, key) — the key
+            # digests mapper identity, rule and reweight content
             import jax
             from jax.sharding import NamedSharding, PartitionSpec
             rw = _replicate_cached(
@@ -1617,45 +1617,43 @@ def submit_finish_ladder(engine: DeviceDispatchEngine, operands, *,
     acting; ops.placement_kernel) through the engine.  ``operands`` is
     a placement_kernel.LadderOperands: the raw table is the data
     channel, the per-PG override/pps tables ride aux in lockstep, and
-    the per-OSD state/weight/affinity vectors are captured operands —
-    mesh-replicated on sharded batches exactly like the CRUSH reweight
-    vector.  Pools (and daemons) sharing one epoch's operand digest
+    the per-OSD state/weight/affinity vectors (padded past max_osd)
+    and the max_osd scalar are captured operands — mesh-replicated on
+    sharded batches exactly like the CRUSH reweight vector.  Pools (and daemons) sharing one epoch's operand digest
     and table widths coalesce on the PG axis into ONE device call.
 
     ``key`` defaults to a digest of the captured vectors plus the
     static table shape; pass an explicit (epoch, widths)-style key when
     the caller already knows the map identity."""
-    state, weight, affinity = (operands.state, operands.weight,
-                               operands.affinity)
+    osd_ops = operands.osd_operands()
     if key is None:
         key = ("pg_finish", operands.erasure, operands.width,
-               operands.items.shape[1], hash(state.tobytes()),
-               hash(weight.tobytes()), hash(affinity.tobytes()))
+               operands.items.shape[1], int(operands.max_osd),
+               *(hash(v.tobytes()) for v in osd_ops[:3]))
 
     def fn(batch, *aux, key=key):
         from ceph_tpu.ops.placement_kernel import _ladder_jit
-        st, w, af = state, weight, affinity
+        ops_ = osd_ops
         mesh = getattr(getattr(batch, "sharding", None), "mesh", None)
         if mesh is not None and getattr(mesh, "size", 1) > 1:
             # host-side placement scaffolding (see submit_flat_firstn):
-            # replicate the per-OSD vectors over the batch's mesh so
+            # replicate the per-OSD operands over the batch's mesh so
             # the jitted ladder compiles with consistent shardings
             # (sharded PG tables, replicated osd vectors); cached per
-            # (mesh, key) — the key digests the vector content
+            # (mesh, key) — the key digests their content
             import jax
             from jax.sharding import NamedSharding, PartitionSpec
-            st, w, af = _replicate_cached(
+            ops_ = _replicate_cached(
                 mesh, key,
                 lambda: jax.device_put(
-                    (st, w, af), NamedSharding(mesh, PartitionSpec())))
-        return _ladder_jit(operands.erasure)(batch, *aux, st, w, af)
+                    osd_ops, NamedSharding(mesh, PartitionSpec())))
+        return _ladder_jit(operands.erasure)(batch, *aux, *ops_)
 
     def host_oracle(batch, *aux, erasure=operands.erasure):
         # numpy twin of the fused ladder (placement_kernel.ladder_ref):
         # same packed-row output, bit for bit, no device involved
         from ceph_tpu.ops.placement_kernel import ladder_ref
-        return ladder_ref(batch, *aux, state, weight, affinity,
-                          erasure=erasure)
+        return ladder_ref(batch, *aux, *osd_ops, erasure=erasure)
 
     from ceph_tpu.ops.placement_kernel import ladder_cache_entries
     return engine.submit(key, fn, operands.raw, aux=operands.aux(),

@@ -695,58 +695,64 @@ def _ln_tables_rows():
     return rh_rows, rh128, ll_lo, ll_hi
 
 
-class PallasColumns:
-    """Compiled winner-precompute for one FastRule on the TPU backend.
+def pack_tables(ids, w, lids=None, lw=None) -> tuple:
+    """One map's bucket tables in the column kernels' operand layout,
+    as host arrays.  ``ids`` / ``w`` (S,) are the root's items and
+    weights, ``lids`` / ``lw`` (S, L) a host's a row (None: a flat
+    rule), all zero-padded to the shape class by the caller
+    (fastpath.build_tables; a padded item has weight 0 and never
+    wins):
 
-    Produces (host_win_ids, host_pos, leaf_win, leaf_bad) arrays shaped
-    (R, N) for r in [0, R): drop-in data for fastpath._consume.
+        root ids (1, S) | root zero-weight mask (1, S) | root weights
+        as f32 (1, S) | root magic limbs (5, S) | root limb offsets
+        (1, S) [| leaf_static (S, 9 * L) f32, chooseleaf rules only]
+
+    The order is the kernels' ``tables`` argument.  Pure host work
+    (the magic divisors are the cost): a changed map of the same class
+    is this plus an upload, no program."""
+    from ceph_tpu.ops.straw2_u32 import magic_tables
+    limbs, off = magic_tables(w)
+    tables = [ids[None, :], (w <= 0).astype(np.int32)[None, :],
+              np.maximum(w, 1).astype(np.float32)[None, :],
+              np.ascontiguousarray(limbs.T),            # (5, S)
+              off.astype(np.int32)[None, :]]
+    if lids is not None:
+        l_limbs, l_off = magic_tables(lw)
+        # packed static per-host fields, all exact in f32 except the
+        # raw weight column (col 8), whose f32 rounding the approx
+        # filter's margin absorbs
+        tables.append(np.concatenate([
+            lids.astype(np.float32),
+            (lw <= 0).astype(np.float32),
+            l_off.astype(np.float32),
+        ] + [l_limbs[..., j].astype(np.float32) for j in range(5)]
+          + [lw.astype(np.float32)],
+            axis=1))                                   # (S, 9 * L)
+    return tuple(tables)
+
+
+class PallasColumns:
+    """The winner-precompute kernels of one shape class on the TPU
+    backend: ``root_lanes`` padded root items (the one-hot dot of the
+    leaf kernel wants 128-multiples, so they are the leaf table's host
+    rows too) and ``leaf_lanes`` padded items a host (0: a flat rule).
+    The bucket tables are an argument of every call (``pack_tables``),
+    never state of this object: the programs built around these calls
+    serve every map of the class.
+
+    Produces (host_win_ids, host_pos, leaf_win) arrays shaped (R, N)
+    for r in [0, R): drop-in data for fastpath._consume.
     """
 
-    def __init__(self, fr, interpret: bool = False):
-        from ceph_tpu.ops.straw2_u32 import magic_tables
-        self.fr = fr
+    def __init__(self, root_lanes: int, leaf_lanes: int, vary_r: int,
+                 interpret: bool = False):
         self.interpret = interpret
-        S = _pad_lanes(len(fr.root_ids))
-        self.S_root = S
-        ids = np.zeros(S, dtype=np.int32)
-        ids[:len(fr.root_ids)] = fr.root_ids
-        w = np.zeros(S, dtype=np.int64)
-        w[:len(fr.root_w)] = fr.root_w
-        limbs, off = magic_tables(w)
-        self.root_ids = jnp.asarray(ids[None, :])
-        self.root_wz = jnp.asarray((w <= 0).astype(np.int32)[None, :])
-        self.root_magic = jnp.asarray(
-            np.ascontiguousarray(limbs.T))            # (5, S)
-        self.root_off = jnp.asarray(off.astype(np.int32)[None, :])
+        self.S_root = self.H = root_lanes
+        self.S_leaf = leaf_lanes
+        self.vary_r = vary_r
         rh, self.rh128, ll_lo, ll_hi = _ln_tables_rows()
         self.tabs = (jnp.asarray(rh), jnp.asarray(ll_lo),
                      jnp.asarray(ll_hi))
-
-        self.root_wf = jnp.asarray(
-            np.maximum(w, 1).astype(np.float32)[None, :])
-        if fr.leaf_ids is not None:
-            H, S_l = fr.leaf_ids.shape
-            Sp = _pad_lanes(S_l)
-            Hp = _pad_lanes(H)      # the one-hot dot wants 128-multiples
-            self.H = Hp
-            self.S_leaf = Sp
-            lids = np.zeros((Hp, Sp), dtype=np.int64)
-            lids[:H, :S_l] = fr.leaf_ids
-            lw = np.zeros((Hp, Sp), dtype=np.int64)
-            lw[:H, :S_l] = fr.leaf_w
-            l_limbs, l_off = magic_tables(lw)
-            # packed static per-host fields, all exact in f32 except the
-            # raw weight column (col 8), whose f32 rounding the approx
-            # filter's margin absorbs
-            packed = np.concatenate([
-                lids.astype(np.float32),
-                (lw <= 0).astype(np.float32),
-                l_off.astype(np.float32),
-            ] + [l_limbs[..., j].astype(np.float32) for j in range(5)]
-              + [lw.astype(np.float32)],
-                axis=1)                                # (Hp, 9*Sp)
-            self.leaf_static = jnp.asarray(packed)
-            self.leaf_ids_np = lids                    # for reweight rows
 
     @property
     def D(self) -> float:
@@ -763,11 +769,11 @@ class PallasColumns:
                             lambda i, r: (jnp.int32(0), jnp.int32(0)),
                             memory_space=pltpu.VMEM)
 
-    def root_columns(self, xs, reweight, R: int):
+    def root_columns(self, xs, tables, R: int):
         """xs (N,) uint32 -> (pos, ids) each (R, N) int32.  is_out
         verdicts are computed by the caller in XLA (elementwise).
         Batches that are not a BLOCK multiple are zero-padded here."""
-        del reweight
+        root_ids, root_wz, _wf, root_magic, root_off = tables[:5]
         S = self.S_root
         xs, n, B = _pad_block(xs)
         grid = (n // B, R)     # r innermost: output blocks revisited
@@ -785,17 +791,17 @@ class PallasColumns:
                       fs(rh.shape), fs(ll_lo.shape), fs(ll_hi.shape)],
             out_specs=out_specs,
             interpret=self.interpret,
-        )(xs[None, :], self.root_ids, self.root_wz, self.root_magic,
-          self.root_off, rh, ll_lo, ll_hi)
+        )(xs[None, :], root_ids, root_wz, root_magic, root_off,
+          rh, ll_lo, ll_hi)
         return pos, ids
 
-    def froot_columns(self, xs, reweight, R: int):
+    def froot_columns(self, xs, tables, R: int):
         """Fused single-phase filtered root columns: (pos, ids, ovf) —
         one pallas_call, candidates packed in VMEM, is_out left to the
         caller.  Requires R * _KPACK <= 128."""
-        del reweight
         if R * _KPACK > 128:
             raise ValueError(f"froot_columns: R={R} exceeds the lane pack")
+        root_ids, root_wz, root_wf, root_magic, root_off = tables[:5]
         S = self.S_root
         D = self.D   # concrete before tracing
         xs, n, B = _pad_block(xs)
@@ -821,13 +827,14 @@ class PallasColumns:
                       fs1(rh.shape), fs1(ll_lo.shape), fs1(ll_hi.shape)],
             out_specs=out_specs,
             interpret=self.interpret,
-        )(xs[None, :], self.root_ids, self.root_wz, self.root_wf,
-          self.root_magic, self.root_off, rh, ll_lo, ll_hi)
+        )(xs[None, :], root_ids, root_wz, root_wf, root_magic, root_off,
+          rh, ll_lo, ll_hi)
         return pos, ids, ovf[0]
 
-    def leaf_columns(self, xs, root_pos, R: int):
+    def leaf_columns(self, xs, root_pos, tables, R: int):
         """root winner positions -> leaf_id (R, N).  is_out verdicts are
         computed by the caller in XLA (elementwise)."""
+        leaf_static = tables[5]
         # root_pos comes back padded from root_columns; re-pad from the
         # caller's batch width so both land on the same quantum
         root_pos = root_pos[:, :xs.shape[0]]
@@ -839,16 +846,16 @@ class PallasColumns:
         rh, ll_lo, ll_hi = self.tabs
         (lid,) = pl.pallas_call(
             functools.partial(_leaf_kernel, H=self.H, S=self.S_leaf,
-                              vary_r=self.fr.vary_r,
+                              vary_r=self.vary_r,
                               rh128=self.rh128),
             grid=grid,
             out_shape=outs,
             in_specs=[pl.BlockSpec((1, B), lambda i, r: (jnp.int32(0), i)),
                       pl.BlockSpec((R, B), lambda i, r: (jnp.int32(0), i)),
-                      fs(self.leaf_static.shape),
+                      fs((self.H, 9 * self.S_leaf)),
                       fs(rh.shape), fs(ll_lo.shape), fs(ll_hi.shape)],
             out_specs=out_specs,
             interpret=self.interpret,
-        )(xs[None, :], root_pos, self.leaf_static,
+        )(xs[None, :], root_pos, leaf_static,
           rh, ll_lo, ll_hi)
         return lid

@@ -65,24 +65,29 @@ _OSD_UP = 2
 # ---------------------------------------------------------------------------
 
 def _ladder_impl(raw, pps, raw_len, up_rows, up_len, items, temp_rows,
-                 temp_len, ptemp, state, weight, affinity, *,
+                 temp_len, ptemp, state, weight, affinity, max_osd, *,
                  erasure: bool):
     """See the module docstring.  All tables int32 except pps (uint32)
     and weight (int64); shapes: raw/up_rows/temp_rows (N, W), items
-    (N, P, 2), the rest (N,) or (M,)."""
+    (N, P, 2), the rest (N,) or (M,); max_osd an int32 scalar — the
+    bound of every ``0 <= o < max_osd`` check, while M (the vectors'
+    padded length, osdmap.padded_osds) only bounds the gathers."""
     import jax.numpy as jnp
 
+    from ceph_tpu.ops import telemetry
     from ceph_tpu.ops.crush_kernel import hash32_2
 
+    # only ever called under jit: a trace of it is a program built
+    telemetry.mapping_stats().record_program_build()
     n, w = raw.shape
-    m_osd = state.shape[0]
+    m_pad = state.shape[0]
     iota = jnp.arange(w, dtype=jnp.int32)[None, :]
 
     def in_range(o):
-        return (o >= 0) & (o < m_osd)
+        return (o >= 0) & (o < max_osd)
 
     def gather(vec, o):
-        return vec[jnp.clip(o, 0, m_osd - 1)]
+        return vec[jnp.clip(o, 0, m_pad - 1)]
 
     def exists(o):
         return in_range(o) & ((gather(state, o) & _OSD_EXISTS) != 0)
@@ -236,7 +241,7 @@ def _hash32_2_np(a, b):
 
 
 def ladder_ref(raw, pps, raw_len, up_rows, up_len, items, temp_rows,
-               temp_len, ptemp, state, weight, affinity, *,
+               temp_len, ptemp, state, weight, affinity, max_osd, *,
                erasure: bool) -> np.ndarray:
     """Numpy twin of ``_ladder_impl`` — the bit-exact host oracle the
     dispatch engine degrades the ``pg_finish`` channel to when the
@@ -257,14 +262,15 @@ def ladder_ref(raw, pps, raw_len, up_rows, up_len, items, temp_rows,
     affinity = np.asarray(affinity, dtype=np.int32)
 
     n, w = raw.shape
-    m_osd = state.shape[0]
+    m_pad = state.shape[0]
+    max_osd = int(max_osd)
     iota = np.arange(w, dtype=np.int32)[None, :]
 
     def in_range(o):
-        return (o >= 0) & (o < m_osd)
+        return (o >= 0) & (o < max_osd)
 
     def gather(vec, o):
-        return vec[np.clip(o, 0, m_osd - 1)]
+        return vec[np.clip(o, 0, m_pad - 1)]
 
     def exists(o):
         return in_range(o) & ((gather(state, o) & _OSD_EXISTS) != 0)
@@ -381,8 +387,7 @@ def run_ladder(operands: "LadderOperands") -> np.ndarray:
              padded(operands.raw_len), padded(operands.up_rows),
              padded(operands.up_len), padded(operands.items),
              padded(operands.temp_rows), padded(operands.temp_len),
-             padded(operands.ptemp), operands.state, operands.weight,
-             operands.affinity)
+             padded(operands.ptemp), *operands.osd_operands())
     # analysis: allow[blocking] -- engine-less entry point: callers want the host table
     return np.asarray(out)[:n]
 
@@ -397,16 +402,18 @@ class LadderOperands:
     ``raw``/``pps``/``raw_len`` and the override tables have the PG
     leading axis (they coalesce/shard through the engine's data+aux
     channels); ``state``/``weight``/``affinity`` are the per-OSD
-    vectors shared by every pool of the epoch (captured operands,
-    mesh-replicated by the submit helper)."""
+    vectors shared by every pool of the epoch, padded past
+    ``max_osd`` (OSDMap.dense_osd_vectors), and ``max_osd`` the bound
+    of the ladder's range checks (captured operands, mesh-replicated
+    by the submit helper)."""
 
     __slots__ = ("raw", "pps", "raw_len", "up_rows", "up_len", "items",
                  "temp_rows", "temp_len", "ptemp", "state", "weight",
-                 "affinity", "erasure", "width")
+                 "affinity", "max_osd", "erasure", "width")
 
     def __init__(self, *, raw, pps, raw_len, up_rows, up_len, items,
                  temp_rows, temp_len, ptemp, state, weight, affinity,
-                 erasure, width):
+                 max_osd, erasure, width):
         self.raw = raw
         self.pps = pps
         self.raw_len = raw_len
@@ -419,8 +426,13 @@ class LadderOperands:
         self.state = state
         self.weight = weight
         self.affinity = affinity
+        self.max_osd = np.int32(max_osd)
         self.erasure = bool(erasure)
         self.width = int(width)
+
+    def osd_operands(self) -> tuple:
+        """The per-OSD operands, in the ladder's argument order."""
+        return (self.state, self.weight, self.affinity, self.max_osd)
 
     def aux(self) -> tuple:
         """The per-PG side arrays in submit_finish_ladder's aux order."""
@@ -460,7 +472,7 @@ def build_operands(m, pool_id: int, pool, raw: np.ndarray,
         up_rows=up_rows, up_len=up_len, items=items,
         temp_rows=temp_rows, temp_len=temp_len, ptemp=ptemp,
         state=state, weight=weight, affinity=affinity,
-        erasure=pool.is_erasure(), width=width)
+        max_osd=m.max_osd, erasure=pool.is_erasure(), width=width)
 
 
 def pool_widths(m, pools=None) -> tuple[int, int]:

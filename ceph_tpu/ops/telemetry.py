@@ -745,6 +745,15 @@ class MappingStats:
     bytes of both tables uploaded for them, and ``delta_host_diffs``
     the diffs the host computed (tables at or under
     ``FUSED_DIFF_HOST_MAX``, a changed layout, or no device).
+
+    The CRUSH-TABLE counters say what a changed CRUSH map cost:
+    ``crush_table_builds`` counts host builds of a map's bucket tables
+    (``crush.fastpath.build_tables``: new crush content),
+    ``crush_table_upload_bytes`` the bytes of them put on devices, and
+    ``crush_program_builds`` the CRUSH and ladder programs traced anew
+    (each a compile or a cache load; the first of the process
+    included) — which stands still while edits stay inside a shape
+    class.
     """
 
     __slots__ = ("_lock", "epoch_updates", "epoch_skips",
@@ -754,7 +763,8 @@ class MappingStats:
                  "phase_device", "phase_delta", "phase_host_tail",
                  "fused_epochs", "unfused_epochs", "fused_lookups",
                  "delta_device_diffs", "delta_host_diffs",
-                 "delta_upload_bytes")
+                 "delta_upload_bytes", "crush_table_builds",
+                 "crush_table_upload_bytes", "crush_program_builds")
 
     def __init__(self):
         self._lock = lockdep.make_lock("MappingStats::lock")
@@ -781,6 +791,10 @@ class MappingStats:
         self.delta_device_diffs = 0
         self.delta_host_diffs = 0
         self.delta_upload_bytes = 0
+        # what changed CRUSH maps cost (see class docstring)
+        self.crush_table_builds = 0
+        self.crush_table_upload_bytes = 0
+        self.crush_program_builds = 0
 
     def clear(self) -> None:
         with self._lock:
@@ -799,6 +813,25 @@ class MappingStats:
             self.fused_lookups = 0
             self.delta_device_diffs = self.delta_host_diffs = 0
             self.delta_upload_bytes = 0
+            self.crush_table_builds = 0
+            self.crush_table_upload_bytes = 0
+            self.crush_program_builds = 0
+
+    def record_crush_table_build(self) -> None:
+        """One host build of a crush map's bucket tables."""
+        with self._lock:
+            self.crush_table_builds += 1
+
+    def record_crush_table_upload(self, nbytes: int) -> None:
+        """Bucket tables put on a device (or replicated over a mesh)."""
+        with self._lock:
+            self.crush_table_upload_bytes += int(nbytes)
+
+    def record_program_build(self) -> None:
+        """A CRUSH or ladder program traced anew (called from inside
+        the trace)."""
+        with self._lock:
+            self.crush_program_builds += 1
 
     def record_delta_diff(self, device: bool,
                           upload_bytes: int = 0) -> None:
@@ -884,6 +917,9 @@ class MappingStats:
                 "delta_device_diffs": self.delta_device_diffs,
                 "delta_host_diffs": self.delta_host_diffs,
                 "delta_upload_bytes": self.delta_upload_bytes,
+                "crush_table_builds": self.crush_table_builds,
+                "crush_table_upload_bytes": self.crush_table_upload_bytes,
+                "crush_program_builds": self.crush_program_builds,
                 "host_tail_share": round(self._host_tail_share(), 6),
                 "phase_seconds": {
                     "device": self.phase_device.dump(),
@@ -932,6 +968,9 @@ class MappingStats:
                 "delta_device_diffs": self.delta_device_diffs,
                 "delta_host_diffs": self.delta_host_diffs,
                 "delta_upload_bytes": self.delta_upload_bytes,
+                "crush_table_builds": self.crush_table_builds,
+                "crush_table_upload_bytes": self.crush_table_upload_bytes,
+                "crush_program_builds": self.crush_program_builds,
                 "host_tail_share": round(self._host_tail_share(), 6),
             }
 

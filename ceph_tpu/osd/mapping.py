@@ -17,8 +17,11 @@ Three layers:
   INCREMENTAL: each pool carries a signature (crush content, rule, size,
   pg_num/pgp_num, the reweights of the OSDs its rule can actually reach) and
   only pools whose signature moved recompute; untouched pools reuse their raw
-  tables.  One BatchMapper is cached per crush-map identity, so
-  unchanged-crush epochs skip the mapper rebuild entirely.  Remaps submit
+  tables.  The BatchMapper of the current crush content is kept, so
+  unchanged-crush epochs skip the table build; a changed crush map
+  costs a host-side build of its bucket tables and their upload, and
+  runs the programs the first map of its shape class built
+  (crush.mapper_jax).  Remaps submit
   through the context's dispatch engine (ops.dispatch.submit_do_rule) when
   one is supplied: pools sharing a rule — and daemons sharing a context —
   coalesce into one device call, and the double-buffered pipeline overlaps
@@ -52,7 +55,8 @@ from collections import deque
 import numpy as np
 
 from ceph_tpu.common import lockdep, tracing
-from ceph_tpu.crush.types import CRUSH_ITEM_NONE, CrushMap
+from ceph_tpu.crush.types import (CRUSH_ITEM_NONE, CrushMap,
+                                  padded_osds)
 from ceph_tpu.ops import telemetry
 
 from .osdmap import MAX_AFFINITY, OSDMap, PGPool
@@ -84,8 +88,8 @@ def crush_signature(crush: CrushMap) -> int:
     """Content hash of everything placement reads from the crush map:
     bucket structure/weights, rules, tunables, choose_args.  O(map
     size) per epoch — noise next to one pool remap — and it is what
-    lets unchanged-crush epochs reuse both the compiled BatchMapper
-    and every pool's raw table."""
+    lets unchanged-crush epochs reuse both the BatchMapper (the map's
+    bucket tables) and every pool's raw table."""
     buckets = tuple(
         (b.id, b.type, b.alg, b.hash, tuple(b.items),
          tuple(b.item_weights), b.weight)
@@ -406,10 +410,15 @@ class OSDMapMapping:
         #: (up, acting, primaries) tables next to the raw ones.
         #: Ignored on the scalar backend.
         self.fused = fused
-        #: one BatchMapper per crush-map identity (content signature),
-        #: kept across update() calls so unchanged-crush epochs skip
-        #: the compile_map/mapper rebuild
-        self._mappers: dict[int, object] = {}
+        #: (content signature, BatchMapper) of the crush map last
+        #: mapped, kept across update() calls so unchanged-crush epochs
+        #: skip the table build.  A mapper holds a map's content only
+        #: (its bucket tables); the compiled programs are the
+        #: process's, one per shape class (crush.mapper_jax)
+        self._mapper: tuple[int, object] | None = None
+        #: rule -> shape class of the tables last built for it (the
+        #: `class_changed` attribute of the `mapping crush tables` span)
+        self._fast_shapes: dict[int, object] = {}
         self._raw: dict[int, np.ndarray] = {}    # pool -> (pg_num, size) raw
         self._pps: dict[int, np.ndarray] = {}    # pool -> (pg_num,) pps seeds
         self._sigs: dict[int, tuple] = {}        # pool -> placement signature
@@ -421,20 +430,45 @@ class OSDMapMapping:
         self.backend = backend
 
     def mapper_for(self, crush: CrushMap, csig: int | None = None):
-        """The cached BatchMapper for this crush content (built on
-        miss).  Offline tools share the production mapper path here."""
+        """The BatchMapper of this crush content: the one kept if the
+        content is unchanged, else a new one (which replaces it: one
+        map's tables are held, not a history of them).  New content is
+        no new program — see ``_crush_tables``.  Offline tools share
+        the production mapper path here."""
         if csig is None:
             csig = crush_signature(crush)
-        bm = self._mappers.get(csig)
-        if bm is None:
+        if self._mapper is None or self._mapper[0] != csig:
             from ceph_tpu.crush.mapper_jax import BatchMapper
-            bm = BatchMapper(crush)
-            self._mappers[csig] = bm
-            # bound: the tool path (place() with per-run crush maps)
-            # must not accumulate compiled programs for process life
-            while len(self._mappers) > 4:
-                self._mappers.pop(next(iter(self._mappers)))
-        return bm
+            self._mapper = (csig, BatchMapper(crush))
+        return self._mapper[1]
+
+    def _crush_tables(self, bm, ruleno: int, engine) -> None:
+        """New crush content on the fast path: build the map's bucket
+        tables on the host and put them where the engine will place
+        the batch, under spans of their own.  A no-op once the mapper
+        has them — every epoch that leaves the crush map alone."""
+        if bm.has_fast_tables(ruleno):
+            return
+        with tracing.span("mapping crush tables", daemon="mapping") as sp:
+            ft = bm.fast_tables(ruleno)
+            if ft is None:
+                return
+            tracing.set_attrs(
+                sp, hosts=ft.shape.root_lanes,
+                leaf_lanes=ft.shape.leaf_lanes,
+                class_changed=self._fast_shapes.get(ruleno) != ft.shape)
+            self._fast_shapes[ruleno] = ft.shape
+        mesh = None
+        if engine is not None:
+            try:
+                mesh = engine.placement_mesh()
+            except Exception:
+                mesh = None
+            if mesh is not None and getattr(mesh, "size", 1) <= 1:
+                mesh = None
+        with tracing.span("mapping crush tables upload", daemon="mapping",
+                          bytes=ft.nbytes):
+            ft.on(mesh)
 
     def update(self, osdmap: OSDMap | None = None,
                engine=None) -> _UpdateInfo:
@@ -461,8 +495,12 @@ class OSDMapMapping:
                            if k[0] == csig}
         # pps seeds, weights and the per-pool CRUSH remaps
         with tracing.span("mapping crush", daemon="mapping"):
-            weights = np.zeros(max(m.max_osd, 1), dtype=np.int64)
-            weights[:len(m.osd_weight)] = m.osd_weight
+            # zero-padded to the OSD axis quantum: weight 0 is out,
+            # is_out's verdict for an id past the vector already, and
+            # a max_osd that grows inside the quantum keeps the shape
+            weights = np.zeros(padded_osds(m.max_osd), dtype=np.int64)
+            k = min(m.max_osd, len(m.osd_weight))
+            weights[:k] = m.osd_weight[:k]
             raw: dict[int, np.ndarray] = {}
             pps_t: dict[int, np.ndarray] = {}
             recomputed: list[int] = []
@@ -506,10 +544,10 @@ class OSDMapMapping:
                     pps = pps_batch(pool, pgids)
                 pps_t[pool_id] = pps
                 if bm is None:
-                    # mapper_for reuses the compiled mapper across epochs
-                    # for unchanged crush content (and bounds the dict for
-                    # the tool path)
+                    # mapper_for keeps the mapper (its tables) across
+                    # epochs of unchanged crush content
                     bm = self.mapper_for(m.crush, csig)
+                self._crush_tables(bm, pool.crush_rule, engine)
                 if engine is not None:
                     from ceph_tpu.ops.dispatch import BACKGROUND_BEST_EFFORT
                     from ceph_tpu.ops.dispatch import submit_do_rule
@@ -1238,7 +1276,8 @@ class SharedPGMappingService:
             temp_len=np.zeros(b, dtype=np.int32),
             ptemp=np.full(b, -1, dtype=np.int32),
             state=state, weight=weight, affinity=affinity,
-            erasure=pool.is_erasure(), width=width)
+            max_osd=osdmap.max_osd, erasure=pool.is_erasure(),
+            width=width)
         try:
             engine = self._engine()
             if engine is not None:

@@ -23,7 +23,8 @@ from dataclasses import dataclass, field
 
 from ceph_tpu.crush.hashfn import crush_hash32_2
 from ceph_tpu.crush.mapper_ref import crush_do_rule
-from ceph_tpu.crush.types import CRUSH_ITEM_NONE, CrushMap
+from ceph_tpu.crush.types import (CRUSH_ITEM_NONE, CrushMap,
+                                  padded_osds)
 
 CEPH_NOSD = -1
 
@@ -239,12 +240,16 @@ class OSDMap:
 
     def dense_osd_vectors(self):
         """(state, weight, affinity) numpy vectors of length
-        max(max_osd, 1) — the per-OSD operands of the fused placement
-        ladder (ops.placement_kernel).  Sliced to max_osd exactly: the
-        scalar pipeline's bounds checks all read ``0 <= o < max_osd``,
-        so entries past it must not exist on device either."""
+        ``padded_osds(max_osd)`` — the per-OSD operands of the fused
+        placement ladder (ops.placement_kernel).  Padded past max_osd
+        (state 0: does not exist; weight 0: out; default affinity) to
+        the OSD axis quantum (crush.types), so a cluster that grows inside the quantum
+        keeps the ladder's compiled program.  The scalar pipeline's
+        bounds checks all read ``0 <= o < max_osd``: the ladder holds
+        them to that with max_osd as a scalar operand
+        (``LadderOperands.max_osd``), not with the vectors' length."""
         import numpy as np
-        n = max(self.max_osd, 1)
+        n = padded_osds(self.max_osd)
         state = np.zeros(n, dtype=np.int32)
         weight = np.zeros(n, dtype=np.int64)
         affinity = np.full(n, MAX_AFFINITY, dtype=np.int32)

@@ -274,7 +274,7 @@ def served_ec(sz: Sizes, seed: int, base_path: str) -> dict:
 # ---------------------------------------------------------------------------
 
 def placement(sz: Sizes, seed: int, crush_map, rid: int, reweight) -> dict:
-    from ceph_tpu.crush import crush_do_rule
+    from ceph_tpu.crush import crush_do_rule, fastpath
     from ceph_tpu.crush.mapper_jax import BatchMapper
     from ceph_tpu.crush.types import CRUSH_ITEM_NONE
     from ceph_tpu.tools.crush_test import run_test
@@ -287,8 +287,9 @@ def placement(sz: Sizes, seed: int, crush_map, rid: int, reweight) -> dict:
                      out=io.StringIO())[rid]
     t_tool = time.perf_counter() - t0
 
-    # the same call again for its rows: a fresh mapper, so the program
-    # is traced anew and comes out of the compile cache
+    # the same call again for its rows: a fresh mapper builds and
+    # uploads the map's tables and runs the program the tool's call
+    # built for the shape class
     xs = np.arange(sz.n_pgs, dtype=np.uint32)
     bm = BatchMapper(crush_map)
     t0 = time.perf_counter()
@@ -305,7 +306,8 @@ def placement(sz: Sizes, seed: int, crush_map, rid: int, reweight) -> dict:
 
     # the XLA fast path (what every CPU test runs), all lanes
     bm_xla = BatchMapper(crush_map)
-    bm_xla._fastpath(rid)._pallas = None
+    bm_xla._fast_cache[rid] = fastpath.tables_of(
+        fastpath.detect(crush_map, rid), pallas=False)
     rows_xla = np.asarray(bm_xla.do_rule(rid, xs, sz.numrep, reweight))
     bad = int((rows != rows_xla).any(axis=1).sum())
     require(bad == 0, f"{bad} of {sz.n_pgs} lanes differ from the XLA path")
@@ -322,7 +324,7 @@ def placement(sz: Sizes, seed: int, crush_map, rid: int, reweight) -> dict:
     return {"pgs": sz.n_pgs, "osds": crush_map.max_devices,
             "numrep": sz.numrep, "sizes": stats["sizes"],
             "xla_lanes_equal": sz.n_pgs, "oracle_lanes_equal": len(sample),
-            "pallas": bm._fastpath(rid)._pallas is not None,
+            "pallas": bm.fast_tables(rid).shape.pallas,
             "tool_call_s": round(t_tool, 3),
             "fresh_mapper_call_s": round(t_again, 3),
             "warm_call_s": round(t_warm, 3),
@@ -397,7 +399,7 @@ def map_epochs(sz: Sizes, seed: int, crush_map, rid: int, reweight,
                            "quiet_pgs_checked": len(quiet),
                            "update_s": round(dt, 3)})
             m = new
-        mappers = list(svc._mapping._mappers.values())
+        mapper = svc._mapping._mapper[1]
     finally:
         stop_engines(ctx)
     after = telemetry.mapping_summary()
@@ -406,8 +408,7 @@ def map_epochs(sz: Sizes, seed: int, crush_map, rid: int, reweight,
         "fused_lookups", "lookup_fallbacks")}
     return {"pool_pgs": sz.n_pgs, "osds": n_osds, "epochs": epochs,
             "first_build_s": round(first_s, 3), "mapping": delta,
-            "pallas": bool(mappers) and all(
-                bm._fastpath(rid)._pallas is not None for bm in mappers)}
+            "pallas": mapper.fast_tables(rid).shape.pallas}
 
 
 # ---------------------------------------------------------------------------
@@ -474,6 +475,7 @@ def mesh_path(sz: Sizes, seed: int, crush_map, rid: int, reweight,
     kernel_mesh_devices = 0), each compared bit for bit with the same
     call through a single-device engine."""
     from ceph_tpu.common.context import CephTpuContext
+    from ceph_tpu.crush import mapper_jax
     from ceph_tpu.crush.mapper_jax import BatchMapper
     from ceph_tpu.ec import registry_instance
     from ceph_tpu.ops import gf_kernel, telemetry
@@ -538,9 +540,10 @@ def mesh_path(sz: Sizes, seed: int, crush_map, rid: int, reweight,
                 "crush: mesh result differs from one device")
         facts["crush"] = {
             "pgs": sz.n_pgs,
-            "pallas": bm._fastpath(rid)._pallas is not None,
+            "pallas": bm.fast_tables(rid).shape.pallas,
             "shard_map_programs": sum(
-                1 for key in bm._jit_cache if key[0] == "fast_sh"),
+                1 for key in mapper_jax._FAST_PROGRAMS
+                if key[2] is not None),
             "lanes_equal": sz.n_pgs}
         facts["dispatch"] = {
             key: telemetry.dispatch_summary()[key]

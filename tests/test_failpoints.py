@@ -678,7 +678,7 @@ class TestChannelBitExactness:
             affinity=np.where(rng.random(m_osd) < 0.5, 0x10000,
                               rng.integers(0, 0x10000,
                                            m_osd)).astype(np.int32),
-            erasure=False, width=w)
+            max_osd=m_osd, erasure=False, width=w)
         eng = _engine()
         try:
             device = np.asarray(
@@ -692,8 +692,7 @@ class TestChannelBitExactness:
             assert (degraded == device).all()
             # and the standalone oracle agrees (ladder_ref twin)
             ref = pk.ladder_ref(operands.raw, *operands.aux(),
-                                operands.state, operands.weight,
-                                operands.affinity, erasure=False)
+                                *operands.osd_operands(), erasure=False)
             assert (ref == device).all()
         finally:
             failpoint.clear()

@@ -158,7 +158,7 @@ def test_none_frm_pair_never_pollutes_pad_cells():
         up_rows=up_rows, up_len=up_len, items=items,
         temp_rows=temp_rows, temp_len=temp_len, ptemp=ptemp,
         state=state, weight=weight, affinity=affinity,
-        erasure=True, width=width))
+        max_osd=m.max_osd, erasure=True, width=width))
     # oracle: pair 1 (NONE frm) skipped, pair 2 rewrites 1 -> x
     want = m._finish_pg_mapping(pool, (2, 0), [0, 1, 2, 3], 12345)
     assert pk.unpack_row(packed[0], width) == want
@@ -448,9 +448,9 @@ def test_fastpath_pallas_sharded_batch_matches_scalar_oracle():
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec
 
-    from ceph_tpu.crush.fastpath import FastMapper, detect
+    from ceph_tpu.crush import mapper_jax
+    from ceph_tpu.crush.fastpath import detect, tables_of
     from ceph_tpu.crush.mapper_jax import BatchMapper
-    from ceph_tpu.ops.pallas_straw2 import PallasColumns
     from ceph_tpu.parallel.mesh import make_mesh
 
     if len(jax.devices()) < 2:
@@ -458,11 +458,9 @@ def test_fastpath_pallas_sharded_batch_matches_scalar_oracle():
     crush_map, _root, rid = build_two_level_map(6, 4)
     fr = detect(crush_map, rid)
     assert fr is not None
-    fm = FastMapper(fr)
-    assert fm._pallas is None        # CPU backend: not auto-selected
-    fm._pallas = PallasColumns(fr, interpret=True)
+    assert not tables_of(fr).shape.pallas    # CPU: not auto-selected
     bm = BatchMapper(crush_map)
-    bm._fast_cache[rid] = fm
+    ft = bm._fast_cache[rid] = tables_of(fr, pallas=True, interpret=True)
 
     n_dev = len(jax.devices())
     mesh = make_mesh(n_dev)
@@ -475,9 +473,11 @@ def test_fastpath_pallas_sharded_batch_matches_scalar_oracle():
     spec = PartitionSpec(tuple(mesh.axis_names))
     placed = jax.device_put(jnp.asarray(xs), NamedSharding(mesh, spec))
     out = bm.do_rule(rid, placed, 3, reweight)
-    # the sharded fastpath entry really compiled
-    assert any(isinstance(kk, tuple) and kk and kk[0] == "fast_sh"
-               for kk in bm._jit_cache)
+    # the sharded fastpath entry really compiled, and took the map's
+    # tables replicated over the mesh
+    assert any(shape == ft.shape and sh is not None
+               for shape, _rmax, sh in mapper_jax._FAST_PROGRAMS)
+    assert ft.placed(mesh) and not ft.placed(None)
     want = scalar_rows(crush_map, rid, xs, 3, reweight)
     np.testing.assert_array_equal(np.asarray(out), want)
 

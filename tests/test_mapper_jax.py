@@ -131,10 +131,12 @@ def test_fastpath_overflow_falls_back_exactly():
     rw[10] = 0x2000  # nearly everything out -> long retry ladders
     fr = fastpath.detect(m, rid)
     assert fr is not None
-    fm = fastpath.FastMapper(fr)
+    shape = fastpath.shape_of(fr)
+    fm = fastpath.FastMapper(shape)
     xs = rng.integers(0, 2**32, 100, dtype=np.uint32)
     got = np.asarray(jax.jit(functools.partial(fm.run, result_max=3, block=1))(
-        xs, np.asarray(rw, dtype=np.int64)))
+        xs, np.asarray(rw, dtype=np.int64),
+        fastpath.build_tables(fr, shape)))
     for i, x in enumerate(xs):
         want = crush_do_rule(m, rid, int(x), 3, rw)
         compact = [int(v) for v in got[i] if v != CRUSH_ITEM_NONE]
@@ -196,8 +198,7 @@ def test_two_stage_pallas_schedule_interpret():
     mode — the TPU-only glue otherwise never runs in CI."""
     import jax.numpy as jnp
 
-    from ceph_tpu.crush.fastpath import FastMapper, detect
-    from ceph_tpu.ops.pallas_straw2 import PallasColumns
+    from ceph_tpu.crush.fastpath import FastMapper, detect, tables_of
 
     crush_map, _root, rid = build_two_level_map(20, 4)
     # small tries -> small Rf fallback range: interpret-mode tracing of
@@ -221,27 +222,28 @@ def test_two_stage_pallas_schedule_interpret():
     xs = jnp.asarray(np.random.default_rng(2).integers(
         0, 2 ** 32, (1024,), dtype=np.uint32))
 
-    fm = FastMapper(fr)
-    fm._pallas = PallasColumns(fr, interpret=True)
+    ft = tables_of(fr, pallas=True, interpret=True)
+    tables = ft.on()
+    fm = FastMapper(ft.shape)
     fm.TWO_STAGE_MIN = 512     # force the two-stage path at test size
     fm.STAGE2_CAP = 512
-    res_two = np.asarray(fm.run(xs, rw, 3))
+    res_two = np.asarray(fm.run(xs, rw, tables, 3))
 
-    fm_xla = FastMapper(fr)
-    fm_xla._pallas = None
-    res_xla = np.asarray(fm_xla.run(xs, rw, 3))
+    ft_xla = tables_of(fr, pallas=False)
+    res_xla = np.asarray(FastMapper(ft_xla.shape).run(
+        xs, rw, ft_xla.on(), 3))
     np.testing.assert_array_equal(res_two, res_xla)
 
     # cap overflow guard: capacity 8 certainly overflows -> whole-batch
     # recompute path, still exact
     fm.STAGE2_CAP, fm.STAGE2_SHARE = 8, 1 << 20
-    res_cap = np.asarray(fm.run(xs, rw, 3))
+    res_cap = np.asarray(fm.run(xs, rw, tables, 3))
     np.testing.assert_array_equal(res_cap, res_xla)
 
     # the capacity grows with the batch: one lane in four of 1,024
     # holds what 8 could not, through the merge and not the guard
     fm.STAGE2_SHARE = 4
-    res_share = np.asarray(fm.run(xs, rw, 3))
+    res_share = np.asarray(fm.run(xs, rw, tables, 3))
     np.testing.assert_array_equal(res_share, res_xla)
 
 

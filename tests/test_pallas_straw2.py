@@ -11,9 +11,8 @@ import jax.numpy as jnp
 import pytest
 
 from ceph_tpu.crush import build_two_level_map
-from ceph_tpu.crush.fastpath import detect
+from ceph_tpu.crush.fastpath import FastMapper, detect, tables_of
 from ceph_tpu.ops.crush_kernel import is_out
-from ceph_tpu.ops.pallas_straw2 import PallasColumns
 from ceph_tpu.ops.straw2_u32 import magic_tables, straw2_choose_index_u32
 
 
@@ -33,11 +32,17 @@ def skewed_map():
     return crush_map, rid
 
 
+def columns_of(fr, interpret):
+    """The column kernels of the rule's shape class, and its tables."""
+    ft = tables_of(fr, pallas=True, interpret=interpret)
+    return FastMapper(ft.shape)._pallas, ft.on()
+
+
 def test_pallas_columns_match_u32_kernel(skewed_map):
     crush_map, rid = skewed_map
     fr = detect(crush_map, rid)
     assert fr is not None
-    pc = PallasColumns(fr, interpret=True)
+    pc, tables = columns_of(fr, True)
     N, R = 256, 5
     rng = np.random.default_rng(0)
     xs = jnp.asarray(rng.integers(0, 2 ** 32, (N,), dtype=np.uint32))
@@ -46,8 +51,8 @@ def test_pallas_columns_match_u32_kernel(skewed_map):
     reweight[7] = 0x8000      # a half-reweighted osd
     rw = jnp.asarray(reweight)
 
-    pos, ids = pc.root_columns(xs, rw, R)
-    lid = pc.leaf_columns(xs, pos, R)
+    pos, ids = pc.root_columns(xs, tables, R)
+    lid = pc.leaf_columns(xs, pos, tables, R)
     lbad = np.asarray(is_out(rw, lid, jnp.asarray(
         np.pad(np.asarray(xs), (0, lid.shape[1] - N)))[None, :])
     ).astype(np.int32)
@@ -84,14 +89,14 @@ def test_pallas_flat_rule(skewed_map):
     crush_map, _root, rid = build_flat_map(300)
     fr = detect(crush_map, rid)
     assert fr is not None and fr.kind == "choose_flat"
-    pc = PallasColumns(fr, interpret=True)
+    pc, tables = columns_of(fr, True)
     N, R = 128, 3
     rng = np.random.default_rng(1)
     xs = jnp.asarray(rng.integers(0, 2 ** 32, (N,), dtype=np.uint32))
     reweight = np.full(300, 0x10000, dtype=np.int64)
     reweight[5] = 0
     rw = jnp.asarray(reweight)
-    pos, ids = pc.root_columns(xs, rw, R)
+    pos, ids = pc.root_columns(xs, tables, R)
     bad = np.asarray(is_out(rw, ids, jnp.asarray(
         np.pad(np.asarray(xs), (0, ids.shape[1] - N)))[None, :])
     ).astype(np.int32)
@@ -142,7 +147,7 @@ def test_froot_columns_match_exact(skewed_map):
     certificate clean on realistic weights."""
     crush_map, rid = skewed_map
     fr = detect(crush_map, rid)
-    pc = PallasColumns(fr, interpret=True)
+    pc, tables = columns_of(fr, True)
     N, R = 256, 5
     rng = np.random.default_rng(1)
     xs = jnp.asarray(rng.integers(0, 2 ** 32, (N,), dtype=np.uint32))
@@ -151,8 +156,8 @@ def test_froot_columns_match_exact(skewed_map):
     reweight[7] = 0x8000
     rw = jnp.asarray(reweight)
 
-    pos, ids = pc.root_columns(xs, rw, R)
-    fpos, fids, ovf = pc.froot_columns(xs, rw, R)
+    pos, ids = pc.root_columns(xs, tables, R)
+    fpos, fids, ovf = pc.froot_columns(xs, tables, R)
     assert int(np.asarray(ovf).max()) == 0, "certificate fired on clean map"
     np.testing.assert_array_equal(np.asarray(fpos), np.asarray(pos))
     np.testing.assert_array_equal(np.asarray(fids), np.asarray(ids))

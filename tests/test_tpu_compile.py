@@ -24,7 +24,8 @@ from jax.sharding import (
     Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding)
 
 from ceph_tpu.crush import build_flat_map, build_skewed_two_level_map
-from ceph_tpu.crush.fastpath import detect
+from ceph_tpu.crush.fastpath import (FastMapper, build_tables, detect,
+                                     shape_of)
 from ceph_tpu.ops import pallas_straw2 as ps
 
 
@@ -123,7 +124,14 @@ N_PGS = 65536
 def columns():
     """PallasColumns over the 250-host x 40-OSD deployment map."""
     crush_map, rid, _rw = build_skewed_two_level_map(250, 40)
-    return ps.PallasColumns(detect(crush_map, rid))
+    return _columns_of(detect(crush_map, rid))
+
+
+def _columns_of(fr):
+    """The column kernels of the rule's shape class on a TPU, and its
+    tables (host arrays: only their shapes are compiled against)."""
+    shape = shape_of(fr, pallas=True)
+    return FastMapper(shape)._pallas, build_tables(fr, shape)
 
 
 #: the two-stage schedule's shapes at numrep 3: every lane gets
@@ -138,16 +146,20 @@ STAGES_1M = [(1 << 20, 4), ((1 << 20) // 16, 9)]
 
 @pytest.mark.parametrize("n,R", STAGES + STAGES_1M)
 def test_root_columns(one_chip, columns, n, R):
-    text = _compile(lambda xs: columns.root_columns(xs, None, R),
-                    _spec(one_chip, (n,), jnp.uint32))
+    pc, tables = columns
+    text = _compile(lambda xs, *t: pc.root_columns(xs, t, R),
+                    _spec(one_chip, (n,), jnp.uint32),
+                    *(_spec(one_chip, t.shape, t.dtype) for t in tables))
     assert "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("n,R", STAGES + STAGES_1M)
 def test_leaf_columns(one_chip, columns, n, R):
-    text = _compile(lambda xs, pos: columns.leaf_columns(xs, pos, R),
+    pc, tables = columns
+    text = _compile(lambda xs, pos, *t: pc.leaf_columns(xs, pos, t, R),
                     _spec(one_chip, (n,), jnp.uint32),
-                    _spec(one_chip, (R, n), jnp.int32))
+                    _spec(one_chip, (R, n), jnp.int32),
+                    *(_spec(one_chip, t.shape, t.dtype) for t in tables))
     assert "tpu_custom_call" in text
 
 
@@ -174,11 +186,12 @@ def test_froot_columns(one_chip, monkeypatch):
     items (not the smoke's map); its error bound is a measurement on the
     chip, so the test supplies a value of the measured order."""
     fmap, _root, frid = build_flat_map(600)
-    pc = ps.PallasColumns(detect(fmap, frid))
+    pc, tables = _columns_of(detect(fmap, frid))
     assert 512 <= pc.S_root <= 1024
     monkeypatch.setattr(ps, "_ln_f32_bound", lambda interpret: 2.0 ** 22)
-    text = _compile(lambda xs: pc.froot_columns(xs, None, 4),
-                    _spec(one_chip, (8192,), jnp.uint32))
+    text = _compile(lambda xs, *t: pc.froot_columns(xs, t, 4),
+                    _spec(one_chip, (8192,), jnp.uint32),
+                    *(_spec(one_chip, t.shape, t.dtype) for t in tables))
     assert "tpu_custom_call" in text
 
 
@@ -209,8 +222,9 @@ def test_bitplane_transpose(one_chip):
 @pytest.mark.parametrize("erasure,n_pgs", [(False, 1024), (True, 1024),
                                             (False, 1 << 20)])
 def test_placement_ladder(one_chip, erasure, n_pgs):
+    from ceph_tpu.crush.types import padded_osds
     from ceph_tpu.ops.placement_kernel import _ladder_jit
-    w, pairs, n_osds = (12 if erasure else 3), 1, 10000
+    w, pairs, n_osds = (12 if erasure else 3), 1, padded_osds(10000)
     i32 = functools.partial(_spec, one_chip, dtype=jnp.int32)
     _ladder_jit(erasure).lower(
         i32((n_pgs, w)),                                   # raw
@@ -220,7 +234,7 @@ def test_placement_ladder(one_chip, erasure, n_pgs):
         i32((n_pgs, w)), i32((n_pgs,)), i32((n_pgs,)),     # temps
         i32((n_osds,)),                                    # state
         _spec(one_chip, (n_osds,), jnp.int64),             # weight
-        i32((n_osds,))).compile()                          # affinity
+        i32((n_osds,)), i32(())).compile()       # affinity, max_osd
 
 
 def test_mapping_delta_diff_of_two_1m_row_tables(one_chip):

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ceph_tpu.crush import build_flat_map, build_skewed_two_level_map
-from ceph_tpu.crush.fastpath import FastMapper, detect
+from ceph_tpu.crush.fastpath import detect, mapper_for, tables_of
 
 
 @pytest.fixture(scope="module")
@@ -46,13 +46,18 @@ def test_two_stage_pallas_matches_xla_bulk():
     rw = jnp.asarray(reweight)
     xs = jnp.asarray(np.random.default_rng(0).integers(
         0, 2 ** 32, (65536,), dtype=np.uint32))
-    fm = FastMapper(fr)
-    assert fm._pallas is not None
-    res_pl = np.asarray(fm.run(xs, rw, 3))
-    fm_xla = FastMapper(fr)
-    fm_xla._pallas = None
-    res_xla = np.asarray(fm_xla.run(xs, rw, 3))
+    res_pl, res_xla = _both_paths(fr, xs, rw)
     np.testing.assert_array_equal(res_pl, res_xla)
+
+
+def _both_paths(fr, xs, rw):
+    """The rule's placements by the Pallas kernels (what a TPU selects)
+    and by the XLA path, each on its own tables."""
+    ft = tables_of(fr)
+    assert ft.shape.pallas
+    ft_xla = tables_of(fr, pallas=False)
+    return (np.asarray(mapper_for(ft.shape).run(xs, rw, ft.on(), 3)),
+            np.asarray(mapper_for(ft_xla.shape).run(xs, rw, ft_xla.on(), 3)))
 
 
 # 300 items take the exact column kernels; 600 (512..1024 items) take the
@@ -65,10 +70,5 @@ def test_flat_rule_pallas_matches_xla(n_osds):
                               0x10000).astype(np.int64))
     xs = jnp.asarray(np.random.default_rng(1).integers(
         0, 2 ** 32, (8192,), dtype=np.uint32))
-    fm = FastMapper(fr)
-    assert fm._pallas is not None
-    res_pl = np.asarray(fm.run(xs, rw, 3))
-    fm_xla = FastMapper(fr)
-    fm_xla._pallas = None
-    res_xla = np.asarray(fm_xla.run(xs, rw, 3))
+    res_pl, res_xla = _both_paths(fr, xs, rw)
     np.testing.assert_array_equal(res_pl, res_xla)
