@@ -14,6 +14,14 @@ fixed every process is the fast one.  A Ceph OSD fixes its allocator's
 behaviour from its environment too (tcmalloc's thread cache, in
 /etc/default/ceph).
 
+The PG mapping service pins them as well: every epoch of a large pool
+it builds and drops tables of the pool's size (five ladder operands of
+4-12 MB for a million PGs), and with glibc's moving thresholds each
+epoch's 54 MB came from the top of the heap, was trimmed back to the
+system when freed and page-faulted anew.  On the v5e host that build
+took 31-46 ms of an epoch in one process and 4 ms in the next
+(PERF.md, PR 38); pinned, it is 4 ms in every one.
+
 Setting either threshold turns glibc's adjustment off.  No option: one
 value serves every daemon, and a libc without ``mallopt`` is left as it
 is.

@@ -32,10 +32,46 @@ shared width ``W`` and pairs to ``P``, so pools (and daemons) sharing
 one epoch's operand digest coalesce into one device call through
 ``ops.dispatch.submit_finish_ladder``; the per-OSD state/weight/
 affinity vectors are captured operands, mesh-replicated on sharded
-batches exactly like the CRUSH reweight vector.  Every step is
-row-independent along the PG axis, so a mesh-sharded engine splits the
-batch across devices with bit-identical results (the crush_kernel mesh
-contract).
+batches exactly like the CRUSH reweight vector.
+
+Inside the program the PG axis is the lane axis.  The (N, W) tables
+are cut once into W *planes* of shape (N,) — the device keeps a table
+of three columns with N minor already, so a plane is a dense vector
+and nothing is lane-padded — and the whole ladder is elementwise on
+planes: an ``any`` / ``all`` / first-true over a row is a fold,
+unrolled over the W planes at trace time.  The reason is the chip's
+gather: XLA's costs a v5e 7-11 ns a cell, and ``take_along_axis``
+behind a row sort is one more — per-OSD vectors gathered for (N, W)
+tables and rows sorted made 261 ms for a million PGs, every ms of it
+gathers, where this form takes 3.5 (PERF.md, PR 38).
+
+  * **No sort.**  The two stable compactions (NONE holes out of a
+    replicated raw row; down OSDs out of ``up``) are a fixed network:
+    cell j lands at ``pos_j`` = the number of kept cells before it, so
+    ``out_k`` is the one cell with ``keep_j & (pos_j == k)`` — W(W+1)/2
+    selects on dense vectors, the same formulation for W = 3 and 16.
+  * **One attribute word an OSD**, built in the program from the three
+    vectors: bit 0 exists, bit 1 up, bit 2 in (``weight != 0``), bits
+    3..19 the primary affinity (clipped to [0, 0x10001], which keeps
+    both of its tests for any int32) — all the ladder ever asks of an
+    OSD, in 20 bits.  The words of the three id tables (base row,
+    ``pg_upmap`` row, each pair's ``to``) are fetched at once; ``up``
+    and ``acting`` carry their cells' words through pair rewrite,
+    wholesale replacement and compaction; a ``pg_temp`` row asks
+    nothing of its members.  An id outside ``[0, max_osd)`` has word 0.
+  * **The fetch is an exact one-hot product**, not a gather: the id
+    splits into (table row, lane), the lane's one-hot meets the
+    (M / 128, 128) table on the MXU (f32, ``Precision.HIGHEST``: a
+    word is under 2^24), a select over the rows keeps the id's own.
+    XLA fuses one-hot, product and select into one pass; nothing of
+    size N x 128 is ever stored.
+  * 32 bits everywhere: only the M-entry ``weight`` operand is int64,
+    until it is folded into the word.
+
+Every step is row-independent along the PG axis, so a mesh-sharded
+engine (GSPMD over N) splits the planes across devices as it split
+the rows, with bit-identical results and no collective (the
+crush_kernel mesh contract).
 
 Output packing: one (N, 2*W + 4) int32 array per call —
 ``[up (W) | acting (W) | up_len | up_primary | acting_len |
@@ -58,6 +94,13 @@ NOSD = -1                       # CEPH_NOSD — up/acting hole
 _MAX_AFFINITY = 0x10000
 _OSD_EXISTS = 1
 _OSD_UP = 2
+# an OSD's attribute word, as the jitted ladder packs it: the two state
+# bits where they are, bit 2 in (weight != 0), the affinity above
+_W_IN = 4
+_AFF_SHIFT = 3
+# the word table is (M / 128, 128): an id is a row and a lane of it
+_LANE_BITS = 7
+_LANES = 1 << _LANE_BITS
 
 
 # ---------------------------------------------------------------------------
@@ -71,7 +114,8 @@ def _ladder_impl(raw, pps, raw_len, up_rows, up_len, items, temp_rows,
     and weight (int64); shapes: raw/up_rows/temp_rows (N, W), items
     (N, P, 2), the rest (N,) or (M,); max_osd an int32 scalar — the
     bound of every ``0 <= o < max_osd`` check, while M (the vectors'
-    padded length, osdmap.padded_osds) only bounds the gathers."""
+    padded length, osdmap.padded_osds) only bounds the gather."""
+    import jax
     import jax.numpy as jnp
 
     from ceph_tpu.ops import telemetry
@@ -79,36 +123,92 @@ def _ladder_impl(raw, pps, raw_len, up_rows, up_len, items, temp_rows,
 
     # only ever called under jit: a trace of it is a program built
     telemetry.mapping_stats().record_program_build()
-    n, w = raw.shape
+    i32 = jnp.int32
+    w = raw.shape[1]
+    p_pairs = items.shape[1]
     m_pad = state.shape[0]
-    iota = jnp.arange(w, dtype=jnp.int32)[None, :]
 
-    def in_range(o):
-        return (o >= 0) & (o < max_osd)
+    def planes(table):
+        """(N, K) -> K planes of shape (N,)."""
+        t = table.T
+        return [t[j] for j in range(t.shape[0])]
 
-    def gather(vec, o):
-        return vec[jnp.clip(o, 0, m_pad - 1)]
+    def any_of(masks):
+        return functools.reduce(jnp.logical_or, masks)
 
-    def exists(o):
-        return in_range(o) & ((gather(state, o) & _OSD_EXISTS) != 0)
+    def all_of(masks):
+        return functools.reduce(jnp.logical_and, masks)
 
-    def is_up(o):
-        return in_range(o) & ((gather(state, o) & _OSD_UP) != 0)
+    def first_of(masks, cells, default):
+        """The cell of the first plane whose mask is set."""
+        out = default
+        for mask, cell in zip(reversed(masks), reversed(cells)):
+            out = jnp.where(mask, cell, out)
+        return out
 
-    def not_out(o):
-        return in_range(o) & (gather(weight, o) != 0)
+    def compact(keep, *carried):
+        """Stable compaction of W planes: the kept cells move to the
+        front in order.  Cell j lands at k = the number of kept cells
+        before it, so out_k is the one cell with keep_j & (pos_j == k)
+        (j >= k) — a fixed network of selects.  ``carried`` is (planes,
+        fill) pairs moved alike; returns their compacted planes and the
+        count of kept cells."""
+        pos, count = [], jnp.zeros_like(raw_len)
+        for j in range(w):
+            pos.append(count)
+            count = count + keep[j].astype(i32)
+        lands = [[keep[j] & (pos[j] == k) for j in range(k, w)]
+                 for k in range(w)]
+        outs = [[first_of(lands[k], cells[k:], fill) for k in range(w)]
+                for cells, fill in carried]
+        return outs, count
+
+    # -- one attribute word an OSD: all the ladder ever asks of one.
+    # The affinity is clipped to [0, MAX + 1], which keeps both of its
+    # tests (!= MAX, hash16 < aff) for every int32 it could hold.
+    word = ((state & (_OSD_EXISTS | _OSD_UP))
+            | jnp.where(weight != 0, _W_IN, 0)
+            | (jnp.clip(affinity, 0, _MAX_AFFINITY + 1) << _AFF_SHIFT)
+            ).astype(i32)
+
+    def words_of(ids):
+        """The words of a list of id planes, fetched at once; 0 (not
+        existing, down, out) for an id outside [0, max_osd).  The
+        one-hot product of the module docstring: the lane's one-hot
+        times the table gives every table row's candidate, the id's
+        own row is selected.  Exact: a word is under 2^24, f32 holds
+        it, and HIGHEST keeps the f32 operand whole on the MXU."""
+        o = jnp.stack(ids)                                  # (K, N)
+        at = jnp.clip(o, 0, m_pad - 1)
+        n_rows = -(-m_pad // _LANES)
+        table = jnp.pad(word, (0, n_rows * _LANES - m_pad)).astype(
+            jnp.float32).reshape(n_rows, _LANES)
+        lane = jnp.arange(_LANES, dtype=i32)[:, None, None]
+        onehot = ((at & (_LANES - 1))[None] == lane).astype(jnp.float32)
+        rows = jax.lax.dot_general(                         # (rows, K, N)
+            table, onehot, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST)
+        row = jnp.arange(n_rows, dtype=i32)[:, None, None]
+        got = jnp.sum(jnp.where((at >> _LANE_BITS)[None] == row, rows, 0.0),
+                      axis=0).astype(i32)
+        got = jnp.where((o >= 0) & (o < max_osd), got, 0)
+        return [got[j] for j in range(len(ids))]
+
+    def has(words, bits):
+        return (words & bits) == bits
 
     # -- base row: the raw list _finish_from hands to _apply_upmap
     # (replicated compacts NONE holes first; erasure keeps positions)
-    if erasure:
-        base = raw
-        base_len = raw_len
-    else:
-        keep0 = raw != NONE
-        order0 = jnp.argsort(~keep0, axis=1, stable=True)
-        base = jnp.take_along_axis(raw, order0, axis=1)
-        base_len = jnp.sum(keep0, axis=1).astype(jnp.int32)
-        base = jnp.where(iota < base_len[:, None], base, NONE)
+    base = planes(raw)
+    base_len = raw_len
+    if not erasure:
+        (base,), base_len = compact([c != NONE for c in base],
+                                    (base, NONE))
+    ups = planes(up_rows)
+    pairs = planes(items.reshape(items.shape[0], 2 * p_pairs))
+    tos = pairs[1::2]
+    got = words_of(base + ups + tos)
+    base_w, ups_w, tos_w = got[:w], got[w:2 * w], got[2 * w:]
 
     # -- pg_upmap_items: sequential pair rewrites (each pair sees the
     # previous pair's result — a static unroll over the pair axis).
@@ -119,83 +219,74 @@ def _ladder_impl(raw, pps, raw_len, up_rows, up_len, items, temp_rows,
     # has no cells past it, and an unmasked NONE `frm` would match a
     # NONE pad cell on a hole-free row — writing `to` into the pad and
     # making a later pair's `to not in raw` check wrongly fail.
-    wrow = base
-    base_mask = iota < base_len[:, None]
-    p_pairs = items.shape[1]
+    row, row_w = list(base), list(base_w)
+    active = [j < base_len for j in range(w)]
     for p in range(p_pairs):
-        frm = items[:, p, 0]
-        to = items[:, p, 1]
-        match = base_mask & (wrow == frm[:, None])
-        has = jnp.any(match, axis=1)
-        to_in = jnp.any(base_mask & (wrow == to[:, None]), axis=1)
-        cond = has & ~to_in & exists(to) & not_out(to)
-        first = jnp.argmax(match, axis=1).astype(jnp.int32)
-        wrow = jnp.where(cond[:, None] & (iota == first[:, None]),
-                         to[:, None], wrow)
+        frm, to, to_w = pairs[2 * p], tos[p], tos_w[p]
+        match = [active[j] & (row[j] == frm) for j in range(w)]
+        to_in = any_of([active[j] & (row[j] == to) for j in range(w)])
+        cond = ~to_in & has(to_w, _OSD_EXISTS | _W_IN)
+        for j in range(w):          # the first occurrence of `frm`
+            hit = cond & match[j]
+            row[j] = jnp.where(hit, to, row[j])
+            row_w[j] = jnp.where(hit, to_w, row_w[j])
+            cond = cond & ~match[j]
 
     # -- pg_upmap: wholesale replacement when present and every entry
     # exists and is in (OSDMap._apply_upmap's validity gate); an
     # invalid or absent entry falls through to the items result
-    upmask = iota < up_len[:, None]
-    ent_ok = ~upmask | (exists(up_rows) & not_out(up_rows))
-    allok = jnp.all(ent_ok, axis=1) & (up_len > 0)
-    row = jnp.where(allok[:, None], up_rows, wrow)
+    allok = all_of([(j >= up_len) | has(ups_w[j], _OSD_EXISTS | _W_IN)
+                    for j in range(w)]) & (up_len > 0)
+    row = [jnp.where(allok, ups[j], row[j]) for j in range(w)]
+    row_w = [jnp.where(allok, ups_w[j], row_w[j]) for j in range(w)]
     row_len = jnp.where(allok, up_len, base_len)
 
     # -- raw -> up: drop nonexistent/down osds (NONE-positional for
-    # erasure, stable compaction for replicated; OSDMap.cc:2275-2297)
-    lenmask = iota < row_len[:, None]
-    valid = lenmask & (row != NONE) & exists(row) & is_up(row)
+    # erasure, stable compaction for replicated; OSDMap.cc:2275-2297).
+    # A NONE cell is outside [0, max_osd): its word is 0.
+    valid = [(j < row_len) & has(row_w[j], _OSD_EXISTS | _OSD_UP)
+             for j in range(w)]
     if erasure:
-        up = jnp.where(lenmask, jnp.where(valid, row, NOSD), NOSD)
-        up_len_o = row_len
+        up = [jnp.where(valid[j], row[j], NOSD) for j in range(w)]
+        up_w, up_len_o = row_w, row_len
     else:
-        order = jnp.argsort(~valid, axis=1, stable=True)
-        up = jnp.take_along_axis(row, order, axis=1)
-        up_len_o = jnp.sum(valid, axis=1).astype(jnp.int32)
-        up = jnp.where(iota < up_len_o[:, None], up, NOSD)
-    up_real = up != NOSD
-    has_any = jnp.any(up_real, axis=1)
-    firstj = jnp.argmax(up_real, axis=1)
-    first_val = jnp.take_along_axis(up, firstj[:, None], axis=1)[:, 0]
-    up_primary = jnp.where(has_any, first_val, NOSD)
+        (up, up_w), up_len_o = compact(valid, (row, NOSD), (row_w, 0))
+    up_real = [c != NOSD for c in up]
+    up_primary = first_of(up_real, up, NOSD)
 
     # -- primary affinity (OSDMap.cc _apply_primary_affinity): skip
     # entirely when every member has default affinity; otherwise the
     # first member winning its coin flip (default always wins) takes
-    # primary, falling back to the positional primary
-    aff = jnp.where(in_range(up), gather(affinity, up),
-                    _MAX_AFFINITY).astype(jnp.int32)
-    non_default = up_real & (aff != _MAX_AFFINITY)
-    default_all = ~jnp.any(non_default, axis=1)
-    h = (hash32_2(pps[:, None], up.astype(jnp.uint32))
-         >> jnp.uint32(16)).astype(jnp.int32)
-    win = up_real & ((aff == _MAX_AFFINITY) | (h < aff))
-    has_win = jnp.any(win, axis=1)
-    wj = jnp.argmax(win, axis=1)
-    wval = jnp.take_along_axis(up, wj[:, None], axis=1)[:, 0]
+    # primary, falling back to the positional primary.  A real member
+    # of `up` exists, so it carries its own word.
+    aff = [c >> _AFF_SHIFT for c in up_w]
+    default_all = ~any_of([up_real[j] & (aff[j] != _MAX_AFFINITY)
+                           for j in range(w)])
+    # (the hash runs once, on the planes stacked: W times fewer ops
+    # to compile for the same work)
+    flip = (hash32_2(pps[None, :], jnp.stack(up).astype(jnp.uint32))
+            >> jnp.uint32(16)).astype(i32)
+    win = [up_real[j] & ((aff[j] == _MAX_AFFINITY) | (flip[j] < aff[j]))
+           for j in range(w)]
     prim = jnp.where(default_all, up_primary,
-                     jnp.where(has_win, wval, up_primary))
+                     first_of(win, up, up_primary))
 
     # -- temps (OSDMap.cc:2417-2445): pg_temp replaces acting when
     # present and non-empty; primary_temp overrides, else the first
-    # non-NOSD member — with acting == up inheriting up_primary
+    # non-NOSD member — with acting == up inheriting up_primary.  None
+    # of it asks anything of an OSD: a pg_temp row needs no words.
     tset = temp_len > 0
-    acting = jnp.where(tset[:, None], temp_rows, up)
+    temps = planes(temp_rows)
+    acting = [jnp.where(tset, temps[j], up[j]) for j in range(w)]
     act_len = jnp.where(tset, temp_len, up_len_o)
-    act_real = acting != NOSD
-    act_has = jnp.any(act_real, axis=1)
-    aj = jnp.argmax(act_real, axis=1)
-    act_first = jnp.where(
-        act_has, jnp.take_along_axis(acting, aj[:, None], axis=1)[:, 0],
-        NOSD)
-    same = (act_len == up_len_o) & jnp.all(acting == up, axis=1)
+    act_first = first_of([c != NOSD for c in acting], acting, NOSD)
+    same = (act_len == up_len_o) & all_of(
+        [acting[j] == up[j] for j in range(w)])
     ap = jnp.where(ptemp != NOSD, ptemp,
                    jnp.where(same, prim, act_first))
 
-    return jnp.concatenate(
-        [up, acting, up_len_o[:, None], prim[:, None],
-         act_len[:, None], ap[:, None]], axis=1).astype(jnp.int32)
+    return jnp.stack(up + acting + [up_len_o, prim, act_len, ap],
+                     axis=1).astype(i32)
 
 
 @functools.lru_cache(maxsize=2)
@@ -246,8 +337,9 @@ def ladder_ref(raw, pps, raw_len, up_rows, up_len, items, temp_rows,
     """Numpy twin of ``_ladder_impl`` — the bit-exact host oracle the
     dispatch engine degrades the ``pg_finish`` channel to when the
     device path is out (and the unit tests' ground truth for the
-    fused ladder).  Operand-for-operand and step-for-step the same
-    pipeline; see ``_ladder_impl`` for the semantics commentary."""
+    fused ladder).  Operand-for-operand the same pipeline, on (N, W)
+    rows with numpy's own gathers and sorts where the program folds
+    over planes; see ``_ladder_impl`` for the semantics commentary."""
     raw = np.asarray(raw, dtype=np.int32)
     pps = np.asarray(pps, dtype=np.uint32)
     raw_len = np.asarray(raw_len, dtype=np.int32)

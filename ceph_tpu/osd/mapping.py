@@ -55,6 +55,7 @@ from collections import deque
 import numpy as np
 
 from ceph_tpu.common import lockdep, tracing
+from ceph_tpu.common.allocator import pin_malloc_thresholds
 from ceph_tpu.crush.types import (CRUSH_ITEM_NONE, CrushMap,
                                   padded_osds)
 from ceph_tpu.ops import telemetry
@@ -736,6 +737,9 @@ class SharedPGMappingService:
         #: computed against those tables, so it must not be logged
         self._chain_valid = True
         self.stats = telemetry.mapping_stats()
+        # table-sized buffers from here on, every epoch: see
+        # common/allocator.py
+        pin_malloc_thresholds()
 
     # -- plumbing -------------------------------------------------------------
 

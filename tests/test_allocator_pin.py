@@ -1,7 +1,7 @@
-"""glibc's malloc thresholds are fixed by the first OSD a process
-starts (common/allocator.py): whether a process reuses its object-sized
-buffers or maps each anew may not hang on what it happened to free
-first."""
+"""glibc's malloc thresholds are fixed by the first OSD or PG mapping
+service a process starts (common/allocator.py): whether a process
+reuses its object- and table-sized buffers or maps each anew may not
+hang on what it happened to free first."""
 
 import ctypes
 
@@ -51,3 +51,15 @@ def test_an_osd_pins_before_it_mounts_its_store(monkeypatch, tmp_path):
         assert len(calls) == 2
     finally:
         c.stop()
+
+
+def test_a_mapping_service_pins_before_its_first_epoch(monkeypatch):
+    """The PG mapping service builds and drops tables of the pool's
+    size every epoch: it pins, engine-less or behind a context."""
+    from ceph_tpu.osd import mapping
+    calls = []
+    monkeypatch.setattr(mapping, "pin_malloc_thresholds",
+                        lambda: calls.append(1) or True)
+    mapping.SharedPGMappingService()
+    mapping.SharedPGMappingService(backend="scalar", fused=False)
+    assert len(calls) == 2

@@ -194,6 +194,229 @@ def test_ladder_bucket_padding_bit_exact():
     np.testing.assert_array_equal(pk.run_ladder(ops), full[:cut])
 
 
+# -- the plane program against its twin, branch by branch ---------------------
+
+_MAX_OSD = 40           # _branchy_map: 10 hosts x 4
+
+
+def _branchy_map(rng):
+    """A 40-OSD map whose OSDs are down, nonexistent, up without
+    existing, out, and of four non-default affinities; the last id
+    (max_osd - 1) exists, is up, in and default."""
+    m, _rule = _base_map(hosts=10, per_host=4)
+    for o in range(_MAX_OSD - 1):
+        m.osd_state[o] = int(rng.choice(
+            (OSD_EXISTS | OSD_UP, OSD_EXISTS, 0, OSD_UP),
+            p=(0.7, 0.1, 0.1, 0.1)))
+        m.osd_weight[o] = int(rng.choice((0x10000, 0x8000, 0),
+                                         p=(0.6, 0.25, 0.15)))
+        m.osd_primary_affinity[o] = int(rng.choice(
+            (0x10000, 0, 0x4000, 0x8000, 0xC000),
+            p=(0.5, 0.125, 0.125, 0.125, 0.125)))
+    return m
+
+
+def _branchy_operands(m, rng, n, w, pairs, erasure):
+    """Dense ladder operands drawn to take every branch: NONE holes,
+    valid and invalid pg_upmap rows, chained pairs and a NONE `frm`,
+    ids at max_osd - 1, at max_osd, inside the vectors' padding, past
+    it and below 0, pg_temp rows with and without primary_temp, and —
+    after a first pass — pg_temp rows equal to `up`.  The vectors'
+    padding is poisoned (exists, up, in, affinity 0x4000): only the
+    max_osd scalar keeps the ladder off it."""
+    state, weight, affinity = m.dense_osd_vectors()
+    m_pad = len(state)
+    state[_MAX_OSD:], weight[_MAX_OSD:] = OSD_EXISTS | OSD_UP, 0x10000
+    affinity[_MAX_OSD:] = 0x4000
+    good = [o for o in range(_MAX_OSD)
+            if m.exists(o) and not m.is_out(o)]
+    odd = np.array([_MAX_OSD - 1, _MAX_OSD, _MAX_OSD + 5, m_pad - 1,
+                    m_pad, m_pad + 77, -1, -7, pk.NONE], dtype=np.int64)
+
+    def ids(shape, odd_share=0.15):
+        out = rng.integers(0, _MAX_OSD, shape)
+        return np.where(rng.random(shape) < odd_share,
+                        rng.choice(odd, shape), out).astype(np.int32)
+
+    raw_w = max(1, w - w // 4)
+    raw = np.full((n, w), pk.NONE, dtype=np.int32)
+    raw[:, :raw_w] = np.where(rng.random((n, raw_w)) < 0.1, pk.NONE,
+                              ids((n, raw_w), 0.05))
+    pps = rng.integers(0, 2 ** 32, n, dtype=np.uint32)
+    raw_len = np.full(n, raw_w, dtype=np.int32)
+
+    up_rows = np.full((n, w), pk.NONE, dtype=np.int32)
+    up_len = np.where(rng.random(n) < 0.125,
+                      rng.integers(1, w + 1, n), 0).astype(np.int32)
+    cells = np.arange(w)[None, :] < up_len[:, None]
+    valid_rows = rng.random(n) < 0.6          # every entry exists, in
+    fill = np.where(valid_rows[:, None], rng.choice(good, (n, w)),
+                    ids((n, w), 0.3))
+    up_rows[cells] = fill[cells]
+
+    items = np.full((n, pairs, 2), -1, dtype=np.int32)
+    n_pairs = np.where(rng.random(n) < 0.25,
+                       rng.integers(1, pairs + 1, n), 0)
+    col = rng.integers(0, raw_w, n)
+    frm = np.where(rng.random(n) < 0.8, raw[np.arange(n), col],
+                   rng.choice([pk.NONE, 3, _MAX_OSD - 1], n))
+    for p in range(pairs):
+        live = n_pairs > p
+        to = np.where(rng.random(n) < 0.6, rng.choice(good, n),
+                      ids(n, 0.4))
+        items[live, p, 0] = frm[live]
+        items[live, p, 1] = to[live]
+        # the next pair chains on this one's `to`, or starts anew
+        frm = np.where(rng.random(n) < 0.5, to,
+                       raw[np.arange(n), rng.integers(0, raw_w, n)])
+
+    temp_rows = np.full((n, w), pk.NOSD, dtype=np.int32)
+    temp_len = np.where(rng.random(n) < 0.2,
+                        rng.integers(1, w + 1, n), 0).astype(np.int32)
+    cells = np.arange(w)[None, :] < temp_len[:, None]
+    temp_rows[cells] = ids((n, w), 0.2)[cells]
+    ptemp = np.where(rng.random(n) < 0.2, ids(n), pk.NOSD).astype(np.int32)
+
+    def operands():
+        return (raw, pps, raw_len, up_rows, up_len, items, temp_rows,
+                temp_len, ptemp, state, weight, affinity,
+                np.int32(_MAX_OSD))
+
+    # acting == up: a third of the pg_temp rows repeat the row's `up`
+    first = pk.ladder_ref(*operands(), erasure=erasure)
+    same = (temp_len > 0) & (first[:, 2 * w] > 0) & (rng.random(n) < 0.34)
+    temp_rows[same] = first[same, :w]
+    temp_len[same] = first[same, 2 * w]
+    return operands()
+
+
+def _scalar_rows(m, pool_id, operands, rows):
+    """`_finish_from` on `rows`, the dense overrides turned back into
+    the map's sparse ones."""
+    (raw, pps, raw_len, up_rows, up_len, items, temp_rows, temp_len,
+     ptemp) = operands[:9]
+    m = m.copy()
+    m.pg_upmap, m.pg_upmap_items, m.pg_temp, m.primary_temp = {}, {}, {}, {}
+    raw_tab = {pool_id: {}}
+    for pg in rows:
+        key = (pool_id, pg)
+        raw_tab[pool_id][pg] = raw[pg, :raw_len[pg]]
+        if up_len[pg]:
+            m.pg_upmap[key] = up_rows[pg, :up_len[pg]].tolist()
+        prs = [(int(f), int(t)) for f, t in items[pg] if (f, t) != (-1, -1)]
+        if prs:
+            m.pg_upmap_items[key] = prs
+        if temp_len[pg]:
+            m.pg_temp[key] = temp_rows[pg, :temp_len[pg]].tolist()
+        if ptemp[pg] != pk.NOSD:
+            m.primary_temp[key] = int(ptemp[pg])
+    return {pg: _finish_from(m, m.pools[pool_id], pool_id, pg, raw_tab,
+                             {pool_id: pps}) for pg in rows}
+
+
+@pytest.mark.parametrize("n", [1, 127, 4096 + 5])
+@pytest.mark.parametrize("pairs", [1, 2, 4])
+@pytest.mark.parametrize("w", [1, 3, 4, 12, 16])
+@pytest.mark.parametrize("erasure", [False, True])
+def test_plane_ladder_equals_its_twin_on_every_branch(erasure, w, pairs, n):
+    """The jitted program (PGs on the lane axis, one attribute word an
+    OSD, compaction by a select network) is bit-equal to `ladder_ref`
+    on every row and to the scalar `_finish_from` on sampled rows."""
+    rng = np.random.default_rng((n, w, pairs, erasure))
+    m = _branchy_map(rng)
+    operands = _branchy_operands(m, rng, n, w, pairs, erasure)
+    got = np.asarray(pk._ladder_jit(erasure)(*operands))
+    assert got.dtype == np.int32 and got.shape == (n, 2 * w + 4)
+    np.testing.assert_array_equal(
+        got, pk.ladder_ref(*operands, erasure=erasure))
+    rows = sorted(set(rng.integers(0, n, 48).tolist()))
+    want = _scalar_rows(m, 2 if erasure else 1, operands, rows)
+    for pg in rows:
+        assert pk.unpack_row(got[pg], w) == want[pg], pg
+    if n > 1000:
+        # the draw reaches the branches it is meant to reach
+        up_len, temp_len, ptemp = operands[4], operands[7], operands[8]
+        wcol = got[:, 2 * w:]
+        assert (up_len > 0).any() and (operands[5][:, 0, 0] != -1).any()
+        assert ((temp_len > 0) & (ptemp != pk.NOSD)).any()
+        assert ((temp_len > 0) & (ptemp == pk.NOSD)).any()
+        assert ((temp_len > 0) & (got[:, :w] == got[:, w:2 * w]).all(1)
+                & (wcol[:, 0] == wcol[:, 2])).any()         # acting == up
+        assert (wcol[:, 1] != got[:, 0]).any() or w == 1     # a loser first
+
+
+def _cell_shapes(n, w, pairs, m_pad):
+    """The ladder's operands as shapes only (what `lower` needs)."""
+    import jax
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, np.int32)
+
+    return (i32(n, w), jax.ShapeDtypeStruct((n,), np.uint32), i32(n),
+            i32(n, w), i32(n), i32(n, pairs, 2), i32(n, w), i32(n), i32(n),
+            i32(m_pad), jax.ShapeDtypeStruct((m_pad,), np.int64),
+            i32(m_pad), i32())
+
+
+@pytest.mark.parametrize("erasure,w,pairs", [
+    (False, 3, 1), (True, 3, 1), (False, 4, 2), (True, 12, 4)])
+def test_lowered_ladder_has_no_sort_no_wide_int64_and_few_gathers(
+        erasure, w, pairs):
+    """What made the old body slow on the chip, held where a CPU can
+    hold it: at the 1 Mi cell's shapes (and two wider pools') the
+    program as lowered sorts nothing, keeps every tensor of the PG
+    axis's length out of 64 bits (only the per-OSD `weight` operand is
+    int64, until it is folded into the word), and fetches OSD
+    attributes at most once an id table (2 + P; the one-hot product
+    needs no gather at all)."""
+    import re
+
+    from ceph_tpu.crush.types import padded_osds
+    n = 1 << 20
+    text = pk._ladder_jit(erasure).lower(
+        *_cell_shapes(n, w, pairs, padded_osds(10000))).as_text()
+    assert "module @jit__unknown " in text      # the traced name stays
+    ops = re.findall(r"=\s*\"?stablehlo\.(\w+)", text)
+    assert ops and "sort" not in ops
+    wide = [t for t in re.findall(r"tensor<([0-9x]*)x[su]?i64>", text)
+            if str(n) in t.split("x")]
+    assert not wide, wide[:3]
+    assert sum("gather" in op for op in ops) <= 2 + pairs
+    assert f"tensor<{n}x{2 * w + 4}xi32>" in text
+
+
+@pytest.mark.parametrize("erasure", [False, True])
+def test_plane_ladder_on_a_mesh_equals_one_device(erasure):
+    """GSPMD splits the planes on the PG axis as it split the rows:
+    the program over four of the host's eight faked devices answers as
+    on one, and its result stays sharded over the four."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from ceph_tpu.parallel.mesh import make_mesh
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four devices")
+    n, w, pairs = 4096, 4, 2
+    rng = np.random.default_rng((7, erasure))
+    operands = _branchy_operands(_branchy_map(rng), rng, n, w, pairs,
+                                 erasure)
+    mesh = make_mesh(4)
+    placed = [
+        jax.device_put(a, NamedSharding(mesh, PartitionSpec(
+            *((tuple(mesh.axis_names),) + (None,) * (a.ndim - 1))
+            if a.ndim and a.shape[0] == n else ())))
+        for a in map(np.asarray, operands)]
+    fn = pk._ladder_jit(erasure)
+    out = fn(*placed)
+    assert len(out.sharding.device_set) == 4
+    assert not out.sharding.is_fully_replicated
+    one = np.asarray(fn(*operands))
+    np.testing.assert_array_equal(np.asarray(out), one)
+    np.testing.assert_array_equal(
+        one, pk.ladder_ref(*operands, erasure=erasure))
+
+
 # -- service property test ----------------------------------------------------
 
 def test_fused_service_matches_oracle_and_exact_delta():
