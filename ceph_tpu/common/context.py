@@ -63,7 +63,15 @@ class CephTpuContext:
             "dump_tracing", trace_dump,
             "span-structured cross-daemon trace timelines "
             "[trace_id]: time-ordered rows with span_id, "
-            "parent_span_id, duration and attributes",
+            "parent_span_id, duration and attributes; cpu_ns / thread "
+            "(the CPU time and ident of the one thread that opened and "
+            "closed the span; in one trace of four, absent on "
+            "cross-thread spans; 0 or a multiple of the step where the "
+            "host's thread CPU clock is coarse), attribute "
+            "wait on a wait by design, and on a message hop sent_us / "
+            "first_byte_us / framed_us / dequeued_us (us from its "
+            "start: sender's last byte written; receiver's first byte "
+            "seen, frame whole, taken off the dispatch queue)",
             aliases=("dump_traces",))
         self.admin.register_command(
             "dump_slow_traces", lambda **kw: tracing.slow_traces(),

@@ -22,14 +22,41 @@ wall-clock step can skew a duration.  Wall-clock time is kept once per
 trace (at its first row) and rows carry ``t`` = that anchor plus the
 span's offset, for display and for correlating with logs.
 
+RUNNING AND WAITING.  In a CPU-CLOCKED trace a span that one thread
+opens and closes (the ``span(...)`` / ``root(...)`` context managers,
+the dispatch engine's phases) also carries ``cpu_ns``, what
+``time.thread_time_ns()`` of that thread advanced by over the span, and
+``thread``, its ident: interval less CPU is the time the thread was off
+the processor inside the span.  That clock is a system call which, on
+the benchmark's host, holds the interpreter lock for 6 us (15 among
+twelve busy threads; ``PERF.md``, PR 39) where ``perf_counter_ns`` takes
+0.09, and steps by 10 ms there: so one root in ``CPU_CLOCKED_ONE_IN`` is
+clocked (the first of a profiler session, then every fourth; one in
+sixteen where only the sample rate arms them; a forced ``trace_ctx``
+always), the choice rides the trace id's low bits so that every daemon
+of the trace agrees without a field on the wire, and a reading at most
+``_CPU_REUSE_NS`` old is carried forward by the time since (the thread
+was running to get from there to here).  A site whose span is a wait by
+design says so (attribute ``wait``; ``device_wait`` for a wait on a
+device result), so that what is off the CPU in every other span is
+waiting nobody named: the interpreter lock, an unnamed lock, a blocking
+call.  A cross-thread span (an op's root, a message hop, a queue wait,
+an engine request) has no thread to ask and carries neither.  A message
+hop is split by its receiver instead: ``sent_us`` (the sender's last
+byte written), ``first_byte_us`` / ``framed_us`` (the receiver's reader
+thread saw the frame's first byte / had it whole) and ``dequeued_us``
+(its dispatch thread took it off the queue), microseconds from the
+hop's start.
+
 TWO SINKS.  Every span goes to the in-memory table below.  A span
-opened and closed on one thread (the ``span(...)`` context manager) is
-also entered as a ``jax.profiler.TraceAnnotation`` of the same name
-while a profiler session is live, so that in any profile the program's
-spans lie in the host plane of the same xplane as the device's
-operations, on the profiler's clock.  Cross-thread spans (an op's
-root, a message hop, a queue wait, an engine request) live in the
-table only.
+opened and closed on one thread (the ``span(...)`` context manager, a
+phase of the dispatch engine between two of its marks) is also entered
+as a ``jax.profiler.TraceAnnotation`` of the same name while a
+profiler session is live, so that in any profile the program's spans
+lie in the host plane of the same xplane as the device's operations,
+on the profiler's clock.  Cross-thread spans (an op's root, a message
+hop, a queue wait, an engine request and its ``queue_wait``) live in
+the table only.
 
 Sampling policy — head sampling plus tail retention:
 
@@ -80,6 +107,18 @@ _tls = threading.local()
 _lock = lockdep.make_lock("tracing::registry")
 
 now_ns = time.perf_counter_ns
+#: the calling thread's CPU time: read beside ``now_ns`` by whatever
+#: opens and closes a span on one thread
+thread_cpu_ns = time.thread_time_ns
+#: roots per CPU-clocked root under a profiler session (a power of two:
+#: the choice is the trace id's low bits); how many times fewer where
+#: only the sample rate arms the roots (a clocked 4 KiB write is a sixth
+#: slower on the benchmark's host, and arming may cost its median a
+#: tenth all told: ROADMAP S6); and how old a reading of the CPU clock
+#: may be to be carried forward instead of read anew
+CPU_CLOCKED_ONE_IN = 4
+_SAMPLED_THIN = 4
+_CPU_REUSE_NS = 20_000
 
 #: active/recent traces kept for stitching (FIFO eviction; slow traces
 #: survive in the dedicated ring below, a profiler session's are pinned)
@@ -108,6 +147,9 @@ _slow: "dict[int, dict]" = {}
 #: traces it has pinned
 _session_live = False
 _session_pinned = 0
+#: sampled roots so far (of this session, under one): counted from the
+#: first, every CPU_CLOCKED_ONE_IN-th (x _SAMPLED_THIN) is CPU-clocked
+_root_counter = itertools.count()
 
 
 # -- layers -------------------------------------------------------------------
@@ -143,7 +185,6 @@ LAYERS = {
     "ec daemon lock wait": LAYER_ECB, "ec local commit": LAYER_ECB,
     "ec fan-out": LAYER_ECB, "ec sub-write": LAYER_ECB,
     "ec sub-write ack": LAYER_ECB, "osd reply": LAYER_ECB,
-    "rep op": LAYER_ECB,
     # the EC read path: the primary's gather (the wait for k shards),
     # a shard's side of it, the decode engine's submit and continuation
     "ec read prepare": LAYER_ECB, "ec read gather": LAYER_ECB,
@@ -200,10 +241,13 @@ def armed() -> bool:
 
 class Span:
     """One node of a trace tree.  ``start``/``end`` are
-    ``time.perf_counter_ns()`` readings (``end`` None while open)."""
+    ``time.perf_counter_ns()`` readings (``end`` None while open);
+    ``cpu_ns``/``thread`` are what the one thread that opened and
+    closed it used of its CPU meanwhile and its ident (None on a
+    cross-thread span)."""
 
     __slots__ = ("trace_id", "span_id", "parent_span_id", "name",
-                 "daemon", "start", "end", "attrs")
+                 "daemon", "start", "end", "attrs", "cpu_ns", "thread")
 
     def __init__(self, trace_id: int, span_id: int, parent_span_id: int,
                  name: str, daemon: str, start: int,
@@ -216,6 +260,8 @@ class Span:
         self.start = start
         self.end: int | None = None
         self.attrs = attrs if attrs is not None else {}
+        self.cpu_ns: int | None = None
+        self.thread: int | None = None
 
     @property
     def duration(self) -> float | None:
@@ -260,6 +306,9 @@ class _Trace:
                  "parent_span_id": sp.parent_span_id,
                  "dur": sp.duration, "start_ns": sp.start,
                  "end_ns": sp.end, "layer": layer_of(sp.name)}
+            if sp.cpu_ns is not None:
+                r["cpu_ns"] = sp.cpu_ns
+                r["thread"] = sp.thread
             if sp.attrs:
                 r["attrs"] = dict(sp.attrs)
             out.append(r)
@@ -283,6 +332,29 @@ _id_counter = itertools.count(1)
 def new_span_id() -> int:
     """A span id — or a trace id: one sequence serves both."""
     return _ID_PREFIX | (next(_id_counter) & 0xFFFFFFFF) or 1
+
+
+def cpu_clocked(trace_id: int) -> bool:
+    """Whether the spans of this trace read their threads' CPU clock."""
+    return not trace_id & (CPU_CLOCKED_ONE_IN - 1)
+
+
+def _new_trace_id(clocked: bool) -> int:
+    tid = new_span_id()
+    while cpu_clocked(tid) != clocked and CPU_CLOCKED_ONE_IN > 1:
+        tid = new_span_id()
+    return tid
+
+
+def _thread_cpu(now: int) -> int:
+    """The calling thread's CPU clock at `now`, a ``perf_counter_ns``
+    reading just taken on it."""
+    last = getattr(_tls, "cpu", None)
+    if last is not None and now - last[0] <= _CPU_REUSE_NS:
+        return last[1] + now - last[0]
+    cpu = thread_cpu_ns()
+    _tls.cpu = (now, cpu)
+    return cpu
 
 
 def current() -> int:
@@ -430,15 +502,18 @@ def finish_span(span: Span | None, end: int | None = None) -> None:
 
 
 def add_span(name: str, daemon: str, trace_id: int, parent_span_id: int,
-             start: int, end: int, attrs: dict | None = None
+             start: int, end: int, attrs: dict | None = None,
+             cpu_ns: int | None = None, thread: int | None = None
              ) -> Span | None:
     """Record a span whose interval is already known (an engine phase
-    read off the batch's marks)."""
+    read off the batch's marks), with the CPU time of the one thread
+    that ran it, where one did."""
     sp = begin_span(name, daemon, trace_id=trace_id,
                     parent_span_id=parent_span_id, attrs=attrs,
                     start=start)
     if sp is not None:
         sp.end = max(end, start)
+        sp.cpu_ns, sp.thread = cpu_ns, thread
     return sp
 
 
@@ -456,9 +531,11 @@ def set_attrs(span: Span | None, **attrs) -> None:
 class _SpanCtx:
     """``with span(...)``: a child span of the thread's current span
     for the duration of the block, entered as a profiler annotation of
-    the same name while a session is live."""
+    the same name while a session is live.  In a CPU-clocked trace the
+    thread's CPU clock is read at the span's two clock readings."""
 
-    __slots__ = ("_name", "_daemon", "_attrs", "_span", "_prev", "_ann")
+    __slots__ = ("_name", "_daemon", "_attrs", "_span", "_prev", "_ann",
+                 "_cpu0")
 
     def __init__(self, name: str, daemon: str, attrs: dict | None):
         self._name = name
@@ -475,6 +552,8 @@ class _SpanCtx:
         self._ann = None
         if sp is None:        # row-cap hit, or a root left unsampled
             return None
+        self._cpu0 = (None if sp.trace_id & (CPU_CLOCKED_ONE_IN - 1)
+                      else _thread_cpu(sp.start))     # cpu_clocked()
         self._prev = set_current(sp.trace_id, sp.span_id)
         if _profiler_on():
             self._ann = _Annotation(self._name)
@@ -487,7 +566,11 @@ class _SpanCtx:
             if self._ann is not None:
                 self._ann.__exit__(*exc)
             set_current(self._prev)
-            self._finish(sp)
+            end = now_ns()
+            if self._cpu0 is not None:
+                sp.cpu_ns = max(0, _thread_cpu(end) - self._cpu0)
+                sp.thread = threading.get_ident()
+            self._finish(sp, end)
         return False
 
 
@@ -505,7 +588,7 @@ def span(name: str, daemon: str = "", **attrs):
 def _sampled() -> bool:
     """Whether this untraced root opens a trace; True with the second
     value says a profiler session pins it."""
-    global _session_live, _session_pinned
+    global _session_live, _session_pinned, _root_counter
     if _profiler_on():
         with _lock:
             if not _session_live:
@@ -513,6 +596,7 @@ def _sampled() -> bool:
                 # (or never will be) and age out with the rest
                 _session_live = True
                 _session_pinned = 0
+                _root_counter = itertools.count()
                 for tr in _traces.values():
                     tr.pinned = False
                 while _n_unpinned_locked() > _active_cap:
@@ -533,8 +617,9 @@ def begin_root(name: str, daemon: str, attrs: dict | None = None
     global _session_pinned
     if not _sampled():
         return None
-    sp = begin_span(name, daemon, trace_id=new_span_id(), parent_span_id=0,
-                    attrs=attrs)
+    every = CPU_CLOCKED_ONE_IN * (1 if _session_live else _SAMPLED_THIN)
+    sp = begin_span(name, daemon, parent_span_id=0, attrs=attrs,
+                    trace_id=_new_trace_id(next(_root_counter) % every == 0))
     if sp is not None and _session_live:
         with _lock:
             tr = _traces.get(sp.trace_id)
@@ -544,12 +629,12 @@ def begin_root(name: str, daemon: str, attrs: dict | None = None
     return sp
 
 
-def finish_root(root: Span | None) -> None:
+def finish_root(root: Span | None, end: int | None = None) -> None:
     """Close a root and complete its trace (the tail-retention check
     against tracing_slow_threshold)."""
     if root is None:
         return
-    finish_span(root)
+    finish_span(root, end)
     _maybe_complete(root.trace_id, root)
 
 
@@ -574,6 +659,78 @@ def root(name: str, daemon: str, **attrs):
     return _RootCtx(name, daemon, attrs or None) if armed() else _NULL
 
 
+# -- consecutive phases on one thread -------------------------------------------
+
+class PhaseMarks:
+    """Consecutive phases of one thread's work that no ``with`` block
+    brackets (the dispatch engine's, between the marks of its phase
+    ledger): ``begin(name)`` ends the phase before and starts the next,
+    ``end()`` ends the last.  For a batch that holds a CPU-clocked
+    trace each phase's share of the thread's CPU clock is kept in
+    ``cpu`` by name, for the spans recorded afterwards (``add_span``);
+    while a profiler session is live each phase is an annotation of its
+    name."""
+
+    __slots__ = ("cpu", "thread", "t_cpu", "_clocked", "_profiled",
+                 "_name", "_ann")
+
+    def __init__(self, clocked: bool, profiled: bool):
+        #: phase name -> ns of this thread's CPU
+        self.cpu: dict[str, int] = {}
+        self.thread = threading.get_ident()
+        #: the thread's CPU clock at the last mark
+        self.t_cpu = 0
+        self._clocked = clocked
+        self._profiled = profiled
+        self._name = None
+        self._ann = None
+
+    def begin(self, name: str | None) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if self._clocked:
+            t = _thread_cpu(now_ns())
+            if self._name is not None:
+                self.cpu[self._name] = max(0, t - self.t_cpu)
+            self.t_cpu = t
+        self._name = name
+        if name is not None and self._profiled:
+            self._ann = _Annotation(name)
+            self._ann.__enter__()
+
+    def end(self) -> None:
+        if self._name is not None:
+            self.begin(None)
+
+    def finish_span(self, span: Span | None) -> None:
+        """Close a span this thread opened at the last mark."""
+        if (span is not None and self._clocked
+                and cpu_clocked(span.trace_id)):
+            end = now_ns()
+            span.cpu_ns = max(0, _thread_cpu(end) - self.t_cpu)
+            span.thread = self.thread
+            finish_span(span, end)
+        else:
+            finish_span(span)
+
+
+#: what an untraced batch outside any profiler session marks its
+#: phases on: one shared object that reads and opens nothing
+_NO_MARKS = PhaseMarks(False, False)
+
+
+def phase_marks(trace_ids):
+    """Marks for one thread's run of phases on behalf of the traces
+    `trace_ids`: real ones where a CPU-clocked trace's spans will read
+    them or a profiler session is live — the one check an untraced
+    batch pays."""
+    clocked = any(map(cpu_clocked, trace_ids))
+    profiled = _profiler_on()
+    return (PhaseMarks(clocked, profiled) if clocked or profiled
+            else _NO_MARKS)
+
+
 class trace_ctx:
     """Open (or join) a trace for the calling thread — a FORCED trace,
     whatever the sampling policy.  Opens a span; when that span is the
@@ -589,7 +746,7 @@ class trace_ctx:
         self._daemon = daemon
 
     def __enter__(self) -> int:
-        tid = self._tid or new_span_id()
+        tid = self._tid or _new_trace_id(True)
         self._tid = tid
         join = current() == tid
         sp = self._span = begin_span(
@@ -690,11 +847,22 @@ def sent(msg) -> None:
         finish_span(sp, t)
 
 
-def received(trace_id: int, hop_span_id: int) -> None:
+def received(trace_id: int, hop_span_id: int, rx_stamps=None) -> None:
     """Receiver hook: dispatch of a traced message begins, so its hop
-    — if this process holds it — ends here."""
+    — if this process holds it — ends here.  ``rx_stamps``: the
+    ``perf_counter_ns`` readings the transport took on the frame's way
+    in (first byte seen, frame whole, taken off the dispatch queue; a
+    first byte of 0 was not seen armed), kept on the hop like
+    ``sent_us``."""
     sp = find_span(trace_id, hop_span_id)
     if sp is not None and sp.name.startswith("msg "):
+        if rx_stamps is not None:
+            first, framed, dequeued = rx_stamps
+            start, attrs = sp.start, sp.attrs
+            if first:
+                attrs["first_byte_us"] = max(0, first - start) // 1000
+            attrs["framed_us"] = max(0, framed - start) // 1000
+            attrs["dequeued_us"] = max(0, dequeued - start) // 1000
         finish_span(sp)
 
 
@@ -754,6 +922,9 @@ def tree_from_rows(rows: list[dict]) -> list[dict]:
                 "layer": r.get("layer", ""),
                 "attrs": r.get("attrs", {}),
                 "events": [], "children": []}
+            if "cpu_ns" in r:
+                nodes[r["span_id"]].update(cpu_ns=r["cpu_ns"],
+                                           thread=r["thread"])
     roots: list[dict] = []
     for r in rows:
         if r.get("kind") == "span":
@@ -859,12 +1030,13 @@ def configure_from_conf(conf) -> None:
 def reset() -> None:
     """Drop every trace and restore default policy (test isolation)."""
     global _sample_rate, _slow_threshold, _slow_ring_size, _active_cap
-    global _session_live, _session_pinned
+    global _session_live, _session_pinned, _root_counter
     with _lock:
         _traces.clear()
         _slow.clear()
         _session_live = False
         _session_pinned = 0
+        _root_counter = itertools.count()
     _sample_rate = _DEFAULT_SAMPLE_RATE
     _slow_threshold = _DEFAULT_SLOW_THRESHOLD
     _slow_ring_size = _DEFAULT_SLOW_RING

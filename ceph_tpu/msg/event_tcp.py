@@ -126,6 +126,10 @@ class EventConnection(Connection):
         self._wlock = lockdep.make_lock(
             f"EventConnection::wlock({messenger.my_name})")
         self.inbuf = bytearray()
+        #: perf_counter_ns of the open frame's first byte and of the
+        #: last recv(), for a traced message's hop (0: tracing unarmed
+        #: when the frame began; _on_readable)
+        self._rx_first = self._rx_last = 0
         self._down = False
         # handshake scratch
         self.hs_stage = "banner"
@@ -657,6 +661,14 @@ class EventConnection(Connection):
         if not data:
             self._close_now(reset=True)
             return
+        if not self.inbuf:
+            # the buffer leaves a frame boundary: a frame's first byte.
+            # Whether this frame's way in is stamped (for its hop span,
+            # tracing.received) is asked here, once a frame
+            self._rx_first = self._rx_last = (
+                tracing.now_ns() if tracing.armed() else 0)
+        elif self._rx_first:
+            self._rx_last = tracing.now_ns()
         self.inbuf += data
         try:
             if self.state == _HANDSHAKE:
@@ -694,7 +706,12 @@ class EventConnection(Connection):
                     raise ConnectionError(
                         f"decompressed frame exceeds cap from "
                         f"{self.peer_name}")
-            m.enqueue_dispatch(self, data, wire_len=total)
+            first = self._rx_first
+            m.enqueue_dispatch(self, data, wire_len=total, rx_first=first,
+                               rx_framed=tracing.now_ns() if first else 0)
+            # what the buffer still holds came with this frame's last
+            # bytes: the next frame's first byte
+            self._rx_first = self._rx_last
 
     def _update_interest(self) -> None:
         if self.sock is None:
@@ -820,12 +837,15 @@ class EventMessenger(Messenger):
                 .add_u64("msg_send_inline").add_u64("msg_send_queued"))
 
     def enqueue_dispatch(self, con: EventConnection, data: bytes,
-                         wire_len: int = 0) -> None:
+                         wire_len: int = 0, rx_first: int = 0,
+                         rx_framed: int = 0) -> None:
+        """Reader thread: hand a whole frame to the dispatch thread,
+        with the reader's two stamps of its way in (0 = not taken)."""
         with self._lock:
             self._dispatch_bytes += len(data)
             if self._dispatch_bytes >= self.DISPATCH_HIGH:
                 self.paused = True
-        self._dispatch_q.put((con, data, wire_len))
+        self._dispatch_q.put((con, data, wire_len, rx_first, rx_framed))
 
     def register_accepted(self, con: EventConnection) -> None:
         """Handshake done on an accepted session: index it so redials
@@ -1038,9 +1058,12 @@ class EventMessenger(Messenger):
             item = self._dispatch_q.get()
             if item is None or self._stop:
                 return
-            con, data, wire_len = item
+            con, data, wire_len, rx_first, rx_framed = item
+            t_dequeued = tracing.now_ns() if rx_framed else 0
             try:
                 msg = Message.decode(data)
+                if rx_framed:
+                    msg.rx_stamps = (rx_first, rx_framed, t_dequeued)
                 # on-wire size (header + possibly-compressed payload):
                 # matches the sender's flush-time count_sent
                 msg.wire_bytes = wire_len or len(data)

@@ -197,10 +197,10 @@ class Messenger:
             size = msg.frame_size()
             policy.throttler_bytes.get(size)
             tb = (policy.throttler_bytes, size)
-        tid = getattr(msg, "trace_id", 0)
-        rx_span = None
-        prev_trace = (0, 0)
-        if tid:
+        try:
+            tid = getattr(msg, "trace_id", 0)
+            if not tid:
+                return self._dispatch(msg)
             # the handling thread JOINS the trace under an rx dispatch
             # span parented to the sender's span (the frame's
             # parent_span_id): everything it sends while dispatching
@@ -209,29 +209,26 @@ class Messenger:
             from ceph_tpu.common import tracing
             hop = getattr(msg, "parent_span_id", 0)
             # the message's hop span (send queue + encode + wire +
-            # decode) ends where its dispatch begins
-            tracing.received(tid, hop)
-            rx_span = tracing.begin_span(
-                f"rx {type(msg).__name__}", str(self.my_name),
-                trace_id=tid, parent_span_id=hop)
-            if rx_span is not None:
-                msg.parent_span_id = rx_span.span_id
-            prev_trace = tracing.set_current(
-                tid, rx_span.span_id if rx_span else 0)
-        try:
-            with self._lock:
-                chain = list(self._dispatchers)
-            for d in chain:
-                if d.ms_dispatch(msg):
-                    return True
-            return False
+            # decode) ends where its dispatch begins, split by the
+            # stamps its transport took on the way in
+            tracing.received(tid, hop, getattr(msg, "rx_stamps", None))
+            with tracing.joined(tid, hop), tracing.span(
+                    f"rx {type(msg).__name__}",
+                    str(self.my_name)) as rx_span:
+                if rx_span is not None:
+                    msg.parent_span_id = rx_span.span_id
+                return self._dispatch(msg)
         finally:
-            if tid:
-                from ceph_tpu.common import tracing
-                tracing.finish_span(rx_span)
-                tracing.set_current(prev_trace)
             if tb:
                 tb[0].put(tb[1])
+
+    def _dispatch(self, msg: Message) -> bool:
+        with self._lock:
+            chain = list(self._dispatchers)
+        for d in chain:
+            if d.ms_dispatch(msg):
+                return True
+        return False
 
     def notify_reset(self, con: Connection) -> None:
         with self._lock:

@@ -1162,7 +1162,7 @@ class BlueStoreLite(ObjectStore):
             if self._block_dirty:
                 t_sync = _time.perf_counter()
                 with tracing.span("bluestore fsync", daemon="bluestore",
-                                  what="block"):
+                                  what="block", wait=True):
                     self._f.flush()
                     os.fsync(self._f.fileno())
                 self.perf.tinc("fsync_lat",

@@ -167,7 +167,7 @@ class LogDB(MemDB):
             assert self._f is not None, "LogDB not open"
             self._f.write(_FRAME.pack(len(blob), zlib.crc32(blob)) + blob)
             with tracing.span("bluestore fsync", daemon="bluestore",
-                              what="kv"):
+                              what="kv", wait=True):
                 self._f.flush()
                 os.fsync(self._f.fileno())
         MemDB.submit_transaction(self, t)

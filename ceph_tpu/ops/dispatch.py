@@ -860,8 +860,15 @@ class DeviceDispatchEngine:
         Returns the batch it put in flight (None: the engine wedged
         meanwhile and the futures were failed); ``on_caller``: the
         submitter's thread runs this and will complete the batch
-        itself (submit_waiting)."""
-        now = time.monotonic()
+        itself (submit_waiting): its request waited in no queue, so
+        its ledger starts at the submit."""
+        now = reqs[0].t_submit if on_caller else time.monotonic()
+        # a CPU-clocked trace's phase spans want each phase's CPU time,
+        # a live profiler session each phase as an annotation: both are
+        # read at the ledger's marks below (one check a batch otherwise)
+        marks = tracing.phase_marks(
+            r.trace[0] for r in reqs if r.trace is not None)
+        marks.begin("engine build")
         # slices first (pure arithmetic, cannot fail): the completion
         # thread zips reqs against slices, so every request must have
         # one even when the batch build below dies
@@ -923,10 +930,14 @@ class DeviceDispatchEngine:
                        "build": 0.0, "place": 0.0, "launch": 0.0,
                        "t_launch_end": now, "bucket": bucket,
                        "devices": devices, "stripes": total,
-                       "family": reqs[0].label}
+                       "family": reqs[0].label, "marks": marks}
             batch_arr, aux_batch = self._assemble(reqs, pad)
-            t_build_end = time.monotonic()
+            t_build_end = t_place_end = time.monotonic()
             profile["build"] = t_build_end - now
+            # without a mesh nothing is placed: the phase has no length
+            # (no span, no annotation) and the ledger goes on to launch
+            marks.begin("engine place" if placement is not None
+                        else "engine launch")
             if not via_fallback:
                 # h2d boundary failpoint: fires for EVERY device-path
                 # batch — on an unmeshed engine the transfer is
@@ -944,8 +955,9 @@ class DeviceDispatchEngine:
                 # and fans to the batch's futures like any build error.
                 batch_arr = placement.put(batch_arr)
                 aux_batch = tuple(placement.put(a) for a in aux_batch)
-            t_place_end = time.monotonic()
-            profile["place"] = t_place_end - t_build_end
+                t_place_end = time.monotonic()
+                profile["place"] = t_place_end - t_build_end
+                marks.begin("engine launch")
             before = None
             if reqs[0].cache_entries is not None and not via_fallback:
                 try:
@@ -961,6 +973,7 @@ class DeviceDispatchEngine:
                 failpoint.hit("dispatch.launch", tag=channel)
                 out = reqs[0].fn(batch_arr, *aux_batch)  # async dispatch
             profile["t_launch_end"] = time.monotonic()
+            marks.end()
             # span bookkeeping + the cache probe sit between place and
             # launch: charge them to launch so the ledger stays gapless
             profile["launch"] = profile["t_launch_end"] - t_place_end
@@ -972,6 +985,7 @@ class DeviceDispatchEngine:
         except BaseException as e:          # noqa: BLE001 — fan to futures
             exc = e
         finally:
+            marks.end()
             try:
                 self.stats.record_batch(
                     requests=len(reqs), stripes=total, padded=pad,
@@ -1042,8 +1056,13 @@ class DeviceDispatchEngine:
         channel = batch.reqs[0].label
         host, exc = None, batch.exc
         t_ready = t_mat = 0.0
+        # this thread's half of the phases' CPU times and annotations
+        # (see _dispatch_batch); `compute` is read from the pick-up
+        marks = tracing.phase_marks(
+            r.trace[0] for r in batch.reqs if r.trace is not None)
         if exc is None:
             try:
+                marks.begin("engine compute")
                 # split device compute from d2h: waiting out the
                 # async execution first (free — the work is already
                 # in flight) leaves np.asarray measuring only the
@@ -1061,10 +1080,13 @@ class DeviceDispatchEngine:
                     except Exception:
                         pass   # np.asarray below surfaces the error
                 t_ready = time.monotonic()
+                marks.begin("engine materialize")
                 host = np.asarray(batch.out)   # d2h materialize
                 t_mat = time.monotonic()
             except BaseException as e:         # noqa: BLE001
                 exc = e
+            finally:
+                marks.end()
         # supervised recovery: a failed device-path batch walks the
         # bounded retry ladder, then the channel's host oracle; a
         # batch the dispatch thread already served via the oracle
@@ -1101,7 +1123,7 @@ class DeviceDispatchEngine:
                 value = None if exc is not None else host[a:b]
                 if req.trace is not None:
                     self._deliver_traced(req, value, exc, batch,
-                                         t_ready, t_mat)
+                                         t_ready, t_mat, marks)
                 else:
                     req.future._deliver(value, exc)
             except BaseException as e:  # noqa: BLE001 — see below
@@ -1176,17 +1198,24 @@ class DeviceDispatchEngine:
 
     @staticmethod
     def _deliver_traced(req: _Request, value, exc, batch: _Batch,
-                        t_ready: float, t_mat: float) -> None:
+                        t_ready: float, t_mat: float, marks) -> None:
         """Deliver a traced submitter's result under its spans: the
-        request (submit -> delivered) with the batch's seven phases
+        request (submit -> delivered) with the batch's phases
         (telemetry.PHASES) as children over their real intervals, read
-        off the same marks the phase ledger records.  Continuations
-        run under the `engine deliver` child, so whatever they fan out
-        stays in the op's tree.  The marks are time.monotonic()
-        readings: on Linux the clock of the spans' perf_counter_ns.
-        The batch is already popped from _inflight, so nothing here
-        may raise past the delivery: tracing must never wedge
-        completions."""
+        off the same marks the phase ledger records — `time.monotonic()`
+        readings of `_dispatch_batch` (submit, `t0`, build end, place
+        end, launch end) and of `_complete_batch` (`t_ready`, `t_mat`),
+        on Linux the clock of the spans' perf_counter_ns.  A phase
+        without length (`place` without a mesh, `queue_wait` on the
+        caller's own thread) gets no span.  In a CPU-clocked trace each
+        phase that one thread ran carries that thread's CPU time over
+        it, read at the same marks (`tracing.PhaseMarks`: the launching
+        thread's in the batch's profile, the completing thread's in
+        `marks`); `queue_wait` crosses threads and carries none.
+        Continuations run under the `engine deliver` child, so whatever
+        they fan out stays in the op's tree.  The batch is already
+        popped from _inflight, so nothing here may raise past the
+        delivery: tracing must never wedge completions."""
         tid, parent = req.trace
         rs = deliver = None
         try:
@@ -1206,17 +1235,24 @@ class DeviceDispatchEngine:
             pr = batch.profile
             if rs is not None and pr is not None and exc is None:
                 t_build = pr["t0"] + pr["build"]
-                marks = (req.t_submit, pr["t0"], t_build,
-                         t_build + pr["place"], pr["t_launch_end"],
-                         t_ready, t_mat)
-                for name, t_a, t_b in zip(tracing.ENGINE_PHASES, marks,
-                                          marks[1:]):
+                bounds = (req.t_submit, pr["t0"], t_build,
+                          t_build + pr["place"], pr["t_launch_end"],
+                          t_ready, t_mat)
+                ran = {name: (ns, m.thread) for m in (pr["marks"], marks)
+                       for name, ns in m.cpu.items()
+                       } if tracing.cpu_clocked(tid) else {}
+                for phase, t_a, t_b in zip(tracing.ENGINE_PHASES, bounds,
+                                           bounds[1:]):
+                    if t_b <= t_a:
+                        continue
+                    name = f"engine {phase}"
+                    cpu_ns, thread = ran.get(name, (None, None))
                     tracing.add_span(
-                        f"engine {name}", "device", tid, rs.span_id,
+                        name, "device", tid, rs.span_id,
                         int(t_a * 1e9), int(t_b * 1e9),
                         # the host's wait for the device's result
-                        {"device_wait": True} if name == "compute"
-                        else None)
+                        {"device_wait": True} if phase == "compute"
+                        else None, cpu_ns=cpu_ns, thread=thread)
                 deliver = tracing.begin_span(
                     "engine deliver", "device", trace_id=tid,
                     parent_span_id=rs.span_id, start=int(t_mat * 1e9))
@@ -1228,7 +1264,7 @@ class DeviceDispatchEngine:
                                 else parent):
                 req.future._deliver(value, exc)
         finally:
-            tracing.finish_span(deliver)
+            marks.finish_span(deliver)
             tracing.finish_span(rs)
 
     # -- supervised recovery (retry ladder, breaker, probe) -------------------

@@ -3278,7 +3278,8 @@ class OSDDaemon(Dispatcher):
         """``with self._lock`` whose WAIT is a span of the op's tree:
         the continuation runs on the engine's completion thread and
         meets the shard workers here (PERF.md D1)."""
-        with tracing.span("ec daemon lock wait", daemon=self._tname):
+        with tracing.span("ec daemon lock wait", daemon=self._tname,
+                          wait=True):
             # analysis: allow[blocking] -- the daemon lock the continuation always took (`with self._lock`), spelled out so that its wait is a span
             self._lock.acquire()
         try:

@@ -181,7 +181,7 @@ def _changed_rows(old: np.ndarray, new: np.ndarray,
             dev_mask = _delta_diff_program()(o, n)  # async dispatch
         # the host's wait for the device's answer, and its copy back
         with tracing.span("mapping delta read-back", daemon="mapping",
-                          device_wait=True):
+                          device_wait=True, wait=True):
             mask = np.asarray(dev_mask)
         stats.record_delta_diff(device=True,
                                 upload_bytes=old.nbytes + new.nbytes)
@@ -799,7 +799,8 @@ class SharedPGMappingService:
 
     def _update_to(self, osdmap: OSDMap,
                    from_epoch: int | None) -> MapUpdate:
-        with tracing.span("mapping cv wait", daemon="mapping"), self._cv:
+        with tracing.span("mapping cv wait", daemon="mapping",
+                          wait=True), self._cv:
             if from_epoch is None:
                 from_epoch = self.epoch
             target = osdmap.epoch

@@ -3,12 +3,15 @@ an EC pool, ``aio_read`` of an object that lost a shard and one
 ``update_to`` epoch give one complete tree each, with a span of every
 layer on it and a critical path that is named end to end; a live
 profiler session arms the roots and receives the same-thread spans as
-annotations; unarmed, nothing is recorded."""
+annotations; unarmed, nothing is recorded and no clock is read.  Spans
+tell running from waiting: a same-thread span and an engine phase carry
+their thread's CPU time, a hop its receiver's stamps."""
 
 from __future__ import annotations
 
 import os
 import sys
+import threading
 import time
 
 import numpy as np
@@ -29,7 +32,12 @@ UNNAMED_MAX = 0.10
 
 
 @pytest.fixture(autouse=True)
-def _clean_tracing():
+def _clean_tracing(monkeypatch):
+    """Every trace CPU-clocked, where the program clocks one root in
+    four or sixteen (`test_one_root_in_a_few_reads_the_cpu_clock` runs
+    it as it is)."""
+    monkeypatch.setattr(tracing, "CPU_CLOCKED_ONE_IN", 1)
+    monkeypatch.setattr(tracing, "_SAMPLED_THIN", 1)
     tracing.reset()
     yield
     tracing.reset()
@@ -68,6 +76,37 @@ def _write(io, names, size=4096):
     time.sleep(0.2)
 
 
+def _phases(req, spans):
+    """The engine phases under request span `req`, held to the ledger's
+    order: every phase that had a length, so no ``queue_wait`` on the
+    caller's own thread and ``place`` only where a mesh placed the
+    batch (the tests' engines have one: eight virtual devices)."""
+    phases = [r for r in spans if r["parent_span_id"] == req["span_id"]]
+    names = [r["event"] for r in phases]
+    want = [f"engine {p}" for p in tracing.ENGINE_PHASES
+            if not (p == "queue_wait" and req["attrs"]["caller_thread"])]
+    assert [n for n in names if n != "engine place"] \
+        == [n for n in want if n != "engine place"], names
+    assert all(r["end_ns"] > r["start_ns"] for r in phases[:-1]), phases
+    return phases
+
+
+def _hops_are_stamped(rows) -> int:
+    """Every hop of an in-process trace carries its receiver's stamps
+    beside the sender's, in order inside the hop; returns how many."""
+    hops = [r for r in rows if r["kind"] == "span"
+            and r["event"].startswith("msg ")]
+    for r in hops:
+        a = r["attrs"]
+        assert {"sent_us", "road", "first_byte_us", "framed_us",
+                "dequeued_us"} <= set(a), (r["event"], a)
+        assert 0 <= a["first_byte_us"] <= a["framed_us"] \
+            <= a["dequeued_us"] <= (r["end_ns"] - r["start_ns"]) // 1000, \
+            (r["event"], a, r["end_ns"] - r["start_ns"])
+        assert "cpu_ns" not in r and "thread" not in r
+    return len(hops)
+
+
 def test_aio_write_gives_one_complete_tree_per_op(ec_cluster):
     _c, io = ec_cluster
     tracing.set_sample_rate(1.0)
@@ -102,6 +141,21 @@ def test_aio_write_gives_one_complete_tree_per_op(ec_cluster):
             assert want in names_here, (want, sorted(names_here))
         for phase in tracing.ENGINE_PHASES:
             assert f"engine {phase}" in names_here
+        # op, five sub-writes and their acks, reply: each hop split by
+        # the thread that read it
+        assert _hops_are_stamped(rows) == 12
+        # a span one thread opened and closed says what it ran of it;
+        # roots, hops, queue waits and engine requests cross threads
+        for r in spans:
+            same_thread = not (r is root or r["event"].startswith(
+                ("msg ", "opq ", "device ")) or r["event"]
+                == "engine queue_wait")
+            assert ("cpu_ns" in r) == ("thread" in r) == same_thread, r
+            if same_thread:
+                assert 0 <= r["cpu_ns"] and r["thread"]
+        for name in ("ec daemon lock wait", "bluestore fsync"):
+            assert all(r["attrs"].get("wait") is True for r in spans
+                       if r["event"] == name)
         # five remote shards, each with its queue wait and its commit
         assert sum(r["event"] == "ec sub-write" for r in spans) == 5
         assert sum(r["event"] == "bluestore commit" for r in spans) == 6
@@ -128,6 +182,54 @@ def test_aio_write_gives_one_complete_tree_per_op(ec_cluster):
                 "ec fan-out", "client complete"} <= on_path
 
 
+def test_one_root_in_a_few_reads_the_cpu_clock(ec_cluster, monkeypatch):
+    """The CPU clock is a system call: where the sample rate arms the
+    roots the first and every sixteenth after it are clocked, under a
+    profiler session every fourth, whole — every daemon reads the
+    choice off the trace id — and the spans of the others carry no CPU
+    time at all, their hops' stamps all the same."""
+    _c, io = ec_cluster
+    monkeypatch.undo()
+    assert (tracing.CPU_CLOCKED_ONE_IN, tracing._SAMPLED_THIN) == (4, 4)
+    reads = []
+    real = tracing.thread_cpu_ns
+    monkeypatch.setattr(tracing, "thread_cpu_ns",
+                        lambda: reads.append(1) or real())
+
+    def clocked_roots(n: int) -> list[int]:
+        tracing.reset()
+        tracing.set_sample_rate(1.0)
+        _write(io, [f"clocked-{i}" for i in range(n)])  # roots in order
+        tracing.set_sample_rate(0.0)
+        traces = sorted(_op_traces(), key=lambda rows: span_readers.root_of(
+            rows)["start_ns"])
+        assert len(traces) == n
+        out = []
+        for i, rows in enumerate(traces):
+            spans = [r for r in rows if r["kind"] == "span"]
+            clocked = [r for r in spans if "cpu_ns" in r]
+            assert bool(clocked) == tracing.cpu_clocked(rows[0]["trace_id"])
+            if clocked:
+                out.append(i)
+                assert {r["event"] for r in spans} - {
+                    r["event"] for r in clocked} == {
+                        span_readers.root_of(rows)["event"], "msg MOSDOp",
+                        "msg MOSDECSubOpWrite", "msg MOSDECSubOpWriteReply",
+                        "msg MOSDOpReply", "opq wait", "device ec_encode",
+                        "engine queue_wait"}
+            assert _hops_are_stamped(rows) == 12
+        # adjacent readings are shared: under two calls a clocked span
+        n_clocked = sum("cpu_ns" in r for rows in traces for r in rows)
+        assert 0 < len(reads) < 2 * n_clocked
+        del reads[:]
+        return out
+
+    assert clocked_roots(18) == [0, 16]
+    # as under a live session (no profile is taken here)
+    monkeypatch.setattr(tracing, "_profiler_on", lambda: True)
+    assert clocked_roots(9) == [0, 4, 8]
+
+
 def test_engine_phases_are_child_spans_with_their_intervals(ec_cluster):
     _c, io = ec_cluster
     tracing.set_sample_rate(1.0)
@@ -136,17 +238,27 @@ def test_engine_phases_are_child_spans_with_their_intervals(ec_cluster):
     (rows,) = _op_traces()
     spans = [r for r in rows if r["kind"] == "span"]
     req = next(r for r in spans if r["event"] == "device ec_encode")
-    phases = [r for r in spans if r["parent_span_id"] == req["span_id"]]
-    assert [r["event"] for r in phases] \
-        == [f"engine {p}" for p in tracing.ENGINE_PHASES]
+    assert not req["attrs"]["caller_thread"]
+    phases = _phases(req, spans)
     # gapless, in order, inside the request: a reader can lay them on
     # a timeline and subtract them from their parent
     assert phases[0]["start_ns"] == req["start_ns"]
     for a, b in zip(phases, phases[1:]):
         assert a["end_ns"] == b["start_ns"]
     assert phases[-1]["end_ns"] <= req["end_ns"]
-    compute = phases[tracing.ENGINE_PHASES.index("compute")]
-    assert compute["attrs"] == {"device_wait": True}
+    by_name = {r["event"][len("engine "):]: r for r in phases}
+    assert by_name["compute"]["attrs"] == {"device_wait": True}
+    # each phase carries the CPU time of the engine thread that ran it
+    # (the dispatch thread's up to the launch, the completion thread's
+    # after); the wait in the queue crosses threads and carries none
+    assert "cpu_ns" not in by_name["queue_wait"]
+    for r in phases[1:]:
+        assert 0 <= r["cpu_ns"] <= r["end_ns"] - r["start_ns"] + 1_000_000, r
+    launcher = {by_name[p]["thread"] for p in ("build", "launch")}
+    completer = {by_name[p]["thread"]
+                 for p in ("compute", "materialize", "deliver")}
+    assert len(launcher) == len(completer) == 1 and launcher != completer
+    assert "thread" not in req and "cpu_ns" not in req
     assert {"h2d_bytes", "d2h_bytes", "retrace", "batch"} \
         <= set(req["attrs"])
     # no duration is formatted into a name any more
@@ -174,6 +286,65 @@ def test_unarmed_aio_writes_record_nothing(ec_cluster, monkeypatch):
     tracing.set_sample_rate(1.0)
     _write(io, ["loud"])
     assert made and len(tracing.trace_ids()) == 1
+
+
+def test_unarmed_threads_read_no_clock(ec_cluster, degraded_cluster,
+                                       monkeypatch):
+    """Neither the threads' CPU clock nor the reader threads' stamps
+    are read while nothing is armed: a site that did would take its
+    messenger's loop or its op down with it."""
+    _c, io = ec_cluster
+    _dc, dio, blobs, _lost = degraded_cluster
+    read = []
+
+    def clock(which):
+        def boom():
+            read.append((which, threading.current_thread().name))
+            raise AssertionError(f"{which} read on an unarmed path")
+        return boom
+
+    assert not tracing.armed()
+    monkeypatch.setattr(tracing, "thread_cpu_ns", clock("thread_cpu_ns"))
+    monkeypatch.setattr(tracing, "now_ns", clock("now_ns"))
+    _write(io, [f"clockless-{i}" for i in range(12)])
+    _write(io, ["clockless-wide"], size=4 * 8 * 4096)
+    _read(dio, blobs)
+    assert read == [] and tracing.trace_ids() == []
+
+
+def test_same_thread_span_tells_running_from_waiting():
+    with tracing.trace_ctx(name="t", daemon="test") as tid:
+        with tracing.span("asleep", "test"):
+            time.sleep(0.05)
+        with tracing.span("spinning", "test", wait=True) as outer:
+            t0 = time.perf_counter()
+            while time.perf_counter() - t0 < 0.05:
+                pass
+            # a span that another thread closes has no thread to ask
+            crossing = tracing.begin_span("crossing", "test")
+            closer = threading.Thread(target=tracing.finish_span,
+                                      args=(crossing,))
+            closer.start()
+            closer.join(10.0)
+            assert not closer.is_alive()
+        tracing.add_span("phase", "test", tid, outer.span_id,
+                         outer.start, outer.end, cpu_ns=7, thread=11)
+    rows = {r["event"]: r for r in tracing.dump(tid)}
+    asleep, spinning = rows["asleep"], rows["spinning"]
+    assert asleep["end_ns"] - asleep["start_ns"] >= 50_000_000
+    assert asleep["cpu_ns"] < 10_000_000
+    # near its interval: all of it but what other threads of this
+    # process took of a shared CPU meanwhile
+    assert spinning["cpu_ns"] > 25_000_000
+    assert spinning["cpu_ns"] <= spinning["end_ns"] - spinning["start_ns"]
+    assert asleep["thread"] == spinning["thread"] == threading.get_ident()
+    assert spinning["attrs"] == {"wait": True} and "attrs" not in asleep
+    assert (rows["phase"]["cpu_ns"], rows["phase"]["thread"]) == (7, 11)
+    for name in ("t", "crossing"):
+        assert rows[name]["end_ns"] is not None
+        assert "cpu_ns" not in rows[name] and "thread" not in rows[name]
+    # a name no site opens has no layer
+    assert tracing.layer_of("rep op") == ""
 
 
 @pytest.fixture(scope="module")
@@ -234,9 +405,20 @@ def test_update_to_gives_one_complete_tree_per_epoch(mapping_service):
                  "mapping install", "mapping account",
                  "device crush_rule", "device pg_finish"):
         assert names.count(want) == 1, (want, names)
-    # both engine round trips carry their seven phases
+    # both engine round trips carry their phases, CPU time on each
+    # that one thread ran; the epoch's own spans carry theirs too
     for phase in tracing.ENGINE_PHASES:
-        assert names.count(f"engine {phase}") == 2
+        if phase != "place":
+            assert names.count(f"engine {phase}") == 2
+    for r in spans:
+        if r["event"].startswith("mapping ") or (
+                r["event"].startswith("engine ")
+                and r["event"] != "engine queue_wait"):
+            assert r["cpu_ns"] >= 0 and r["thread"], r
+    assert root["cpu_ns"] >= 0 and root["thread"] == threading.get_ident()
+    for name in ("mapping cv wait", "mapping delta read-back"):
+        assert all(r["attrs"].get("wait") is True for r in spans
+                   if r["event"] == name)
     path = span_readers.critical_path(
         rows, span_readers.by_layer_and_wait)
     assert {tracing.LAYER_MAPPING, tracing.LAYER_ENGINE,
@@ -262,7 +444,7 @@ def test_profiler_session_arms_roots_and_receives_annotations(
     jax.profiler.start_trace(str(tmp_path))
     try:
         assert tracing.armed()
-        _write(io, ["profiled-0", "profiled-1"])
+        _write(io, ["profiled-0", "profiled-1"], size=4 * 8 * 4096)
         epoch(0)
     finally:
         jax.profiler.stop_trace()
@@ -276,18 +458,36 @@ def test_profiler_session_arms_roots_and_receives_annotations(
     assert len(tracing.completed_traces()) == 3
     # ... and the host plane of the xplane holds the same-thread spans
     host: dict[str, int] = {}
+    threads: list[set] = []         # the annotations of each host thread
     path = trace_mod.find_xplane(str(tmp_path))
     for plane in jax.profiler.ProfileData.from_file(path).planes:
         if plane.name.startswith("/host:"):
             for line in plane.lines:
+                threads.append(set())
                 for ev in line.events:
                     host[ev.name] = host.get(ev.name, 0) + 1
+                    threads[-1].add(ev.name)
     for want, n in (("client submit", 2), ("client complete", 2),
                     ("ec continuation", 2), ("ec sub-write", 10),
                     ("bluestore commit", 12), ("bluestore fsync", 24),
                     ("update_to", 1), ("mapping crush", 1),
                     ("mapping delta sort", 1)):
         assert host.get(want) == n, (want, n, host.get(want))
+    # the engine's phases lie there too, between the marks of its
+    # ledger: on its dispatch thread up to the launch and on its
+    # completion thread after (an encode a write, two requests the
+    # epoch), or all on a caller's own (a replica shard's digest
+    # request, under the commit that waits for it)
+    phases = ("engine build", "engine launch", "engine compute",
+              "engine materialize")
+    assert all(host.get(p, 0) >= 2 + 2 + 1 for p in phases), host
+    assert any({"engine build", "engine launch"} <= t
+               and "engine compute" not in t for t in threads)
+    assert any({"engine compute", "engine materialize"} <= t
+               and "engine launch" not in t for t in threads)
+    assert any({"bluestore csum settle", *phases} <= t for t in threads)
+    assert not [n for n in host if n.startswith("engine ")
+                and n not in (*phases, "engine place")]
     # cross-thread spans live in the table only
     assert not [n for n in host if n.startswith(("msg ", "opq ",
                                                  "osd_op", "device "))]
@@ -393,6 +593,8 @@ def test_degraded_aio_read_gives_one_complete_tree_per_op(degraded_cluster):
                      "osd reply", "msg MOSDOpReply", "client complete"):
             assert want in names, (name, want, sorted(set(names)))
         assert names.count("ec read gather") == 1
+        # op, three sub-reads and their replies, reply
+        assert _hops_are_stamped(rows) == 8
         # k shards: the primary's own and k - 1 asked for over the wire
         assert names.count("bluestore read") == 4
         assert names.count("ec sub-read") == 3
@@ -464,9 +666,10 @@ def test_degraded_aio_read_gives_one_complete_tree_per_op(degraded_cluster):
 
 def _digest_requests_keep_their_spans(rows, waiter: str) -> int:
     """Every ``device bluestore_data`` request of the trace hangs under
-    the store span that waits for it and carries the engine's seven
-    phases, gapless and in order, whichever thread ran it; one that
-    its caller's own thread ran (``caller_thread``) queued for nothing.
+    the store span that waits for it and carries the engine's phases,
+    gapless and in order, whichever thread ran it; one that its
+    caller's own thread ran (``caller_thread``) queued for nothing and
+    its phases carry that thread's CPU time.
     Returns how many ran on their caller's thread."""
     spans = [r for r in rows if r["kind"] == "span"]
     by_id = {r["span_id"]: r for r in spans}
@@ -475,19 +678,23 @@ def _digest_requests_keep_their_spans(rows, waiter: str) -> int:
     for req in reqs:
         assert by_id[req["parent_span_id"]]["event"] == waiter
         assert req["layer"] == tracing.LAYER_ENGINE
-        phases = [r for r in spans if r["parent_span_id"] == req["span_id"]]
-        assert [r["event"] for r in phases] \
-            == [f"engine {p}" for p in tracing.ENGINE_PHASES]
+        assert {"h2d_bytes", "d2h_bytes", "retrace", "batch",
+                "caller_thread"} <= set(req["attrs"])
+        phases = _phases(req, spans)
         assert phases[0]["start_ns"] == req["start_ns"]
         for a, b in zip(phases, phases[1:]):
             assert a["end_ns"] == b["start_ns"]
         assert phases[-1]["end_ns"] <= req["end_ns"]
-        assert {"h2d_bytes", "d2h_bytes", "retrace", "batch",
-                "caller_thread"} <= set(req["attrs"])
         if req["attrs"]["caller_thread"]:
-            wait = phases[0]
-            assert wait["end_ns"] - wait["start_ns"] < 1_000_000
+            # it waited in no queue, and every phase ran on the thread
+            # of the store span that waits for it, CPU time and all
+            assert phases[0]["event"] == "engine build"
             assert req["attrs"]["batch"] == 1
+            waiter_row = by_id[req["parent_span_id"]]
+            assert {r["thread"] for r in phases} == {waiter_row["thread"]}
+            assert all(r["cpu_ns"] >= 0 for r in phases)
+            assert sum(r["cpu_ns"] for r in phases) \
+                <= waiter_row["cpu_ns"] + 1_000_000
         # the store's span waits for the request: it ends no earlier
         assert by_id[req["parent_span_id"]]["end_ns"] >= req["end_ns"]
     return sum(bool(r["attrs"]["caller_thread"]) for r in reqs)
