@@ -38,6 +38,14 @@ truncated-quotient ties between items are common at realistic bucket weights
 exactness fallback triggers on virtually every bulk call.  The argmax over
 full per-item draws handles ties for free.)
 
+No XLA gather and no row sort on a table of the batch's size: the
+winners' is_out verdicts come from the devices' reweight words, fetched
+by an exact one-hot product (crush_kernel.out_columns), and the NONE
+holes leave the result rows by a fixed network of selects
+(_compact_rows) — on a TPU XLA's gather costs 7-11 ns a cell, and the
+two were a sixth of the program (PERF.md, PR 40).  What is left of
+either kind is the two-stage schedule's own, on stage 2's lanes.
+
 Bit-exactness: validated against the scalar oracle (crush.mapper_ref) in
 tests/test_mapper_jax.py::test_fastpath_* across skewed weights, reweights,
 out OSDs, uneven host sizes, and forced-fallback configurations.
@@ -62,7 +70,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ceph_tpu.ops import telemetry
-from ceph_tpu.ops.crush_kernel import is_out
+from ceph_tpu.ops.crush_kernel import (
+    compact_planes, out_columns, reweight_words)
 from ceph_tpu.ops.straw2_u32 import (
     _ln_f32_error_bound, magic_tables, straw2_choose_index_approx)
 
@@ -245,9 +254,19 @@ def _consume(host_win, leaf_win, leaf_bad, numrep, tries, R, n):
     return out_h, out_l, overflow
 
 
-def _compact_rows(rows):
-    order = jnp.argsort(rows == NONE, axis=1)
-    return jnp.take_along_axis(rows, order, axis=1)
+def _compact_rows(cols, result_max: int):
+    """(numrep, N) selections with NONE holes -> the (N, result_max)
+    rows of do_rule: the holes move behind the placed cells by the
+    select network (``compact_planes``: no row sort, no
+    ``take_along_axis``, each a gather on the chip), on planes with the
+    batch on the lane axis, then one transpose; NONE fills a result
+    wider than numrep."""
+    planes = [cols[j] for j in range(cols.shape[0])]
+    (planes,), _count = compact_planes(
+        [c != NONE for c in planes], (planes, NONE))
+    planes = planes[:result_max]
+    planes += [jnp.full_like(planes[0], NONE)] * (result_max - len(planes))
+    return jnp.stack(planes, axis=1)
 
 
 #: lanes the XLA path pads a bucket to (the Pallas kernels pad to
@@ -408,7 +427,7 @@ class FastMapper:
                 shape.root_lanes, shape.leaf_lanes, shape.vary_r,
                 interpret=shape.interpret)
 
-    def _winners(self, xs, reweight, tables, R: int):
+    def _winners(self, xs, word, tables, R: int):
         """host_win/leaf_win/leaf_bad for r in [0, R): a fori_loop producing
         one r column per step (bounds the (N, H) ln-matmul intermediates to a
         single r; an unrolled R-wide block OOMs HBM at bulk batch sizes)."""
@@ -439,7 +458,7 @@ class FastMapper:
                 lpos = _draw_argmax(xs, ids, w, r_leaf,
                                     leaf_magic[pos], leaf_off[pos])
                 leaf = jnp.take_along_axis(ids, lpos[:, None], 1)[:, 0]
-            bad = is_out(reweight, leaf, xs)
+            bad = out_columns(word, leaf[None, :], xs)[0]
             hw = jax.lax.dynamic_update_slice(hw, first[:, None], (0, i))
             lw = jax.lax.dynamic_update_slice(lw, leaf[:, None], (0, i))
             lb = jax.lax.dynamic_update_slice(lb, bad[:, None], (0, i))
@@ -447,7 +466,7 @@ class FastMapper:
 
         return jax.lax.fori_loop(0, R, body, (hw0, lw0, lb0))
 
-    def _winners_cols(self, xs, reweight, tables, R: int):
+    def _winners_cols(self, xs, word, tables, R: int):
         """(host_win, leaf_win, leaf_bad) in the native (R, n_padded)
         column layout of the Pallas kernels (no transposes).
 
@@ -479,18 +498,18 @@ class FastMapper:
         xs_pad = jnp.concatenate(
             [xs, jnp.zeros((n_pad - xs.shape[0],), dtype=xs.dtype)]) \
             if n_pad > xs.shape[0] else xs
+        # is_out runs OUTSIDE the kernels, in XLA over the (R, n_pad)
+        # winner planes: the in-kernel variant hit a Mosaic miscompile
+        # (hash32_2 fed from the winner gather/sum pipeline went wrong
+        # for ~0.03% of lanes, compiled mode only; caught by TPU-vs-XLA
+        # cross-validation in round 3).  It is not cheap by itself — a
+        # gather of the reweight vector for the planes was 68 ms of a
+        # 567 ms call at 1 Mi lanes (PERF.md, PR 40) — so the devices'
+        # words are fetched by a one-hot product (out_columns)
         if self.shape.kind == "choose_flat":
-            # is_out runs OUTSIDE the kernels: it is elementwise in
-            # (winner, x), one cheap XLA op over the columns — and the
-            # in-kernel variant hit a Mosaic miscompile (hash32_2 fed
-            # from the winner gather/sum pipeline went wrong for ~0.03%
-            # of lanes, compiled mode only; caught by TPU-vs-XLA
-            # cross-validation in round 3)
-            bad = is_out(reweight, ids, xs_pad[None, :])
-            return ids, ids, bad
+            return ids, ids, out_columns(word, ids, xs_pad)
         lid = pc.leaf_columns(xs, pos, tables, R)
-        lbad = is_out(reweight, lid, xs_pad[None, :])
-        return ids, lid, lbad
+        return ids, lid, out_columns(word, lid, xs_pad)
 
     #: minimum batch for the two-stage schedule; below it one pass at R0
     #: is cheaper than the compaction plumbing
@@ -508,7 +527,7 @@ class FastMapper:
     #: is the 4096 above.
     STAGE2_SHARE = 16
 
-    def _run_pallas(self, xs, reweight, tables, result_max, numrep,
+    def _run_pallas(self, xs, word, tables, result_max, numrep,
                     R0, Rf):
         """Winner columns and the consume ladder both on-device in their
         native (R, N) layout — no transposes, no XLA while_loops.
@@ -528,7 +547,7 @@ class FastMapper:
 
         def attempt(xv, R):
             m = xv.shape[0]
-            hw, lw, lb = self._winners_cols(xv, reweight, tables, R)
+            hw, lw, lb = self._winners_cols(xv, word, tables, R)
             oh, ol, ovf = consume_columns(
                 hw, lw, lb, numrep=numrep, tries=shape.tries,
                 interpret=interp)
@@ -566,13 +585,8 @@ class FastMapper:
                 jnp.sum(need) > cap,
                 lambda _: attempt_full(xs, R0),
                 merged, None)
-        res = out_l if shape.kind == "chooseleaf" else out_h
-        res = _compact_rows(res.T)
-        if numrep < result_max:
-            res = jnp.concatenate(
-                [res, jnp.full((n, result_max - numrep), NONE,
-                               dtype=jnp.int32)], axis=1)
-        return res[:, :result_max]
+        return _compact_rows(
+            out_l if shape.kind == "chooseleaf" else out_h, result_max)
 
     def run(self, xs, reweight, tables, result_max: int,
             block: int = DEFAULT_BLOCK):
@@ -594,24 +608,23 @@ class FastMapper:
         Rf = shape.tries + numrep
         R0 = min(numrep + block, Rf)
 
+        # all is_out asks of a device, built once a program from the
+        # int64 operand and fetched without a gather (out_columns)
+        word = reweight_words(reweight)
+
         if self._pallas is not None:
-            return self._run_pallas(xs, reweight, tables, result_max,
+            return self._run_pallas(xs, word, tables, result_max,
                                     numrep, R0, Rf)
 
-        hw, lw, lb = self._winners(xs, reweight, tables, R0)
+        hw, lw, lb = self._winners(xs, word, tables, R0)
         out_h, out_l, ovf = _consume(hw, lw, lb, numrep, shape.tries, R0, n)
 
         def slow(_):
-            hw2, lw2, lb2 = self._winners(xs, reweight, tables, Rf)
+            hw2, lw2, lb2 = self._winners(xs, word, tables, Rf)
             oh, ol, _ = _consume(hw2, lw2, lb2, numrep, shape.tries, Rf, n)
             return oh, ol
 
         out_h, out_l = jax.lax.cond(
             jnp.any(ovf), slow, lambda _: (out_h, out_l), None)
-        res = out_l if shape.kind == "chooseleaf" else out_h
-        res = _compact_rows(res)
-        if numrep < result_max:
-            res = jnp.concatenate(
-                [res, jnp.full((n, result_max - numrep), NONE,
-                               dtype=jnp.int32)], axis=1)
-        return res[:, :result_max]
+        return _compact_rows(
+            (out_l if shape.kind == "chooseleaf" else out_h).T, result_max)

@@ -222,19 +222,110 @@ def straw2_choose_index(x, ids, r, weights):
 # is_out — probabilistic rejection by the reweight vector (mapper.c:424-438)
 # ---------------------------------------------------------------------------
 
-def is_out(reweight, item, x):
-    """reweight: (D,) 16.16 per-device; item: (...,) device ids; x: (...,) inputs.
-    Ids beyond the reweight vector are out, like the weight_max guard in
-    mapper.c:424-427 (jax gathers clamp, so the bound is checked explicitly)."""
-    reweight = jnp.asarray(reweight)
-    n = reweight.shape[0]
-    oob = (item < 0) | (item >= n)
-    w = reweight[jnp.clip(item, 0, n - 1)]
+def verdict(w, item, x):
+    """is_out's arithmetic on the devices' reweights ``w``, however they
+    were fetched: in at 0x10000 or more, out at 0, else in when the
+    16-bit hash of (x, item) is under ``w``."""
     keep_full = w >= 0x10000
     zero = w == 0
     h = hash32_2(x, item.astype(jnp.uint32)) & jnp.uint32(0xFFFF)
-    keep_prob = h.astype(jnp.int64) < w.astype(jnp.int64)
-    return oob | ~(keep_full | (~zero & keep_prob))
+    keep_prob = h.astype(w.dtype) < w
+    return ~(keep_full | (~zero & keep_prob))
+
+
+def is_out(reweight, item, x):
+    """reweight: (D,) 16.16 per-device; item: (...,) device ids; x: (...,) inputs.
+    Ids beyond the reweight vector are out, like the weight_max guard in
+    mapper.c:424-427 (jax gathers clamp, so the bound is checked explicitly).
+    The fetch is an XLA gather: right for the (N,) items of the generic
+    interpreter's loops and of ``flat_firstn``; the fused fast path
+    fetches ``reweight_words`` by ``fetch_words`` instead."""
+    reweight = jnp.asarray(reweight)
+    n = reweight.shape[0]
+    oob = (item < 0) | (item >= n)
+    w = reweight[jnp.clip(item, 0, n - 1)].astype(jnp.int64)
+    return oob | verdict(w, item, x)
+
+
+def reweight_words(reweight):
+    """All ``verdict`` asks of a device, in an int32 word: its reweight
+    clipped to [0, 0x10000] (17 bits) keeps the three tests for any
+    int64 it could hold."""
+    return jnp.clip(jnp.asarray(reweight), 0, 0x10000).astype(jnp.int32)
+
+
+# a word table is (M / 128, 128): an id is a row and a lane of it
+_LANE_BITS = 7
+_LANES = 1 << _LANE_BITS
+
+
+def fetch_words(word, ids):
+    """``word[clip(ids, 0, M - 1)]`` without a gather, which costs a
+    v5e 7-11 ns a cell (PERF.md, PR 38).  ``word`` (M,) int32 of values
+    under 2^24, ``ids`` int32 planes with the batch on the last axis.
+    The id splits into (table row, lane); the lane's one-hot meets the
+    (M / 128, 128) table on the MXU, which gives every table row's
+    candidate, and a select over the rows keeps the id's own.  Exact:
+    f32 holds a word, and HIGHEST keeps the f32 operand whole on the
+    MXU.  XLA fuses one-hot, product and select into one pass; nothing
+    of size rows x ids is stored.  The caller zeroes the words of ids
+    outside its bounds."""
+    m_pad = word.shape[0]
+    at = jnp.clip(ids, 0, m_pad - 1)
+    n_rows = -(-m_pad // _LANES)
+    table = jnp.pad(word, (0, n_rows * _LANES - m_pad)).astype(
+        jnp.float32).reshape(n_rows, _LANES)
+    over = (slice(None),) + (None,) * ids.ndim
+    lane = jnp.arange(_LANES, dtype=jnp.int32)[over]
+    onehot = ((at & (_LANES - 1))[None] == lane).astype(jnp.float32)
+    rows = jax.lax.dot_general(                         # (rows, *ids.shape)
+        table, onehot, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST)
+    row = jnp.arange(n_rows, dtype=jnp.int32)[over]
+    return jnp.sum(jnp.where((at >> _LANE_BITS)[None] == row, rows, 0.0),
+                   axis=0).astype(jnp.int32)
+
+
+def out_columns(word, item, x):
+    """``is_out`` for id planes ``item`` (R, N) against ``x`` (N,), from
+    the devices' ``reweight_words``: the fast path's fetch.  An id
+    outside the vector (a NONE hole, a padded lane) has word 0: out."""
+    got = fetch_words(word, item)
+    got = jnp.where((item >= 0) & (item < word.shape[0]), got, 0)
+    return verdict(got, item, x[None, :])
+
+
+# ---------------------------------------------------------------------------
+# folds over planes (the batch on the lane axis): what the fast path and the
+# placement ladder use where rows would want a sort
+# ---------------------------------------------------------------------------
+
+def first_of(masks, cells, default):
+    """The cell of the first plane whose mask is set."""
+    out = default
+    for mask, cell in zip(reversed(masks), reversed(cells)):
+        out = jnp.where(mask, cell, out)
+    return out
+
+
+def compact_planes(keep, *carried):
+    """Stable compaction of W planes without a sort: the kept cells
+    move to the front in order.  Cell j lands at k = the number of kept
+    cells before it, so out_k is the one cell with keep_j & (pos_j == k)
+    (j >= k) — a fixed network of W (W + 1) / 2 selects on dense
+    vectors.  ``keep`` is W masks; ``carried`` is (planes, fill) pairs
+    moved alike; returns their compacted planes and the count of kept
+    cells."""
+    w = len(keep)
+    pos, count = [], jnp.zeros(keep[0].shape, dtype=jnp.int32)
+    for j in range(w):
+        pos.append(count)
+        count = count + keep[j].astype(jnp.int32)
+    lands = [[keep[j] & (pos[j] == k) for j in range(k, w)]
+             for k in range(w)]
+    outs = [[first_of(lands[k], cells[k:], fill) for k in range(w)]
+            for cells, fill in carried]
+    return outs, count
 
 
 # ---------------------------------------------------------------------------

@@ -4,15 +4,21 @@ The XLA path (ops.straw2_u32 driven by crush.fastpath) is bit-exact but
 this backend leaves long u32 elementwise chains unfused: a single
 (65536, 256) draw column costs ~25 ms against a ~0.5 ms roofline, with
 hundreds of materialized (N, S) intermediates.  These kernels fuse one
-whole column — rjenkins hash, crush_ln limb pipeline, magic division,
-first-min winner select, and the is_out verdict — into one VMEM-resident
-Pallas program per (r, block) grid step:
+whole column — rjenkins hash, crush_ln limb pipeline, magic division
+and first-min winner select — into one VMEM-resident Pallas program per
+(r, block) grid step:
 
-  root kernel:  xs block -> winner position/id per r  (+ is_out for flat
-                rules, whose first level already lands on devices)
+  root kernel:  xs block -> winner position/id per r
   leaf kernel:  root winner position -> the winning host's device row
                 (fetched with an exact f32 one-hot MXU dot — a vectorized
-                row gather the VPU cannot do) -> device winner + is_out
+                row gather the VPU cannot do) -> device winner
+
+The is_out verdicts of the winners are NOT the kernels': the fast path
+computes them in XLA over the winner planes the kernels return
+(crush_kernel.out_columns — the devices' reweight words fetched by a
+one-hot product, since an XLA gather for the planes cost an eighth of
+the CRUSH program, PERF.md PR 40), then consume_columns walks the firstn
+ladder over winners and verdicts.
 
 Bit-exactness contract: identical output to ops.straw2_u32 (itself
 validated exhaustively against the s64 kernel and the scalar C-semantics
@@ -241,11 +247,12 @@ def _root_kernel(xs_ref, ids_ref, wz_ref, magic_ref, off_ref,
     so the kernel stays small enough for Mosaic to compile quickly.
 
     is_out verdicts are NOT computed here: they are elementwise in
-    (winner, x) and run as one cheap XLA op over the output columns
-    (crush_kernel.is_out).  Keeping them out of the kernel also dodged a
-    real Mosaic miscompile: hash32_2 fed from the gather/sum winner
-    pipeline produced wrong values for ~0.03% of lanes (see r03 notes in
-    fastpath._winners_cols)."""
+    (winner, x) and run in XLA over the output columns
+    (crush_kernel.out_columns: a one-hot product, not a gather — the
+    gather was 68 ms a call at 1 Mi lanes).  Keeping them out of the
+    kernel also dodged a real Mosaic miscompile: hash32_2 fed from the
+    gather/sum winner pipeline produced wrong values for ~0.03% of
+    lanes (see r03 notes in fastpath._winners_cols)."""
     r = pl.program_id(1)
     x = xs_ref[0, :]
     tabs = (rhlh_ref, ll_lo_ref, ll_hi_ref, rh128)
@@ -771,7 +778,7 @@ class PallasColumns:
 
     def root_columns(self, xs, tables, R: int):
         """xs (N,) uint32 -> (pos, ids) each (R, N) int32.  is_out
-        verdicts are computed by the caller in XLA (elementwise).
+        verdicts are computed by the caller in XLA (out_columns).
         Batches that are not a BLOCK multiple are zero-padded here."""
         root_ids, root_wz, _wf, root_magic, root_off = tables[:5]
         S = self.S_root
@@ -833,7 +840,7 @@ class PallasColumns:
 
     def leaf_columns(self, xs, root_pos, tables, R: int):
         """root winner positions -> leaf_id (R, N).  is_out verdicts are
-        computed by the caller in XLA (elementwise)."""
+        computed by the caller in XLA (out_columns)."""
         leaf_static = tables[5]
         # root_pos comes back padded from root_columns; re-pad from the
         # caller's batch width so both land on the same quantum

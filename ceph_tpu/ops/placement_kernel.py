@@ -49,7 +49,9 @@ gathers, where this form takes 3.5 (PERF.md, PR 38).
     replicated raw row; down OSDs out of ``up``) are a fixed network:
     cell j lands at ``pos_j`` = the number of kept cells before it, so
     ``out_k`` is the one cell with ``keep_j & (pos_j == k)`` — W(W+1)/2
-    selects on dense vectors, the same formulation for W = 3 and 16.
+    selects on dense vectors, the same formulation for W = 3 and 16
+    (``crush_kernel.compact_planes``; the CRUSH fast path compacts its
+    result rows with it too).
   * **One attribute word an OSD**, built in the program from the three
     vectors: bit 0 exists, bit 1 up, bit 2 in (``weight != 0``), bits
     3..19 the primary affinity (clipped to [0, 0x10001], which keeps
@@ -64,7 +66,8 @@ gathers, where this form takes 3.5 (PERF.md, PR 38).
     (M / 128, 128) table on the MXU (f32, ``Precision.HIGHEST``: a
     word is under 2^24), a select over the rows keeps the id's own.
     XLA fuses one-hot, product and select into one pass; nothing of
-    size N x 128 is ever stored.
+    size N x 128 is ever stored (``crush_kernel.fetch_words``, which
+    the CRUSH fast path's ``is_out`` calls too).
   * 32 bits everywhere: only the M-entry ``weight`` operand is int64,
     until it is folded into the word.
 
@@ -98,9 +101,6 @@ _OSD_UP = 2
 # bits where they are, bit 2 in (weight != 0), the affinity above
 _W_IN = 4
 _AFF_SHIFT = 3
-# the word table is (M / 128, 128): an id is a row and a lane of it
-_LANE_BITS = 7
-_LANES = 1 << _LANE_BITS
 
 
 # ---------------------------------------------------------------------------
@@ -115,18 +115,17 @@ def _ladder_impl(raw, pps, raw_len, up_rows, up_len, items, temp_rows,
     (N, P, 2), the rest (N,) or (M,); max_osd an int32 scalar — the
     bound of every ``0 <= o < max_osd`` check, while M (the vectors'
     padded length, osdmap.padded_osds) only bounds the gather."""
-    import jax
     import jax.numpy as jnp
 
     from ceph_tpu.ops import telemetry
-    from ceph_tpu.ops.crush_kernel import hash32_2
+    from ceph_tpu.ops.crush_kernel import (
+        compact_planes as compact, fetch_words, first_of, hash32_2)
 
     # only ever called under jit: a trace of it is a program built
     telemetry.mapping_stats().record_program_build()
     i32 = jnp.int32
     w = raw.shape[1]
     p_pairs = items.shape[1]
-    m_pad = state.shape[0]
 
     def planes(table):
         """(N, K) -> K planes of shape (N,)."""
@@ -139,30 +138,6 @@ def _ladder_impl(raw, pps, raw_len, up_rows, up_len, items, temp_rows,
     def all_of(masks):
         return functools.reduce(jnp.logical_and, masks)
 
-    def first_of(masks, cells, default):
-        """The cell of the first plane whose mask is set."""
-        out = default
-        for mask, cell in zip(reversed(masks), reversed(cells)):
-            out = jnp.where(mask, cell, out)
-        return out
-
-    def compact(keep, *carried):
-        """Stable compaction of W planes: the kept cells move to the
-        front in order.  Cell j lands at k = the number of kept cells
-        before it, so out_k is the one cell with keep_j & (pos_j == k)
-        (j >= k) — a fixed network of selects.  ``carried`` is (planes,
-        fill) pairs moved alike; returns their compacted planes and the
-        count of kept cells."""
-        pos, count = [], jnp.zeros_like(raw_len)
-        for j in range(w):
-            pos.append(count)
-            count = count + keep[j].astype(i32)
-        lands = [[keep[j] & (pos[j] == k) for j in range(k, w)]
-                 for k in range(w)]
-        outs = [[first_of(lands[k], cells[k:], fill) for k in range(w)]
-                for cells, fill in carried]
-        return outs, count
-
     # -- one attribute word an OSD: all the ladder ever asks of one.
     # The affinity is clipped to [0, MAX + 1], which keeps both of its
     # tests (!= MAX, hash16 < aff) for every int32 it could hold.
@@ -172,26 +147,11 @@ def _ladder_impl(raw, pps, raw_len, up_rows, up_len, items, temp_rows,
             ).astype(i32)
 
     def words_of(ids):
-        """The words of a list of id planes, fetched at once; 0 (not
-        existing, down, out) for an id outside [0, max_osd).  The
-        one-hot product of the module docstring: the lane's one-hot
-        times the table gives every table row's candidate, the id's
-        own row is selected.  Exact: a word is under 2^24, f32 holds
-        it, and HIGHEST keeps the f32 operand whole on the MXU."""
+        """The words of a list of id planes, fetched at once (the
+        one-hot product of the module docstring, ``fetch_words``); 0
+        (not existing, down, out) for an id outside [0, max_osd)."""
         o = jnp.stack(ids)                                  # (K, N)
-        at = jnp.clip(o, 0, m_pad - 1)
-        n_rows = -(-m_pad // _LANES)
-        table = jnp.pad(word, (0, n_rows * _LANES - m_pad)).astype(
-            jnp.float32).reshape(n_rows, _LANES)
-        lane = jnp.arange(_LANES, dtype=i32)[:, None, None]
-        onehot = ((at & (_LANES - 1))[None] == lane).astype(jnp.float32)
-        rows = jax.lax.dot_general(                         # (rows, K, N)
-            table, onehot, (((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST)
-        row = jnp.arange(n_rows, dtype=i32)[:, None, None]
-        got = jnp.sum(jnp.where((at >> _LANE_BITS)[None] == row, rows, 0.0),
-                      axis=0).astype(i32)
-        got = jnp.where((o >= 0) & (o < max_osd), got, 0)
+        got = jnp.where((o >= 0) & (o < max_osd), fetch_words(word, o), 0)
         return [got[j] for j in range(len(ids))]
 
     def has(words, bits):

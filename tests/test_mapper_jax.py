@@ -247,6 +247,58 @@ def test_two_stage_pallas_schedule_interpret():
     np.testing.assert_array_equal(res_share, res_xla)
 
 
+@pytest.mark.parametrize("kind,n,numrep,result_max", [
+    ("chooseleaf", 32768, 0, 3),     # the two-stage schedule as it ships
+    ("chooseleaf", 1000, 0, 3),      # one stage, a batch the block pads
+    ("choose_flat", 32768, 0, 3),
+    ("choose_flat", 1000, 2, 4),     # a result wider than numrep
+])
+def test_pallas_route_equals_the_scalar_oracle(kind, n, numrep, result_max):
+    """`FastMapper.run` on the Pallas route (interpret mode), with
+    nothing patched — at 32,768 lanes the two-stage schedule, below it
+    one stage — held to `mapper_ref` itself on sampled lanes, on a map
+    with OSDs out, at partial and at full reweight."""
+    import jax.numpy as jnp
+
+    from ceph_tpu.crush.fastpath import FastMapper, detect, tables_of
+
+    if kind == "chooseleaf":
+        crush_map, _root, rid = build_two_level_map(20, 4)
+        n_osds = 80
+    else:
+        crush_map, _root, _rid = build_flat_map(24)
+        n_osds = 24
+        rid = crush_map.add_rule(Rule(
+            ruleset=7, type=1, min_size=1, max_size=10, steps=[
+                RuleStep(RULE_TAKE, -1, 0),
+                RuleStep(RULE_CHOOSE_FIRSTN, numrep, 0),
+                RuleStep(RULE_EMIT, 0, 0)]))
+    # few tries, so a short full range: interpret mode takes minutes
+    # over the 54 columns of the default 51
+    crush_map.tunables.choose_total_tries = 7
+    reweight = np.full(n_osds, 0x10000, dtype=np.int64)
+    reweight[::7] = 0x4000
+    reweight[::13] = 0
+    local = np.random.default_rng((n, numrep))
+    xs = local.integers(0, 2 ** 32, n, dtype=np.uint32)
+    ft = tables_of(detect(crush_map, rid), pallas=True, interpret=True)
+    assert ft.shape.kind == kind
+    fm = FastMapper(ft.shape)
+    assert (n >= fm.TWO_STAGE_MIN) == (n == 32768)
+    got = np.asarray(fm.run(jnp.asarray(xs), jnp.asarray(reweight),
+                            ft.on(), result_max))
+    assert got.shape == (n, result_max)
+    short = 0
+    for i in local.choice(n, 400, replace=False):
+        want = crush_do_rule(crush_map, rid, int(xs[i]), result_max,
+                             list(reweight))
+        short += len(want) < result_max
+        assert [int(v) for v in got[i]] == \
+            want + [CRUSH_ITEM_NONE] * (result_max - len(want)), int(xs[i])
+    if numrep:
+        assert short == 400     # every row NONE-filled past numrep
+
+
 # -- tree buckets (batched descent vs the scalar oracle) ---------------------
 
 def test_tree_hosts_chooseleaf_firstn():

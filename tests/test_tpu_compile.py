@@ -173,6 +173,37 @@ def test_consume_columns(one_chip, n, R):
     assert "tpu_custom_call" in text
 
 
+# what FastMapper._winners_cols runs in XLA between the Pallas calls:
+# stage 1's and stage 2's planes of the 1 Mi cell, a chip's share of four
+@pytest.mark.parametrize("planes,n", [(4, 1 << 20), (9, (1 << 20) // 16),
+                                      (9, N_PGS // 4)])
+def test_is_out_of_the_winner_planes(one_chip, planes, n):
+    """The reweight words of the winner planes come by a one-hot
+    product the compiler fuses with its one-hot and its select: no
+    gather (7-11 ns a cell on the chip), and nothing of table rows x
+    planes x lanes is stored."""
+    from ceph_tpu.crush.types import padded_osds
+    from ceph_tpu.ops.crush_kernel import out_columns, reweight_words
+    compiled = jax.jit(
+        lambda rw, ids, xs: out_columns(reweight_words(rw), ids, xs)).lower(
+        _spec(one_chip, (padded_osds(10000),), jnp.int64),
+        _spec(one_chip, (planes, n), jnp.int32),
+        _spec(one_chip, (n,), jnp.uint32)).compile()
+    text = compiled.as_text()
+    assert "convolution" in text
+    assert "gather" not in text and " sort(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= 8 * planes * n
+
+
+def test_compact_rows_of_1m_lanes(one_chip):
+    """NONE holes leave the (numrep, N) selections by a select network
+    and one transpose: no row sort, no take_along_axis."""
+    from ceph_tpu.crush.fastpath import _compact_rows
+    text = _compile(functools.partial(_compact_rows, result_max=3),
+                    _spec(one_chip, (3, 1 << 20), jnp.int32))
+    assert "gather" not in text and " sort(" not in text
+
+
 def test_ln_f32_table(one_chip):
     """The eager program _ln_f32_bound measures the filter's error
     bound with."""
