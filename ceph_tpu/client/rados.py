@@ -766,6 +766,14 @@ class IoCtx:
                                            data)], direct=self.direct,
             tenant=self.tenant)
 
+    def aio_write(self, oid: str, data: bytes,
+                  offset: int = 0) -> "AioCompletion":
+        """librados rados_aio_write: `data` at `offset` of the object."""
+        return self.client.aio_operate(
+            self.pool_id, oid, [OSDOpField(OP_WRITE, offset, len(data),
+                                           data)], direct=self.direct,
+            tenant=self.tenant)
+
     def aio_read(self, oid: str, length: int = 0,
                  offset: int = 0) -> "AioCompletion":
         return self.client.aio_operate(

@@ -191,6 +191,9 @@ LAYERS = {
     "ec sub-read": LAYER_ECB, "ec sub-read reply": LAYER_ECB,
     "ec decode submit": LAYER_ECB, "ec decode continuation": LAYER_ECB,
     "ec read finish": LAYER_ECB,
+    # the rmw gather of an overwrite (the wait for k chunks of the
+    # stripes it touches) on a pool with allow_ec_overwrites
+    "ec rmw gather": LAYER_ECB,
     # ops/dispatch.py: the request and its phases; ops/telemetry.py
     "device": LAYER_ENGINE, "engine": LAYER_ENGINE,
     "kernel": LAYER_KERNELS,

@@ -297,21 +297,29 @@ class MOSDECSubOpWriteReply(Message):
 
 @register_message
 class MOSDECSubOpRead(Message):
+    """primary -> shard holder: read shard `shard` of `oid`.  v2 carries
+    the shard extent ``(offset, length)`` (ECSubRead::to_read); length 0
+    is the whole shard, as every v1 read was."""
+
     TYPE = 110
+    HEAD_VERSION = 2       # v2: the shard extent
 
     def __init__(self, reqid: tuple[int, int] = (0, 0),
                  pgid: tuple[int, int] = (0, 0), oid: str = "",
-                 shard: int = 0):
+                 shard: int = 0, offset: int = 0, length: int = 0):
         super().__init__()
         self.reqid = reqid
         self.pgid = pgid
         self.oid = oid
         self.shard = shard
+        self.offset = offset
+        self.length = length
 
     def encode_payload(self, enc):
-        enc.versioned(1, 1, lambda e: (
+        enc.versioned(2, 1, lambda e: (
             e.u64(self.reqid[0]), e.u64(self.reqid[1]),
-            _enc_pgid(e, self.pgid), e.str(self.oid), e.u8(self.shard)))
+            _enc_pgid(e, self.pgid), e.str(self.oid), e.u8(self.shard),
+            e.u64(self.offset), e.u64(self.length)))
 
     def decode_payload(self, dec, version):
         def body(d, v):
@@ -319,16 +327,23 @@ class MOSDECSubOpRead(Message):
             self.pgid = _dec_pgid(d)
             self.oid = d.str()
             self.shard = d.u8()
-        dec.versioned(1, body)
+            self.offset = d.u64() if v >= 2 else 0
+            self.length = d.u64() if v >= 2 else 0
+        dec.versioned(2, body)
 
 
 @register_message
 class MOSDECSubOpReadReply(Message):
+    """v3 returns the extent the read asked for (ECSubReadReply's
+    buffers_read offsets); length 0 = the whole shard."""
+
     TYPE = 111
+    HEAD_VERSION = 2       # payload v3: the extent read
 
     def __init__(self, reqid: tuple[int, int] = (0, 0), shard: int = 0,
                  from_osd: int = 0, result: int = 0, chunk: bytes = b"",
-                 ver: tuple[int, int] = (0, 0)):
+                 ver: tuple[int, int] = (0, 0), offset: int = 0,
+                 length: int = 0):
         super().__init__()
         self.reqid = reqid
         self.shard = shard
@@ -336,12 +351,15 @@ class MOSDECSubOpReadReply(Message):
         self.result = result
         self.chunk = chunk
         self.ver = ver          # shard's object version (v2+; recovery reads)
+        self.offset = offset
+        self.length = length
 
     def encode_payload(self, enc):
-        enc.versioned(2, 1, lambda e: (
+        enc.versioned(3, 1, lambda e: (
             e.u64(self.reqid[0]), e.u64(self.reqid[1]), e.u8(self.shard),
             e.s32(self.from_osd), e.s32(self.result), e.bytes(self.chunk),
-            e.u32(self.ver[0]), e.u64(self.ver[1])))
+            e.u32(self.ver[0]), e.u64(self.ver[1]),
+            e.u64(self.offset), e.u64(self.length)))
 
     def decode_payload(self, dec, version):
         def body(d, v):
@@ -352,7 +370,9 @@ class MOSDECSubOpReadReply(Message):
             self.chunk = d.bytes()
             if v >= 2:
                 self.ver = (d.u32(), d.u64())
-        dec.versioned(2, body)
+            self.offset = d.u64() if v >= 3 else 0
+            self.length = d.u64() if v >= 3 else 0
+        dec.versioned(3, body)
 
 
 @register_message

@@ -48,28 +48,36 @@ from ceph_tpu.msg.messenger import (
     ConnectionPolicy, Dispatcher, EntityName, Messenger)
 from ceph_tpu.objectstore.kv import LogDB, MemDB
 from ceph_tpu.osd.map_codec import decode_osdmap, encode_osdmap
-from ceph_tpu.osd.osdmap import OSDMap, PGPool, POOL_TYPE_ERASURE
+from ceph_tpu.osd.osdmap import (FLAG_EC_OVERWRITES, OSDMap, PGPool,
+                                  POOL_TYPE_ERASURE)
 
 
 @register_message
 class MOSDBoot(Message):
-    """osd -> mon: I'm up at this address (messages/MOSDBoot.h analog)."""
+    """osd -> mon: I'm up at this address (messages/MOSDBoot.h analog).
+    v2 carries the OSD's metadata that the mon checks pools against:
+    its objectstore (the reference's `osd_objectstore`)."""
 
     TYPE = 71
+    HEAD_VERSION = 2       # v2: the OSD's objectstore
 
-    def __init__(self, osd_id: int = 0, addr: str = ""):
+    def __init__(self, osd_id: int = 0, addr: str = "",
+                 objectstore: str = ""):
         super().__init__()
         self.osd_id = osd_id
         self.addr = addr
+        self.objectstore = objectstore
 
     def encode_payload(self, enc: Encoder):
-        enc.versioned(1, 1, lambda e: (e.s32(self.osd_id), e.str(self.addr)))
+        enc.versioned(2, 1, lambda e: (e.s32(self.osd_id), e.str(self.addr),
+                                       e.str(self.objectstore)))
 
     def decode_payload(self, dec: Decoder, version: int):
         def body(d, v):
             self.osd_id = d.s32()
             self.addr = d.str()
-        dec.versioned(1, body)
+            self.objectstore = d.str() if v >= 2 else ""
+        dec.versioned(2, body)
 
 
 @register_message
@@ -303,6 +311,9 @@ class Monitor(Dispatcher):
         #: not fail every healthy rank on its first tick)
         self._mds_watch_since: float | None = None
         self._osd_addrs: dict[int, str] = {}
+        #: osd -> the objectstore its boot reported (`osd metadata`'s
+        #: osd_objectstore); allow_ec_overwrites is checked against it
+        self._osd_objectstore: dict[int, str] = {}
         #: rank -> address.  Runtime membership (`mon add/rm`) keeps
         #: this in lockstep with the committed mon_db; `mon rm` leaves
         #: rank holes, hence a dict rather than a list
@@ -1215,6 +1226,7 @@ class Monitor(Dispatcher):
                 self._crush_add_osd(m, osd, 0x10000)
         with self._lock:
             self._osd_addrs[msg.osd_id] = msg.addr
+            self._osd_objectstore[msg.osd_id] = msg.objectstore
             self._failure_reports.pop(msg.osd_id, None)
         was_up = self.osdmap.is_up(msg.osd_id)
         if self._mutate(fn) and not was_up \
@@ -2155,6 +2167,10 @@ class Monitor(Dispatcher):
             if new < pool.pgp_num:
                 return (f"pgp_num {new} < current {pool.pgp_num}: "
                         "shrinking is not supported", -22)
+        elif var == "allow_ec_overwrites":
+            return self._cmd_allow_ec_overwrites(pool, str(cmd["val"]))
+        elif var == "flags":
+            return "flags are set by name", -22
 
         def fn(m: OSDMap):
             p = m.pools[pool_id]
@@ -2164,6 +2180,40 @@ class Monitor(Dispatcher):
             setattr(p, var,
                     cast(cmd["val"]) if cast is not bool
                     else cmd["val"] in ("1", "true", "True"))
+        if not self._mutate(fn):
+            return "commit failed", -11
+        return json.dumps({"epoch": self.osdmap.epoch}), 0
+
+    def _cmd_allow_ec_overwrites(self, pool: PGPool,
+                                 val: str) -> tuple[str, int]:
+        """`osd pool set <pool> allow_ec_overwrites true`
+        (OSDMonitor::prepare_command_pool_set): only an erasure pool,
+        only when every OSD is BlueStore — whose per-block checksums
+        stand in for the whole-shard hash an overwritten shard cannot
+        keep — and never back to false once set."""
+        on = val.lower() in ("1", "true", "yes")
+        if not on and val.lower() not in ("0", "false", "no"):
+            return f"allow_ec_overwrites: bad value {val!r}", -22
+        if not pool.is_erasure():
+            return "ec overwrites can only be enabled for an erasure " \
+                   "coded pool", -22
+        if not on:
+            if pool.allows_ecoverwrites():
+                return "ec overwrites cannot be disabled once enabled", -22
+            return json.dumps({"epoch": self.osdmap.epoch}), 0
+        m = self.osdmap
+        with self._lock:
+            stores = dict(self._osd_objectstore)
+        not_blue = [o for o in range(m.max_osd) if m.exists(o)
+                    and stores.get(o) != "bluestore"]
+        if not_blue:
+            return ("pool must only be stored on bluestore for scrubbing "
+                    "to work: osd." + ", osd.".join(map(str, not_blue))
+                    + " not bluestore"), -22
+        pool_id = pool.pool_id
+
+        def fn(mm: OSDMap):
+            mm.pools[pool_id].flags |= FLAG_EC_OVERWRITES
         if not self._mutate(fn):
             return "commit failed", -11
         return json.dumps({"epoch": self.osdmap.epoch}), 0

@@ -164,6 +164,8 @@ def _enc_pool(e2: Encoder, p: PGPool) -> None:
     # v13: per-pool objectstore compression (pg_pool_t compression opts)
     e2.str(p.compression_mode)
     e2.str(p.compression_algorithm)
+    # v14 (incremental v5): pg_pool_t::flags
+    e2.u64(p.flags)
 
 
 def _dec_pool(d2: Decoder, version: int = 999) -> PGPool:
@@ -185,6 +187,8 @@ def _dec_pool(d2: Decoder, version: int = 999) -> PGPool:
     if version >= 13:
         p.compression_mode = d2.str()
         p.compression_algorithm = d2.str()
+    if version >= 14:
+        p.flags = d2.u64()
     return p
 
 
@@ -252,7 +256,7 @@ def encode_osdmap(m: OSDMap, *, with_auth: bool = False) -> bytes:
         # the mgr slo module's burn-rate engine reads them off the map
         e.bytes(_json.dumps(m.slo_db).encode() if m.slo_db else b"")
 
-    enc.versioned(13, 1, body)
+    enc.versioned(14, 1, body)
     return enc.tobytes()
 
 
@@ -404,7 +408,7 @@ def encode_incremental(inc: dict) -> bytes:
             if has:
                 e.bytes(inc[attr].encode())
 
-    enc.versioned(4, 1, body)
+    enc.versioned(5, 1, body)
     return enc.tobytes()
 
 
@@ -423,7 +427,9 @@ def decode_incremental(data: bytes) -> dict:
         ch = d.map(lambda d2: d2.u32(), lambda d2: d2.str())
         if ch:
             inc["addrs"] = ch
-        pools = d.map(lambda d2: d2.s64(), _dec_pool)
+        # a v4 incremental carries pools in the full map's v13 layout
+        pools = d.map(lambda d2: d2.s64(),
+                      lambda d2: _dec_pool(d2, 999 if version >= 5 else 13))
         if pools:
             inc["pools"] = pools
         old_pools = d.list(lambda d2: d2.s64())

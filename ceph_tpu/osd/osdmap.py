@@ -30,6 +30,9 @@ CEPH_NOSD = -1
 
 POOL_TYPE_REPLICATED = 1
 POOL_TYPE_ERASURE = 3
+#: pg_pool_t::FLAG_EC_OVERWRITES (`osd pool set <p> allow_ec_overwrites
+#: true`): partial overwrites on an erasure pool
+FLAG_EC_OVERWRITES = 1 << 14
 
 OSD_EXISTS = 1
 OSD_UP = 2
@@ -85,6 +88,8 @@ class PGPool:
     # falls back to the bluestore_compression_* conf
     compression_mode: str = ""        # "" | "none" | "aggressive" | "force"
     compression_algorithm: str = ""   # "" | a compressor plugin name
+    # pg_pool_t::flags (FLAG_EC_OVERWRITES, ...)
+    flags: int = 0
 
     def __post_init__(self):
         if self.pgp_num == 0:
@@ -106,6 +111,11 @@ class PGPool:
 
     def is_erasure(self) -> bool:
         return self.type == POOL_TYPE_ERASURE
+
+    def allows_ecoverwrites(self) -> bool:
+        """pg_pool_t::allows_ecoverwrites: an erasure pool whose objects
+        take partial overwrites (stripe-ranged read-modify-write)."""
+        return bool(self.flags & FLAG_EC_OVERWRITES)
 
 
 @dataclass

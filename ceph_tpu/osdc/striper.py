@@ -101,8 +101,12 @@ class StripedObject:
     SIZE_KEY = "striper.size"
 
     def __init__(self, ioctx, name: str,
-                 layout: StripeLayout | None = None):
+                 layout: StripeLayout | None = None, meta_io=None):
         self.io = ioctx
+        #: the pool of the size object (an omap object): an erasure-
+        #: coded data pool holds no omap, so rbd keeps it beside the
+        #: image's header
+        self.meta_io = meta_io or ioctx
         self.name = name
         self.layout = layout or StripeLayout()
         self.striper = Striper(self.layout)
@@ -112,15 +116,15 @@ class StripedObject:
 
     def size(self) -> int:
         try:
-            omap = self.io.get_omap(self._size_obj())
+            omap = self.meta_io.get_omap(self._size_obj())
         except OSError:
             return 0
         blob = omap.get(self.SIZE_KEY)
         return int(blob.decode()) if blob else 0
 
     def _set_size(self, size: int) -> None:
-        self.io.set_omap(self._size_obj(),
-                         {self.SIZE_KEY: str(size).encode()})
+        self.meta_io.set_omap(self._size_obj(),
+                              {self.SIZE_KEY: str(size).encode()})
 
     def write(self, data: bytes, offset: int = 0) -> None:
         pos = 0
@@ -172,6 +176,6 @@ class StripedObject:
             except OSError:
                 pass
         try:
-            self.io.remove(self._size_obj())
+            self.meta_io.remove(self._size_obj())
         except OSError:
             pass

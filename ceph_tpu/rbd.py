@@ -20,13 +20,19 @@ I/O path:
     and `flatten` severs the link.  Child snapshots freeze their own
     parent record, so flatten/resize of the head never rewrites what a
     snap could see
+  * a separate data pool (`rbd create --data-pool`, librbd's data-pool
+    feature): header, directory, object map and journal stay in the
+    image's pool, the rbd_data objects go to the data pool — an
+    erasure-coded one only with allow_ec_overwrites
 """
 
 from __future__ import annotations
 
 import binascii
 import json
+import threading
 
+from ceph_tpu.common.lockdep import make_lock
 from ceph_tpu.osdc.journaler import Journaler
 from ceph_tpu.osdc.striper import StripeLayout, StripedObject
 
@@ -51,6 +57,11 @@ class Image:
         self.io = ioctx
         self.name = name
         self._meta = None
+        self._data_ioctx = None
+        #: the data objects' high-water mark this handle last saw, and
+        #: the image size it saw it at: aio_write reads it again past
+        #: it, or once the image was resized
+        self._hwm = (0, 0)
 
     # -- lifecycle ------------------------------------------------------------
 
@@ -58,10 +69,22 @@ class Image:
     def create(cls, ioctx, name: str, size: int,
                order: int = 22, stripe_unit: int = 1 << 16,
                stripe_count: int = 4, primary: bool = True,
-               features: list[str] | None = None) -> "Image":
+               features: list[str] | None = None,
+               data_pool=None) -> "Image":
         """order = log2(object size), like rbd create --order.
         primary=False creates a demoted replication target atomically
-        (no primary window for a mirror-daemon crash to leave open)."""
+        (no primary window for a mirror-daemon crash to leave open).
+        `data_pool` (an IoCtx; rbd create --data-pool) holds the data
+        objects; an erasure-coded pool must allow overwrites."""
+        if data_pool is not None:
+            pool = data_pool.client.osdmap.pools.get(data_pool.pool_id)
+            if pool is None:
+                raise OSError(2, f"data pool {data_pool.pool_id} "
+                                 "does not exist")
+            if pool.is_erasure() and not pool.allows_ecoverwrites():
+                raise OSError(95, f"data pool {data_pool.pool_id} is "
+                                  "erasure-coded without "
+                                  "allow_ec_overwrites")
         header = cls.HEADER_FMT.format(name=name)
         exists = True
         try:
@@ -74,6 +97,8 @@ class Image:
                 "stripe_unit": stripe_unit,
                 "stripe_count": stripe_count, "snaps": {},
                 "features": list(features or []), "primary": primary}
+        if data_pool is not None:
+            meta["data_pool"] = data_pool.pool_id
         ioctx.write_full(header, json.dumps(meta).encode())
         ioctx.set_omap(RBD_DIRECTORY, {name: b"1"})
         img = cls(ioctx, name)
@@ -94,13 +119,24 @@ class Image:
             self._meta = json.loads(blob.decode())
         return self._meta
 
+    def _data_io(self):
+        """The IoCtx of the data objects: the data pool's, or the
+        image's own pool."""
+        pool = self._load().get("data_pool")
+        if pool is None:
+            return self.io
+        if self._data_ioctx is None:
+            self._data_ioctx = self.io.client.open_ioctx(pool)
+        return self._data_ioctx
+
     def _striped(self) -> StripedObject:
         m = self._load()
         layout = StripeLayout(stripe_unit=m["stripe_unit"],
                               stripe_count=m["stripe_count"],
                               object_size=1 << m["order"])
-        return StripedObject(self.io, self.DATA_FMT.format(name=self.name),
-                             layout)
+        return StripedObject(self._data_io(),
+                             self.DATA_FMT.format(name=self.name),
+                             layout, meta_io=self.io)
 
     # -- features / journaling (librbd/Journal.h:43 analog) -------------------
 
@@ -247,6 +283,13 @@ class Image:
             self._om_cache = None
 
     def write(self, data: bytes, offset: int = 0) -> int:
+        self._write_gate(data, offset)
+        self._striped().write(data, offset)
+        return len(data)
+
+    def _write_gate(self, data: bytes, offset: int) -> None:
+        """What a write does before its data: primary and lock checks,
+        journal, object map, copy-up."""
         self._check_primary()   # refreshes the header cache too
         m = self._load()
         if offset + len(data) > m["size"]:
@@ -256,8 +299,30 @@ class Image:
                              "data": binascii.hexlify(data).decode()})
         self._om_mark_write(offset, len(data))
         self._copyup(offset, len(data))
-        self._striped().write(data, offset)
-        return len(data)
+
+    def aio_write(self, data: bytes, offset: int = 0):
+        """librbd rbd_aio_write: the gating of write(), then a ranged
+        write of each object extent, all in flight at once.  Returns
+        the object's AioCompletion, or one over all of them when the
+        write crosses objects."""
+        self._write_gate(data, offset)
+        st = self._striped()
+        end = offset + len(data)
+        hwm, at = self._hwm
+        if end > hwm or at != self._load()["size"]:
+            hwm = st.size()
+            if end > hwm:
+                st._set_size(end)
+                hwm = end
+            self._hwm = (hwm, self._load()["size"])
+        parts = []
+        pos = 0
+        for objno, obj_off, n in st.layout.extents(offset, len(data)):
+            parts.append(st.io.aio_write(
+                st.striper.object_name(st.name, objno),
+                data[pos:pos + n], obj_off))
+            pos += n
+        return parts[0] if len(parts) == 1 else ImageCompletion(parts)
 
     def mirror_apply(self, event: dict) -> None:
         """Apply one replayed journal event (rbd-mirror's Replayer):
@@ -370,6 +435,11 @@ class Image:
         self._meta = m
 
     def snap_create(self, snap: str) -> int:
+        if self._load().get("data_pool") is not None:
+            # the pool snapshots below would freeze the header's pool,
+            # not the data pool's objects
+            raise OSError(95, "snapshots of an image with a data pool "
+                              "are not supported")
         self._check_primary()
         snapid = self._snap_create_internal(snap)
         # journal AFTER the mon op succeeds: a failed snap must never
@@ -587,7 +657,7 @@ class Image:
 
     def _obj_exists(self, objno: int) -> bool:
         try:
-            self.io.stat(self._obj_name(objno))
+            self._data_io().stat(self._obj_name(objno))
             return True
         except OSError:
             return False
@@ -769,7 +839,7 @@ class Image:
         found = 0
         for objno in range(om.n_objs):
             try:
-                self.io.stat(st.striper.object_name(st.name, objno))
+                st.io.stat(st.striper.object_name(st.name, objno))
             except OSError:
                 continue
             om.set(objno, OBJECT_EXISTS)
@@ -918,6 +988,64 @@ class Image:
         except OSError:
             pass
         self._meta = None
+
+
+class ImageCompletion:
+    """A librbd AioCompletion over the object writes of one image
+    write: complete when they all are; the first failure is its
+    return value.  ``_w.event`` is set with the last part, as a
+    rados completion's waiter event is set with its reply."""
+
+    def __init__(self, parts: list):
+        self._parts = parts
+        self._w = _Whole()
+        self._left = len(parts)
+        self._mu = make_lock("rbd::ImageCompletion")
+        for part in parts:
+            theirs, part._w.event = part._w.event, _PartDone(self)
+            if theirs.is_set():
+                part._w.event.set()
+
+    def _part_done(self) -> None:
+        with self._mu:
+            self._left -= 1
+            last = self._left == 0
+        if last:
+            self._w.event.set()
+
+    def is_complete(self) -> bool:
+        return self._w.event.is_set()
+
+    def wait_for_complete(self, timeout: float | None = None) -> bool:
+        return self._w.event.wait(timeout)
+
+    def get_return_value(self) -> int:
+        return min(p.get_return_value() for p in self._parts)
+
+    def cancel(self) -> None:
+        for part in self._parts:
+            if not part.is_complete():
+                part.cancel()
+
+
+class _Whole:
+    def __init__(self):
+        self.event = threading.Event()
+
+
+class _PartDone(threading.Event):
+    """A part's waiter event that tells its image completion."""
+
+    def __init__(self, whole: ImageCompletion):
+        super().__init__()
+        self._whole = whole
+        self._told = False
+
+    def set(self) -> None:
+        super().set()
+        if not self._told:
+            self._told = True
+            self._whole._part_done()
 
 
 def list_images(ioctx, probe: list[str] | None = None) -> list[str]:

@@ -379,16 +379,24 @@ class MiniCluster:
         raise TimeoutError(f"never saw {n} up osds")
 
     def create_pool(self, client: RadosClient, *,
-                    epoch_timeout: float = 10.0, **cmd) -> int:
+                    epoch_timeout: float = 10.0,
+                    ec_overwrites: bool = False, **cmd) -> int:
         """``epoch_timeout``: a new pool's first map application can
         pay a cold jit trace+compile inside _handle_map (the fused
         placement ladder, when osdmap_mapping_min_pgs admits toy
         pools) — tens of seconds on a 1-core host; callers running
-        fused-on-toy-pools setups pass a compile-sized timeout."""
+        fused-on-toy-pools setups pass a compile-sized timeout.
+        ``ec_overwrites``: then `osd pool set <pool>
+        allow_ec_overwrites true` (BlueStore OSDs only)."""
         res, out = client.mon_command(
             dict({"prefix": "osd pool create"}, **cmd))
         assert res == 0, out
         pool_id = int(out.split()[1])
+        if ec_overwrites:
+            res, out = client.mon_command({
+                "prefix": "osd pool set", "pool": str(pool_id),
+                "var": "allow_ec_overwrites", "val": "true"})
+            assert res == 0, out
         epoch = self.mon.osdmap.epoch
         self.wait_for_epoch(epoch, timeout=epoch_timeout)
         client.wait_for_epoch(epoch)

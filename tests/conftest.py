@@ -99,6 +99,30 @@ _OUTGROWN = {
         "PR 39 appended seven behind them (test_perfbench_offcpu.py "
         "holds the cell's metrics and the older lists to the same "
         "contract again)",
+    # PR 41's configuration, cell and three metrics (the new entries
+    # are held to the same contract by test_perfbench_rbd_overwrite.py,
+    # which also holds the older ones where these cases held them)
+    ("test_perfbench_manifest.py",
+     "test_configuration_entry_and_file[rbd-ec84-overwrite]"):
+        "the list of systems in test_perfbench_manifest.py is closed "
+        "and lacks rbd_ec_overwrite",
+    ("test_perfbench_manifest.py",
+     "test_cell_entry_and_traffic_file[rbdec84.randwrite_4k_t1]"):
+        "the list of traffic kinds in test_perfbench_manifest.py is "
+        "closed and lacks closed_loop_rbd_write",
+    ("test_perfbench_reshape.py",
+     "test_the_configuration_is_the_1m_ones_with_a_spare_host_and_nothing_cut"):
+        "it takes the reshape configuration and cell for the last of "
+        "their lists and counts seven cells: rbd-ec84-overwrite and "
+        "rbdec84.randwrite_4k_t1 come after them",
+    ("test_perfbench_offcpu.py",
+     "test_the_manifest_lists_the_seven_on_the_three_ec_cells"):
+        "it takes PR 39's seven metrics for the last seven of per_layer: "
+        "PR 41 appended three behind them",
+    ("test_perfbench_offcpu.py",
+     "test_the_older_entries_are_as_they_were_before_the_seven"):
+        "it finds PR 37's three by their place from the end of "
+        "per_layer: PR 41 appended three behind the seven",
 }
 
 

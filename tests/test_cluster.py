@@ -18,6 +18,17 @@ def cluster():
     c.stop()
 
 
+@pytest.fixture
+def blue_cluster(tmp_path):
+    """BlueStore OSDs: an erasure pool takes overwrites only there
+    (allow_ec_overwrites)."""
+    c = MiniCluster(n_osds=3, ms_type="loopback", store_type="bluestore",
+                    base_path=str(tmp_path)).start()
+    c.wait_for_osd_count(3)
+    yield c
+    c.stop()
+
+
 def test_cluster_forms(cluster):
     st = cluster.mon.status()
     assert st["num_up_osds"] == 3
@@ -276,12 +287,14 @@ def test_shec_and_clay_pools_end_to_end():
         c.stop()
 
 
-def test_ec_partial_write_rmw(cluster):
-    """OP_WRITE at arbitrary offsets on an EC pool round-trips through
-    the stripe-aligned read-modify-write pipeline (ECBackend start_rmw)."""
+def test_ec_partial_write_rmw(blue_cluster):
+    """OP_WRITE at arbitrary offsets on an EC pool with overwrites
+    round-trips through the stripe-ranged read-modify-write pipeline
+    (ECBackend start_rmw)."""
+    cluster = blue_cluster
     client = cluster.client()
     pool = cluster.create_pool(client, pg_num=4, pool_type="erasure",
-                               k=2, m=1)
+                               k=2, m=1, ec_overwrites=True)
     io = client.open_ioctx(pool)
     base = bytearray(b"A" * 20000)
     io.write_full("rmw", bytes(base))
@@ -353,12 +366,14 @@ def test_ec_corrupt_shard_detected_and_reconstructed(cluster):
     assert cur != bytes(blob), "corrupt shard never repaired"
 
 
-def test_ec_bitmatrix_technique_pool(cluster):
+def test_ec_bitmatrix_technique_pool(blue_cluster):
     """Bitmatrix techniques need chunk % w == 0: the stripe unit rounds
     up to the codec's alignment quantum (w=7 for liberation)."""
+    cluster = blue_cluster
     client = cluster.client()
     pool = cluster.create_pool(client, pg_num=4, pool_type="erasure",
-                               k=2, m=2, technique="liberation")
+                               k=2, m=2, technique="liberation",
+                               ec_overwrites=True)
     io = client.open_ioctx(pool)
     payload = b"w-aligned-stripes" * 700
     io.write_full("lb", payload)

@@ -14,8 +14,10 @@ from ceph_tpu.tools.vstart import MiniCluster
 
 
 @pytest.fixture
-def cluster():
-    c = MiniCluster(n_osds=3, ms_type="loopback").start()
+def cluster(tmp_path):
+    # BlueStore OSDs: an erasure pool takes overwrites only there
+    c = MiniCluster(n_osds=3, ms_type="loopback", store_type="bluestore",
+                    base_path=str(tmp_path)).start()
     c.wait_for_osd_count(3)
     yield c
     c.stop()
@@ -31,7 +33,7 @@ def _counter(cluster, name: str) -> int:
 def test_overlapping_writes_one_gather(cluster):
     client = cluster.client()
     pool = cluster.create_pool(client, pg_num=1, pool_type="erasure",
-                               k=2, m=1)
+                               k=2, m=1, ec_overwrites=True)
     io = client.open_ioctx(pool)
     base = bytes(16384)
     io.write_full("pipe", base)
@@ -62,7 +64,7 @@ def test_overlapping_writes_one_gather(cluster):
 def test_pipelined_writefull_replaces_projected_base(cluster):
     client = cluster.client()
     pool = cluster.create_pool(client, pg_num=1, pool_type="erasure",
-                               k=2, m=1)
+                               k=2, m=1, ec_overwrites=True)
     io = client.open_ioctx(pool)
     io.write_full("wf", b"A" * 8192)
 
@@ -87,7 +89,7 @@ def test_interleaved_objects_do_not_cross_pipeline(cluster):
     # other's projected bases
     client = cluster.client()
     pool = cluster.create_pool(client, pg_num=2, pool_type="erasure",
-                               k=2, m=1)
+                               k=2, m=1, ec_overwrites=True)
     io = client.open_ioctx(pool)
     rng = np.random.default_rng(11)
     bases = {}
@@ -116,7 +118,7 @@ def test_burst_survives_repeat(cluster):
     # from committed state between bursts)
     client = cluster.client()
     pool = cluster.create_pool(client, pg_num=1, pool_type="erasure",
-                               k=2, m=1)
+                               k=2, m=1, ec_overwrites=True)
     io = client.open_ioctx(pool)
     expected = bytearray(4096)
     io.write_full("rep", bytes(expected))

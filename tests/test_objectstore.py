@@ -246,7 +246,7 @@ def test_bluestore_cluster_end_to_end(tmp_path):
         io.write_full("b", b"bluestore-backed" * 100)
         assert io.read("b") == b"bluestore-backed" * 100
         ec = c.create_pool(client, pg_num=4, pool_type="erasure",
-                           k=2, m=1)
+                           k=2, m=1, ec_overwrites=True)
         io2 = client.open_ioctx(ec)
         io2.write_full("e", b"E" * 9000)
         io2.write("e", b"Z" * 2000, offset=4000)

@@ -226,16 +226,19 @@ class TestIciStack:
     payloads ride cross-device placement while the daemons run the same
     code path as on tcp/loopback."""
 
-    def test_ec_over_ici_mesh(self):
+    def test_ec_over_ici_mesh(self, tmp_path):
         from ceph_tpu.msg.ici import IciTransport
         t = IciTransport.instance()
         before = (t.transfers, t.bytes_staged)
-        c = MiniCluster(n_osds=4, ms_type="ici").start()
+        # BlueStore OSDs: the partial rmw below needs allow_ec_overwrites
+        c = MiniCluster(n_osds=4, ms_type="ici", store_type="bluestore",
+                        base_path=str(tmp_path)).start()
         try:
             c.wait_for_osd_count(4)
             client = c.client(timeout=15.0)
             pool = c.create_pool(client, pg_num=4,
-                                 pool_type="erasure", k=2, m=2)
+                                 pool_type="erasure", k=2, m=2,
+                                 ec_overwrites=True)
             io = client.open_ioctx(pool)
             payload = bytes(range(256)) * 128     # 32 KiB
             io.write_full("mesh-obj", payload)
