@@ -269,11 +269,12 @@ def _compact_rows(cols, result_max: int):
     return jnp.stack(planes, axis=1)
 
 
-#: lanes the XLA path pads a bucket to (the Pallas kernels pad to
-#: their 128-lane vreg, ``pallas_straw2._pad_lanes``): a shape class
-#: holds every map whose root and widest host round up to the same
-#: multiples, so a host or an OSD added inside the padding reuses the
-#: class's compiled program
+#: lanes the XLA path pads a bucket to (the Pallas kernels pad the
+#: root to their 128-lane vreg, ``pallas_straw2._pad_lanes``, and a
+#: host to the leaf kernel's group width, ``_leaf_lanes``): a shape
+#: class holds every map whose root and widest host round up alike, so
+#: a host or an OSD added inside the padding reuses the class's
+#: compiled program
 XLA_LANES = 8
 
 
@@ -294,14 +295,24 @@ class FastShape:
     """What a fast-path program is traced for, and nothing of a map's
     content: the rule's kind and retry constants and the padded table
     shapes.  Maps of one FastShape share one FastMapper and its
-    compiled programs."""
+    compiled programs.
+
+    On the Pallas route ``leaf_lanes`` is the leaf kernel's group
+    width: the widest host's items rounded up to 32 or 64, where
+    128 / width r-columns share a 128-lane slab, and past 64 items to
+    a multiple of 128 (one column over one slab or more).  An edit
+    that keeps the widest host inside its width serves on the class's
+    programs; one that grows it past 32, 64, 128, 256 ... items
+    crosses a class and compiles once.  The root pads to 128 lanes and
+    crosses at 128, 256 ... hosts; the XLA route pads both to
+    ``XLA_LANES``."""
 
     kind: str                 # "chooseleaf" | "choose_flat"
     numrep_arg: int
     tries: int
     vary_r: int
     root_lanes: int           # padded root items (= leaf-table rows)
-    leaf_lanes: int           # padded items a host; 0 for a flat rule
+    leaf_lanes: int           # a host's lanes (above); 0 for a flat rule
     #: the fused Pallas column kernels (2.5x the XLA path on a TPU);
     #: the CPU mesh tests keep the XLA path
     pallas: bool
@@ -315,11 +326,13 @@ def shape_of(fr: FastRule, pallas: bool | None = None,
     if pallas is None:
         pallas = _on_tpu()
     if pallas:
-        from ceph_tpu.ops.pallas_straw2 import _pad_lanes as pad
+        from ceph_tpu.ops.pallas_straw2 import _leaf_lanes, _pad_lanes
+        pad, pad_leaf = _pad_lanes, _leaf_lanes
     else:
         def pad(n):
             return -(-n // XLA_LANES) * XLA_LANES
-    leaf = 0 if fr.leaf_ids is None else pad(fr.leaf_ids.shape[1])
+        pad_leaf = pad
+    leaf = 0 if fr.leaf_ids is None else pad_leaf(fr.leaf_ids.shape[1])
     return FastShape(fr.kind, fr.numrep_arg, fr.tries, fr.vary_r,
                      pad(len(fr.root_ids)), leaf, bool(pallas),
                      bool(interpret))
@@ -395,6 +408,11 @@ def tables_of(fr: FastRule, pallas: bool | None = None,
     """A rule's tables, built for its shape class on this backend (or
     on the path named: the chip's cross-validation runs both)."""
     shape = shape_of(fr, pallas, interpret)
+    if shape.pallas and shape.leaf_lanes:
+        from ceph_tpu.ops.pallas_straw2 import _columns_per_slab
+        telemetry.mapping_stats().record_leaf_layout(
+            _columns_per_slab(shape.leaf_lanes),
+            fr.leaf_ids.shape[1] / shape.leaf_lanes)
     return FastTables(shape, build_tables(fr, shape))
 
 

@@ -689,6 +689,14 @@ class Module(MgrModule):
                     "CRUSH and ladder programs traced anew (stands "
                     "still while map edits stay inside a shape class)",
                     d.get("crush_program_builds", 0))
+        exp.gauge(f"{p}_crush_leaf_columns_per_slab",
+                  "r-columns one 128-lane slab of the leaf column "
+                  "kernel carries (newest Pallas chooseleaf tables)",
+                  d.get("crush_leaf_columns_per_slab", 0))
+        exp.gauge(f"{p}_crush_leaf_lane_fill",
+                  "the widest host's items over the lanes its leaf "
+                  "column draws (newest Pallas chooseleaf tables)",
+                  d.get("crush_leaf_lane_fill", 0.0))
         exp.gauge(f"{p}_host_tail_share",
                   "host-tail share of the total mapping epoch cost "
                   "(device + delta + host_tail) — collapses toward 0 "

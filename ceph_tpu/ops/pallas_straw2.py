@@ -11,7 +11,10 @@ and first-min winner select — into one VMEM-resident Pallas program per
   root kernel:  xs block -> winner position/id per r
   leaf kernel:  root winner position -> the winning host's device row
                 (fetched with an exact f32 one-hot MXU dot — a vectorized
-                row gather the VPU cannot do) -> device winner
+                row gather the VPU cannot do) -> device winner; a host
+                takes the group width g of the widest host (32 or 64
+                lanes), so one 128-lane slab draws 128 // g r-columns
+                and a grid step is (block, slab)
 
 The is_out verdicts of the winners are NOT the kernels': the fast path
 computes them in XLA over the winner planes the kernels return
@@ -272,48 +275,166 @@ def _root_kernel(xs_ref, ids_ref, wz_ref, magic_ref, off_ref,
     _store_row(id_ref, r, wid)
 
 
-def _leaf_kernel(xs_ref, pos_ref, static_ref,
+#: fields of a host row in the leaf table, each ``L`` lanes wide:
+#: item ids, zero-weight mask, limb offset, the five magic limbs
+_LEAF_FIELDS = 8
+
+#: bits of an item's position in a column in the leaf kernel's packed
+#: draw key (the low bits of its second word)
+_POS_BITS = 15
+
+
+def _leaf_lanes(items: int) -> int:
+    """The leaf kernel's group width for a widest host of ``items``: the
+    power of two at or above it, at least 32, while 128 // it columns
+    share a 128-lane slab; past 64 items whole slabs (``_pad_lanes``).
+    At 32 a slab's four columns' one-hot products fit the scoped VMEM
+    side by side; eight of 16 lanes would overrun it."""
+    if items > 64:
+        return _pad_lanes(items)
+    return max(32, 1 << max(0, items - 1).bit_length())
+
+
+def _columns_per_slab(leaf_lanes: int) -> int:
+    """r-columns one 128-lane slab of the leaf kernel carries."""
+    return 128 // leaf_lanes if 0 < leaf_lanes < 128 else 1
+
+
+def _draw_key(q_hi, q_lo, bad, pos):
+    """(q_hi, q_lo, position) as one lexicographic two-word key, each
+    word sign-biased to i32 so that plain signed compares order it:
+    q_hi < 2^17 (Q <= 2^48) and the position fits _POS_BITS; a zero
+    weight draws above every real item, still ordered by position."""
+    hi = jnp.where(bad, _U32(0xFFFFFFFF), (q_hi << 15) | (q_lo >> 17))
+    lo = jnp.where(bad, _U32(0xFFFF8000), (q_lo & _U32(0x1FFFF)) << 15) \
+        | pos.astype(_U32)
+    bias = _U32(0x80000000)
+    return (hi ^ bias).astype(_I32), (lo ^ bias).astype(_I32)
+
+
+def _key_less(ah, al, bh, bl):
+    return (ah < bh) | ((ah == bh) & (al < bl))
+
+
+def _segment_first_min(kh, kl, width):
+    """Segmented minimum of the draw keys over aligned groups of
+    ``width`` lanes, all groups at once: a roll-and-select ladder of
+    log2(width) steps after which the first lane of each group holds its
+    group's key (other lanes hold partial minima).  Ties cannot occur —
+    the key carries the position — so the lowest position wins them."""
+    d = 1
+    while d < width:
+        # lane l looks at lane l + d (jnp.roll semantics: shift 128 - d)
+        ph = pltpu.roll(kh, _I32(128 - d), 1)
+        pl_ = pltpu.roll(kl, _I32(128 - d), 1)
+        take = _key_less(ph, pl_, kh, kl)
+        kh = jnp.where(take, ph, kh)
+        kl = jnp.where(take, pl_, kl)
+        d *= 2
+    return kh, kl
+
+
+def _leaf_kernel(xs_ref, pos_ref, fields_ref,
                  rhlh_ref, ll_lo_ref, ll_hi_ref,
-                 id_ref, *, H, S, vary_r, rh128):
-    r = pl.program_id(1)
+                 id_ref, *, H, L, R, vary_r, rh128):
+    """Grid (n//B, ceil(R/G)): one 128-lane slab of G = 128 // L
+    r-columns a step (G = 1 and L // 128 slabs a column for hosts past
+    64 items).  Lane l of a slab is item l mod L of column
+    r = G * step + l // L, drawn from that column's host."""
+    G = _columns_per_slab(L)
+    s = pl.program_id(1)
+    x = xs_ref[0, :]
+    B = x.shape[0]
+    lane = jax.lax.broadcasted_iota(_I32, (1, 128), 1)
+    group = lane // _I32(L) if G > 1 else jnp.zeros_like(lane)
     if vary_r:
-        r_leaf = (r >> (vary_r - 1)).astype(_U32)
+        r_leaf = ((s * G + group) >> (vary_r - 1)).astype(_U32)
     else:
         r_leaf = _U32(0)
-    x = xs_ref[0, :]
     iota = jax.lax.broadcasted_iota(_I32, (1, H), 1)
-    tabs = (rhlh_ref, ll_lo_ref, ll_hi_ref, rh128)
-    pos = pos_ref[pl.dslice(r, 1), :][0, :]   # this r's root winners
-    # exact f32 one-hot row gather of the winning host's packed
-    # fields: [ids | wz | off | magic0..magic4] (each S wide) — a
-    # vectorized row gather on the MXU
-    oh = jnp.where(pos[:, None] == iota, jnp.float32(1.0),
-                   jnp.float32(0.0))
-    # HIGHEST precision: the default TPU matmul truncates f32 operands
-    # to bf16, mangling ids and 16-bit magic limbs
-    rows = jnp.dot(oh, static_ref[...],
-                   preferred_element_type=jnp.float32,
-                   precision=jax.lax.Precision.HIGHEST)   # (B, 8*S)
 
-    def operands(slab):
-        sl = slice(slab * 128, (slab + 1) * 128)
-        # f32 -> u32 is an unhandled Mosaic cast; go via i32 (limb
-        # values < 2^16, so fptosi is exact)
-        return (rows[:, sl].astype(_I32),
-                rows[:, S + slab * 128:S + (slab + 1) * 128]
-                .astype(_I32),
-                [rows[:, (3 + j) * S + slab * 128:
-                      (3 + j) * S + (slab + 1) * 128]
-                 .astype(_I32).astype(_U32) for j in range(5)],
-                rows[:, 2 * S + slab * 128:2 * S + (slab + 1) * 128]
-                .astype(_I32))
+    def fetch(k):
+        """Column k's winning hosts' rows: an exact f32 one-hot row
+        gather on the MXU of the fields [ids | wz | off | magic0..4]
+        (each L wide).  HIGHEST precision: the default TPU matmul
+        truncates f32 operands to bf16, mangling ids and 16-bit magic
+        limbs."""
+        pos = pos_ref[pl.dslice(s * G + k, 1), :][0, :]
+        oh = jnp.where(pos[:, None] == iota, jnp.float32(1.0),
+                       jnp.float32(0.0))
+        return jnp.dot(oh, fields_ref[...],
+                       preferred_element_type=jnp.float32,
+                       precision=jax.lax.Precision.HIGHEST)
 
-    def rw_of(slab, first):
-        return jnp.zeros((x.shape[0],), dtype=_I32)
+    def lay_out(k, rows_k, fields, slab=0):
+        """Column k's fields onto its group's lanes [k L, (k + 1) L) of
+        the (B, 128) field slabs.  f32 -> u32 is an unhandled Mosaic
+        cast; go via i32 (values < 2^16, exact)."""
+        out = []
+        for f in range(_LEAF_FIELDS):
+            col = f * L + slab * 128
+            base = col - col % 128
+            piece = rows_k[:, base:base + 128].astype(_I32)
+            if G > 1:
+                piece = pltpu.roll(
+                    piece, (k * L + 128 - col % 128) & _I32(127), 1)
+                piece = jnp.where(group == k, piece, fields[f])
+            out.append(piece)
+        return out
 
-    _qh, _ql, _pos_l, wid, _rwv = _column_over_slabs(
-        x, S, tabs, r_leaf, operands, rw_of)
-    _store_row(id_ref, r, wid)
+    def may_be_empty(k):
+        """Group k of the last slab lies past R when R is not a
+        multiple of G: it is neither fetched nor stored."""
+        return R % G and k >= R % G
+
+    if G == 1:
+        rows = fetch(0)
+    else:
+        # the columns unrolled, so their lane rolls are static: in a
+        # loop they are dynamic rotates, and the cells' leaf call took
+        # 95.0 ms where this takes 61.9 on a v5e (PERF.md)
+        fields = [jnp.zeros((B, 128), _I32)] * _LEAF_FIELDS
+        for k in range(G):
+            if may_be_empty(k):
+                fields = jax.lax.cond(
+                    s * G + k < R,
+                    lambda fl, k=k: lay_out(k, fetch(k), fl),
+                    lambda fl: list(fl), fields)
+            else:
+                fields = lay_out(k, fetch(k), fields)
+    best = None
+    for slab in range(max(1, L // 128)):
+        if G == 1:
+            fields = lay_out(0, rows, None, slab)
+        ids, wz, off = fields[:3]
+        magic = [m.astype(_U32) for m in fields[3:]]
+        u = hash32_3(x[:, None], ids, r_leaf) & _U32(0xFFFF)
+        p_hi, p_lo = _ln_p48_pl(u, rhlh_ref, ll_lo_ref, ll_hi_ref, rh128)
+        q_hi, q_lo = _magic_div_pl(p_hi, p_lo, magic, off)
+        kh, kl = _segment_first_min(
+            *_draw_key(q_hi, q_lo, wz != 0, lane % _I32(min(L, 128))
+                       + _I32(slab * 128)), min(L, 128))
+        # the winner's lane in this slab, from the first lane of a group
+        at = (lane + (kl & _I32((1 << _POS_BITS) - 1))
+              - _I32(slab * 128)) & _I32(127)
+        wid = _row_lookup(jnp.broadcast_to(at, (B, 128)), ids)
+        if best is None:
+            best = (kh, kl, wid)
+        else:
+            take = _key_less(kh, kl, best[0], best[1])
+            best = tuple(jnp.where(take, a, b)
+                         for a, b in zip((kh, kl, wid), best))
+    # a group's answer sits on its first lane: lanes onto sublanes
+    out = best[2].T                                     # (128, B)
+    for k in range(G):
+        r = s * G + k
+
+        def store(k=k, r=r):
+            id_ref[pl.dslice(r, 1), :] = out[k * L:k * L + 1, :]
+        if may_be_empty(k):
+            pl.when(r < R)(store)
+        else:
+            store()
 
 
 # ---------------------------------------------------------------------------
@@ -712,7 +833,7 @@ def pack_tables(ids, w, lids=None, lw=None) -> tuple:
 
         root ids (1, S) | root zero-weight mask (1, S) | root weights
         as f32 (1, S) | root magic limbs (5, S) | root limb offsets
-        (1, S) [| leaf_static (S, 9 * L) f32, chooseleaf rules only]
+        (1, S) [| leaf fields (S, 8 * L) f32, chooseleaf rules only]
 
     The order is the kernels' ``tables`` argument.  Pure host work
     (the magic divisors are the cost): a changed map of the same class
@@ -725,16 +846,14 @@ def pack_tables(ids, w, lids=None, lw=None) -> tuple:
               off.astype(np.int32)[None, :]]
     if lids is not None:
         l_limbs, l_off = magic_tables(lw)
-        # packed static per-host fields, all exact in f32 except the
-        # raw weight column (col 8), whose f32 rounding the approx
-        # filter's margin absorbs
+        # a host's fields, _LEAF_FIELDS blocks of L lanes, every value
+        # exact in f32 (ids < 2^24, limbs < 2^16)
         tables.append(np.concatenate([
             lids.astype(np.float32),
             (lw <= 0).astype(np.float32),
             l_off.astype(np.float32),
-        ] + [l_limbs[..., j].astype(np.float32) for j in range(5)]
-          + [lw.astype(np.float32)],
-            axis=1))                                   # (S, 9 * L)
+        ] + [l_limbs[..., j].astype(np.float32) for j in range(5)],
+            axis=1))                                   # (S, 8 * L)
     return tuple(tables)
 
 
@@ -742,7 +861,8 @@ class PallasColumns:
     """The winner-precompute kernels of one shape class on the TPU
     backend: ``root_lanes`` padded root items (the one-hot dot of the
     leaf kernel wants 128-multiples, so they are the leaf table's host
-    rows too) and ``leaf_lanes`` padded items a host (0: a flat rule).
+    rows too) and ``leaf_lanes`` the leaf kernel's group width
+    (``_leaf_lanes`` of the widest host; 0: a flat rule).
     The bucket tables are an argument of every call (``pack_tables``),
     never state of this object: the programs built around these calls
     serve every map of the class.
@@ -756,6 +876,10 @@ class PallasColumns:
         self.interpret = interpret
         self.S_root = self.H = root_lanes
         self.S_leaf = leaf_lanes
+        self.columns_per_slab = _columns_per_slab(leaf_lanes)
+        if leaf_lanes >= 1 << _POS_BITS:
+            raise ValueError(f"{leaf_lanes} leaf lanes overflow the "
+                             f"leaf kernel's {_POS_BITS}-bit positions")
         self.vary_r = vary_r
         rh, self.rh128, ll_lo, ll_hi = _ln_tables_rows()
         self.tabs = (jnp.asarray(rh), jnp.asarray(ll_lo),
@@ -839,30 +963,32 @@ class PallasColumns:
         return pos, ids, ovf[0]
 
     def leaf_columns(self, xs, root_pos, tables, R: int):
-        """root winner positions -> leaf_id (R, N).  is_out verdicts are
-        computed by the caller in XLA (out_columns)."""
-        leaf_static = tables[5]
+        """root winner positions -> leaf_id (R, N): ``columns_per_slab``
+        r-columns a grid step.  is_out verdicts are computed by the
+        caller in XLA (out_columns)."""
+        leaf_fields = tables[5]
+        L, G = self.S_leaf, self.columns_per_slab
         # root_pos comes back padded from root_columns; re-pad from the
         # caller's batch width so both land on the same quantum
         root_pos = root_pos[:, :xs.shape[0]]
         xs, n, B, root_pos = _pad_block(xs, root_pos)
-        grid = (n // B, R)
+        grid = (n // B, -(-R // G))
         outs = [jax.ShapeDtypeStruct((R, n), jnp.int32)]
-        out_specs = [pl.BlockSpec((R, B), lambda i, r: (jnp.int32(0), i))]
+        out_specs = [pl.BlockSpec((R, B), lambda i, s: (jnp.int32(0), i))]
         fs = self._fullspec
         rh, ll_lo, ll_hi = self.tabs
         (lid,) = pl.pallas_call(
-            functools.partial(_leaf_kernel, H=self.H, S=self.S_leaf,
+            functools.partial(_leaf_kernel, H=self.H, L=L, R=R,
                               vary_r=self.vary_r,
                               rh128=self.rh128),
             grid=grid,
             out_shape=outs,
-            in_specs=[pl.BlockSpec((1, B), lambda i, r: (jnp.int32(0), i)),
-                      pl.BlockSpec((R, B), lambda i, r: (jnp.int32(0), i)),
-                      fs((self.H, 9 * self.S_leaf)),
+            in_specs=[pl.BlockSpec((1, B), lambda i, s: (jnp.int32(0), i)),
+                      pl.BlockSpec((R, B), lambda i, s: (jnp.int32(0), i)),
+                      fs((self.H, _LEAF_FIELDS * L)),
                       fs(rh.shape), fs(ll_lo.shape), fs(ll_hi.shape)],
             out_specs=out_specs,
             interpret=self.interpret,
-        )(xs[None, :], root_pos, leaf_static,
+        )(xs[None, :], root_pos, leaf_fields,
           rh, ll_lo, ll_hi)
         return lid

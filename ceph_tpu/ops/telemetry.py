@@ -753,7 +753,11 @@ class MappingStats:
     ``crush_program_builds`` the CRUSH and ladder programs traced anew
     (each a compile or a cache load; the first of the process
     included) — which stands still while edits stay inside a shape
-    class.
+    class.  Two gauges say how the newest Pallas chooseleaf tables lay
+    a host out in the leaf kernel (``crush.fastpath.tables_of``):
+    ``crush_leaf_columns_per_slab``, the r-columns one 128-lane slab
+    carries, and ``crush_leaf_lane_fill``, the widest host's items over
+    the lanes its column draws.
     """
 
     __slots__ = ("_lock", "epoch_updates", "epoch_skips",
@@ -764,7 +768,8 @@ class MappingStats:
                  "fused_epochs", "unfused_epochs", "fused_lookups",
                  "delta_device_diffs", "delta_host_diffs",
                  "delta_upload_bytes", "crush_table_builds",
-                 "crush_table_upload_bytes", "crush_program_builds")
+                 "crush_table_upload_bytes", "crush_program_builds",
+                 "crush_leaf_columns_per_slab", "crush_leaf_lane_fill")
 
     def __init__(self):
         self._lock = lockdep.make_lock("MappingStats::lock")
@@ -795,6 +800,8 @@ class MappingStats:
         self.crush_table_builds = 0
         self.crush_table_upload_bytes = 0
         self.crush_program_builds = 0
+        self.crush_leaf_columns_per_slab = 0     # gauges (see above)
+        self.crush_leaf_lane_fill = 0.0
 
     def clear(self) -> None:
         with self._lock:
@@ -816,6 +823,8 @@ class MappingStats:
             self.crush_table_builds = 0
             self.crush_table_upload_bytes = 0
             self.crush_program_builds = 0
+            self.crush_leaf_columns_per_slab = 0
+            self.crush_leaf_lane_fill = 0.0
 
     def record_crush_table_build(self) -> None:
         """One host build of a crush map's bucket tables."""
@@ -826,6 +835,14 @@ class MappingStats:
         """Bucket tables put on a device (or replicated over a mesh)."""
         with self._lock:
             self.crush_table_upload_bytes += int(nbytes)
+
+    def record_leaf_layout(self, columns_per_slab: int,
+                           lane_fill: float) -> None:
+        """The leaf kernel's layout of the newest Pallas chooseleaf
+        tables (gauges)."""
+        with self._lock:
+            self.crush_leaf_columns_per_slab = int(columns_per_slab)
+            self.crush_leaf_lane_fill = float(lane_fill)
 
     def record_program_build(self) -> None:
         """A CRUSH or ladder program traced anew (called from inside
@@ -920,6 +937,9 @@ class MappingStats:
                 "crush_table_builds": self.crush_table_builds,
                 "crush_table_upload_bytes": self.crush_table_upload_bytes,
                 "crush_program_builds": self.crush_program_builds,
+                "crush_leaf_columns_per_slab":
+                    self.crush_leaf_columns_per_slab,
+                "crush_leaf_lane_fill": round(self.crush_leaf_lane_fill, 6),
                 "host_tail_share": round(self._host_tail_share(), 6),
                 "phase_seconds": {
                     "device": self.phase_device.dump(),
@@ -971,6 +991,9 @@ class MappingStats:
                 "crush_table_builds": self.crush_table_builds,
                 "crush_table_upload_bytes": self.crush_table_upload_bytes,
                 "crush_program_builds": self.crush_program_builds,
+                "crush_leaf_columns_per_slab":
+                    self.crush_leaf_columns_per_slab,
+                "crush_leaf_lane_fill": round(self.crush_leaf_lane_fill, 6),
                 "host_tail_share": round(self._host_tail_share(), 6),
             }
 

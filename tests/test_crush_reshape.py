@@ -195,6 +195,71 @@ def test_a_class_boundary_builds_exactly_the_program_it_needs(seed):
     assert len(seen) in (3, 4)
 
 
+def pallas_rows(c, xs):
+    """do_rule on the Pallas route in interpret mode (few tries, so a
+    short full range: interpret mode takes minutes over the 54 columns
+    of the default 51), held to the oracle; returns the shape class."""
+    crush, rid = c.crush()
+    crush.tunables.choose_total_tries = 7
+    bm = mapper_jax.BatchMapper(crush)
+    ft = bm._fast_cache[rid] = fastpath.tables_of(
+        fastpath.detect(crush, rid), pallas=True, interpret=True)
+    got = np.asarray(bm.do_rule(rid, xs, SIZE, c.reweight))
+    np.testing.assert_array_equal(
+        got, oracle_rows(crush, rid, xs, c.reweight))
+    return ft.shape
+
+
+def test_the_leaf_group_width_holds_a_class_across_host_edits():
+    """Hosts of 40 OSDs: the leaf kernel's group is 64 lanes, two
+    r-columns a slab.  A host of 40 added, a crush weight halved, a host
+    removed — the reshape cell's epochs — run on the first map's
+    program; the gauges say how the leaf is laid out."""
+    c = Cluster(4, 40, 51)
+    xs = np.random.default_rng(51).integers(0, 2**32, 136, dtype=np.uint32)
+    shape = pallas_rows(c, xs)
+    assert (shape.root_lanes, shape.leaf_lanes) == (128, 64)
+    s = telemetry.mapping_summary()
+    assert s["crush_leaf_columns_per_slab"] == 2
+    assert s["crush_leaf_lane_fill"] == 0.625
+    before = builds()
+    for kind in ("host_add", "item_reweight", "host_remove"):
+        getattr(c, kind)()
+        assert pallas_rows(c, xs) == shape, kind
+    assert builds() == before
+
+
+def test_a_host_past_64_crosses_the_leaf_class_once():
+    c = Cluster(3, 64, 52)
+    xs = np.random.default_rng(52).integers(0, 2**32, 144, dtype=np.uint32)
+    assert pallas_rows(c, xs).leaf_lanes == 64
+    before = builds()
+    c.osd_add()                              # a host of 65
+    shape = pallas_rows(c, xs)
+    assert shape.leaf_lanes == 128
+    assert builds() - before == 1
+    s = telemetry.mapping_summary()
+    assert s["crush_leaf_columns_per_slab"] == 1
+    assert s["crush_leaf_lane_fill"] == pytest.approx(65 / 128, abs=1e-6)
+    c.item_reweight()
+    assert pallas_rows(c, xs) == shape
+    assert builds() - before == 1
+
+
+def test_the_cells_shape_reports_two_columns_a_slab_filled_to_five_eighths():
+    from ceph_tpu.crush import build_skewed_two_level_map
+    crush, rid, _rw = build_skewed_two_level_map(250, 40)
+    ft = fastpath.tables_of(fastpath.detect(crush, rid), pallas=True,
+                            interpret=True)
+    assert (ft.shape.root_lanes, ft.shape.leaf_lanes) == (256, 64)
+    s = telemetry.mapping_summary()
+    assert s["crush_leaf_columns_per_slab"] == 2
+    assert s["crush_leaf_lane_fill"] == 0.625
+    # the leaf's fields, 8 of 64 lanes a host: 0.52 MB where the
+    # 128-lane table with its raw-weight block was 1.18 MB
+    assert ft.host[5].nbytes == 256 * 8 * 64 * 4
+
+
 def test_max_osd_grows_inside_the_quantum_on_one_program_and_across_it_on_two():
     assert OSD_AXIS_QUANTUM % 128 == 0
     assert padded_osds(10000) == padded_osds(10040) == 10240

@@ -163,6 +163,47 @@ def test_leaf_columns(one_chip, columns, n, R):
     assert "tpu_custom_call" in text
 
 
+def test_leaf_columns_under_the_four_chip_shard_map(mesh4, columns):
+    """crush10k.weight_churn_x4's route: the single-stage R = 9 leaf
+    call on each chip's 16,384 lanes, two columns a slab, the tables
+    replicated; Mosaic refuses a kernel over the scoped VMEM limit."""
+    pc, tables = columns
+    assert (pc.S_leaf, pc.columns_per_slab) == (64, 2)
+    R, n = 9, 4 * 16384
+    rows = NamedSharding(mesh4, PartitionSpec(("dp", "ec")))
+    cols = NamedSharding(mesh4, PartitionSpec(None, ("dp", "ec")))
+    rep = NamedSharding(mesh4, PartitionSpec())
+    fn = jax.jit(jax.shard_map(
+        lambda xs, pos, *t: pc.leaf_columns(xs, pos, t, R), mesh=mesh4,
+        in_specs=(rows.spec, cols.spec) + (rep.spec,) * len(tables),
+        out_specs=cols.spec, check_vma=False))
+    compiled = fn.lower(_spec(rows, (n,), jnp.uint32),
+                        _spec(cols, (R, n), jnp.int32),
+                        *(_spec(rep, t.shape, t.dtype)
+                          for t in tables)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    (out_sh,) = jax.tree_util.tree_leaves(compiled.output_shardings)
+    assert len(out_sh.device_set) == 4
+
+
+@pytest.mark.parametrize("widest,lanes", [(16, 32), (30, 32), (100, 128),
+                                          (130, 256)])
+def test_leaf_columns_of_each_group_width(one_chip, widest, lanes):
+    """Four r-columns a slab (a host of 16 takes the 32-lane floor, so
+    four columns' one-hot products stay inside the scoped VMEM), one,
+    and one column over two slabs: each layout compiles at stage 2's
+    shape of the 1 Mi cell."""
+    crush_map, rid, _rw = build_skewed_two_level_map(250, widest)
+    pc, tables = _columns_of(detect(crush_map, rid))
+    assert pc.S_leaf == lanes
+    R, n = 9, (1 << 20) // 16
+    text = _compile(lambda xs, pos, *t: pc.leaf_columns(xs, pos, t, R),
+                    _spec(one_chip, (n,), jnp.uint32),
+                    _spec(one_chip, (R, n), jnp.int32),
+                    *(_spec(one_chip, t.shape, t.dtype) for t in tables))
+    assert "tpu_custom_call" in text
+
+
 # R = tries + numrep is the cannot-overflow recompute
 @pytest.mark.parametrize("n,R", STAGES + [(N_PGS, 54)] + STAGES_1M)
 def test_consume_columns(one_chip, n, R):
