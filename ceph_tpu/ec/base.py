@@ -51,9 +51,9 @@ class ErasureCode(ErasureCodeInterface):
     (ErasureCodeIsaTableCache analog) — lives here.
     """
 
-    #: MDS matrix codecs with batched encode_chunks/decode_chunks can be
-    #: laid out striped for range rmw (ECUtil stripe math); non-MDS or
-    #: layered codecs fall back to whole-object writes
+    #: MDS codecs with batched encode_chunks/decode_chunks can be laid
+    #: out striped for range rmw (ECUtil stripe math); non-MDS codecs
+    #: (shec, lrc) fall back to whole-object writes
     supports_rmw_striping = True
 
     #: codecs whose recovery matrices live at chunk granularity can
@@ -61,6 +61,11 @@ class ErasureCode(ErasureCodeInterface):
     #: (submit_decode_chunks); packet-level bitmatrix codecs override
     #: to False and keep the synchronous decode path
     supports_submit_decode = True
+
+    #: erasure patterns a decode table generation holds before it
+    #: retires; None = PATTERN_TABLE_CAP (a codec whose per-pattern
+    #: operands are large holds fewer)
+    pattern_table_cap: int | None = None
 
     #: profile keys consumed by init (reference: parse() per plugin)
     _PROFILE_KEYS = ("k", "m", "technique", "runtime", "plugin",
@@ -281,9 +286,9 @@ class ErasureCode(ErasureCodeInterface):
             from ceph_tpu.ops.gf_kernel import _jit_entries
             cache_entries = _jit_entries
             # mesh placement only fits the BASE dense-matrix encode:
-            # codecs overriding encode_chunks (packet-level bitmatrix,
-            # clay's layered transform) run their own host/packet
-            # pipelines a sharded batch would break or gather back
+            # codecs overriding encode_chunks (packet-level bitmatrix)
+            # run their own host/packet pipelines a sharded batch would
+            # break or gather back
             if type(self).encode_chunks is ErasureCode.encode_chunks:
                 place = True
                 mesh = engine.placement_mesh()
@@ -371,7 +376,8 @@ class ErasureCode(ErasureCodeInterface):
         bits = bit_matrix(padded)
         with self._decode_lock:
             tab = self._pattern_tables.get(tb)
-            if tab is None or len(tab["mats"]) >= PATTERN_TABLE_CAP:
+            cap = self.pattern_table_cap or PATTERN_TABLE_CAP
+            if tab is None or len(tab["mats"]) >= cap:
                 # retire the full table: new submissions start a fresh
                 # generation (new engine key); in-flight batches keep
                 # their captured table object alive until delivered

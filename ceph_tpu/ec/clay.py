@@ -29,6 +29,16 @@ parity equations, and the pair algebra then yields the failed node's
 off-S sub-chunks from helper row y0's coupled values.  All transforms
 are elementwise table lookups over the sub-chunk byte axis — batched,
 vectorized compute, no per-byte loops.
+
+Layout and devices.  A pool lays an object out as Ceph does
+(ECUtil::encode): stripes of k stripe units, each stripe coded on its
+own, its chunk alpha contiguous sub-chunks of su / alpha bytes — one
+stripe is what encode() of its k * su bytes gives.  encode_chunks /
+decode_chunks take (S, k, su) and submit_chunks / submit_decode_chunks
+are their dispatch-engine forms; on the device a pattern's whole layered
+decode is one dense bit matrix (``ops/clay_kernel.py``), built from the
+host layered code below, which stays the oracle (``runtime`` cpu) and
+the engines' host stand-in.  The single-node repair stays on the host.
 """
 
 from __future__ import annotations
@@ -51,12 +61,20 @@ def _mul(coef: int, arr: np.ndarray) -> np.ndarray:
 
 
 class ErasureCodeClay(ErasureCode):
-    supports_rmw_striping = False
+    #: Ceph's layout: the object is cut into stripes of k stripe units
+    #: (ECUtil), and each stripe's chunk is alpha contiguous sub-chunks of
+    #: su / alpha bytes, so one stripe is what encode() of its k * su
+    #: bytes gives.  The stripe unit is a multiple of alpha
+    #: (get_alignment() // k).
+    supports_rmw_striping = True
+
+    _PROFILE_KEYS = ErasureCode._PROFILE_KEYS + ("d",)
 
     def __init__(self):
         super().__init__()
         self.q = 0
         self.t = 0
+        self.d = 0
 
     def _default_k(self) -> int:
         return 4
@@ -71,6 +89,11 @@ class ErasureCodeClay(ErasureCode):
             raise ValueError(
                 f"clay requires m | (k+m); got k={self.k} m={self.m} "
                 f"(the reference shortens instead; not implemented)")
+        # q = d - k + 1 is m only at Ceph's default d
+        self.d = self.to_int("d", profile, n - 1)
+        if self.d != n - 1:
+            raise ValueError(f"clay d={self.d}: only d = k + m - 1 = "
+                             f"{n - 1} is implemented")
         self.q = self.m
         self.t = n // self.q
 
@@ -113,10 +136,12 @@ class ErasureCodeClay(ErasureCode):
 
     # -- the generic layered decoder ------------------------------------------
 
-    def _decode_planes(self, C: dict, erased: list[int]):
+    def _decode_planes(self, C: dict, erased: list[int],
+                       host: bool = False):
         """C: {(node, z): uint8 array} for all surviving nodes and all
         planes.  Returns (U, C) completed for every node and plane
-        (ErasureCodeClay recover: intersection-score order)."""
+        (ErasureCodeClay recover: intersection-score order); ``host``
+        keeps the plane code on the numpy oracle."""
         n = self.k + self.m
         planes = self._planes()
         er = set(erased)
@@ -148,7 +173,7 @@ class ErasureCodeClay(ErasureCode):
             chosen = surv[:self.k]
             arr = np.stack([U[(i, z)] for i in chosen])
             rmat = self._recovery(tuple(chosen), tuple(sorted(er)))
-            rebuilt = self._apply(rmat, arr)
+            rebuilt = self._apply(rmat, arr, host)
             for idx, i in enumerate(sorted(er)):
                 U[(i, z)] = rebuilt[idx]
         # couple the erased nodes' C back from U
@@ -163,9 +188,11 @@ class ErasureCodeClay(ErasureCode):
                     C[(i, z)] = U[(i, z)] ^ _mul(GAMMA, U[(partner, zp)])
         return U, C
 
-    def _apply(self, mat: np.ndarray, arr: np.ndarray) -> np.ndarray:
-        """(r, c) GF matrix times (c, B) rows, on the selected runtime."""
-        if self.runtime == "cpu":
+    def _apply(self, mat: np.ndarray, arr: np.ndarray,
+               host: bool = False) -> np.ndarray:
+        """(r, c) GF matrix times (c, B) rows, on the selected runtime
+        (``host``: the numpy oracle whatever the runtime)."""
+        if host or self.runtime == "cpu":
             from ceph_tpu.ops.gf_kernel import ec_encode_ref
             return ec_encode_ref(mat, arr[None])[0]
         from ceph_tpu.ops.gf_kernel import ec_encode_jax
@@ -183,47 +210,179 @@ class ErasureCodeClay(ErasureCode):
     def _join(self, per_plane: dict) -> bytes:
         return b"".join(per_plane[z].tobytes() for z in self._planes())
 
-    # -- encode: parities are erasures of the generic decoder -----------------
+    # -- striped encode / decode: (S, k, su) chunks of S stripes --------------
 
-    def encode(self, want_to_encode: set, data: bytes) -> dict:
-        chunks = self.encode_prepare(data)     # (k, chunk)
-        C: dict = {}
-        for i in range(self.k):
-            for z, sub in self._split(chunks[i]).items():
-                C[(i, z)] = sub.copy()
-        erased = list(range(self.k, self.k + self.m))
-        _U, C = self._decode_planes(C, erased)
-        out = {}
-        for i in want_to_encode:
-            per_plane = {z: C[(i, z)] for z in self._planes()}
-            out[i] = self._join(per_plane)
-        return out
+    #: a pattern's dense bit matrix is (k * alpha * 8, t * alpha * 8):
+    #: 4 MiB at k = 8, alpha = 64 and two chunks wanted; a table
+    #: generation holds at most this many before it retires
+    pattern_table_cap = 64
+
+    def _erased_of(self, chosen) -> tuple:
+        n = self.k + self.m
+        return tuple(j for j in range(n) if j not in set(chosen))
+
+    def _host_layered(self, chosen, chunks) -> np.ndarray:
+        """The layered decode on the host, every stripe at once (one
+        erasure pattern): (S, k, su) chunks of the nodes ``chosen`` ->
+        (S, m, su) chunks of the others, ascending.  A sub-chunk of the
+        decoder is the S stripes' sub-chunk z side by side."""
+        data = np.asarray(chunks, dtype=np.uint8)
+        s, _k, su = data.shape
+        alpha = self.get_sub_chunk_count()
+        sb = su // alpha
+        by_plane = data.reshape(s, self.k, alpha, sb)
+        planes = self._planes()
+        C = {(node, z): np.ascontiguousarray(by_plane[:, c, i]).reshape(-1)
+             for c, node in enumerate(chosen)
+             for i, z in enumerate(planes)}
+        erased = self._erased_of(chosen)
+        _U, C = self._decode_planes(C, list(erased), host=True)
+        out = np.empty((s, len(erased), alpha, sb), dtype=np.uint8)
+        for e, node in enumerate(erased):
+            for i, z in enumerate(planes):
+                out[:, e, i] = C[(node, z)].reshape(s, sb)
+        return out.reshape(s, len(erased), su)
+
+    def _host_decode(self, chosen, targets, chunks) -> np.ndarray:
+        rows = [self._erased_of(chosen).index(t) for t in targets]
+        return self._host_layered(tuple(chosen), chunks)[:, rows]
+
+    def _dense_bits(self, chosen, targets) -> np.ndarray:
+        """The layered decode of one pattern as one GF(2^8) matrix over a
+        stripe's sub-chunk byte columns, bit-expanded: (k * alpha * 8,
+        len(targets) * alpha * 8) int8 (clay_kernel's operand).  Column
+        c * alpha + z of the matrix is what a lone 1 in byte column b of
+        chunk c's sub-chunk z rebuilds in byte column b of the targets'
+        sub-chunks: the host layered code run on those unit columns."""
+        from ceph_tpu.gf.tables import bit_matrix
+        k, alpha = self.k, self.get_sub_chunk_count()
+        cols = k * alpha
+        unit = np.zeros((1, k, alpha, cols), dtype=np.uint8)
+        unit[0].reshape(cols, cols)[np.arange(cols), np.arange(cols)] = 1
+        out = self._host_decode(chosen, targets,
+                                unit.reshape(1, k, alpha * cols))
+        return bit_matrix(out.reshape(len(targets) * alpha, cols)).astype(
+            np.int8)
+
+    def _dense(self, chosen: tuple, targets: tuple):
+        """(pattern index, table, device bit matrix) of a pattern, the
+        matrix built on first sight (outside the codec lock; a racing
+        duplicate is idempotent) and kept with the table generation."""
+        idx, _tb, tab = self._register_pattern(chosen, targets)
+        with self._decode_lock:
+            w = tab.setdefault("dense", {}).get(idx)
+        if w is None:
+            import jax
+            w = jax.device_put(self._dense_bits(chosen, targets))
+            with self._decode_lock:
+                w = tab["dense"].setdefault(idx, w)
+        return idx, tab, w
 
     def encode_chunks(self, data_chunks):
-        raise NotImplementedError("clay encodes via its coupled layers")
+        """(S, k, su) data chunks -> (S, m, su) parity chunks, each
+        stripe encoded on its own (ECUtil::encode)."""
+        data = np.asarray(data_chunks, dtype=np.uint8)
+        if self.runtime != "tpu":
+            return self._host_layered(tuple(range(self.k)), data)
+        if self._encoder is None:
+            import jax
+            self._encoder = jax.device_put(self._dense_bits(
+                tuple(range(self.k)), tuple(range(self.k, self.k + self.m))))
+        from ceph_tpu.ops import clay_kernel
+        return clay_kernel.run("encode", [(self._encoder, None)], data,
+                               alpha=self.get_sub_chunk_count())
 
-    # -- decode ---------------------------------------------------------------
+    def decode_chunks(self, chosen, chunks, targets):
+        """(S, k, su) chunks of the nodes ``chosen`` -> (S, len(targets),
+        su)."""
+        chosen, targets = tuple(chosen), tuple(targets)
+        if self.runtime != "tpu":
+            return self._host_decode(chosen, targets, chunks)
+        from ceph_tpu.ops import clay_kernel
+        _idx, _tab, w = self._dense(chosen, targets)
+        return clay_kernel.run(
+            "decode", [(w, None)], np.asarray(chunks, dtype=np.uint8),
+            alpha=self.get_sub_chunk_count())
 
-    def decode(self, want_to_read: set, chunks: dict) -> dict:
-        available = set(chunks)
-        missing = sorted(want_to_read - available)
-        if not missing:
-            return {i: chunks[i] for i in want_to_read}
-        C: dict = {}
-        for i in available:
-            arr = np.frombuffer(chunks[i], dtype=np.uint8)
-            for z, sub in self._split(arr).items():
-                C[(i, z)] = sub.copy()
-        erased = [i for i in range(self.k + self.m) if i not in available]
-        _U, C = self._decode_planes(C, erased)
-        out = {}
-        for i in want_to_read:
-            if i in available:
-                out[i] = chunks[i]
-            else:
-                out[i] = self._join({z: C[(i, z)]
-                                     for z in self._planes()})
+    def submit_chunks(self, engine, data_chunks, cost_tag=None):
+        """The encode through a dispatch engine: concurrent submits of
+        one chunk width coalesce on the stripe axis (zeros encode to
+        zeros), the host layered code standing in when the device path
+        stays broken.  Batches stay whole on one device (no mesh
+        placement)."""
+        data = np.asarray(data_chunks, dtype=np.uint8)
+        key = ("ec_encode_clay", id(self), self.k, self.m, data.shape[-1],
+               self.runtime)
+        from ceph_tpu.ops import clay_kernel
+        return engine.submit(
+            key, self.encode_chunks, data, label="ec_encode_clay",
+            cache_entries=(clay_kernel.jit_entries
+                           if self.runtime == "tpu" else None),
+            place=False,
+            fallback=lambda batch: self._host_layered(
+                tuple(range(self.k)), np.asarray(batch)),
+            cost_tag=cost_tag)
+
+    def _host_patterns(self, tab: dict, host_pidx, data) -> np.ndarray:
+        """A coalesced batch on the host, pattern group by group."""
+        data = np.asarray(data, dtype=np.uint8)
+        with self._decode_lock:
+            pats = {i: key for key, i in tab["ids"].items()}
+        out = None
+        for p in np.unique(host_pidx):
+            rows = np.nonzero(host_pidx == p)[0]
+            got = self._host_decode(*pats[int(p)], data[rows])
+            if out is None:
+                out = np.zeros((data.shape[0],) + got.shape[1:], np.uint8)
+            out[rows] = got
         return out
+
+    def submit_decode_chunks(self, engine, chosen, chunks, targets,
+                             cost_tag=None):
+        """The decode through the decode engine: a stripe carries its
+        erasure pattern's index, so reads with different patterns (and
+        as many chunks wanted) share one engine batch, and the batch is
+        one program call a pattern group (``clay_kernel.run``).  The
+        future gives (S, len(targets), su)."""
+        data = np.asarray(chunks, dtype=np.uint8)
+        chosen, targets = tuple(chosen), tuple(targets)
+        device = self.runtime == "tpu"
+        if device:
+            idx, tab, _w = self._dense(chosen, targets)
+        else:
+            idx, _tb, tab = self._register_pattern(chosen, targets)
+        pidx = np.full(data.shape[0], idx, dtype=np.int32)
+        key = ("ec_decode_clay", id(self), self.k, len(targets),
+               data.shape[-1], self.runtime, tab["gen"])
+        from ceph_tpu.ops import clay_kernel, telemetry
+        stats = engine.stats if isinstance(
+            engine.stats, telemetry.DecodeDispatchStats) \
+            else telemetry.decode_dispatch_stats()
+        alpha = self.get_sub_chunk_count()
+
+        def fn(batch, batch_pidx):
+            # analysis: allow[blocking] -- the pattern indices are a small host array (place=False)
+            host_pidx = np.asarray(batch_pidx)
+            uniq = np.unique(host_pidx)
+            stats.record_patterns(int(uniq.size), len(tab["mats"]))
+            if not device:
+                return self._host_patterns(tab, host_pidx, batch)
+            with self._decode_lock:
+                dense = dict(tab["dense"])
+            if uniq.size == 1:
+                groups = [(dense[int(uniq[0])], None)]
+            else:
+                groups = [(dense[int(p)], np.nonzero(host_pidx == p)[0])
+                          for p in uniq]
+            return clay_kernel.run("decode", groups, batch, alpha=alpha)
+
+        return engine.submit(
+            key, fn, data, aux=(pidx,), label="ec_decode_clay",
+            cache_entries=clay_kernel.jit_entries if device else None,
+            place=False,
+            fallback=lambda batch, batch_pidx: self._host_patterns(
+                tab, np.asarray(batch_pidx), batch),
+            cost_tag=cost_tag)
 
     # -- repair-bandwidth-optimal single-node repair --------------------------
 

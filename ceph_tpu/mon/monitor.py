@@ -1869,9 +1869,9 @@ class Monitor(Dispatcher):
                            "k": str(cmd.get("k", 4)),
                            "m": str(cmd.get("m", 2))}
                 # plugin-specific keys ride through (shec's c, lrc's
-                # mapping/layers, jerasure/isa techniques); non-string
-                # values must be JSON, not python repr
-                for key in ("technique", "c", "mapping", "layers"):
+                # mapping/layers, clay's d, jerasure/isa techniques);
+                # non-string values must be JSON, not python repr
+                for key in ("technique", "c", "d", "mapping", "layers"):
                     if key in cmd:
                         v = cmd[key]
                         profile[key] = (v if isinstance(v, str)

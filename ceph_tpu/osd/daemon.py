@@ -325,6 +325,7 @@ class OSDDaemon(Dispatcher):
                      .add_u64("ec_decode_submits")
                      .add_u64("ec_degraded_reads")
                      .add_u64("ec_decode_targets")
+                     .add_u64("ec_decode_subchunks")
                      .add_u64("recovery_decode_stripes")
                      .add_u64("map_epochs")
                      .add_u64("map_pgs_scanned")
@@ -2914,10 +2915,11 @@ class OSDDaemon(Dispatcher):
             return c
 
     def _ec_stripe_info(self, codec, pool):
-        """StripeInfo for MDS matrix codecs; None = whole-object layout
-        (shec/lrc/clay encode through their own bespoke paths).  The
-        stripe unit rounds up to the codec's per-chunk alignment quantum
-        — bitmatrix techniques need chunk % w == 0."""
+        """StripeInfo for MDS codecs (matrix codecs and Clay); None =
+        whole-object layout (shec/lrc encode through their own bespoke
+        paths).  The stripe unit rounds up to the codec's per-chunk
+        alignment quantum — bitmatrix techniques need chunk % w == 0,
+        Clay a whole number of sub-chunks a chunk."""
         if not getattr(codec, "supports_rmw_striping", False):
             return None
         from ceph_tpu.osd.ec_util import StripeInfo
@@ -3312,7 +3314,7 @@ class OSDDaemon(Dispatcher):
         self.perf.inc("ec_encode_stripes")
         t_kernel = time.perf_counter()
         if si is None:
-            # synchronous path: whole-object codecs (shec/lrc/clay)
+            # synchronous path: whole-object codecs (shec/lrc)
             # encode through their own bespoke layouts
             sub = self._ec_encode_object(codec, window)
             shard_len = len(next(iter(sub.values()))) if sub else 0
@@ -4017,9 +4019,16 @@ class OSDDaemon(Dispatcher):
         # the engine's `device <kernel>` request span parents under
         # this one, and the continuation under its delivery
         shard_len, size = self._ec_gather_span(si, state)
-        with tracing.span("ec decode submit", daemon=self._tname):
+        alpha = codec.get_sub_chunk_count()
+        with tracing.span("ec decode submit", daemon=self._tname,
+                          subchunks=alpha) as sp:
             chosen, arr, targets, stripes = self._ec_gathered_stripes(
                 si, k, state["shards"], shard_len)
+            # the nodes the decode solves: a layered (sub-chunked) code
+            # solves every node outside the k it read
+            tracing.set_attrs(sp, erased=(
+                codec.get_chunk_count() - len(chosen) if alpha > 1
+                else len(targets)))
             # targets cannot be empty here: the pre-check above bailed
             # on the all-data-shards case, so at least one parity shard
             # is in `chosen` and at least one data row is missing
@@ -4031,6 +4040,8 @@ class OSDDaemon(Dispatcher):
                 return False
         self.perf.inc("ec_decode_submits")
         self.perf.inc("ec_decode_targets", len(targets))
+        self.perf.inc("ec_decode_subchunks",
+                      int(arr.shape[0]) * alpha * len(targets))
         if state["kind"] == "rmw":
             self.perf.inc("ec_rmw_decodes")
         if state["kind"] == "recover":
@@ -4198,6 +4209,9 @@ class OSDDaemon(Dispatcher):
         if targets:
             if state["kind"] == "rmw":
                 self.perf.inc("ec_rmw_decodes")
+            self.perf.inc("ec_decode_subchunks",
+                          int(arr.shape[0]) * len(targets)
+                          * codec.get_sub_chunk_count())
             # analysis: allow[blocking] -- synchronous scalar fallback path: decode_chunks returns host numpy
             rec = np.asarray(codec.decode_chunks(chosen, arr, targets))
             for idx, d in enumerate(targets):

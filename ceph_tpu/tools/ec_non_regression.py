@@ -66,6 +66,7 @@ CONFIGS = [
     ("lrc_2x3", "lrc", {"mapping": "_DDD_DDD", "layers": LRC_LAYERS}),
     ("clay_k4m2", "clay", {"k": "4", "m": "2"}),
     ("clay_k2m2", "clay", {"k": "2", "m": "2"}),
+    ("clay_k8m4", "clay", {"k": "8", "m": "4"}),
 ]
 
 

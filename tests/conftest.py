@@ -123,6 +123,27 @@ _OUTGROWN = {
      "test_the_older_entries_are_as_they_were_before_the_seven"):
         "it finds PR 37's three by their place from the end of "
         "per_layer: PR 41 appended three behind the seven",
+    # the Clay configuration, its cell and three metrics (held to the same
+    # contract by test_perfbench_clay.py, which also holds the older
+    # entries where these cases held them)
+    ("test_perfbench_manifest.py",
+     "test_configuration_entry_and_file[clay84-degraded]"):
+        "the list of systems in test_perfbench_manifest.py is closed "
+        "and lacks clay_pool_degraded",
+    ("test_perfbench_manifest.py",
+     "test_cell_entry_and_traffic_file[clay84deg.seq_read_4m_t1]"):
+        "the list of traffic kinds in test_perfbench_manifest.py is "
+        "closed and lacks closed_loop_seq_read",
+    ("test_perfbench_rbd_overwrite.py",
+     "test_the_new_metrics_are_the_last_three_and_list_only_the_cell"):
+        "it takes the rbd cell's three metrics for the last three of "
+        "per_layer: the Clay cell's three come after them",
+    ("test_perfbench_rbd_overwrite.py",
+     "test_the_older_entries_are_where_they_were_before_these"):
+        "it counts eight cells and 46 per-layer metrics and takes the "
+        "rbd configuration and cell for the last: the Clay configuration, "
+        "its cell and three metrics come after them, and the Clay cell "
+        "ends the lists the rbd cell ended",
 }
 
 

@@ -259,10 +259,11 @@ def _case_scrub_row_over_max_width_scalar_loop(tmp_path, monkeypatch):
 
 def _case_whole_object_codec_sync_encode_and_decode(tmp_path,
                                                     monkeypatch):
-    """A Clay pool (whole-object layout, no StripeInfo) encodes its
-    write synchronously and ``_ec_submit_decode`` declines its degraded
-    read; a jerasure pool on the same OSDs submits both.  Either way
-    the read returns the acknowledged bytes."""
+    """A Clay pool is laid out per stripe as Ceph lays it (alpha
+    sub-chunks a stripe unit), so it takes the engines' path as a
+    jerasure pool on the same OSDs does: the write through the encode
+    engine, the degraded read submitted to the decode engine.  Either
+    way the read returns the acknowledged bytes."""
     answers: dict[int, list] = {}
     orig = OSDDaemon._ec_submit_decode
 
@@ -299,14 +300,14 @@ def _case_whole_object_codec_sync_encode_and_decode(tmp_path,
         s0 = submits()
         io = client.open_ioctx(clay)
         io.write_full("obj", payload)
-        assert submits() == s0                  # encoded synchronously
+        assert submits() == s0 + 1              # through the engine
         lose_shard(clay, "obj")
         assert io.read("obj") == payload
-        assert answers.get(clay) and not any(answers[clay])
+        assert answers.get(clay) == [True]
 
         io2 = client.open_ioctx(rs)
         io2.write_full("obj", payload)
-        assert submits() == s0 + 1              # through the engine
+        assert submits() == s0 + 2              # through the engine
         lose_shard(rs, "obj")
         assert io2.read("obj") == payload
         assert answers.get(rs) == [True]
@@ -637,3 +638,46 @@ CASES = {
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_old_path_taken_for_observed_reason(case, tmp_path, monkeypatch):
     CASES[case](tmp_path, monkeypatch)
+
+
+def test_clay_pool_two_osds_down_reads_back_through_the_decode_engine():
+    """A Clay 8+4 pool on twelve OSDs, two of them down: objects written
+    through the encode engine read back bit-exact, the rebuilds through
+    the decode engine, each counting stripes x 64 sub-chunks x chunks."""
+    c = MiniCluster(n_osds=12, ms_type="loopback").start()
+    try:
+        c.wait_for_osd_count(12)
+        client = c.client(timeout=60.0)
+        pool = c.create_pool(client, pg_num=4, pool_type="erasure",
+                             plugin="clay", k=8, m=4, d=11)
+        io = client.open_ioctx(pool)
+        rng = np.random.default_rng(12)
+        objs = {f"c{i}": rng.bytes(2 * 8 * 4096 + 777 * (i + 1))
+                for i in range(6)}
+        for name, data in objs.items():
+            io.write_full(name, data)
+        for osd in (1, 6):
+            c.kill_osd(osd)
+            rc, out = client.mon_command({"prefix": "osd down",
+                                          "id": str(osd)})
+            assert rc == 0, out
+        epoch = c.mon.osdmap.epoch
+        c.wait_for_epoch(epoch, timeout=60.0)
+        client.wait_for_epoch(epoch)
+        daemons = [d for o, d in c.osds.items() if o not in (1, 6)]
+
+        def total(key):
+            return sum(d.perf.value(key) for d in daemons)
+
+        s0, t0, u0 = (total("ec_decode_submits"),
+                      total("ec_decode_targets"),
+                      total("ec_decode_subchunks"))
+        for name, data in objs.items():
+            assert io.read(name) == data
+        submits = total("ec_decode_submits") - s0
+        assert submits > 0
+        # each object is three stripes here
+        assert total("ec_decode_subchunks") - u0 == 3 * 64 * (
+            total("ec_decode_targets") - t0)
+    finally:
+        c.stop()

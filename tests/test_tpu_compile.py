@@ -100,6 +100,18 @@ def test_decode_pattern_table(one_chip, s):
         k=k, t=t).compile()
 
 
+@pytest.mark.parametrize("program,s,t", [
+    ("decode", 128, 2), ("decode", 1024, 1), ("encode", 128, 4)])
+def test_clay_dense(one_chip, program, s, t):
+    """Clay k=8 m=4 d=11 (alpha 64) at a 4 KiB stripe unit: one 4 MiB
+    object's 128 stripes rebuilt, a batch past one program tile, and
+    the encode — one (k * 64 * 8, t * 64 * 8) bit matrix each."""
+    from ceph_tpu.ops import clay_kernel
+    getattr(clay_kernel, f"clay_{program}").lower(
+        _spec(one_chip, (8 * 64 * 8, t * 64 * 8), jnp.int8),
+        _spec(one_chip, (s, 8, 4096), jnp.uint8), alpha=64).compile()
+
+
 def test_encode_shard_map_four_chips(mesh4):
     """The engine's mesh route: one fused Pallas program per device
     under shard_map, batch split on the stripe axis."""
