@@ -58,11 +58,23 @@ operands (``build_tables``, held with their device copies by
 ``FastTables``).  A host added, a host removed, an item reweighted is a
 host-side table build and an upload; a program is built only when an
 edit crosses a padded class (tests/test_crush_reshape.py).
+
+What is drawn once a map.  A straw2 winner is a function of x, r and
+the bucket tables alone — is_out rejects after the descent and the
+retry moves to r + 1 (mapper.c) — so the first attempt's winner planes
+of a batch are the same in every epoch that leaves the CRUSH map and
+the batch's seeds alone: reweights, OSDs out, down or back.  ``run``
+returns them and takes them back (``columns``): one program holds both
+branches, the drawing one and the one that reuses the operand, under a
+``lax.cond``; ``FastTables`` keeps them for the map by the batch's
+content (``Seeds``), and they die with the tables
+(tests/test_crush_column_cache.py).
 """
 
 from __future__ import annotations
 
 import functools
+import hashlib
 from dataclasses import dataclass
 
 import jax
@@ -370,14 +382,18 @@ class FastTables:
     """One (map, rule)'s bucket tables: the host arrays of
     ``build_tables`` and their device copies, one per placement (the
     default device, or replicated over a mesh for mesh-sharded
-    batches).  The content of a map lives here and nowhere in a
-    program."""
+    batches), and the winner planes batches drew on them (``columns``).
+    The content of a map lives here and nowhere in a program."""
 
     def __init__(self, shape: FastShape, host: tuple):
         self.shape = shape
         self.host = host
         self.nbytes = sum(int(a.nbytes) for a in host)
         self._placed: dict = {}
+        #: (placement, batch key) -> the winner planes a batch drew on
+        #: these tables (``FastMapper.run``): they die with the tables
+        self._columns: dict = {}
+        self._used: set = set()
 
     def placed(self, mesh=None) -> bool:
         """Whether ``on(mesh)`` has uploaded already."""
@@ -401,6 +417,51 @@ class FastTables:
             telemetry.mapping_stats().record_crush_table_upload(
                 self.nbytes * copies)
         return dev
+
+    def columns(self, mesh, key):
+        """The winner planes a batch of ``key`` (its result width and
+        ``Seeds.key``) drew on these tables where ``on(mesh)`` places
+        them, or None."""
+        got = self._columns.get((mesh, key))
+        if got is not None:
+            self._used.add((mesh, key))
+        return got
+
+    def keep_columns(self, mesh, key, planes) -> None:
+        self._columns[(mesh, key)] = planes
+        self._used.add((mesh, key))
+
+    def sweep_columns(self) -> None:
+        """A new epoch's first batch: planes the previous epoch's
+        batches did not use go (a pool resized or gone: 32 MB an entry
+        at 1 Mi lanes)."""
+        self._columns = {k: v for k, v in self._columns.items()
+                         if k in self._used}
+        self._used = set()
+
+
+class Seeds:
+    """One request's CRUSH inputs as the column cache knows them:
+    ``of``, the seeds (kept while the caller reuses the same array,
+    so the digest is made once), their ``digest`` — count and bytes as
+    the engine places them, uint32 — and ``columns``, what the last
+    batch that carried them found: "hit", "miss" or None (no cached
+    program ran)."""
+
+    __slots__ = ("of", "digest", "columns")
+
+    def __init__(self, xs):
+        self.of = xs
+        xs = np.ascontiguousarray(xs, dtype=np.uint32)
+        self.digest = (xs.shape[0], hashlib.blake2b(
+            xs.tobytes(), digest_size=16).digest())
+        self.columns = None
+
+    @staticmethod
+    def key(parts, pad: int) -> tuple:
+        """A batch's key: its parts' digests in order and the zero
+        lanes padded after them — never a pool id or an epoch."""
+        return tuple(p.digest for p in parts), pad
 
 
 def tables_of(fr: FastRule, pallas: bool | None = None,
@@ -445,26 +506,25 @@ class FastMapper:
                 shape.root_lanes, shape.leaf_lanes, shape.vary_r,
                 interpret=shape.interpret)
 
-    def _winners(self, xs, word, tables, R: int):
-        """host_win/leaf_win/leaf_bad for r in [0, R): a fori_loop producing
-        one r column per step (bounds the (N, H) ln-matmul intermediates to a
-        single r; an unrolled R-wide block OOMs HBM at bulk batch sizes)."""
+    def _winners(self, xs, tables, R: int):
+        """The first-level and leaf winner planes (R, N) for r in
+        [0, R): a fori_loop producing one r column per step (bounds the
+        (N, H) ln-matmul intermediates to a single r; an unrolled R-wide
+        block OOMs HBM at bulk batch sizes).  A flat rule's one plane
+        serves as both."""
         shape = self.shape
         root_ids, root_w, root_magic, root_off = tables[:4]
         n = xs.shape[0]
-        hw0 = jnp.full((n, R), NONE, dtype=jnp.int32)
-        lw0 = jnp.full((n, R), NONE, dtype=jnp.int32)
-        lb0 = jnp.zeros((n, R), dtype=bool)
+        flat = shape.kind == "choose_flat"
+        planes0 = tuple(jnp.full((R, n), NONE, dtype=jnp.int32)
+                        for _ in range(1 if flat else 2))
 
-        def body(i, bufs):
-            hw, lw, lb = bufs
+        def body(i, planes):
             r = i.astype(jnp.uint32)
             pos = _draw_argmax(xs, root_ids, root_w, r,
                                root_magic, root_off)
-            first = root_ids[pos]                              # (N,)
-            if shape.kind == "choose_flat":
-                leaf = first
-            else:
+            cols = (root_ids[pos],)                            # (N,)
+            if not flat:
                 leaf_ids, leaf_w, leaf_magic, leaf_off = tables[4:]
                 # r_leaf = vary_r ? r >> (vary_r-1) : 0 (mapper.c:578)
                 if shape.vary_r:
@@ -475,18 +535,16 @@ class FastMapper:
                 w = leaf_w[pos]                                # (N, S)
                 lpos = _draw_argmax(xs, ids, w, r_leaf,
                                     leaf_magic[pos], leaf_off[pos])
-                leaf = jnp.take_along_axis(ids, lpos[:, None], 1)[:, 0]
-            bad = out_columns(word, leaf[None, :], xs)[0]
-            hw = jax.lax.dynamic_update_slice(hw, first[:, None], (0, i))
-            lw = jax.lax.dynamic_update_slice(lw, leaf[:, None], (0, i))
-            lb = jax.lax.dynamic_update_slice(lb, bad[:, None], (0, i))
-            return hw, lw, lb
+                cols += (jnp.take_along_axis(ids, lpos[:, None], 1)[:, 0],)
+            return tuple(jax.lax.dynamic_update_slice(p, c[None, :], (i, 0))
+                         for p, c in zip(planes, cols))
 
-        return jax.lax.fori_loop(0, R, body, (hw0, lw0, lb0))
+        return jax.lax.fori_loop(0, R, body, planes0)
 
-    def _winners_cols(self, xs, word, tables, R: int):
-        """(host_win, leaf_win, leaf_bad) in the native (R, n_padded)
-        column layout of the Pallas kernels (no transposes).
+    def _columns(self, xs, tables, R: int):
+        """The first-level and leaf winner planes (R, n_padded) for r in
+        [0, R), in the Pallas kernels' native column layout (no
+        transposes); a flat rule's one plane serves as both.
 
         Root columns go through the fused approx-filter kernel when the
         R columns' candidates fit one lane block; its certificate flag
@@ -511,23 +569,23 @@ class FastMapper:
                 lambda _: (pos, ids), None)
         else:
             pos, ids = pc.root_columns(xs, tables, R)
-        # the winner columns come back padded to the kernel block quantum
-        n_pad = ids.shape[1]
-        xs_pad = jnp.concatenate(
-            [xs, jnp.zeros((n_pad - xs.shape[0],), dtype=xs.dtype)]) \
-            if n_pad > xs.shape[0] else xs
-        # is_out runs OUTSIDE the kernels, in XLA over the (R, n_pad)
-        # winner planes: the in-kernel variant hit a Mosaic miscompile
-        # (hash32_2 fed from the winner gather/sum pipeline went wrong
-        # for ~0.03% of lanes, compiled mode only; caught by TPU-vs-XLA
-        # cross-validation in round 3).  It is not cheap by itself — a
-        # gather of the reweight vector for the planes was 68 ms of a
-        # 567 ms call at 1 Mi lanes (PERF.md, PR 40) — so the devices'
-        # words are fetched by a one-hot product (out_columns)
         if self.shape.kind == "choose_flat":
-            return ids, ids, out_columns(word, ids, xs_pad)
-        lid = pc.leaf_columns(xs, pos, tables, R)
-        return ids, lid, out_columns(word, lid, xs_pad)
+            return (ids,)
+        return ids, pc.leaf_columns(xs, pos, tables, R)
+
+    @staticmethod
+    def _first_columns(columns, draw):
+        """The first attempt's winner planes: ``draw()``, or — where
+        ``columns`` is (have, planes) and ``have`` is set — the planes
+        an earlier call drew from the same seeds on the same tables.
+        A winner is a function of x, r and the bucket tables alone
+        (mapper.c: is_out rejects after the descent, and the retry
+        moves to r + 1), so the reweights never enter them.  Both
+        branches are one program: a cached answer builds nothing."""
+        if columns is None:
+            return draw()
+        have, planes = columns
+        return jax.lax.cond(have, lambda: tuple(planes), draw)
 
     #: minimum batch for the two-stage schedule; below it one pass at R0
     #: is cheaper than the compaction plumbing
@@ -546,43 +604,62 @@ class FastMapper:
     STAGE2_SHARE = 16
 
     def _run_pallas(self, xs, word, tables, result_max, numrep,
-                    R0, Rf):
+                    R, R0, Rf, columns):
         """Winner columns and the consume ladder both on-device in their
         native (R, N) layout — no transposes, no XLA while_loops.
 
-        Bulk batches run a two-stage schedule: stage 1 computes only
-        numrep+1 columns for every lane (covers lanes whose firstn
-        ladder saw at most one failure in the last replica — ~99% at
-        realistic maps), then gathers the overflowing lanes into one
-        compact STAGE2_CAP batch that gets the full R0 treatment.  The
-        placement for a given x is identical either way — the ladder is
-        deterministic in (x, columns) — so this is pure scheduling, the
-        oracle-equivalence property is untouched."""
+        Bulk batches run a two-stage schedule (``R`` < ``R0``): stage 1
+        computes only numrep+1 columns for every lane (covers lanes
+        whose firstn ladder saw at most one failure in the last replica
+        — ~99% at realistic maps), then gathers the overflowing lanes
+        into one compact STAGE2_CAP batch that gets the full R0
+        treatment.  The placement for a given x is identical either way
+        — the ladder is deterministic in (x, columns) — so this is pure
+        scheduling, the oracle-equivalence property is untouched.
+        Returns the rows and the first attempt's planes."""
         from ceph_tpu.ops.pallas_straw2 import consume_columns
         shape = self.shape
         n = xs.shape[0]
         interp = self._pallas.interpret
 
-        def attempt(xv, R):
+        def attempt(xv, R, cols=None):
             m = xv.shape[0]
-            hw, lw, lb = self._winners_cols(xv, word, tables, R)
+            if cols is None:
+                cols = self._columns(xv, tables, R)
+            # the winner columns come back padded to the kernel block
+            # quantum
+            n_pad = cols[0].shape[1]
+            xs_pad = jnp.concatenate(
+                [xv, jnp.zeros((n_pad - m,), dtype=xv.dtype)]) \
+                if n_pad > m else xv
+            # is_out runs OUTSIDE the kernels, in XLA over the (R, n_pad)
+            # winner planes: the in-kernel variant hit a Mosaic
+            # miscompile (hash32_2 fed from the winner gather/sum
+            # pipeline went wrong for ~0.03% of lanes, compiled mode
+            # only; caught by TPU-vs-XLA cross-validation in round 3).
+            # It is not cheap by itself — a gather of the reweight
+            # vector for the planes was 68 ms of a 567 ms call at 1 Mi
+            # lanes (PERF.md, section 6) — so the devices' words are fetched
+            # by a one-hot product (out_columns)
+            lb = out_columns(word, cols[-1], xs_pad)
             oh, ol, ovf = consume_columns(
-                hw, lw, lb, numrep=numrep, tries=shape.tries,
+                cols[0], cols[-1], lb, numrep=numrep, tries=shape.tries,
                 interpret=interp)
             return oh[:, :m], ol[:, :m], ovf[:m]
 
-        def attempt_full(xv, R):
-            oh, ol, ovf = attempt(xv, R)
+        def attempt_full(xv, R, cols=None):
+            oh, ol, ovf = attempt(xv, R, cols)
             return jax.lax.cond(
                 jnp.any(ovf != 0),
                 lambda _: attempt(xv, Rf)[:2],
                 lambda _: (oh, ol), None)
 
-        R1 = numrep + 1
-        if n < self.TWO_STAGE_MIN or R1 >= R0:
-            out_h, out_l = attempt_full(xs, R0)
+        first = self._first_columns(
+            columns, lambda: self._columns(xs, tables, R))
+        if R == R0:
+            out_h, out_l = attempt_full(xs, R0, first)
         else:
-            oh1, ol1, ovf1 = attempt(xs, R1)
+            oh1, ol1, ovf1 = attempt(xs, R, first)
             cap = max(self.STAGE2_CAP, n // self.STAGE2_SHARE)
             need = ovf1 != 0
             # overflowing lanes first, stable, then fillers
@@ -604,45 +681,103 @@ class FastMapper:
                 lambda _: attempt_full(xs, R0),
                 merged, None)
         return _compact_rows(
-            out_l if shape.kind == "chooseleaf" else out_h, result_max)
+            out_l if shape.kind == "chooseleaf" else out_h,
+            result_max), first
+
+    def _run_xla(self, xs, word, tables, result_max, numrep, R0, Rf,
+                 columns):
+        """The XLA route: winners at R0, the ladder by ``_consume``, the
+        full range Rf when a lane overflows.  Returns the rows and the
+        first attempt's planes."""
+        shape = self.shape
+        n = xs.shape[0]
+
+        def ladder(cols, R):
+            # is_out a plane row at a time: the one-hot fetch of a whole
+            # plane would hold rows x R x N
+            lb = jax.lax.map(
+                lambda row: out_columns(word, row[None, :], xs)[0],
+                cols[-1])
+            return _consume(cols[0].T, cols[-1].T, lb.T, numrep,
+                            shape.tries, R, n)
+
+        first = self._first_columns(
+            columns, lambda: self._winners(xs, tables, R0))
+        out_h, out_l, ovf = ladder(first, R0)
+
+        def slow(_):
+            oh, ol, _ = ladder(self._winners(xs, tables, Rf), Rf)
+            return oh, ol
+
+        out_h, out_l = jax.lax.cond(
+            jnp.any(ovf), slow, lambda _: (out_h, out_l), None)
+        return _compact_rows(
+            (out_l if shape.kind == "chooseleaf" else out_h).T,
+            result_max), first
+
+    def _schedule(self, n: int, result_max: int, block: int):
+        """(numrep, R, R0, Rf) for a batch of n lanes: the replicas a
+        lane places (0: none), the r-columns of the first attempt, of
+        the one-stage block and of the full range.  R < R0 is the
+        two-stage schedule (``_run_pallas``)."""
+        numrep = self.shape.numrep_arg
+        if numrep <= 0:
+            numrep += result_max
+        numrep = max(numrep, 0)
+        Rf = self.shape.tries + numrep
+        R0 = min(numrep + block, Rf)
+        two = (self._pallas is not None and n >= self.TWO_STAGE_MIN
+               and numrep + 1 < R0)
+        return numrep, (numrep + 1 if two else R0), R0, Rf
+
+    def column_shape(self, n: int, result_max: int,
+                     block: int = DEFAULT_BLOCK) -> tuple[int, int, int]:
+        """(planes, R, lanes): the first attempt's winner planes that
+        ``run`` returns for a batch of n lanes (a device's share on the
+        mesh route) are ``planes`` int32 arrays of (R, lanes) — two for
+        chooseleaf, one for a flat rule, none where the rule places
+        nothing."""
+        numrep, R, _R0, _Rf = self._schedule(n, result_max, block)
+        if numrep == 0:
+            return 0, 0, n
+        lanes = n
+        if self._pallas is not None:
+            from ceph_tpu.ops.pallas_straw2 import padded_batch
+            lanes = padded_batch(n)
+        return (1 if self.shape.kind == "choose_flat" else 2), R, lanes
 
     def run(self, xs, reweight, tables, result_max: int,
-            block: int = DEFAULT_BLOCK):
+            block: int = DEFAULT_BLOCK, columns=None):
         """Full do_rule: returns (N, result_max) NONE-compacted placements.
         ``tables`` are the map's (``build_tables``); ``reweight`` may be
         zero-padded past max_osd (weight 0 is out, which is is_out's
-        verdict for an id past the vector already)."""
-        shape = self.shape
+        verdict for an id past the vector already).
+
+        With ``columns`` = (have, planes) — the program the column
+        cache runs (crush.mapper_jax.BatchMapper.do_rule) — the first
+        attempt's winner planes (``column_shape``) are the operand
+        where ``have`` is set and are drawn where it is not, and the
+        result is (rows, planes).  Everything after them runs either
+        way: this call's is_out verdicts, the firstn ladder, stage 2
+        and the full-range fallback, which draw their own columns."""
         if isinstance(xs, jax.core.Tracer):
             # a trace of this function is a program built: a compile
             # or a cache load, on whatever path asked for it
             telemetry.mapping_stats().record_program_build()
-        numrep = shape.numrep_arg
-        if numrep <= 0:
-            numrep += result_max
         n = xs.shape[0]
-        if numrep <= 0:
-            return jnp.full((n, result_max), NONE, dtype=jnp.int32)
-        Rf = shape.tries + numrep
-        R0 = min(numrep + block, Rf)
+        numrep, R, R0, Rf = self._schedule(n, result_max, block)
+        if numrep == 0:
+            rows = jnp.full((n, result_max), NONE, dtype=jnp.int32)
+            return rows if columns is None else (rows, columns[1])
 
         # all is_out asks of a device, built once a program from the
         # int64 operand and fetched without a gather (out_columns)
         word = reweight_words(reweight)
 
         if self._pallas is not None:
-            return self._run_pallas(xs, word, tables, result_max,
-                                    numrep, R0, Rf)
-
-        hw, lw, lb = self._winners(xs, word, tables, R0)
-        out_h, out_l, ovf = _consume(hw, lw, lb, numrep, shape.tries, R0, n)
-
-        def slow(_):
-            hw2, lw2, lb2 = self._winners(xs, word, tables, Rf)
-            oh, ol, _ = _consume(hw2, lw2, lb2, numrep, shape.tries, Rf, n)
-            return oh, ol
-
-        out_h, out_l = jax.lax.cond(
-            jnp.any(ovf), slow, lambda _: (out_h, out_l), None)
-        return _compact_rows(
-            (out_l if shape.kind == "chooseleaf" else out_h).T, result_max)
+            rows, first = self._run_pallas(xs, word, tables, result_max,
+                                           numrep, R, R0, Rf, columns)
+        else:
+            rows, first = self._run_xla(xs, word, tables, result_max,
+                                        numrep, R0, Rf, columns)
+        return rows if columns is None else (rows, first)

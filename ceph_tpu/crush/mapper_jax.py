@@ -433,29 +433,63 @@ def _compact_rows(rows: jax.Array) -> jax.Array:
 #: an item reweighted — runs the program the first one built.
 _FAST_PROGRAMS: dict = {}
 
+#: zero winner planes, one per (program, batch shape): the operand of a
+#: call that draws its own (``have`` false), put once and never read
+_COLUMN_FILLERS: dict = {}
+
 
 def _fast_program(shape, result_max: int, sh=None):
-    """The jitted ``FastMapper.run`` of a shape class; with a row
-    sharding ``sh``, its shard_map wrapper: the Pallas column kernels
-    are opaque custom calls GSPMD cannot split, so each device runs the
-    full fused ladder on its local rows (row-independent by the
-    oracle-equivalence contract) with the reweight vector and the
-    bucket tables replicated."""
+    """The jitted ``FastMapper.run`` of a shape class in its column-cache
+    form — ``fn(xs, reweight, tables, (have, planes)) -> (rows,
+    planes)``.  With a row sharding ``sh`` the outputs carry ``sh`` and
+    its ``lane_sharding``, so planes fed back meet the entry that made
+    them; on the Pallas route through the shard_map wrapper: the
+    column kernels are opaque custom calls GSPMD cannot split, so each
+    device runs the full fused ladder on its local rows
+    (row-independent by the oracle-equivalence contract) with the
+    reweight vector and the bucket tables replicated and the planes
+    split on their lane axis like the rows."""
     key = (shape, result_max, sh)
     fn = _FAST_PROGRAMS.get(key)
     if fn is None:
         from . import fastpath
-        run = functools.partial(fastpath.mapper_for(shape).run,
-                                result_max=result_max)
+        from ceph_tpu.ops.gf_kernel import (
+            build_sharded_rows_fn, lane_sharding)
+        mapper = fastpath.mapper_for(shape)
+
+        def run(xs, reweight, tables, columns):
+            return mapper.run(xs, reweight, tables, result_max,
+                              columns=columns)
+
+        # through a partial the programs keep the names a device trace
+        # shows (BENCHMARK.json's programs.crush): jit names a partial
+        # _unknown, shard_map names it after the function
+        run = functools.partial(run)
         if sh is None:
             fn = jax.jit(run)
-        else:
-            from ceph_tpu.ops.gf_kernel import build_sharded_rows_fn
+        elif shape.pallas:
             # two replicated operands: reweight, and the tuple of
             # tables (one spec covers its leaves)
-            fn = build_sharded_rows_fn(run, sh, n_replicated=2)
+            fn = build_sharded_rows_fn(run, sh, n_replicated=2,
+                                       lane_planes=True)
+        else:
+            fn = jax.jit(run, out_shardings=(sh, lane_sharding(sh)))
         _FAST_PROGRAMS[key] = fn
     return fn
+
+
+def _column_filler(key, shape, sharding):
+    """Zero planes of ``shape`` (fastpath.FastMapper.column_shape, the
+    lanes of the whole batch) where ``sharding`` puts them, or on the
+    default device; kept per ``key``."""
+    k = (key, shape)
+    got = _COLUMN_FILLERS.get(k)
+    if got is None:
+        count, R, lanes = shape
+        zero = np.zeros((R, lanes), dtype=np.int32)
+        got = tuple(jax.device_put(zero, sharding) for _ in range(count))
+        _COLUMN_FILLERS[k] = got
+    return got
 
 
 class BatchMapper:
@@ -524,7 +558,13 @@ class BatchMapper:
                 + sum(f._cache_size()
                       for f in list(_FAST_PROGRAMS.values())))
 
-    def do_rule(self, ruleno: int, xs, result_max: int, reweight) -> jax.Array:
+    def do_rule(self, ruleno: int, xs, result_max: int, reweight,
+                content=None) -> jax.Array:
+        """``content``: the batch's parts (fastpath.Seeds) and the zero
+        lanes padded after them, as the dispatch engine hands them over
+        — a fast-path batch then serves its first attempt's straw2
+        winners from the planes a batch of the same content drew on
+        the same tables (``_fast_call``)."""
         xs = jnp.asarray(xs, dtype=jnp.uint32)
         if not isinstance(reweight, jax.Array):
             # a host vector is zero-padded to the OSD axis quantum
@@ -542,17 +582,8 @@ class BatchMapper:
             return jnp.full((xs.shape[0], result_max), NONE, dtype=jnp.int32)
         ft = self.fast_tables(ruleno)
         if ft is not None:
-            from ceph_tpu.ops.gf_kernel import _multi_device, _row_sharding
-            # a mesh-sharded batch (the engine's placement) takes the
-            # tables replicated over its mesh; only the Pallas kernels
-            # need the shard_map wrapper, GSPMD splits the XLA path
-            mesh = (getattr(xs.sharding, "mesh", None)
-                    if _multi_device(xs) else None)
-            sh = (_row_sharding(xs)
-                  if mesh is not None and ft.shape.pallas else None)
-            key = (ft.shape, result_max, sh)
-            fn = functools.partial(_fast_program(*key), xs, reweight,
-                                   ft.on(mesh))
+            fn, key = self._fast_call(ft, xs, reweight, result_max,
+                                      content)
         else:
             key = (ruleno, result_max)
             if key not in self._jit_cache:
@@ -567,6 +598,59 @@ class BatchMapper:
             bytes_out=n * result_max * 4,
             cache_entries=self._jit_entries,
             signature=("crush", key, n))
+
+    def _fast_call(self, ft, xs, reweight, result_max: int, content):
+        """The fast path's call (a thunk for timed_kernel) and its
+        program key.  A mesh-sharded batch (the engine's placement)
+        takes the tables replicated over its mesh; only the Pallas
+        kernels need the shard_map wrapper, GSPMD splits the XLA path.
+
+        The column cache: with ``content`` the first attempt's winner
+        planes are looked up on ``ft`` by the batch's key and
+        placement.  Found (a hit), the program takes them as an operand
+        and draws none for that attempt; not (a miss), it draws them
+        and they are kept.  One program serves both, so which epochs
+        hit builds nothing."""
+        from . import fastpath
+        from ceph_tpu.ops import telemetry
+        from ceph_tpu.ops.gf_kernel import (
+            _multi_device, _row_sharding, lane_sharding)
+        mesh = (getattr(xs.sharding, "mesh", None)
+                if _multi_device(xs) else None)
+        sh = _row_sharding(xs) if mesh is not None else None
+        key = (ft.shape, result_max, sh)
+        prog = _fast_program(*key)
+        tables = ft.on(mesh)
+        cached = (content is not None
+                  and not isinstance(xs, jax.core.Tracer))
+        # the planes' count of columns follows the replicas placed
+        ckey = (result_max, fastpath.Seeds.key(*content)) if cached \
+            else None
+        planes = ft.columns(mesh, ckey) if cached else None
+        have = planes is not None
+        if not have:
+            n = xs.shape[0]
+            # shard_map: the planes are each device's, side by side
+            shards = (n // xs.sharding.shard_shape(xs.shape)[0]
+                      if sh is not None and ft.shape.pallas else 1)
+            count, R, lanes = fastpath.mapper_for(ft.shape).column_shape(
+                n // shards, result_max)
+            planes = _column_filler(
+                key, (count, R, lanes * shards),
+                None if sh is None else lane_sharding(sh))
+
+        def call():
+            out, cols = prog(xs, reweight, tables,
+                             (np.bool_(have), planes))
+            if cached:
+                if not have:
+                    ft.keep_columns(mesh, ckey, cols)
+                telemetry.mapping_stats().record_crush_columns(have)
+                for part in content[0]:
+                    part.columns = "hit" if have else "miss"
+            return out
+
+        return call, key
 
     # -- the rule interpreter (mapper.c:900-1105) -----------------------------
 

@@ -689,6 +689,15 @@ class Module(MgrModule):
                     "CRUSH and ladder programs traced anew (stands "
                     "still while map edits stay inside a shape class)",
                     d.get("crush_program_builds", 0))
+        exp.counter(f"{p}_crush_column_hits_total",
+                    "fast-path CRUSH batches whose stage-1 straw2 "
+                    "winners the column cache served (same seeds, "
+                    "same bucket tables)",
+                    d.get("crush_column_hits", 0))
+        exp.counter(f"{p}_crush_column_misses_total",
+                    "fast-path CRUSH batches that drew their stage-1 "
+                    "straw2 winners and kept them",
+                    d.get("crush_column_misses", 0))
         exp.gauge(f"{p}_crush_leaf_columns_per_slab",
                   "r-columns one 128-lane slab of the leaf column "
                   "kernel carries (newest Pallas chooseleaf tables)",

@@ -151,12 +151,16 @@ class DispatchFuture:
 class _Request:
     __slots__ = ("key", "fn", "data", "aux", "stripes", "future",
                  "t_submit", "label", "cache_entries", "trace",
-                 "place", "fallback", "cost_tag")
+                 "place", "fallback", "cost_tag", "content")
 
     def __init__(self, key, fn, data, stripes, label=None,
                  cache_entries=None, aux=None, place=True,
-                 fallback=None, cost_tag=None):
+                 fallback=None, cost_tag=None, content=None):
         self.place = place
+        #: what ``data`` holds, as its submitter names it (opaque
+        #: here): a device call whose parts all name theirs gets them,
+        #: in order, with the padded rows (``_dispatch_batch``)
+        self.content = content
         #: (tenant, dmclock class) for the device-time ledger; None
         #: lands in the visible _untagged bucket at completion
         self.cost_tag = cost_tag
@@ -598,7 +602,7 @@ class DeviceDispatchEngine:
     def submit(self, key, fn, data, *, label=None,
                cache_entries=None, aux=None,
                place: bool = True, fallback=None,
-               cost_tag=None) -> DispatchFuture:
+               cost_tag=None, content=None) -> DispatchFuture:
         """``aux``: optional tuple of per-stripe side arrays (each with
         the SAME leading axis as ``data``) that coalesce alongside it —
         concatenated per component, edge-padded (last row repeated) to
@@ -629,10 +633,19 @@ class DeviceDispatchEngine:
         share and accounted under its tag in
         ``telemetry.TenantDeviceStats``.  Untagged requests land in
         the visible ``_untagged`` bucket — never dropped, so the
-        ledger's tenant sum conserves the engine's busy-seconds."""
+        ledger's tenant sum conserves the engine's busy-seconds.
+
+        ``content``: an opaque token for what ``data`` holds.  The
+        device call of a batch whose requests all carry one is
+        ``fn(batch, *aux, content=(tokens, pad))`` — the tokens in
+        batch order and the zero rows padded after them — so that a
+        kernel may reuse work it did for the same content (the CRUSH
+        column cache).  Requests sharing a key must agree on whether
+        they carry one."""
         return self._enqueue(self._make_request(
             key, fn, data, label=label, cache_entries=cache_entries,
-            aux=aux, place=place, fallback=fallback, cost_tag=cost_tag))
+            aux=aux, place=place, fallback=fallback, cost_tag=cost_tag,
+            content=content))
 
     def submit_waiting(self, key, fn, data, **kw) -> DispatchFuture:
         """``submit`` for a caller that will block on the result at
@@ -701,7 +714,8 @@ class DeviceDispatchEngine:
 
     def _make_request(self, key, fn, data, *, label=None,
                       cache_entries=None, aux=None, place: bool = True,
-                      fallback=None, cost_tag=None) -> _Request:
+                      fallback=None, cost_tag=None,
+                      content=None) -> _Request:
         # analysis: allow[blocking] -- caller-input normalization: submit() receives host arrays (numpy/bytes), not device values
         data = np.asarray(data)
         stripes = int(data.shape[0]) if data.ndim else 1
@@ -714,7 +728,8 @@ class DeviceDispatchEngine:
                         f"aux leading axis {a.shape} != stripes {stripes}")
         return _Request(key, fn, data, stripes, label=label,
                         cache_entries=cache_entries, aux=aux, place=place,
-                        fallback=fallback, cost_tag=cost_tag)
+                        fallback=fallback, cost_tag=cost_tag,
+                        content=content)
 
     def _enqueue(self, req: _Request) -> DispatchFuture:
         fn, data, aux, fallback = req.fn, req.data, req.aux, req.fallback
@@ -971,7 +986,12 @@ class DeviceDispatchEngine:
                 out = reqs[0].fallback(batch_arr, *aux_batch)
             else:
                 failpoint.hit("dispatch.launch", tag=channel)
-                out = reqs[0].fn(batch_arr, *aux_batch)  # async dispatch
+                kw = {}
+                if reqs[0].content is not None:
+                    parts = tuple(r.content for r in reqs)
+                    kw["content"] = ((parts, pad) if None not in parts
+                                     else None)
+                out = reqs[0].fn(batch_arr, *aux_batch, **kw)  # async
             profile["t_launch_end"] = time.monotonic()
             marks.end()
             # span bookkeeping + the cache probe sit between place and
@@ -1585,7 +1605,8 @@ def submit_flat_firstn(engine: DeviceDispatchEngine, x, ids, weights,
 
 def submit_do_rule(engine: DeviceDispatchEngine, mapper, ruleno: int,
                    xs, result_max: int, reweight, *,
-                   key=None, cost_tag=None) -> DispatchFuture:
+                   key=None, cost_tag=None,
+                   content=None) -> DispatchFuture:
     """Submit a general-rule bulk PG remap (BatchMapper.do_rule)
     through the engine.  Pool remaps for the SAME (map, rule, size,
     reweight) — e.g. several pools sharing one crush rule, or several
@@ -1598,13 +1619,16 @@ def submit_do_rule(engine: DeviceDispatchEngine, mapper, ruleno: int,
     ``do_rule`` signature); ``key`` defaults to the mapper identity +
     rule + shape + a reweight digest, so callers holding one mapper
     per crush-map identity get cross-request coalescing for free.
+    ``content`` (a crush.fastpath.Seeds of ``xs``) lets the batch's
+    device call reuse the straw2 winners an earlier batch of the same
+    seeds drew on the mapper's tables.
     """
     reweight = np.asarray(reweight, dtype=np.int64)
     if key is None:
         key = ("crush_rule", id(mapper), ruleno, result_max,
                hash(reweight.tobytes()))
 
-    def fn(batch, key=key):
+    def fn(batch, key=key, content=None):
         rw = reweight
         mesh = getattr(getattr(batch, "sharding", None), "mesh", None)
         if mesh is not None and getattr(mesh, "size", 1) > 1:
@@ -1620,7 +1644,10 @@ def submit_do_rule(engine: DeviceDispatchEngine, mapper, ruleno: int,
                 mesh, key,
                 lambda: jax.device_put(
                     rw, NamedSharding(mesh, PartitionSpec())))
-        return mapper.do_rule(ruleno, batch, result_max, rw)
+        if content is None:
+            return mapper.do_rule(ruleno, batch, result_max, rw)
+        return mapper.do_rule(ruleno, batch, result_max, rw,
+                              content=content)
 
     host_oracle = None
     cmap = getattr(mapper, "map", None)
@@ -1644,7 +1671,7 @@ def submit_do_rule(engine: DeviceDispatchEngine, mapper, ruleno: int,
 
     return engine.submit(key, fn, np.asarray(xs, dtype=np.uint32),
                          label="crush_rule", fallback=host_oracle,
-                         cost_tag=cost_tag)
+                         cost_tag=cost_tag, content=content)
 
 
 def submit_finish_ladder(engine: DeviceDispatchEngine, operands, *,

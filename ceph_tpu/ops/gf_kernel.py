@@ -358,7 +358,8 @@ def _row_sharding(x):
     return sh
 
 
-def build_sharded_rows_fn(fn, sh, n_replicated: int = 0):
+def build_sharded_rows_fn(fn, sh, n_replicated: int = 0,
+                          lane_planes: bool = False):
     """jit(shard_map(fn)) over a committed row sharding ``sh`` — the
     ONE construction site for the wrappers that let an opaque
     ``pallas_call`` (a custom call GSPMD cannot split) ride a
@@ -369,16 +370,39 @@ def build_sharded_rows_fn(fn, sh, n_replicated: int = 0):
     the crush_kernel mesh contract); the ``n_replicated`` trailing
     operands broadcast whole to every shard.  Callers cache the
     returned callable per (sharding, static-args) — a fresh wrapper
-    per flush would re-trace on the hot dispatch path."""
+    per flush would re-trace on the hot dispatch path.
+
+    ``lane_planes``: one operand more, last — a (flag, planes) pair,
+    the flag whole on every shard and the (R, lanes) planes split on
+    their lane axis like the rows (``lane_sharding``) — and ``fn``
+    returns (rows, planes) alike: the CRUSH program's winner planes
+    (crush.mapper_jax).  The outputs then carry ``sh`` and
+    ``lane_sharding(sh)`` themselves, so planes fed back meet the
+    program's own entry and compile nothing."""
     from jax.sharding import PartitionSpec
 
     rep_specs = tuple(PartitionSpec() for _ in range(n_replicated))
+    in_specs, out_specs, out_sh = (sh.spec,) + rep_specs, sh.spec, None
+    if lane_planes:
+        planes = lane_sharding(sh)
+        in_specs += ((PartitionSpec(), planes.spec),)
+        out_specs = (sh.spec, planes.spec)
+        out_sh = (sh, planes)
     # check_vma=False: pallas_call has no shard_map replication rule
     # (jax raises NotImplementedError otherwise); replication here is
     # by construction — every replicated operand is broadcast whole
-    return jax.jit(jax.shard_map(
-        fn, mesh=sh.mesh, in_specs=(sh.spec,) + rep_specs,
-        out_specs=sh.spec, check_vma=False))
+    mapped = jax.shard_map(fn, mesh=sh.mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
+    if out_sh is None:
+        return jax.jit(mapped)
+    return jax.jit(mapped, out_shardings=out_sh)
+
+
+def lane_sharding(sh):
+    """The sharding of (R, lanes) planes split on their lane axis as
+    the row sharding ``sh`` splits the rows."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    return NamedSharding(sh.mesh, PartitionSpec(None, sh.spec[0]))
 
 
 def shard_map_rows(fn, data, *replicated):

@@ -255,7 +255,7 @@ def _root_kernel(xs_ref, ids_ref, wz_ref, magic_ref, off_ref,
     gather was 68 ms a call at 1 Mi lanes).  Keeping them out of the
     kernel also dodged a real Mosaic miscompile: hash32_2 fed from the
     gather/sum winner pipeline produced wrong values for ~0.03% of
-    lanes (see r03 notes in fastpath._winners_cols)."""
+    lanes (see the notes in fastpath.FastMapper._run_pallas)."""
     r = pl.program_id(1)
     x = xs_ref[0, :]
     tabs = (rhlh_ref, ll_lo_ref, ll_hi_ref, rh128)
@@ -790,6 +790,13 @@ def _pad_lanes(n: int) -> int:
     return max(128, -(-n // 128) * 128)
 
 
+def padded_batch(n: int) -> int:
+    """The lanes a batch of n pads to, and every winner plane of the
+    column kernels has: a multiple of its block."""
+    B = min(BLOCK, _pad_lanes(n))
+    return -(-n // B) * B
+
+
 def _pad_block(xs, *more):
     """Pad 1-D xs (and the last axis of any extra arrays) to a multiple
     of the batch block; returns (xs, padded_n, B, *more).  Small batches
@@ -797,7 +804,7 @@ def _pad_block(xs, *more):
     bulk block's padding."""
     n = xs.shape[0]
     B = min(BLOCK, _pad_lanes(n))
-    pad = (-n) % B
+    pad = padded_batch(n) - n
     if pad:
         xs = jnp.concatenate([xs, jnp.zeros((pad,), dtype=xs.dtype)])
         more = tuple(

@@ -758,6 +758,15 @@ class MappingStats:
     ``crush_leaf_columns_per_slab``, the r-columns one 128-lane slab
     carries, and ``crush_leaf_lane_fill``, the widest host's items over
     the lanes its column draws.
+
+    The COLUMN counters say what CRUSH's column cache served
+    (``crush.mapper_jax.BatchMapper._fast_call``): a fast-path batch
+    whose seeds and tables match an earlier batch's takes that batch's
+    stage-1 straw2 winner planes instead of drawing them
+    (``crush_column_hits``); one that finds none draws and keeps them
+    (``crush_column_misses``).  A reweight, out, down or restore
+    epoch of an unchanged CRUSH map is a hit; a CRUSH edit or a
+    resized pool is a miss.
     """
 
     __slots__ = ("_lock", "epoch_updates", "epoch_skips",
@@ -769,7 +778,8 @@ class MappingStats:
                  "delta_device_diffs", "delta_host_diffs",
                  "delta_upload_bytes", "crush_table_builds",
                  "crush_table_upload_bytes", "crush_program_builds",
-                 "crush_leaf_columns_per_slab", "crush_leaf_lane_fill")
+                 "crush_leaf_columns_per_slab", "crush_leaf_lane_fill",
+                 "crush_column_hits", "crush_column_misses")
 
     def __init__(self):
         self._lock = lockdep.make_lock("MappingStats::lock")
@@ -802,6 +812,9 @@ class MappingStats:
         self.crush_program_builds = 0
         self.crush_leaf_columns_per_slab = 0     # gauges (see above)
         self.crush_leaf_lane_fill = 0.0
+        # what the column cache served (see class docstring)
+        self.crush_column_hits = 0
+        self.crush_column_misses = 0
 
     def clear(self) -> None:
         with self._lock:
@@ -825,6 +838,7 @@ class MappingStats:
             self.crush_program_builds = 0
             self.crush_leaf_columns_per_slab = 0
             self.crush_leaf_lane_fill = 0.0
+            self.crush_column_hits = self.crush_column_misses = 0
 
     def record_crush_table_build(self) -> None:
         """One host build of a crush map's bucket tables."""
@@ -843,6 +857,14 @@ class MappingStats:
         with self._lock:
             self.crush_leaf_columns_per_slab = int(columns_per_slab)
             self.crush_leaf_lane_fill = float(lane_fill)
+
+    def record_crush_columns(self, hit: bool) -> None:
+        """One fast-path batch that asked the column cache."""
+        with self._lock:
+            if hit:
+                self.crush_column_hits += 1
+            else:
+                self.crush_column_misses += 1
 
     def record_program_build(self) -> None:
         """A CRUSH or ladder program traced anew (called from inside
@@ -940,6 +962,8 @@ class MappingStats:
                 "crush_leaf_columns_per_slab":
                     self.crush_leaf_columns_per_slab,
                 "crush_leaf_lane_fill": round(self.crush_leaf_lane_fill, 6),
+                "crush_column_hits": self.crush_column_hits,
+                "crush_column_misses": self.crush_column_misses,
                 "host_tail_share": round(self._host_tail_share(), 6),
                 "phase_seconds": {
                     "device": self.phase_device.dump(),
@@ -994,6 +1018,8 @@ class MappingStats:
                 "crush_leaf_columns_per_slab":
                     self.crush_leaf_columns_per_slab,
                 "crush_leaf_lane_fill": round(self.crush_leaf_lane_fill, 6),
+                "crush_column_hits": self.crush_column_hits,
+                "crush_column_misses": self.crush_column_misses,
                 "host_tail_share": round(self._host_tail_share(), 6),
             }
 

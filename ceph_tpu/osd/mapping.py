@@ -25,7 +25,12 @@ Three layers:
   through the context's dispatch engine (ops.dispatch.submit_do_rule) when
   one is supplied: pools sharing a rule — and daemons sharing a context —
   coalesce into one device call, and the double-buffered pipeline overlaps
-  pool N+1's h2d with pool N's compute.
+  pool N+1's h2d with pool N's compute.  Each pool's seeds go with a
+  digest made once per seed array (crush.fastpath.Seeds): an epoch that
+  moves only reweights or the up set serves CRUSH's first-attempt straw2
+  winners from the planes the last epoch's batch of the same seeds drew
+  on the same tables (BatchMapper._fast_call), and the ``mapping crush``
+  span says ``columns`` = ``hit`` or ``miss``.
 
 * ``SharedPGMappingService`` — one instance per CephTpuContext
   (``ctx.mapping_service()``), the epoch-keyed cache every mapping consumer
@@ -422,6 +427,9 @@ class OSDMapMapping:
         self._fast_shapes: dict[int, object] = {}
         self._raw: dict[int, np.ndarray] = {}    # pool -> (pg_num, size) raw
         self._pps: dict[int, np.ndarray] = {}    # pool -> (pg_num,) pps seeds
+        #: pool -> its seeds' digest for CRUSH's column cache
+        #: (crush.fastpath.Seeds), made once per seed array
+        self._seeds: dict[int, object] = {}
         self._sigs: dict[int, tuple] = {}        # pool -> placement signature
         self._fused: dict[int, np.ndarray] = {}  # pool -> packed ladder rows
         self._fused_w: dict[int, int] = {}       # pool -> packed width
@@ -495,7 +503,7 @@ class OSDMapMapping:
             self._reach = {k: v for k, v in self._reach.items()
                            if k[0] == csig}
         # pps seeds, weights and the per-pool CRUSH remaps
-        with tracing.span("mapping crush", daemon="mapping"):
+        with tracing.span("mapping crush", daemon="mapping") as crush_sp:
             # zero-padded to the OSD axis quantum: weight 0 is out,
             # is_out's verdict for an id past the vector already, and
             # a max_osd that grows inside the quantum keeps the shape
@@ -504,6 +512,8 @@ class OSDMapMapping:
             weights[:k] = m.osd_weight[:k]
             raw: dict[int, np.ndarray] = {}
             pps_t: dict[int, np.ndarray] = {}
+            seeds_t: dict[int, object] = {}
+            swept: set[int] = set()
             recomputed: list[int] = []
             reused: list[int] = []
             futures: list[tuple[int, object]] = []
@@ -515,6 +525,8 @@ class OSDMapMapping:
                     raw[pool_id] = prev.raw[pool_id]
                     if pool_id in prev.pps:
                         pps_t[pool_id] = prev.pps[pool_id]
+                    if pool_id in self._seeds:
+                        seeds_t[pool_id] = self._seeds[pool_id]
                     reused.append(pool_id)
                     continue
                 recomputed.append(pool_id)
@@ -549,6 +561,18 @@ class OSDMapMapping:
                     # epochs of unchanged crush content
                     bm = self.mapper_for(m.crush, csig)
                 self._crush_tables(bm, pool.crush_rule, engine)
+                # the seeds' digest once per array: the same one is
+                # reused while pg_num and pgp_num hold
+                seeds = self._seeds.get(pool_id)
+                if seeds is None or seeds.of is not pps:
+                    from ceph_tpu.crush.fastpath import Seeds
+                    seeds = Seeds(pps)
+                seeds.columns = None
+                seeds_t[pool_id] = seeds
+                ft = bm.fast_tables(pool.crush_rule)
+                if ft is not None and id(ft) not in swept:
+                    swept.add(id(ft))
+                    ft.sweep_columns()
                 if engine is not None:
                     from ceph_tpu.ops.dispatch import BACKGROUND_BEST_EFFORT
                     from ceph_tpu.ops.dispatch import submit_do_rule
@@ -558,12 +582,20 @@ class OSDMapMapping:
                     futures.append((pool_id, submit_do_rule(
                         engine, bm, pool.crush_rule, pps, pool.size,
                         weights,
-                        cost_tag=("system", BACKGROUND_BEST_EFFORT))))
+                        cost_tag=("system", BACKGROUND_BEST_EFFORT),
+                        content=seeds)))
                 else:
                     raw[pool_id] = np.asarray(bm.do_rule(
-                        pool.crush_rule, pps, pool.size, weights))
+                        pool.crush_rule, pps, pool.size, weights,
+                        content=((seeds,), 0)))
             for pool_id, fut in futures:
                 raw[pool_id] = _engine_result(engine, fut)
+            # what CRUSH's column cache served this epoch's batches
+            served = {seeds_t[p].columns for p in recomputed
+                      if p in seeds_t} - {None}
+            if served:
+                tracing.set_attrs(crush_sp, columns=(
+                    "miss" if "miss" in served else "hit"))
         fused: dict[int, np.ndarray] = {}
         fused_w: dict[int, int] = {}
         tail_sigs: dict[int, tuple] = {}
@@ -579,6 +611,7 @@ class OSDMapMapping:
                 fused, fused_w, tail_sigs = {}, {}, {}
         self.osdmap = m
         self._raw, self._pps, self._sigs = raw, pps_t, sigs
+        self._seeds = seeds_t
         self._fused, self._fused_w = fused, fused_w
         self._tail_sigs = tail_sigs
         self.epoch = m.epoch

@@ -451,13 +451,18 @@ def test_the_mesh_route_takes_the_tables_replicated_and_one_program():
     # the names a device trace shows (BENCHMARK.json's configurations
     # list them under programs.crush): jit_run on the mesh route ...
     rw = np.zeros(padded_osds(c.max_osd), dtype=np.int64)
+    planes = next(v for k, v in mapper_jax._COLUMN_FILLERS.items()
+                  if k[0] == key)
     text = mapper_jax._FAST_PROGRAMS[key].lower(
-        placed, rw, ft.on(mesh)).as_text()
+        placed, rw, ft.on(mesh), (np.bool_(False), planes)).as_text()
     assert "module @jit_run " in text
     # ... and jit__unknown on one device, for CRUSH and for the ladder
     one = fastpath.tables_of(fastpath.detect(*crush_of(c)))
+    count, R, lanes = fastpath.mapper_for(one.shape).column_shape(
+        len(xs), SIZE)
+    planes = (np.zeros((R, lanes), dtype=np.int32),) * count
     text = mapper_jax._fast_program(one.shape, SIZE).lower(
-        xs, rw, one.on()).as_text()
+        xs, rw, one.on(), (np.bool_(False), planes)).as_text()
     assert "module @jit__unknown " in text
     lowered = placement_kernel._ladder_jit(False).lower(
         *(jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype)

@@ -198,6 +198,40 @@ def test_leaf_columns_under_the_four_chip_shard_map(mesh4, columns):
     assert len(out_sh.device_set) == 4
 
 
+def test_the_column_program_under_the_four_chip_shard_map(mesh4):
+    """crush10k.weight_churn_x4's CRUSH program whole, as the column
+    cache runs it: each chip's 16,384 lanes, the first attempt's winner
+    planes an operand and an output split on their lane axis like the
+    rows, both branches of the cache's cond in one program, still
+    named ``jit_run`` (BENCHMARK.json's programs.crush)."""
+    from ceph_tpu.crush import fastpath, mapper_jax
+    from ceph_tpu.crush.types import padded_osds
+    from ceph_tpu.ops.gf_kernel import lane_sharding
+    crush_map, rid, _rw = build_skewed_two_level_map(250, 40)
+    fr = detect(crush_map, rid)
+    shape = shape_of(fr, pallas=True)
+    tables = build_tables(fr, shape)
+    n = 4 * 16384
+    rows = NamedSharding(mesh4, PartitionSpec(("dp", "ec")))
+    rep = NamedSharding(mesh4, PartitionSpec())
+    planes = lane_sharding(rows)
+    count, R, lanes = fastpath.mapper_for(shape).column_shape(n // 4, 3)
+    assert (count, R, lanes) == (2, 9, 16384)       # single stage, R0
+    compiled = mapper_jax._fast_program(shape, 3, rows).lower(
+        _spec(rows, (n,), jnp.uint32),
+        _spec(rep, (padded_osds(10000),), jnp.int64),
+        tuple(_spec(rep, t.shape, t.dtype) for t in tables),
+        (_spec(rep, (), jnp.bool_),
+         tuple(_spec(planes, (R, 4 * lanes), jnp.int32)
+               for _ in range(count)))).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_run,")
+    assert "tpu_custom_call" in text and " conditional(" in text
+    out_rows, *out_planes = jax.tree_util.tree_leaves(
+        compiled.output_shardings)
+    assert out_planes == [planes] * count and out_rows == rows
+
+
 @pytest.mark.parametrize("widest,lanes", [(16, 32), (30, 32), (100, 128),
                                           (130, 256)])
 def test_leaf_columns_of_each_group_width(one_chip, widest, lanes):
@@ -226,7 +260,7 @@ def test_consume_columns(one_chip, n, R):
     assert "tpu_custom_call" in text
 
 
-# what FastMapper._winners_cols runs in XLA between the Pallas calls:
+# what FastMapper._run_pallas runs in XLA between the Pallas calls:
 # stage 1's and stage 2's planes of the 1 Mi cell, a chip's share of four
 @pytest.mark.parametrize("planes,n", [(4, 1 << 20), (9, (1 << 20) // 16),
                                       (9, N_PGS // 4)])
